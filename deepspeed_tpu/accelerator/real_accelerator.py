@@ -34,12 +34,8 @@ def get_accelerator() -> DeepSpeedAccelerator:
                 f"DS_ACCELERATOR={name!r} unsupported; one of {sorted(_BY_NAME)}")
         return set_accelerator(_BY_NAME[name]())
     # auto-detect from the live jax backend
-    try:
-        import jax
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    cls = TPU_Accelerator if platform == "tpu" else CPU_Accelerator
+    from ..utils.device import on_tpu
+    cls = TPU_Accelerator if on_tpu() else CPU_Accelerator
     return set_accelerator(cls())
 
 

@@ -16,8 +16,8 @@ Rule catalog (docs/ANALYSIS.md has the long form):
   hot roots (`ServeLoop.step`, the engine's prefill/decode surface) or
   inside any `@jax.jit`-decorated function.  This is the bug class that
   cost ~70x in `serve_closed_c8` (PR 2): one accidental materialization
-  in the decode loop ships [max_seqs, vocab] logits through the relay
-  every token.
+  in the decode loop ships [max_seqs, vocab] logits to the host every
+  token.
 - **DST002 traced-control-flow**: Python `if`/`while`/`assert` on a
   value derived from a traced argument inside a jitted function —
   either a trace error waiting for the first non-constant input, or a

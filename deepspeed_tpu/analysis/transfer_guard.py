@@ -18,7 +18,7 @@ recompiles its program, and the fresh trace transfers new constants —
 implicit host->device transfers the guard catches.  The sanitizer is
 thereby also a dynamic recompile detector (DST004's runtime analog).
 
-Platform caveat (measured on this container, jax 0.4.37): the CPU
+Platform caveat (measured): the CPU
 backend shares memory with the host, so device->host reads are
 zero-copy and never trip the guard — d2h enforcement only has teeth on
 a real accelerator.  Host->device enforcement fires everywhere,

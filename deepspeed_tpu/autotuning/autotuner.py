@@ -31,7 +31,7 @@ import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from ..utils.logging import log_dist, logger
+from ..utils.logging import logger
 
 __all__ = ["Autotuner", "Experiment", "estimate_model_states_mem"]
 
@@ -181,8 +181,12 @@ class Autotuner:
         return None
 
     def _prune(self, exp: Experiment) -> bool:
-        """Memory-arithmetic pruning before paying a compile."""
-        if self.mem_budget_bytes is None:
+        """Memory-arithmetic pruning before paying a compile.  Under
+        process isolation the trial child does it (scheduler._child_main):
+        a chip belongs to one process, so this parent must never touch
+        JAX — counting parameters or devices here would take the chip the
+        children need."""
+        if self.mem_budget_bytes is None or self.isolation == "process":
             return False
         n = self._num_params()
         if n is None:
@@ -225,9 +229,11 @@ class Autotuner:
                         else self.warmup_steps))
         out = rm.run(self._trial_config(exp.overrides),
                      model_spec=spec,
-                     train_script=self.train_script)
+                     train_script=self.train_script,
+                     mem_budget_bytes=self.mem_budget_bytes)
         if "error" in out:
             exp.error = out["error"]
+            exp.pruned = exp.error.startswith("pruned:")
             logger.info(f"trial {exp.exp_id} failed: "
                         f"{exp.error.splitlines()[0]}")
         else:
@@ -280,11 +286,12 @@ class Autotuner:
             overrides = candidates[i]
             exp = Experiment(exp_id=i, overrides=overrides)
             self.experiments.append(exp)
-            if self._prune(exp):
+            if not self._prune(exp):
+                self.run_experiment(exp)
+            if exp.pruned:
                 history.append((i, None))
                 continue
             trials += 1
-            self.run_experiment(exp)
             # feed the strategy the OBJECTIVE it should optimize — for
             # latency that is -time/step, not samples/s, else the surrogate
             # routes the trial budget toward throughput configs
@@ -296,9 +303,11 @@ class Autotuner:
                 obj = exp.metric_val
             history.append((i, obj))
             if exp.metric_val is not None:
-                log_dist(f"trial {i} {overrides}: "
-                         f"{exp.metric_val:.1f} samples/s "
-                         f"({exp.time_per_step*1e3:.0f} ms/step)", ranks=[0])
+                # logger, not log_dist: the rank lookup initialises the
+                # backend, and this process must stay off the chip
+                logger.info(f"trial {i} {overrides}: "
+                            f"{exp.metric_val:.1f} samples/s "
+                            f"({exp.time_per_step*1e3:.0f} ms/step)")
 
         ok = [e for e in self.experiments if e.metric_val is not None]
         if not ok:

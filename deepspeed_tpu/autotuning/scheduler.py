@@ -63,8 +63,12 @@ class ResourceManager:
         self.env = env
 
     def run(self, config: Dict, model_spec: Optional[ModelSpec] = None,
-            train_script: Optional[str] = None) -> Dict[str, Any]:
-        """Returns {"time_per_step", "samples_per_s"} or {"error": ...}."""
+            train_script: Optional[str] = None,
+            mem_budget_bytes: Optional[int] = None) -> Dict[str, Any]:
+        """Returns {"time_per_step", "samples_per_s"} or {"error": ...}.
+        `mem_budget_bytes`: the built-in probe child refuses (error
+        "pruned: ...") a config whose estimated model states exceed it,
+        before it builds an engine."""
         if (model_spec is None) == (train_script is None):
             raise ValueError("provide exactly one of model_spec / "
                              "train_script")
@@ -81,7 +85,8 @@ class ResourceManager:
             else:
                 spec_path = os.path.join(td, "model_spec.json")
                 with open(spec_path, "w") as f:
-                    json.dump(model_spec.as_dict(), f)
+                    json.dump(dict(model_spec.as_dict(),
+                                   mem_budget_bytes=mem_budget_bytes), f)
                 cmd = [sys.executable, "-u", "-m",
                        "deepspeed_tpu.autotuning.scheduler",
                        "--config", cfg_path, "--model-spec", spec_path]
@@ -117,14 +122,33 @@ def _child_main(argv: Optional[List[str]] = None) -> int:
     with open(args.model_spec) as f:
         spec = json.load(f)
     try:
+        import jax
         import numpy as np
         import deepspeed_tpu as dstpu
         from ..models import Transformer, get_model_config
+        from ..utils.device import place_compile_cache
+        from .autotuner import estimate_model_states_mem
 
+        place_compile_cache()
         cfg = get_model_config(spec["family"], spec["size"], **spec["kw"]) \
             if spec.get("size") else get_model_config(spec["family"],
                                                       **spec["kw"])
-        engine = dstpu.initialize(model=Transformer(cfg), config=config)
+        model = Transformer(cfg)
+        budget = spec.get("mem_budget_bytes")
+        if budget is not None:
+            # memory-arithmetic pruning, here because this process may
+            # touch the device and the tuner parent may not
+            shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+            need = estimate_model_states_mem(
+                sum(int(x.size) for x in jax.tree.leaves(shapes)),
+                config.get("zero_optimization", {}).get("stage", 0),
+                jax.device_count())
+            if need > budget:
+                print(json.dumps({"error": (
+                    f"pruned: est model states {need/1e9:.2f} GB > "
+                    f"budget {budget/1e9:.2f} GB")}))
+                return 0
+        engine = dstpu.initialize(model=model, config=config)
         S = spec["seq_len"]
         rng = np.random.RandomState(0)
         batch = {"input_ids": rng.randint(

@@ -179,8 +179,7 @@ def build_trajectory(root: str) -> Dict[str, Any]:
             "round": doc["n"],
             "source": os.path.basename(path),
             "date": "",
-            # bench.py rounds predate backend stamping; the tpu_claim
-            # re-exec means they ran whatever the container offered
+            # bench.py rounds carry no backend stamp of their own
             "backend": str(doc.get("backend", "unknown")),
             "value": float(parsed["value"]),
             "note": "",

@@ -20,7 +20,7 @@ from typing import Callable, Dict, List
 import numpy as np
 
 import jax
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
@@ -436,6 +436,8 @@ def main(argv=None) -> int:
             os.environ["XLA_FLAGS"] = (
                 f"--xla_force_host_platform_device_count={args.devices} "
                 + os.environ.get("XLA_FLAGS", ""))
+    from ..utils.device import place_compile_cache
+    place_compile_cache()
     if args.moe:
         rows = run_moe_sweep(trials=args.trials)
         if args.json:

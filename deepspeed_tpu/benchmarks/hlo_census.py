@@ -35,8 +35,10 @@ _DTYPE_BYTES = {"s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
 # an op definition: "%all-reduce.3 = <result type> all-reduce(" — async
 # starts carry the -start suffix; `-done` lines reference the start's
 # buffer and must not double-count
+# keyed on the OPCODE, not the instruction name: the TPU compiler names an
+# instruction after the jax primitive (%all_to_all.6 = ... all-to-all(...))
 _DEF_RE = re.compile(
-    r"%(" + "|".join(COLLECTIVE_OPS) + r")(-start)?[.\d]* = (.*?) \1", )
+    r"%[\w.\-]+ = (.*?) (" + "|".join(COLLECTIVE_OPS) + r")(-start)?\(")
 
 
 def _element_bytes(result_ty: str) -> List[int]:
@@ -65,7 +67,7 @@ def collective_census(txt: str) -> Dict[str, int]:
     """op name -> definition count (async start/done pairs count once)."""
     out = {op: 0 for op in COLLECTIVE_OPS}
     for m in _DEF_RE.finditer(txt):
-        out[m.group(1)] += 1
+        out[m.group(2)] += 1
     return out
 
 
@@ -81,7 +83,7 @@ def collective_wire_bytes(txt: str, world: int) -> float:
     symmetric ops."""
     total = 0.0
     for m in _DEF_RE.finditer(txt):
-        op, is_start, result_ty = m.group(1), m.group(2), m.group(3)
+        result_ty, op, is_start = m.group(1), m.group(2), m.group(3)
         size = _type_bytes(result_ty)
         if is_start and result_ty.lstrip().startswith("("):
             parts = sorted(_element_bytes(result_ty))
@@ -109,7 +111,8 @@ def collective_wire_bytes(txt: str, world: int) -> float:
 def async_overlap_report(txt: str) -> List[Tuple[str, int, bool]]:
     """Evidence of compute-collective overlap in a SCHEDULED HLO module:
     for every async collective pair, whether real compute (fusion /
-    dot / convolution / while) is scheduled between the -start and its
+    dot / convolution / while, or a custom-call — a Pallas kernel is
+    one) is scheduled between the -start and its
     -done.  Returns [(op_name, gap_ops, has_compute_between), ...] —
     empty when the backend emitted no async pairs (e.g. the CPU
     backend), which callers should treat as "no evidence", not failure.
@@ -122,7 +125,10 @@ def async_overlap_report(txt: str) -> List[Tuple[str, int, bool]]:
     done_re = re.compile(
         r"(" + "|".join(COLLECTIVE_OPS) + r")-done[.\d]* = .*%("
         r"(?:" + "|".join(COLLECTIVE_OPS) + r")-start[.\d]*)")
-    compute_re = re.compile(r"%(fusion|dot|convolution|while)[.\d]* =")
+    # by opcode, not by instruction name: a Pallas kernel under shard_map
+    # is named after the region (%shard_map.26 = ... custom-call(...))
+    compute_re = re.compile(
+        r" (fusion|dot|convolution|while|custom-call)\(")
     for i, line in enumerate(lines):
         sm = start_re.search(line)
         if sm:

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.quantization import (dequantize_blockwise, quantize_blockwise)
-from ..utils.jax_compat import axis_size
+from jax.lax import axis_size
 from .comm import comms_logger
 
 __all__ = [

@@ -40,7 +40,7 @@ cache math of models.transformer._layer_decode (reused directly).
 from __future__ import annotations
 
 import jax
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P

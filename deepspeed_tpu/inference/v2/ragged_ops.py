@@ -202,7 +202,7 @@ def _use_paged_kernel(cfg: TransformerConfig, D: int, bs: int,
     odd counts, GQA and MHA — all compile under Mosaic and match the dense
     reference to bf16 tolerance.  Small-budget shapes are additionally
     AOT-compile-asserted against the real TPU compiler by
-    benchmarks/tpu_hlo_check.check_paged_full_range."""
+    tests/test_tpu_compile.py."""
     supported = (_kernel_capable(cfg, D, bs, n_tp)
                  and cfg.sliding_window is None)
     return _gate_fused(
@@ -223,8 +223,8 @@ def _kernel_capable(cfg: TransformerConfig, D: int, bs: int,
     the serving programs wrap the kernels in shard_map over tp
     (_shard_mapped_tp) and the kernels run per-shard — callers substitute
     n_tp=1 here in that case."""
-    from ...ops.attention import _on_tpu
-    return (_on_tpu() and n_tp == 1 and D % 64 == 0 and bs % 8 == 0
+    from ...utils.device import on_tpu
+    return (on_tpu() and n_tp == 1 and D % 64 == 0 and bs % 8 == 0
             and cfg.pos_emb != "alibi"
             and cfg.sliding_window_layers is None)
 
@@ -237,7 +237,7 @@ def _shard_mapped_tp(fn, mesh, n_in_specs_headed, layered=False):
     tp > 1 — a pallas_call does not auto-partition under GSPMD.
     `layered`: the arena keeps its leading [L] layer dim (the layer index
     is threaded to the kernel as a trailing replicated operand)."""
-    from ...utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ...parallel.mesh import AXIS_TP
@@ -247,8 +247,10 @@ def _shard_mapped_tp(fn, mesh, n_in_specs_headed, layered=False):
     else:
         arena_spec = P(None, None, AXIS_TP, None)  # [nb, bs, NKV, D]
     in_specs = (q_spec, arena_spec, arena_spec) + (P(),) * n_in_specs_headed
-    return shard_map(fn, mesh=mesh, axis_names={AXIS_TP},
-                     in_specs=in_specs, out_specs=q_spec, check_vma=False)
+    # manual over EVERY mesh axis (the default), not just tp: Mosaic
+    # refuses to lower a kernel inside a partially-manual region
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
+                     check_vma=False)
 
 
 def _gate_fused(cfg: TransformerConfig, supported: bool,

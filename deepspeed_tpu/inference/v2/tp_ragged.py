@@ -56,7 +56,7 @@ from jax.sharding import PartitionSpec as P
 from ...models.transformer import _norm, _rope
 from ...ops.tp_matmul import ag_matmul, matmul_rs, tile_matmul
 from ...parallel.mesh import AXIS_TP
-from ...utils.jax_compat import shard_map
+from jax import shard_map
 
 PyTree = Any
 
@@ -340,7 +340,7 @@ class TPServingPrograms:
                 block_tables, active)
             return logits, {"k": nk, "v": nv}
 
-        sm = shard_map(local, mesh=self.mesh, axis_names={AXIS_TP},
+        sm = shard_map(local, mesh=self.mesh,
                        in_specs=(self._pspecs, self._aspec) + (P(),) * 4,
                        out_specs=(P(), self._aspec), check_vma=False)
         return sm(params, arena, tokens, seq_lens, block_tables, active)
@@ -375,7 +375,7 @@ class TPServingPrograms:
         if top_k_vec is not None:
             args.append(top_k_vec)
             specs.append(P())
-        sm = shard_map(local, mesh=self.mesh, axis_names={AXIS_TP},
+        sm = shard_map(local, mesh=self.mesh,
                        in_specs=tuple(specs),
                        out_specs=(P(), self._aspec), check_vma=False)
         return sm(*args)
@@ -474,7 +474,7 @@ class TPServingPrograms:
         if top_k_vec is not None:
             args.append(top_k_vec)
             specs.append(P())
-        sm = shard_map(local, mesh=self.mesh, axis_names={AXIS_TP},
+        sm = shard_map(local, mesh=self.mesh,
                        in_specs=tuple(specs),
                        out_specs=(P(), P(), self._aspec), check_vma=False)
         return sm(*args)
@@ -567,7 +567,7 @@ class TPServingPrograms:
                 block_tables, active, total_lens)
             return logits, {"k": nk, "v": nv}
 
-        sm = shard_map(local, mesh=self.mesh, axis_names={AXIS_TP},
+        sm = shard_map(local, mesh=self.mesh,
                        in_specs=(self._pspecs, self._aspec) + (P(),) * 6,
                        out_specs=(P(), self._aspec), check_vma=False)
         return sm(params, arena, tokens, pos0s, n_valids, block_tables,

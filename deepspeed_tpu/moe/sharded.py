@@ -246,7 +246,7 @@ def moe_dispatch_a2a(expert_in: jax.Array, axis_name: str = AXIS_EP,
     buffer for EVERY global expert, owner-major expert order) ->
     [E/ep, ep*C, H] (every rank's buffers for this rank's LOCAL experts).
     Must run inside a shard_map region manual over `axis_name`."""
-    from ..utils.jax_compat import axis_size
+    from jax.lax import axis_size
     ep = axis_size(axis_name)
     E, C, H = expert_in.shape
     if E % ep:
@@ -263,7 +263,7 @@ def moe_combine_a2a(expert_out: jax.Array, axis_name: str = AXIS_EP,
     """Expert->token hop, inverse of `moe_dispatch_a2a`:
     [E/ep, ep*C, H] -> [E, C, H] (this rank's tokens' outputs from every
     global expert, owner-major order — ready for the local combine)."""
-    from ..utils.jax_compat import axis_size
+    from jax.lax import axis_size
     ep = axis_size(axis_name)
     E_loc, PC, H = expert_out.shape
     if PC % ep:
@@ -337,7 +337,7 @@ def _moe_layer_a2a(
     count, so the per-expert slot total matches the einsum form's global
     capacity exactly when T divides evenly."""
     from ..parallel.context import require_topology, shard_map_mesh
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
 
     topo = require_topology()
     ep = topo.size(ep_axis)
@@ -381,13 +381,11 @@ def _moe_layer_a2a(
         # aux loss averages over ranks (each rank's me/ce are local means)
         return out, jax.lax.pmean(l_aux, ep_axis)
 
-    # NOTE: full-manual (axis_names=None), not partial-manual over just
-    # ep: collectives inside a partial-manual region hit the known jaxlib
-    # rot on this image (spmd_partitioner IsManualSubgroup check abort).
-    # Non-ep axes therefore see replicated tokens/weights inside the
-    # region, which is correct (dp replicas compute identical MoE output).
+    # full-manual over every mesh axis, not partial-manual over just ep:
+    # non-ep axes see replicated tokens/weights inside the region, which
+    # is correct (dp replicas compute identical MoE output).
     out, l_aux = shard_map(
-        local, mesh=shard_map_mesh(topo), axis_names=None,
+        local, mesh=shard_map_mesh(topo),
         in_specs=(PartitionSpec(), wspec, PartitionSpec(AXIS_EP, None),
                   PartitionSpec()),
         out_specs=(PartitionSpec(AXIS_EP, None), PartitionSpec()),

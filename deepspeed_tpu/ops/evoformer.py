@@ -70,8 +70,8 @@ def _use_evo_kernel(impl: str, L: int, D: int) -> bool:
     capable = ((L % 128 == 0 or (L <= 128 and L % 16 == 0))
                and D % 8 == 0)
     try:
-        from .attention import _on_tpu
-        capable = capable and _on_tpu()
+        from ..utils.device import on_tpu
+        capable = capable and on_tpu()
     except Exception:
         capable = False
     if impl == "jnp":

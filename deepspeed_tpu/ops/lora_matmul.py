@@ -162,8 +162,8 @@ def lora_delta(x, lora_a, lora_b, adapter_ids, *, scaling: float = 1.0,
             f"[slots,K,r] / [slots,r,N] stack over one slot axis)")
     ids = jnp.asarray(adapter_ids, jnp.int32)
     if impl != "jnp":
-        from .attention import _on_tpu
-        capable = ((_on_tpu() or interpret)
+        from ..utils.device import on_tpu
+        capable = ((on_tpu() or interpret)
                    and lora_delta_supported(S, K, N, A))
         if impl == "pallas" and not capable:
             raise ValueError(

@@ -2,8 +2,8 @@
 
 Plays the role of the reference's op_builder JIT-build machinery
 (op_builder/builder.py:116 `OpBuilder.load`->`jit_load`:540): the shared
-library is compiled with g++ on first use and cached beside the source;
-rebuilds happen when the source is newer than the .so.
+library is compiled with g++ on first use into `<checkout>/.cache/native/`,
+keyed on a hash of the source, so a changed source builds a new library.
 
 Python surface:
 - `adam_step/adagrad_step/lion_step` over numpy fp32 arrays (offloaded
@@ -14,6 +14,7 @@ Python surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,29 +26,35 @@ __all__ = ["lib", "adam_step", "adagrad_step", "lion_step",
            "bf16_to_fp32", "fp32_to_bf16", "AsyncIOHandle", "build"]
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "csrc", "host_ops.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "..", "csrc", "libdstpu_host.so")
+_FLAGS = ["-O3", "-march=native", "-shared", "-fPIC", "-std=c++17", "-pthread"]
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _so_path() -> str:
+    """`<checkout>/.cache/native/libdstpu_host-<hash>.so`: a fixed place
+    inside the checkout that git ignores, keyed on the source and the
+    flags — never on mtimes, which a copy of the tree makes arbitrary."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(_FLAGS).encode())
+    checkout = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                            "..", ".."))
+    return os.path.join(checkout, ".cache", "native",
+                        f"libdstpu_host-{digest.hexdigest()[:16]}.so")
+
+
 def build(force: bool = False) -> str:
-    """Compile the native library (g++ -O3 -march=native)."""
-    src = os.path.abspath(_SRC)
-    so = os.path.abspath(_SO)
-    if force or not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
-        cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-               "-pthread", src, "-o", so]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True)
-        except FileNotFoundError:
-            # no compiler, but a previously-built .so exists: use it rather
-            # than failing — mtime skew after a fresh checkout is common and
-            # the shipped library is still ABI-compatible.  A real compile
-            # *error* (CalledProcessError) is never swallowed: falling back
-            # to a stale .so after a source change would bind new argtypes
-            # against an old ABI.
-            if not os.path.exists(so) or force:
-                raise
+    """Compile the native library from csrc/host_ops.cpp (g++) unless the
+    library for exactly this source is already there.  No compiler is an
+    error for whoever needs the library (offload, aio) — never a reason to
+    load some other binary."""
+    so = _so_path()
+    if force or not os.path.exists(so):
+        os.makedirs(os.path.dirname(so), exist_ok=True)
+        tmp = f"{so}.{os.getpid()}.tmp"     # concurrent builders race safely
+        subprocess.run(["g++", *_FLAGS, os.path.abspath(_SRC), "-o", tmp],
+                       check=True, capture_output=True)
+        os.replace(tmp, so)
     return so
 
 
@@ -55,17 +62,7 @@ def _load() -> ctypes.CDLL:
     global _lib
     with _lock:
         if _lib is None:
-            so = build()
-            try:
-                L = ctypes.CDLL(so)
-            except OSError:
-                # the shipped .so can be linked against a newer runtime
-                # than this host carries (e.g. GLIBCXX symbol versions);
-                # a from-source rebuild with the local toolchain fixes
-                # that — only an environment with neither a loadable .so
-                # nor a compiler fails
-                so = build(force=True)
-                L = ctypes.CDLL(so)
+            L = ctypes.CDLL(build())
             i64, f32 = ctypes.c_int64, ctypes.c_float
             pf = ctypes.POINTER(ctypes.c_float)
             pu16 = ctypes.POINTER(ctypes.c_uint16)

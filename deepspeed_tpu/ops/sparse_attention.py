@@ -296,8 +296,8 @@ def _use_sparse_kernel(impl: str, block: int, D: int) -> bool:
     "jnp" disables."""
     capable = block % 8 == 0 and D % 64 == 0
     try:
-        from .attention import _on_tpu
-        capable = capable and _on_tpu()
+        from ..utils.device import on_tpu
+        capable = capable and on_tpu()
     except Exception:
         capable = False
     if impl == "jnp":

@@ -141,8 +141,8 @@ def tile_matmul(x, w, *, impl: str = "auto"):
     M, K = x.shape
     N = w.shape[1]
     if impl != "jnp":
-        from .attention import _on_tpu
-        capable = _on_tpu() and tile_matmul_supported(M, K, N)
+        from ..utils.device import on_tpu
+        capable = on_tpu() and tile_matmul_supported(M, K, N)
         if impl == "pallas" and not capable:
             raise ValueError(
                 f"impl='pallas' requested but the tile matmul cannot run "

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 from .context import require_topology, shard_map_mesh
 from .mesh import AXIS_SP
 

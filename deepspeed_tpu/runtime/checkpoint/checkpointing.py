@@ -180,6 +180,9 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None):
         # placement — re-sharding a checkpoint written under a different
         # topology happens here (universal-checkpoint elastic resume).
         flat_names = _flatten_with_names(tree, prefix)
+        missing = [n for n in flat_names if n not in data]
+        if missing:      # before any leaf of the live state is given up
+            raise KeyError(f"checkpoint {ckpt_dir} lacks {missing[:5]}")
         restored = {}
         for name, leaf in flat_names.items():
             arr = data[name]
@@ -188,8 +191,14 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None):
                 # in host RAM, no device placement
                 restored[name] = np.asarray(arr, dtype=leaf.dtype)
             else:
-                restored[name] = jax.device_put(
-                    jnp.asarray(arr, dtype=leaf.dtype), leaf.sharding)
+                # cast on the host and place each shard straight from
+                # there (never the whole leaf on one device first); the
+                # leaf being replaced is freed before its replacement
+                # lands — a 1B-class state does not fit one chip twice
+                host = np.asarray(arr).astype(leaf.dtype)
+                sharding = leaf.sharding
+                leaf.delete()
+                restored[name] = jax.device_put(host, sharding)
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         names = list(flat_names.keys())
         return jax.tree_util.tree_unflatten(treedef, [restored[n] for n in names])
@@ -204,13 +213,13 @@ def load_checkpoint(engine, load_dir: str, tag: Optional[str] = None):
 
     from ..engine import TrainState
     engine.state = TrainState(
-        step=jnp.asarray(meta["step"], jnp.int32),
+        step=engine._scalar(meta["step"], jnp.int32),
         params=new_params,
         master=new_master,
         opt_state=new_opt,
-        loss_scale=jnp.asarray(meta["loss_scale"], jnp.float32),
-        good_steps=jnp.asarray(meta["good_steps"], jnp.int32),
-        skipped_steps=jnp.asarray(meta["skipped_steps"], jnp.int32),
+        loss_scale=engine._scalar(meta["loss_scale"], jnp.float32),
+        good_steps=engine._scalar(meta["good_steps"], jnp.int32),
+        skipped_steps=engine._scalar(meta["skipped_steps"], jnp.int32),
     )
     engine.global_steps = meta["step"]
     log_dist(f"loaded checkpoint {ckpt_dir}", ranks=[0])

@@ -24,7 +24,8 @@ from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
-from ..utils.jax_compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

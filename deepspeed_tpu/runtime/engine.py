@@ -46,6 +46,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..config.config import ConfigError, DeepSpeedTPUConfig
 from ..parallel.mesh import MeshTopology, make_mesh
+from ..utils.device import on_tpu
 from ..utils.logging import log_dist, logger
 from ..utils import tree as tu
 from . import lr_schedules, optimizers
@@ -302,9 +303,28 @@ class TrainEngine:
     # state construction
     # ------------------------------------------------------------------
     def _named(self, spec_tree: PyTree) -> PyTree:
+        """Specs -> NamedShardings, each spec in the form the compiled
+        step hands its outputs back in (size-1 mesh axes dropped, trailing
+        Nones trimmed).  Sharding equality is textual: state placed under
+        P(None, 'fsdp', 'tp') and returned as the equivalent
+        P(None, 'fsdp') made the SECOND train_batch compile the whole
+        step again (11.8 s for ZeRO-3 fsdp=4 at 1.1B on the chip)."""
         mesh = self.topology.mesh
+
+        def canonical(spec: PartitionSpec) -> PartitionSpec:
+            out = []
+            for entry in spec:
+                names = entry if isinstance(entry, tuple) else (entry,)
+                names = tuple(n for n in names
+                              if n is not None and mesh.shape[n] > 1)
+                out.append(None if not names else
+                           names[0] if len(names) == 1 else names)
+            while out and out[-1] is None:
+                out.pop()
+            return PartitionSpec(*out)
+
         return jax.tree.map(
-            lambda s: NamedSharding(mesh, s), spec_tree,
+            lambda s: NamedSharding(mesh, canonical(s)), spec_tree,
             is_leaf=lambda x: isinstance(x, PartitionSpec))
 
     def _init_state(self, params: PyTree) -> TrainState:
@@ -330,9 +350,9 @@ class TrainEngine:
             master = None
         else:
             master = jax.tree.map(
-                lambda x, s: jax.device_put(
-                    jnp.asarray(x, dtype=jnp.float32), NamedSharding(mesh, s)),
-                params, o_specs)
+                lambda x, sh: jax.device_put(
+                    jnp.asarray(x, dtype=jnp.float32), sh),
+                params, self._named(o_specs))
         # optimizer moments, sharded like master (ZeRO>=1 partitioned)
         opt_state = jax.jit(
             self.optimizer.init,
@@ -344,14 +364,23 @@ class TrainEngine:
                       if pc.fp16_enabled and pc.loss_scale == 0 else
                       (pc.loss_scale if pc.fp16_enabled else 1.0))
         return TrainState(
-            step=jnp.zeros((), jnp.int32),
+            step=self._scalar(0, jnp.int32),
             params=params,
             master=master,
             opt_state=opt_state,
-            loss_scale=jnp.asarray(init_scale, jnp.float32),
-            good_steps=jnp.zeros((), jnp.int32),
-            skipped_steps=jnp.zeros((), jnp.int32),
+            loss_scale=self._scalar(init_scale, jnp.float32),
+            good_steps=self._scalar(0, jnp.int32),
+            skipped_steps=self._scalar(0, jnp.int32),
         )
+
+    def _scalar(self, value, dtype) -> jax.Array:
+        """A TrainState scalar placed on the mesh like the compiled step
+        returns it: the mesh is part of an array's type, so a scalar built
+        off the mesh makes the second train_batch retrace and recompile
+        the whole step (17 s at 1.1B on the chip)."""
+        return jax.device_put(
+            jnp.asarray(value, dtype),
+            NamedSharding(self.topology.mesh, PartitionSpec()))
 
     def _opt_tree_shardings(self, params, o_specs):
         """Optimizer state is {name: tree-like-params}; build matching
@@ -625,7 +654,7 @@ class TrainEngine:
             # TPU only, and only when a cast is wanted (master mode)
             use_fused = (opt.update_fused is not None
                          and state.master is not None
-                         and jax.default_backend() == "tpu")
+                         and on_tpu())
             new_params_cast = None
             fold_kw = {"grad_scale": gscale} if fold_scale else {}
             if use_fused:
@@ -638,6 +667,11 @@ class TrainEngine:
                     grads, state.opt_state, master, lr,
                     step_num.astype(jnp.float32), **fold_kw)
             new_master = jax.lax.with_sharding_constraint(new_master, self._named(o_specs))
+            # the moments (and their scale trees) come back placed as
+            # _init_state placed them: left to propagation, the replicated
+            # scales returned sharded and the second step recompiled
+            new_opt = jax.lax.with_sharding_constraint(
+                new_opt, self._opt_tree_shardings(master, o_specs))
 
             # skip update on overflow (reference: step skipping engine.py:2400)
             if fp16:

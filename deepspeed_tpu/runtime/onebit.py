@@ -35,7 +35,7 @@ import math
 from typing import Any, Dict, Tuple
 
 import jax
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...parallel.context import require_topology
 from ...parallel.mesh import AXIS_PP
-from ...utils.jax_compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_layers"]
 

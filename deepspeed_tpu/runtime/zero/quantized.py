@@ -71,7 +71,7 @@ from __future__ import annotations
 from typing import Any, Callable, Optional, Tuple
 
 import jax
-from ...utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec

@@ -46,14 +46,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils.device import platform
+
 NEG = -1e30
 
 
 def _supports_host_memory() -> bool:
-    try:
-        return jax.devices()[0].platform in ("tpu", "cpu")
-    except Exception:  # pragma: no cover
-        return False
+    return platform() in ("tpu", "cpu")
 
 
 def _to_host(x):

@@ -9,7 +9,7 @@ the fresh trace constants (PR 4).  The recorder makes it direct:
 
 - **Compile-event hook.**  jax publishes per-compile durations through
   `jax.monitoring` (`/jax/core/compile/backend_compile_duration` fires
-  once per backend compile on this jax 0.4.37 — probed, not assumed).
+  once per backend compile — probed, not assumed).
   Listener registration is process-global and permanent (jax has no
   unregister), so ONE module-level dispatcher is installed lazily and
   fans out to the live recorders in a WeakSet — recorders can come and
@@ -46,7 +46,7 @@ __all__ = ["RecompileFlightRecorder", "COMPILE_EVENTS",
            "program_cache_census"]
 
 #: the jax.monitoring duration events that mean "a backend compile
-#: happened" (probed on jax 0.4.37; trace/lowering events are excluded
+#: happened" (trace/lowering events are excluded
 #: on purpose — re-tracing a cached program is not a recompile)
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
 

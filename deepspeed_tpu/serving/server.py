@@ -1481,8 +1481,8 @@ class ServeLoop:
             # pad to max_seqs rows so the sampler dispatch keeps ONE
             # compiled shape regardless of how many prefills finished
             # this step (each distinct row count would otherwise compile
-            # its own program — measured multi-second relay compiles
-            # inside the serve loop)
+            # its own program — multi-second compiles inside the serve
+            # loop)
             n = len(rows)
             width = max(getattr(self.engine.config, "max_seqs", n), n)
             stacked = np.zeros((width,) + np.asarray(rows[0][1]).shape,

@@ -35,7 +35,7 @@ Exporters: `chrome_trace()` renders traces as Chrome trace-event JSON
 (load it in Perfetto / chrome://tracing — one process row per replica,
 one thread per request, so a failover is visibly a span tree jumping
 rows) and `write_trace_jsonl()` streams one entry per line for ad-hoc
-tooling.  See docs/OBSERVABILITY.md for the span taxonomy.
+tooling.  See docs/OBSERVABILITY.md for the span catalogue.
 """
 from __future__ import annotations
 
@@ -50,7 +50,7 @@ __all__ = ["RequestTrace", "RequestTracer", "StepTimeline",
            "chrome_trace", "write_chrome_trace", "write_trace_jsonl",
            "SPAN_NAMES", "EVENT_NAMES"]
 
-#: the span taxonomy (docs/OBSERVABILITY.md) — phase spans cover the
+#: the span catalogue (docs/OBSERVABILITY.md) — phase spans cover the
 #: request's time in that lifecycle stage; work spans cover one unit of
 #: engine work the request rode
 SPAN_NAMES = (

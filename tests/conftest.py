@@ -31,14 +31,10 @@ jax.config.update("jax_default_matmul_precision", "highest")
 # (gitignored; delete the directory to force a cold run).  The 0.5 s
 # floor keeps trivial compiles out of the cache — their disk round-trip
 # costs more than the recompile.  An explicit JAX_COMPILATION_CACHE_DIR
-# in the environment wins.
-if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    _cache_dir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".cache", "xla")
-    os.makedirs(_cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# in the environment wins (utils.device.place_compile_cache).
+from deepspeed_tpu.utils.device import place_compile_cache  # noqa: E402
+
+place_compile_cache(min_compile_secs=0.5)
 
 
 @pytest.fixture(scope="session")

@@ -108,13 +108,16 @@ def test_offload_policy_grads_match():
 
     fn = ac.checkpoint_wrapper(fwd)  # resolves to offload policy
     l0, g0 = jax.value_and_grad(lambda p: jnp.sum(_mlp(p, x)))(p)
-    # jitted: this jax version only accepts the offload policy's
+    # jitted: jax only accepts the offload policy's
     # TransferToMemoryKind device_put inside jit — which is where
     # cpu_checkpointing runs in real training steps anyway
     l1, g1 = jax.jit(jax.value_and_grad(lambda p: jnp.sum(fn(p, x))))(p)
     assert np.allclose(l0, l1)
+    # eager vs jitted: XLA reassociates the fp32 sums (measured max
+    # relative difference 1.3e-5 on this CPU compiler) — the same bound
+    # the other grads-match tests here use
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
-        np.testing.assert_allclose(a, b, rtol=1e-6)
+        np.testing.assert_allclose(a, b, rtol=5e-5)
 
 
 def test_rng_tracker_fork_streams():

@@ -1,7 +1,7 @@
 """AutoTP classification + optimized linear / LoRA / fp-quant tests
 (reference: tests/unit/model_parallelism, tests/unit/linear/)."""
 import jax
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

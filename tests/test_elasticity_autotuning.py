@@ -1,6 +1,7 @@
 """Tests: elastic batch math (reference: tests/unit/elasticity/) and the
 in-process autotuner."""
 import json
+import subprocess
 import sys
 import os
 
@@ -329,3 +330,34 @@ def test_ssh_runner_carries_extra_env():
     assert len(cmds) == 2
     for _host, argv in cmds:
         assert "DSTPU_ELASTIC_WORLD=8" in argv[-1]
+
+
+def test_autotuner_process_parent_stays_off_the_device(tmp_path):
+    """A chip belongs to one process: under isolation="process" the tuner
+    parent must never initialise a JAX backend (parameter count, device
+    count and rank lookups all used to) or its trial children cannot hold
+    the chip.  Pruning happens in the child."""
+    script = tmp_path / "tune.py"
+    script.write_text(
+        "from deepspeed_tpu.autotuning import Autotuner\n"
+        "from deepspeed_tpu.autotuning.scheduler import ModelSpec\n"
+        "t = Autotuner(base_config={'zero_optimization': {'stage': 1}},\n"
+        "    tuning_space={'train_micro_batch_size_per_gpu': [1]},\n"
+        "    isolation='process', mem_budget_bytes=1,\n"
+        "    model_spec=ModelSpec(family='gpt2', size='tiny', seq_len=16,\n"
+        "                         steps=1, warmup=0),\n"
+        "    trial_env={'JAX_PLATFORMS': 'cpu', 'XLA_FLAGS': ''})\n"
+        "try:\n"
+        "    t.tune()\n"
+        "except RuntimeError as e:\n"
+        "    assert 'no successful trials' in str(e), e\n"
+        "assert t.experiments[0].pruned, t.experiments[0].error\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print('PARENT_OFF_DEVICE')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, str(script)], cwd=root,
+                       capture_output=True, text=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu",
+                                PYTHONPATH=root))
+    assert "PARENT_OFF_DEVICE" in r.stdout, r.stderr[-2000:]

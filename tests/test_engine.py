@@ -573,3 +573,35 @@ def test_client_lr_scheduler_and_training_data():
         dstpu.initialize(loss_fn=loss_fn, params={"w": jnp.ones((4, 2))},
                          lr_scheduler=object(),
                          config={"train_micro_batch_size_per_gpu": 1})
+
+
+@pytest.mark.parametrize("fsdp", [1, 4])
+def test_train_step_compiles_once(fsdp, devices8, caplog):
+    """The state `_init_state` builds is placed exactly as the compiled
+    step returns it — scalars on the mesh, canonical PartitionSpecs,
+    constrained optimizer state (int8 moments carry replicated scale
+    trees).  Otherwise the SECOND train_batch recompiles the whole step:
+    17 s at 1.1B on one chip, 11.8 s under ZeRO-3 fsdp=4 (PR 23)."""
+    import logging
+    from deepspeed_tpu.models import Transformer, llama_config
+    model = Transformer(llama_config("tiny", max_seq_len=32,
+                                     dtype=jnp.bfloat16))
+    from deepspeed_tpu.parallel.mesh import make_mesh
+    engine = dstpu.initialize(
+        model=model,
+        topology=make_mesh(fsdp=fsdp, devices=jax.devices()[:fsdp]),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "adamw",
+                              "params": {"lr": 1e-3,
+                                         "state_dtype": "int8f"}},
+                "zero_optimization": {"stage": 3},
+                "bf16": {"enabled": True}, "steps_per_print": 0})
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 1000, (engine.config.train_batch_size, 33)).astype(np.int32)}
+    with caplog.at_level(logging.WARNING), jax.log_compiles(True):
+        for _ in range(3):
+            engine.train_batch(batch)
+    compiles = [r for r in caplog.records
+                if "Finished XLA compilation of jit(train_step)"
+                in r.getMessage()]
+    assert len(compiles) == 1, [r.getMessage()[:80] for r in compiles]

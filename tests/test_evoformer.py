@@ -97,11 +97,11 @@ class TestEvoformerFlashKernel:
     def _interpret(self, monkeypatch):
         import functools
         import jax.experimental.pallas as pl
-        import deepspeed_tpu.ops.attention as attention_mod
+        import deepspeed_tpu.utils.device as device_mod
         monkeypatch.setattr(pl, "pallas_call",
                             functools.partial(pl.pallas_call,
                                               interpret=True))
-        monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+        monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
         yield
 
     def _qkv(self, B=1, N=3, L=256, H=2, D=64, seed=0):

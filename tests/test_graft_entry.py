@@ -4,8 +4,7 @@ already initialized with too few devices, and call it directly.
 
 Round-1 regression: only ``__main__`` forced the 8-device virtual CPU mesh,
 so the driver's direct import saw the ambient single-device platform and the
-device-count assert failed (MULTICHIP_r01.json ok=false).  The function must
-be self-sufficient now.
+device-count assert failed.  The function must be self-sufficient now.
 """
 import os
 import subprocess
@@ -19,13 +18,10 @@ import pytest
 pytestmark = pytest.mark.slow
 
 
-def _ran_or_rot_skipped(out: str, regime: str) -> None:
-    """A rot-prone regime must either print its `... train step ok` line
-    or the loud `SKIPPED (known jaxlib rot ...)` line the dryrun gate
-    emits on this container's regressed jaxlib (ROADMAP slow-tier env
-    rot) — silence means the regime never ran at all."""
-    assert (f"{regime} train step ok" in out
-            or f"{regime} SKIPPED (known jaxlib rot" in out), out
+def _ran(out: str, regime: str) -> None:
+    """Every regime prints its `... train step ok` line — silence means
+    it never ran at all (there is no skip list any more)."""
+    assert f"{regime} train step ok" in out, out
 
 
 def test_dryrun_multichip_in_process_on_existing_mesh(capfd, devices8):
@@ -41,8 +37,8 @@ def test_dryrun_multichip_in_process_on_existing_mesh(capfd, devices8):
     __graft_entry__.dryrun_multichip(8)
     assert os.environ.get("XLA_FLAGS") == flags_before
     out = capfd.readouterr().out
-    _ran_or_rot_skipped(out, "zero3+tp+pp(1f1b)+sp")
-    _ran_or_rot_skipped(out, "zero2+ring-CP")
+    _ran(out, "zero3+tp+pp(1f1b)+sp")
+    _ran(out, "zero2+ring-CP")
     assert "tp=2 ragged serving ok" in out, out
 
 
@@ -61,7 +57,7 @@ def test_dryrun_multichip_self_sufficient_after_backend_init():
         cwd=REPO, env=env, capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     out = proc.stdout
-    _ran_or_rot_skipped(out, "zero3+tp+pp(1f1b)+sp")
+    _ran(out, "zero3+tp+pp(1f1b)+sp")
     assert "zero3+fsdp+ep MoE train step ok" in out, out
-    _ran_or_rot_skipped(out, "zero2+ring-CP")
+    _ran(out, "zero2+ring-CP")
     assert "tp=2 ragged serving ok" in out, out

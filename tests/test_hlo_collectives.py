@@ -73,7 +73,7 @@ def _lower(engine):
 
 def _count_sharded_constraints(ir_txt, axis, shape="32x32"):
     """Constraints that shard a `shape` tensor over `axis` in the lowered
-    IR.  Matches the Shardy dialect (JAX >= 0.5) first; this jax (0.4.37)
+    IR.  Matches the Shardy dialect first; a jax without it
     lowers with_sharding_constraint to GSPMD-V1 `custom_call @Sharding`
     annotations instead, which carry a devices=[...] assignment but no
     axis NAMES — there, any non-replicated constraint on a `shape` tensor

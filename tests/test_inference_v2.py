@@ -450,11 +450,11 @@ def test_tp_pallas_kernel_gate(monkeypatch):
     """The fused decode kernel does not auto-partition under GSPMD, so the
     gate must turn it off at tp>1 even where it would otherwise run — and
     attn_impl='pallas' must refuse loudly rather than silently fall back.
-    _on_tpu is patched True so the n_tp condition itself is what's tested
+    the platform is patched True so the n_tp condition itself is what's tested
     (on the CPU suite the platform check alone would mask a regression)."""
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     from deepspeed_tpu.inference.v2.ragged_ops import _use_paged_kernel
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     auto = TransformerConfig(vocab_size=128, hidden_size=256, num_layers=1,
                              num_heads=4, max_seq_len=4096,
                              dtype=jnp.float32)
@@ -469,13 +469,13 @@ def test_tp_pallas_kernel_gate(monkeypatch):
 
 def test_prefill_pallas_kernel_gate(monkeypatch):
     """Auto/forced/jnp dispatch of the blocked-flash prefill gate, with
-    _on_tpu patched True so the conditions themselves are exercised.
+    the platform patched to tpu so the conditions themselves are exercised.
     Full range (r7): the gate is capability-only — no KV-budget
     threshold, and non-divisible / sub-8 chunks pad to the query tile
     instead of disqualifying the kernel."""
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     from deepspeed_tpu.inference.v2.ragged_ops import _use_paged_prefill
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     auto = TransformerConfig(vocab_size=128, hidden_size=256, num_layers=1,
                              num_heads=4, max_seq_len=16384,
                              dtype=jnp.float32)
@@ -701,13 +701,13 @@ def test_tp2_serving_with_fused_kernels(monkeypatch):
     """tp=2 with attn_impl='pallas': both paged kernels run PER-SHARD via
     shard_map (a pallas_call does not auto-partition under GSPMD) and the
     logits match the tp=1 jnp engine.  Interpreter mode stands in for the
-    TPU compile; _on_tpu is patched so the gates exercise the tp branch."""
+    TPU compile; the platform is patched so the gates exercise the tp branch."""
     import functools
     import jax.experimental.pallas as pl
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     cfg_kw = dict(vocab_size=128, hidden_size=256, num_layers=2,
                   num_heads=4, num_kv_heads=2, max_seq_len=256,
                   pos_emb="rope", norm="rmsnorm", activation="swiglu",
@@ -875,10 +875,10 @@ def test_small_budget_engine_serves_kernel_class(monkeypatch):
     the chunked-prefill and decode gates both say kernel for the
     sub-2048 budget (on TPU), so the gather-dense program class the old
     ConfigError protected against is simply unreachable under auto."""
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     import deepspeed_tpu.inference.v2.ragged_ops as ro
     from deepspeed_tpu.models import gpt2_config
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     large = gpt2_config("large", max_seq_len=1024, dtype=jnp.float32)
     # 1024-key budget (16 blocks x 64), chunk 256: kernel on, both gates
     assert ro._use_paged_prefill(large, large.head_dim, 64, 256) is True

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.parallel.mesh import (

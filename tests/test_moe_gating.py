@@ -21,7 +21,7 @@ from deepspeed_tpu.moe.sharded import (
     moe_layer, topk_gating)
 from deepspeed_tpu.parallel.context import topology
 from deepspeed_tpu.parallel.mesh import make_mesh
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 # ----------------------------------------------------------------------

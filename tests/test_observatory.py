@@ -469,10 +469,29 @@ def test_open_loop_ramp_detects_collapse_knee_on_real_engine(monkeypatch):
 
 
 # -- perf-regression ledger ------------------------------------------------
-def test_ledger_ingests_the_committed_artifacts():
-    """The five committed BENCH_SERVE_r01–r05 + BENCH_r01–r05 artifacts
-    all validate and build one trajectory with the expected series."""
-    doc = bench_history.build_trajectory(REPO_ROOT)
+def _train_artifact(n: int, value: float) -> dict:
+    """What the driver's bench.py capture looks like (synthetic: the
+    committed training rounds described an installation that is gone)."""
+    parsed = {"metric": "tokens/sec/chip (GPT-2-large 774M, ZeRO bf16, "
+                        "seq 1024)",
+              "value": value, "unit": "tokens/s/chip", "vs_baseline": 1.0}
+    return {"n": n, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(parsed) + "\n", "parsed": parsed}
+
+
+def test_ledger_ingests_the_committed_artifacts(tmp_path):
+    """The committed BENCH_SERVE_r* artifacts plus a training series
+    (synthetic BENCH_r01–r05 beside copies of them) all validate and
+    build one trajectory with the expected series."""
+    import glob
+    import shutil
+    for path in glob.glob(os.path.join(REPO_ROOT, "BENCH_SERVE_r*.json")):
+        shutil.copy(path, tmp_path)
+    for n, value in enumerate((16764.0, 17435.0, 17560.3, 17429.0,
+                               17610.0), start=1):
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
+            json.dumps(_train_artifact(n, value)))
+    doc = bench_history.build_trajectory(str(tmp_path))
     rows = doc["rows"]
     for key in ("serve_spec_c8", "serve_disagg_c8x3",
                 "serve_smallctx_c8", "serve_closed_c8",

@@ -44,8 +44,8 @@ def _interpret_mode(monkeypatch):
 def _fake_tpu(monkeypatch):
     """Flip the platform gate so the serving programs trace the fused
     kernels (which then run in interpreter mode on this CPU suite)."""
-    import deepspeed_tpu.ops.attention as attention_mod
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    import deepspeed_tpu.utils.device as device_mod
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     yield
 
 

@@ -36,7 +36,7 @@ from deepspeed_tpu.benchmarks.hlo_census import (async_overlap_report,
 from deepspeed_tpu.comm.compressed import (
     hierarchical_quantized_reduce_scatter, quantized_all_reduce)
 from deepspeed_tpu.parallel.mesh import make_mesh
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 pytestmark = pytest.mark.slow
 

@@ -569,20 +569,44 @@ def test_serving_config_validation_and_json_wiring():
 
 
 # -- threaded frontend ----------------------------------------------------
+class _GatedEngine(FakeEngine):
+    """From step `hold_after` on, every step first waits on the server's
+    own condition (which releases its lock) until the test lets go.  A
+    free-running fake engine emits 200 tokens in microseconds and the
+    serving thread re-takes the lock between steps, so under load the long
+    request could FINISH before the test thread got to cancel it — the
+    test then failed on scheduling, not on the server."""
+    hold_after = 8
+    cond = None
+    released = False
+    steps = 0
+
+    def step(self, decode=True):
+        self.steps += 1
+        if self.steps > self.hold_after:
+            self.cond.wait_for(lambda: self.released, timeout=10.0)
+        return super().step(decode=decode)
+
+
 def test_threaded_server_submit_result_cancel():
-    eng = FakeEngine(max_seqs=4, budget=32, max_tokens_per_seq=512)
+    eng = _GatedEngine(max_seqs=4, budget=32, max_tokens_per_seq=512)
     server = ThreadedServer(eng)
+    eng.cond = server._cond
     try:
         p1 = np.asarray([2, 3], np.int32)
         r1 = server.submit(p1, max_new_tokens=3)
         r2 = server.submit(np.asarray([9], np.int32), max_new_tokens=200)
         assert list(r1.result(timeout=10.0)) == _expected_tokens(p1, 3)
-        assert server.cancel(r2.uid)
+        assert server.cancel(r2.uid)          # r2 is held mid-generation
+        with server._cond:
+            eng.released = True
+            server._cond.notify_all()
         with pytest.raises(RequestCancelled):
             r2.result(timeout=10.0)
         assert server.telemetry.counters["completed"] == 1
         assert server.telemetry.counters["cancelled"] == 1
     finally:
+        eng.released = True
         server.shutdown(drain=True, timeout=10.0)
     with pytest.raises(RuntimeError, match="shut down"):
         server.submit(np.asarray([1], np.int32))

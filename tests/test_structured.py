@@ -248,6 +248,11 @@ def _serve(tiny, reqs_kw, cfg_kw=None, engine_kw=None, steps=300):
 
 
 FMT = ResponseFormat.regex(r"(ab)+c")
+# every sentence ends inside a 16-token budget whatever the (random) model
+# prefers: under (ab)+c a model that never ranks "c" first just runs out
+# of budget mid-sentence, which no grammar accepts
+BOUNDED = r"ab(ab)?c"
+FMT_BOUNDED = ResponseFormat.regex(BOUNDED)
 
 
 def test_constrained_multistep_property_over_seeds(tiny):
@@ -327,17 +332,17 @@ def test_constrained_k_partition_bit_exact(tiny):
     reqs_kw = [
         (rng.randint(1, 128, 9).astype(np.int32),
          dict(max_new_tokens=16, eos_token_id=EOS,
-              response_format=FMT)),                     # greedy
+              response_format=FMT_BOUNDED)),                     # greedy
         (rng.randint(1, 128, 7).astype(np.int32),
          dict(max_new_tokens=16, eos_token_id=EOS,
-              response_format=FMT, temperature=0.9, top_k=0, seed=7)),
+              response_format=FMT_BOUNDED, temperature=0.9, top_k=0, seed=7)),
     ]
     st = StructuredConfig()
     _, _, r1 = _serve(tiny, reqs_kw,
                       cfg_kw=dict(multi_step=1, structured=st))
     _, _, r8 = _serve(tiny, reqs_kw,
                       cfg_kw=dict(multi_step=8, structured=st))
-    auto = _auto(r"(ab)+c")
+    auto = _auto(BOUNDED)
     for a, b in zip(r1, r8):
         assert list(a.generated) == list(b.generated)
         assert auto.accepts(a.generated, eos_id=EOS)
@@ -352,19 +357,24 @@ def test_constrained_d2h_ledger_identical_and_guard_clean(tiny):
     rng = np.random.RandomState(54)
     p1 = rng.randint(1, 128, 9).astype(np.int32)
     p2 = rng.randint(1, 128, 12).astype(np.int32)
+    guarded = dict(multi_step=4, structured=StructuredConfig(),
+                   transfer_guard="disallow")
+    fsm_kw = dict(max_new_tokens=12, eos_token_id=EOS,
+                  response_format=FMT_BOUNDED)
     fetches = {}
     for name, kw in (
             ("plain", dict(max_new_tokens=12, eos_token_id=None)),
-            ("fsm", dict(max_new_tokens=12, eos_token_id=EOS,
-                         response_format=FMT))):
-        _, eng, _ = _serve(
-            tiny, [(p1, dict(kw)), (p2, dict(max_new_tokens=12))],
-            cfg_kw=dict(multi_step=4, structured=StructuredConfig(),
-                        transfer_guard="disallow"))
+            ("fsm", fsm_kw)):
+        _, eng, _ = _serve(tiny, [(p1, dict(kw))], cfg_kw=guarded)
         fetches[name] = eng.profile["d2h_fetches"]
-    # constrained row may finish EARLIER (EOS at a group boundary) so
-    # fewer groups run; per-dispatch cost must not grow
+    # the constrained row finishes EARLIER (the bounded grammar ends
+    # inside two groups) so fewer groups run; per-dispatch cost must not
+    # grow
     assert fetches["fsm"] <= fetches["plain"], fetches
+    # a mixed batch dispatches its constrained and unconstrained rows as
+    # separate groups (_burst_groups); that loop runs guard-clean too
+    _serve(tiny, [(p1, dict(fsm_kw)), (p2, dict(max_new_tokens=12))],
+           cfg_kw=guarded)
 
 
 def test_spec_compose_prefiltered_drafts_and_uplift(tiny):

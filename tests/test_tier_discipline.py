@@ -27,7 +27,9 @@ DEFAULT_TIER = {
     "test_cli_tools.py",
     "test_compression.py",
     "test_config.py",
+    "test_chip_smoke.py",
     "test_data_pipeline.py",
+    "test_device.py",
     "test_domino_zenflow.py",
     "test_engine.py",
     "test_hpz_mics.py",

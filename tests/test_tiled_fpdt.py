@@ -165,7 +165,7 @@ class TestTiledLinear:
 class TestVocabParallelCE:
     def test_matches_full(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from deepspeed_tpu.sequence import vocab_parallel_cross_entropy
         devs = np.array(jax.devices()[:4])
         mesh = Mesh(devs, ("tp",))

@@ -59,11 +59,11 @@ def test_tile_matmul_interpret_parity(monkeypatch):
     """The Pallas MXU tile kernel must match jnp.dot (f32 accumulation)
     in interpret mode, including multi-block K accumulation."""
     import jax.experimental.pallas as pl
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     import deepspeed_tpu.ops.tp_matmul as tpm
     monkeypatch.setattr(tpm.pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     rng = np.random.RandomState(0)
     for (M, K, N) in ((16, 256, 128), (8, 512, 384), (64, 128, 128)):
         x = jnp.asarray(rng.randn(M, K), jnp.float32)
@@ -77,7 +77,7 @@ def test_tile_matmul_interpret_parity(monkeypatch):
     with pytest.raises(ValueError, match="pallas"):
         tpm.tile_matmul(jnp.zeros((5, 100)), jnp.zeros((100, 60)),
                         impl="pallas")
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: False)
+    monkeypatch.setattr(device_mod, "platform", lambda: "cpu")
     with pytest.raises(ValueError, match="pallas"):
         tpm.tile_matmul(jnp.zeros((16, 256)), jnp.zeros((256, 128)),
                         impl="pallas")
@@ -92,7 +92,7 @@ def test_ring_collective_matmuls_match_xla(devices8):
                                              matmul_rs, matmul_rs_xla,
                                              tile_matmul)
     from deepspeed_tpu.parallel.mesh import AXIS_TP, make_mesh
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     tp = 4
     topo = make_mesh(dp=1, tp=tp, devices=devices8[:tp])
     rng = np.random.RandomState(0)
@@ -171,16 +171,16 @@ def test_tp2_greedy_serving_bit_parity(collectives):
 
 def test_tp2_fused_with_paged_kernels_interpret(monkeypatch):
     """The fused-TP programs' PER-SHARD paged-kernel branch (taken on
-    TPU): interpret mode stands in for the Mosaic compile, _on_tpu is
+    TPU): interpret mode stands in for the Mosaic compile, the platform is
     patched so the gates take the kernel path, and the logits must
     match a tp=1 attn_impl='jnp' engine — the kernel wiring inside the
     shard_map region, not just the CPU dense fallback."""
     import functools
     import jax.experimental.pallas as pl
-    import deepspeed_tpu.ops.attention as attention_mod
+    import deepspeed_tpu.utils.device as device_mod
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
-    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     kw = dict(vocab_size=128, hidden_size=256, num_layers=2, num_heads=4,
               num_kv_heads=2, max_seq_len=256, pos_emb="rope",
               norm="rmsnorm", activation="swiglu", dtype=jnp.float32)

@@ -1,0 +1,170 @@
+"""The main path's kernels, compiled at real widths by the TPU compiler for
+a DESCRIBED v5e chip (nothing attached; on-chip-measurement guide §2.3).
+Interpret-mode parity tests cannot see what Mosaic refuses — an unaligned
+slice, too much VMEM, an op it does not lower — and these cost about two
+seconds each, so they guard every PR at no chip time.
+
+One file on purpose: the worker that runs it loads the TPU library and
+keeps it until it exits.  The topology is described inside a module-scoped
+fixture (never at import) and each test compiles in its own process."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+pytestmark = pytest.mark.kernels
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """`arg(shape, dtype)` -> ShapeDtypeStruct on the first described chip.
+    The persistent cache is off around these compiles: an executable for a
+    described chip is written but cannot be read back without one, and the
+    next run would only warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype,
+                                                         sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def kernels(fn, *args) -> int:
+    """Compile `fn` for the described chip; count its Mosaic kernels.
+    At the precision programs run with: conftest's "highest" (for CPU
+    parity checks) asks Mosaic for an fp32 contraction of bf16 operands,
+    which it refuses."""
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile().as_text().count(
+            "tpu_custom_call")
+
+
+# (B, S, heads, kv heads, head_dim): the smoke's model and bench.py's
+@pytest.mark.parametrize("B,S,NH,NKV,D", [
+    pytest.param(4, 2048, 32, 4, 64, id="tinyllama-1.1b"),
+    pytest.param(4, 2048, 16, 16, 128, id="gpt2-1.3b"),
+])
+def test_flash_attention_fwd_bwd(chip, B, S, NH, NKV, D):
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    q, kv = chip((B, S, NH, D)), chip((B, S, NKV, D))
+    assert kernels(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   q, kv, kv) == 1
+    # forward + the dq and dk/dv backward kernels
+    assert kernels(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+# (B, NH, NKV, nb, bs, MB).  First the serving geometry (32/4 heads, D=64,
+# block 64); then the small budgets interpret mode cannot vouch for: MB=1
+# is the degenerate single-block walk, odd head counts, 1024-key GQA
+@pytest.mark.parametrize("B,NH,NKV,nb,bs,MB", [
+    (32, 32, 4, 256, 64, 32),
+    (3, 8, 2, 4, 8, 1),
+    (2, 6, 3, 8, 16, 2),
+    (8, 16, 4, 128, 64, 16),
+])
+def test_paged_decode(chip, B, NH, NKV, nb, bs, MB):
+    from deepspeed_tpu.ops.paged_attention import paged_decode_attention
+    D = 64
+    arena = chip((nb, bs, NKV, D))
+    assert kernels(paged_decode_attention, chip((B, NH, D)), arena, arena,
+                   chip((B, MB), jnp.int32), chip((B,), jnp.int32)) == 1
+
+
+# (C, NH, NKV, nb, bs, MB).  The serving chunk, then the padded tiles: C=4
+# is a sub-8 verify span (pads to the 8-row query tile), C=20 an odd chunk
+@pytest.mark.parametrize("C,NH,NKV,nb,bs,MB", [
+    (256, 32, 4, 256, 64, 32),
+    (4, 8, 2, 16, 8, 8),
+    (20, 8, 2, 16, 8, 8),
+])
+def test_paged_prefill(chip, C, NH, NKV, nb, bs, MB):
+    from deepspeed_tpu.ops.paged_prefill import paged_prefill_attention
+    D = 64
+    arena = chip((nb, bs, NKV, D))
+
+    def prefill(q, ak, av, table, meta):
+        return paged_prefill_attention(q, ak, av, table, meta[0], meta[1])
+
+    assert kernels(prefill, chip((C, NH, D)), arena, arena,
+                   chip((MB,), jnp.int32), chip((2,), jnp.int32)) == 1
+
+
+def test_merged_arena_decode(chip):
+    """The layered merged arena ([L, nb, bs, NKV*D]) big-context serving
+    uses, at the 32/4·D64 geometry."""
+    from deepspeed_tpu.ops.paged_merged import merged_decode_attention
+    B, NH, NKV, D, L, nb, bs, MB = 32, 32, 4, 64, 22, 256, 64, 32
+    arena = chip((L, nb, bs, NKV * D))
+
+    def decode(q, ak, av, tables, lens, layer):
+        return merged_decode_attention(q, ak, av, tables, lens,
+                                       layer_idx=layer)
+
+    assert kernels(decode, chip((B, NH, D)), arena, arena,
+                   chip((B, MB), jnp.int32), chip((B,), jnp.int32),
+                   chip((), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("M,K,N", [(256, 2048, 5632), (8, 2048, 2048)])
+def test_tile_matmul(chip, M, K, N):
+    """The fused-TP ring's per-hop GEMM (ops.tp_matmul; `tile_matmul`
+    dispatches to it on a TPU) at a prefill-chunk and a decode shape."""
+    from deepspeed_tpu.ops.tp_matmul import (_pallas_matmul,
+                                             tile_matmul_supported)
+    assert tile_matmul_supported(M, K, N)
+    assert kernels(_pallas_matmul, chip((M, K)), chip((K, N))) == 1
+
+
+@pytest.mark.parametrize("S", [8, 256])
+def test_lora_epilogue(chip, S):
+    from deepspeed_tpu.ops.lora_matmul import (_pallas_lora_delta,
+                                               lora_delta_supported)
+    K, N, rank, slots = 2048, 2048, 16, 4
+    assert lora_delta_supported(S, K, N, slots)
+
+    def delta(x, a, b, ids):
+        return _pallas_lora_delta(x, a, b, ids, interpret=False)
+
+    assert kernels(delta, chip((S, K)), chip((slots, K, rank)),
+                   chip((slots, rank, N)), chip((S,), jnp.int32)) == 1
+
+
+def test_fused_adam8_compiles(chip):
+    """Opt-in (`fused_update`), off the smoke's path: compiled, never run."""
+    from deepspeed_tpu.ops.fused_adam8 import fused_adam8_leaf, leaf_supported
+    shape = (2048, 5632)
+    assert leaf_supported(shape, jnp.float32)
+    f32 = jnp.float32
+
+    def step(g, mq, ms, vq, vs, p, lr, gscale, c1, c2):
+        return fused_adam8_leaf(g, mq, ms, vq, vs, p, lr, gscale, c1, c2,
+                                b1=0.9, b2=0.999, eps=1e-8, wd=0.1,
+                                adam_w=True, bias_correction=True)
+
+    scalar = chip((), f32)
+    assert kernels(step, chip(shape), chip(shape, jnp.int8),
+                   chip((shape[0], 1), f32), chip(shape, jnp.int8),
+                   chip((shape[0], 1), f32), chip(shape, f32),
+                   scalar, scalar, scalar, scalar) == 1

@@ -122,7 +122,7 @@ def test_sparse_rows_exactness():
 
 def test_sparse_allgather_matches_dense_allreduce(devices8):
     """Sparse DP reduction (gather rows, deferred sum) == dense psum."""
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(devices8), ("dp",))
     rng = np.random.RandomState(1)
     vocab, hidden = 16, 4
