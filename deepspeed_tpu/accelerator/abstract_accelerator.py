@@ -35,7 +35,7 @@ COLLAPSED (non-abstract defaults, one behavior for all sync backends):
 DROPPED (CUDA-/vendor-only, no TPU meaning — callers must not need them):
   - visible_devices_envs / set_visible_devices_envs (the launcher owns
     process-device mapping via JAX distributed init).
-  - nvtx range_push/pop (utils/nvtx-analog annotates via jax.profiler).
+  - nvtx range_push/pop (utils/spans.py `span` annotates via jax.profiler).
   - LazyCall/TorchTensorOps passthroughs (torch-specific proxying).
   - handles_memory_backpressure, use_host_timers, resolves to fixed
     answers on XLA (False/True) and is read nowhere in this runtime.

@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...utils.spans import span
 from .ragged_manager import DSStateManager, SequenceDescriptor
 from .ragged_ops import (init_arena, prefill_chunks, decode_step,
                          decode_tokens, decode_multi_step, verify_tokens)
@@ -496,53 +497,56 @@ class InferenceEngineV2:
         enabled, so direct engine use (generate/generate_batch) reuses
         prefixes too.  A matched sequence attaches the shared blocks
         read-only and prefills only the uncovered suffix."""
-        # validate EVERY uid before mutating ANY sequence — a mid-loop raise
-        # after partial mutation would double-append tokens on retry
-        for uid, toks in zip(uids, tokens_list):
-            new_tokens = len(np.asarray(toks).ravel())  # dstpu: noqa[DST001] caller-provided prompt tokens are host arrays per the put() contract
-            cur = (self.state.seqs[uid].seen_tokens
-                   if uid in self.state.seqs else 0)
-            if cur + new_tokens > self.max_tokens_per_seq:
-                raise RuntimeError(
-                    f"sequence {uid} would reach {cur + new_tokens} tokens, "
-                    f"over the {self.max_tokens_per_seq} limit "
-                    f"(min of KV lease capacity and model max_seq_len "
-                    f"{self.cfg.max_seq_len})")
-            if uid in self.state.seqs and self.state.seqs[uid].in_prefill:
-                raise RuntimeError(
-                    f"sequence {uid} is still prefilling "
-                    f"({self.state.seqs[uid].seen_tokens}/"
-                    f"{len(self.state.seqs[uid].prompt)} prompt tokens); "
-                    f"drive step() until query({uid}) returns logits "
-                    f"before feeding continuation tokens")
-        for uid, toks in zip(uids, tokens_list):
-            if uid in self.state.seqs:
-                # continuation: append pre-sampled token(s) to an existing
-                # sequence (the reference's next-token put path)
-                self.state.seqs[uid].generated.extend(
-                    int(t) for t in np.asarray(toks).ravel())  # dstpu: noqa[DST001] continuation tokens are host ints the caller sampled
-            else:
-                toks = np.asarray(toks, np.int32)  # dstpu: noqa[DST001] caller-provided prompt tokens are host arrays per the put() contract
-                if prefixes is not None and uid in prefixes:
-                    # the caller already looked this uid up (an entry of
-                    # None records a known miss — no second tree walk,
-                    # no double-counted miss)
-                    lease = prefixes[uid]
-                elif self.prefix_cache is not None:
-                    lease = self.prefix_cache.acquire(toks)
+        # admitting the sequences is part of planning the step that follows
+        with span("engine.plan", rows=len(uids)):
+            # validate EVERY uid before mutating ANY sequence — a mid-loop
+            # raise after partial mutation would double-append tokens on
+            # retry
+            for uid, toks in zip(uids, tokens_list):
+                new_tokens = len(np.asarray(toks).ravel())  # dstpu: noqa[DST001] caller-provided prompt tokens are host arrays per the put() contract
+                cur = (self.state.seqs[uid].seen_tokens
+                       if uid in self.state.seqs else 0)
+                if cur + new_tokens > self.max_tokens_per_seq:
+                    raise RuntimeError(
+                        f"sequence {uid} would reach {cur + new_tokens} "
+                        f"tokens, over the {self.max_tokens_per_seq} limit "
+                        f"(min of KV lease capacity and model max_seq_len "
+                        f"{self.cfg.max_seq_len})")
+                if uid in self.state.seqs and self.state.seqs[uid].in_prefill:
+                    raise RuntimeError(
+                        f"sequence {uid} is still prefilling "
+                        f"({self.state.seqs[uid].seen_tokens}/"
+                        f"{len(self.state.seqs[uid].prompt)} prompt tokens); "
+                        f"drive step() until query({uid}) returns logits "
+                        f"before feeding continuation tokens")
+            for uid, toks in zip(uids, tokens_list):
+                if uid in self.state.seqs:
+                    # continuation: append pre-sampled token(s) to an existing
+                    # sequence (the reference's next-token put path)
+                    self.state.seqs[uid].generated.extend(
+                        int(t) for t in np.asarray(toks).ravel())  # dstpu: noqa[DST001] continuation tokens are host ints the caller sampled
                 else:
-                    lease = None
-                if lease is None:
-                    self.state.create(uid, toks)
-                else:
-                    try:
-                        self.state.create(
-                            uid, toks,
-                            prefix=(lease.blocks, lease.covered))
-                    except Exception:
-                        self.prefix_cache.abandon(lease)
-                        raise
-                    self._prefix_leases[uid] = lease
+                    toks = np.asarray(toks, np.int32)  # dstpu: noqa[DST001] caller-provided prompt tokens are host arrays per the put() contract
+                    if prefixes is not None and uid in prefixes:
+                        # the caller already looked this uid up (an entry of
+                        # None records a known miss — no second tree walk,
+                        # no double-counted miss)
+                        lease = prefixes[uid]
+                    elif self.prefix_cache is not None:
+                        lease = self.prefix_cache.acquire(toks)
+                    else:
+                        lease = None
+                    if lease is None:
+                        self.state.create(uid, toks)
+                    else:
+                        try:
+                            self.state.create(
+                                uid, toks,
+                                prefix=(lease.blocks, lease.covered))
+                        except Exception:
+                            self.prefix_cache.abandon(lease)
+                            raise
+                        self._prefix_leases[uid] = lease
         return self.step(decode=decode)
 
     def step(self, decode: bool = True) -> Dict[int, np.ndarray]:
@@ -584,75 +588,81 @@ class InferenceEngineV2:
                 d.seen_tokens > d.prefix_covered and d.in_prefill
                 and not d.done
                 for d in self.state.seqs.values()):
-            pad_cap = 128
-            while pad_cap < 2 * budget:
-                pad_cap *= 2
-            # floor: a full batch of minimum-bucket (128-slot) prompts is
-            # always affordable — without this, a small budget would
-            # de-batch short prompts (the real-token budget still
-            # governs).  NOTE this floor makes the effective padded-slot
-            # cap max(2 * budget_bucket, max_seqs * 128): for small
-            # budgets the batch-width floor wins over the budget bucket.
-            pad_cap = max(pad_cap, self.config.max_seqs * 128)
-            full_budget = budget
-            if any(d.seen_tokens == d.prefix_covered and not d.done
-                   and d.in_prefill
-                   and (len(d.prompt) > budget or d.prefix_covered > 0)
-                   for d in self.state.seqs.values()):
-                # fairness reservation for a pending prompt that can
-                # ONLY prefill through the chunked loop: an over-budget
-                # fresh prompt, or a prefix-attached one (seen ==
-                # prefix_covered > 0 — ineligible for the fast path at
-                # any length, and not yet protected by the mid-prefill
-                # suspension above).  Without it, a sustained stream of
-                # fresh arrivals totalling >= budget/step could defer
-                # either indefinitely (ADVICE r5 finding 1).
-                full_budget = max(budget - C, 0)
-            fresh: List = []
-            S = 128
-            for d in self.state.seqs.values():
-                if not (d.seen_tokens == 0 and not d.done
-                        and 0 < len(d.prompt) <= full_budget - sum(
-                            len(f.prompt) for f in fresh)
-                        and len(fresh) < self.config.max_seqs
-                        # adapter rows need the chunked path's gather-
-                        # LoRA epilogue (prefill_full has none)
-                        and self._adapter_slots.get(d.uid, -1) < 0):
-                    continue
-                bucket = 128
-                while bucket < len(d.prompt):
-                    bucket *= 2
-                if fresh and bucket != S:
-                    continue          # one length bucket per batch
-                ns_next = 1
-                while ns_next < len(fresh) + 1:
-                    ns_next *= 2
-                if ns_next * bucket > pad_cap:
-                    continue          # padded-slot budget guard
-                S = bucket
-                fresh.append(d)
+            with span("engine.plan") as plan:
+                pad_cap = 128
+                while pad_cap < 2 * budget:
+                    pad_cap *= 2
+                # floor: a full batch of minimum-bucket (128-slot) prompts is
+                # always affordable — without this, a small budget would
+                # de-batch short prompts (the real-token budget still
+                # governs).  NOTE this floor makes the effective padded-slot
+                # cap max(2 * budget_bucket, max_seqs * 128): for small
+                # budgets the batch-width floor wins over the budget bucket.
+                pad_cap = max(pad_cap, self.config.max_seqs * 128)
+                full_budget = budget
+                if any(d.seen_tokens == d.prefix_covered and not d.done
+                       and d.in_prefill
+                       and (len(d.prompt) > budget or d.prefix_covered > 0)
+                       for d in self.state.seqs.values()):
+                    # fairness reservation for a pending prompt that can
+                    # ONLY prefill through the chunked loop: an over-budget
+                    # fresh prompt, or a prefix-attached one (seen ==
+                    # prefix_covered > 0 — ineligible for the fast path at
+                    # any length, and not yet protected by the mid-prefill
+                    # suspension above).  Without it, a sustained stream of
+                    # fresh arrivals totalling >= budget/step could defer
+                    # either indefinitely (ADVICE r5 finding 1).
+                    full_budget = max(budget - C, 0)
+                fresh: List = []
+                S = 128
+                for d in self.state.seqs.values():
+                    if not (d.seen_tokens == 0 and not d.done
+                            and 0 < len(d.prompt) <= full_budget - sum(
+                                len(f.prompt) for f in fresh)
+                            and len(fresh) < self.config.max_seqs
+                            # adapter rows need the chunked path's gather-
+                            # LoRA epilogue (prefill_full has none)
+                            and self._adapter_slots.get(d.uid, -1) < 0):
+                        continue
+                    bucket = 128
+                    while bucket < len(d.prompt):
+                        bucket *= 2
+                    if fresh and bucket != S:
+                        continue          # one length bucket per batch
+                    ns_next = 1
+                    while ns_next < len(fresh) + 1:
+                        ns_next *= 2
+                    if ns_next * bucket > pad_cap:
+                        continue          # padded-slot budget guard
+                    S = bucket
+                    fresh.append(d)
+                if fresh:
+                    from .ragged_ops import prefill_full
+                    NS = 1
+                    while NS < len(fresh):
+                        NS *= 2
+                    ftokens = np.zeros((NS, S), np.int32)
+                    flens = np.zeros(NS, np.int32)
+                    ftables = np.zeros((NS, self.config.max_blocks_per_seq),
+                                       np.int32)
+                    factive = np.zeros(NS, bool)
+                    for i, d in enumerate(fresh):
+                        n = len(d.prompt)
+                        self.state.ensure_capacity(d, n)
+                        ftokens[i, :n] = d.prompt
+                        flens[i] = n
+                        ftables[i] = self.state.block_table(d)
+                        factive[i] = True
+                plan.set_metadata(rows=len(fresh))
             if fresh:
-                from .ragged_ops import prefill_full
-                NS = 1
-                while NS < len(fresh):
-                    NS *= 2
-                ftokens = np.zeros((NS, S), np.int32)
-                flens = np.zeros(NS, np.int32)
-                ftables = np.zeros((NS, self.config.max_blocks_per_seq),
-                                   np.int32)
-                factive = np.zeros(NS, bool)
-                for i, d in enumerate(fresh):
-                    n = len(d.prompt)
-                    self.state.ensure_capacity(d, n)
-                    ftokens[i, :n] = d.prompt
-                    flens[i] = n
-                    ftables[i] = self.state.block_table(d)
-                    factive[i] = True
-                logits, self.arena = prefill_full(
-                    self.cfg, self.params, self.arena,
-                    self._host_in(ftokens), self._host_in(flens),
-                    self._host_in(ftables), self._host_in(factive))
-                logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one prefill-logits fetch per fresh batch feeds first-token sampling; explicit so the transfer guard admits it
+                with span("engine.dispatch", program="prefill_full"):
+                    logits, self.arena = prefill_full(
+                        self.cfg, self.params, self.arena,
+                        self._host_in(ftokens), self._host_in(flens),
+                        self._host_in(ftables), self._host_in(factive))
+                with span("engine.fetch", program="prefill_full",
+                          bytes=logits.nbytes):
+                    logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one prefill-logits fetch per fresh batch feeds first-token sampling; explicit so the transfer guard admits it
                 self.profile["d2h_fetches"] += 1
                 for i, d in enumerate(fresh):
                     d.seen_tokens = len(d.prompt)
@@ -663,60 +673,67 @@ class InferenceEngineV2:
         # contributes at most one partial (tail) chunk, so this cap never
         # throttles below what the budget itself allows; staging arrays are
         # allocated at the next power of two so NC below never clips
-        cap = budget // C + self.config.max_seqs
-        cap_alloc = 1
-        while cap_alloc < cap:
-            cap_alloc *= 2
-        # 1) prefill: plan the step's chunks (FIFO over pending prompts,
-        #    possibly several chunks of one long prompt, budget-bounded),
-        #    then advance them all in ONE compiled call — the ragged-batch
-        #    composition of Dynamic SplitFuse (reference: ragged_wrapper +
-        #    atom_builder build one forward from many sequences' chunks).
-        #    The chunk-slot count is padded to a power of two so the
-        #    program compiles once per bucket, and a lone small chunk pays
-        #    the 1-slot program, not the worst case.
-        planned: List[tuple] = []          # (d, start, n)
-        pseen = {d.uid: d.seen_tokens for d in self.state.seqs.values()}
-        tokens = np.zeros((cap_alloc, C), np.int32)
-        pos0s = np.zeros(cap_alloc, np.int32)
-        nvalids = np.zeros(cap_alloc, np.int32)
-        tlens = np.zeros(cap_alloc, np.int32)
-        tables = np.zeros((cap_alloc, self.config.max_blocks_per_seq),
-                          np.int32)
-        active = np.zeros(cap_alloc, bool)
-        while budget > 0 and len(planned) < cap:
-            d = next((s for s in self.state.seqs.values()
-                      if pseen[s.uid] < len(s.prompt) and not s.done), None)
-            if d is None:
-                break
-            start = pseen[d.uid]
-            n = min(C, len(d.prompt) - start, budget)
-            self.state.ensure_capacity(d, start + n)
-            i = len(planned)
-            tokens[i, :n] = d.prompt[start:start + n]
-            pos0s[i] = start
-            nvalids[i] = n
-            # full prompt length, so longrope chooses the short/long band
-            # the way HF's one-shot prompt forward does, for every chunk
-            tlens[i] = len(d.prompt)
-            tables[i] = self.state.block_table(d)
-            active[i] = True
-            planned.append((d, start, n))
-            pseen[d.uid] = start + n
-            budget -= n
+        with span("engine.plan") as plan:
+            cap = budget // C + self.config.max_seqs
+            cap_alloc = 1
+            while cap_alloc < cap:
+                cap_alloc *= 2
+            # 1) prefill: plan the step's chunks (FIFO over pending prompts,
+            #    possibly several chunks of one long prompt, budget-bounded),
+            #    then advance them all in ONE compiled call — the
+            #    ragged-batch composition of Dynamic SplitFuse (reference:
+            #    ragged_wrapper + atom_builder build one forward from many
+            #    sequences' chunks).
+            #    The chunk-slot count is padded to a power of two so the
+            #    program compiles once per bucket, and a lone small chunk pays
+            #    the 1-slot program, not the worst case.
+            planned: List[tuple] = []          # (d, start, n)
+            pseen = {d.uid: d.seen_tokens for d in self.state.seqs.values()}
+            tokens = np.zeros((cap_alloc, C), np.int32)
+            pos0s = np.zeros(cap_alloc, np.int32)
+            nvalids = np.zeros(cap_alloc, np.int32)
+            tlens = np.zeros(cap_alloc, np.int32)
+            tables = np.zeros((cap_alloc, self.config.max_blocks_per_seq),
+                              np.int32)
+            active = np.zeros(cap_alloc, bool)
+            while budget > 0 and len(planned) < cap:
+                d = next((s for s in self.state.seqs.values()
+                          if pseen[s.uid] < len(s.prompt) and not s.done),
+                         None)
+                if d is None:
+                    break
+                start = pseen[d.uid]
+                n = min(C, len(d.prompt) - start, budget)
+                self.state.ensure_capacity(d, start + n)
+                i = len(planned)
+                tokens[i, :n] = d.prompt[start:start + n]
+                pos0s[i] = start
+                nvalids[i] = n
+                # full prompt length, so longrope chooses the short/long band
+                # the way HF's one-shot prompt forward does, for every chunk
+                tlens[i] = len(d.prompt)
+                tables[i] = self.state.block_table(d)
+                active[i] = True
+                planned.append((d, start, n))
+                pseen[d.uid] = start + n
+                budget -= n
+            plan.set_metadata(rows=len(planned))
         if planned:
-            NC = 1
-            while NC < len(planned):
-                NC *= 2
-            aids = self._batch_adapter_ids([d for d, _, _ in planned], NC)
-            lkw = ({} if aids is None else
-                   dict(adapter_ids=self._host_in(aids), lora=self._lora))
-            logits, self.arena = self._programs.prefill_chunks(
-                self.params, self.arena, self._host_in(tokens[:NC]),
-                self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
-                self._host_in(tables[:NC]), self._host_in(active[:NC]),
-                self._host_in(tlens[:NC]), **lkw)
-            logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one chunk-logits fetch per prefill step (prompt-completion detection); explicit for the transfer guard
+            with span("engine.dispatch", program="prefill_chunks"):
+                NC = 1
+                while NC < len(planned):
+                    NC *= 2
+                aids = self._batch_adapter_ids([d for d, _, _ in planned], NC)
+                lkw = ({} if aids is None else
+                       dict(adapter_ids=self._host_in(aids), lora=self._lora))
+                logits, self.arena = self._programs.prefill_chunks(
+                    self.params, self.arena, self._host_in(tokens[:NC]),
+                    self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
+                    self._host_in(tables[:NC]), self._host_in(active[:NC]),
+                    self._host_in(tlens[:NC]), **lkw)
+            with span("engine.fetch", program="prefill_chunks",
+                      bytes=logits.nbytes):
+                logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one chunk-logits fetch per prefill step (prompt-completion detection); explicit for the transfer guard
             self.profile["d2h_fetches"] += 1
             for i, (d, start, n) in enumerate(planned):
                 d.seen_tokens = start + n
@@ -726,30 +743,37 @@ class InferenceEngineV2:
         #    (suppressed under decode=False: the burst serve path keeps one
         #    pending token per chained sequence, which must wait for the
         #    next decode_burst_step, not be host-decoded here)
-        batch = [d for d in self.state.decode_batch() if d.generated
-                 and d.seen_tokens < len(d.prompt) + len(d.generated)
-                 ] if decode else []
+        with span("engine.plan") as plan:
+            batch = [d for d in self.state.decode_batch() if d.generated
+                     and d.seen_tokens < len(d.prompt) + len(d.generated)
+                     ] if decode else []
+            if batch:
+                B = self.config.max_seqs
+                tokens = np.zeros(B, np.int32)
+                lens = np.zeros(B, np.int32)
+                tables = np.zeros((B, self.config.max_blocks_per_seq),
+                                  np.int32)
+                active = np.zeros(B, bool)
+                for i, d in enumerate(batch):
+                    pending_idx = d.seen_tokens - len(d.prompt)
+                    tokens[i] = d.generated[pending_idx]
+                    lens[i] = d.seen_tokens
+                    self.state.ensure_capacity(d, d.seen_tokens + 1)
+                    tables[i] = self.state.block_table(d)
+                    active[i] = True
+            plan.set_metadata(rows=len(batch))
         if batch:
-            B = self.config.max_seqs
-            tokens = np.zeros(B, np.int32)
-            lens = np.zeros(B, np.int32)
-            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
-            active = np.zeros(B, bool)
-            for i, d in enumerate(batch):
-                pending_idx = d.seen_tokens - len(d.prompt)
-                tokens[i] = d.generated[pending_idx]
-                lens[i] = d.seen_tokens
-                self.state.ensure_capacity(d, d.seen_tokens + 1)
-                tables[i] = self.state.block_table(d)
-                active[i] = True
-            aids = self._batch_adapter_ids(batch, B)
-            lkw = ({} if aids is None else
-                   dict(adapter_ids=self._host_in(aids), lora=self._lora))
-            logits, self.arena = self._programs.decode_step(
-                self.params, self.arena, self._host_in(tokens),
-                self._host_in(lens), self._host_in(tables),
-                self._host_in(active), **lkw)
-            logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: the host-sampling path ships one [B, V] logits batch per decode token BY DESIGN — burst serving (decode_burst > 1) exists to avoid this
+            with span("engine.dispatch", program="decode_step"):
+                aids = self._batch_adapter_ids(batch, B)
+                lkw = ({} if aids is None else
+                       dict(adapter_ids=self._host_in(aids), lora=self._lora))
+                logits, self.arena = self._programs.decode_step(
+                    self.params, self.arena, self._host_in(tokens),
+                    self._host_in(lens), self._host_in(tables),
+                    self._host_in(active), **lkw)
+            with span("engine.fetch", program="decode_step",
+                      bytes=logits.nbytes):
+                logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: the host-sampling path ships one [B, V] logits batch per decode token BY DESIGN — burst serving (decode_burst > 1) exists to avoid this
             self.profile["d2h_fetches"] += 1
             for i, d in enumerate(batch):
                 d.seen_tokens += 1
@@ -863,7 +887,8 @@ class InferenceEngineV2:
             raise RuntimeError(
                 "no census rider in the arena — enable_expert_paging "
                 "first")
-        out = np.asarray(jax.device_get(census))  # dstpu: noqa[DST001] intended: the census drain IS the explicit periodic fetch (one [L, E+1] int32 buffer per drain interval)
+        with span("engine.fetch", program="moe_census", bytes=census.nbytes):
+            out = np.asarray(jax.device_get(census))  # dstpu: noqa[DST001] intended: the census drain IS the explicit periodic fetch (one [L, E+1] int32 buffer per drain interval)
         self.profile["d2h_fetches"] += 1
         self.arena["moe_census"] = jnp.zeros_like(census)
         return out
@@ -953,89 +978,94 @@ class InferenceEngineV2:
                 rng=rng, max_tokens=max_tokens, drafts=drafts,
                 draft_span=draft_span, fsm=fsm, fsm_states=fsm_states,
                 fsm_eos=fsm_eos)
-        n_steps = n_steps or self.config.decode_burst
-        batch = [d for d in self.state.decode_batch() if d.generated
-                 and d.seen_tokens < len(d.prompt) + len(d.generated)]
-        if uids is not None:
-            sel = set(uids)
-            batch = [d for d in batch if d.uid in sel]
-        if not batch:
-            return {}
-        B = self.config.max_seqs
-        tokens = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        max_lens = np.ones(B, np.int32)
-        tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
-        active = np.zeros(B, bool)
-        for i, d in enumerate(batch):
-            pending = d.seen_tokens - len(d.prompt)
-            if pending != len(d.generated) - 1:
-                raise RuntimeError(
-                    f"sequence {d.uid} has {len(d.generated) - pending} "
-                    f"pending tokens; burst decode needs exactly 1 (drive "
-                    f"step() to drain extras first)")
-            tokens[i] = d.generated[pending]
-            lens[i] = d.seen_tokens
-            # cap the lease at the sequence's KV budget: a tail burst that
-            # overshoots must not demand blocks past the lease (or any
-            # blocks the overshoot alone would waste); the compiled
-            # program clamps positions to max_lens-1 so overshot steps
-            # re-write the last leased slot (their tokens are trimmed)
-            capped = min(d.seen_tokens + n_steps, self.max_tokens_per_seq)
-            if max_tokens is not None and d.uid in max_tokens:
-                capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
-            capped = max(capped, d.seen_tokens)
-            max_lens[i] = capped
-            self.state.ensure_capacity(d, capped)
-            tables[i] = self.state.block_table(d)
-            active[i] = True
-        if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
-        aids = self._batch_adapter_ids(batch, B)
-        lkw = ({} if aids is None else
-               dict(adapter_ids=self._host_in(aids), lora=self._lora))
-        if seeds and mode == "greedy":
-            raise ValueError(
-                "seeds= with mode='greedy': greedy rows never consume "
-                "their sampling stream — drop the seeds or pick a "
-                "stochastic mode")
-        if mode == "per_row" or (seeds and mode == "sample"):
-            temp_vec = np.zeros(B, np.float32)
-            topk_vec = np.zeros(B, np.int32)
-            if mode == "per_row":
-                temperature = dict(temperature or {})
-                top_k = dict(top_k or {})
-                for i, d in enumerate(batch):
-                    temp_vec[i] = float(temperature.get(d.uid, 0.0))
-                    topk_vec[i] = int(top_k.get(d.uid, 0))
+        with span("engine.plan") as plan:
+            n_steps = n_steps or self.config.decode_burst
+            batch = [d for d in self.state.decode_batch() if d.generated
+                     and d.seen_tokens < len(d.prompt) + len(d.generated)]
+            if uids is not None:
+                sel = set(uids)
+                batch = [d for d in batch if d.uid in sel]
+            if not batch:
+                return {}
+            B = self.config.max_seqs
+            tokens = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            max_lens = np.ones(B, np.int32)
+            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            active = np.zeros(B, bool)
+            for i, d in enumerate(batch):
+                pending = d.seen_tokens - len(d.prompt)
+                if pending != len(d.generated) - 1:
+                    raise RuntimeError(
+                        f"sequence {d.uid} has {len(d.generated) - pending} "
+                        f"pending tokens; burst decode needs exactly 1 (drive "
+                        f"step() to drain extras first)")
+                tokens[i] = d.generated[pending]
+                lens[i] = d.seen_tokens
+                # cap the lease at the sequence's KV budget: a tail burst that
+                # overshoots must not demand blocks past the lease (or any
+                # blocks the overshoot alone would waste); the compiled
+                # program clamps positions to max_lens-1 so overshot steps
+                # re-write the last leased slot (their tokens are trimmed)
+                capped = min(d.seen_tokens + n_steps, self.max_tokens_per_seq)
+                if max_tokens is not None and d.uid in max_tokens:
+                    capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
+                capped = max(capped, d.seen_tokens)
+                max_lens[i] = capped
+                self.state.ensure_capacity(d, capped)
+                tables[i] = self.state.block_table(d)
+                active[i] = True
+            plan.set_metadata(rows=len(batch))
+        with span("engine.dispatch", program="decode_tokens"):
+            if rng is None:
+                self._rng, rng = jax.random.split(self._rng)
+            aids = self._batch_adapter_ids(batch, B)
+            lkw = ({} if aids is None else
+                   dict(adapter_ids=self._host_in(aids), lora=self._lora))
+            if seeds and mode == "greedy":
+                raise ValueError(
+                    "seeds= with mode='greedy': greedy rows never consume "
+                    "their sampling stream — drop the seeds or pick a "
+                    "stochastic mode")
+            if mode == "per_row" or (seeds and mode == "sample"):
+                temp_vec = np.zeros(B, np.float32)
+                topk_vec = np.zeros(B, np.int32)
+                if mode == "per_row":
+                    temperature = dict(temperature or {})
+                    top_k = dict(top_k or {})
+                    for i, d in enumerate(batch):
+                        temp_vec[i] = float(temperature.get(d.uid, 0.0))
+                        topk_vec[i] = int(top_k.get(d.uid, 0))
+                else:
+                    # a uniform stochastic group with seeded rows rides the
+                    # per-row program: the seed flags need a row axis
+                    temp_vec[:len(batch)] = float(temperature)  # dstpu: noqa[DST001] scalar-mode temperature is a host python/np scalar per the method contract
+                    topk_vec[:len(batch)] = int(top_k)  # dstpu: noqa[DST001] scalar-mode top_k is a host python int per the method contract
+                skw = {}
+                if seeds:
+                    skw = self._seed_operands(batch, B, seeds, seed_positions)
+                toks, self.arena = self._programs.decode_tokens(
+                    self.params, self.arena, self._host_in(tokens),
+                    self._host_in(lens), self._host_in(tables),
+                    self._host_in(active), rng, self._host_in(temp_vec),
+                    self._host_in(max_lens), self._host_in(topk_vec),
+                    n_steps=n_steps, mode="per_row", top_k=0, **skw, **lkw)
             else:
-                # a uniform stochastic group with seeded rows rides the
-                # per-row program: the seed flags need a row axis
-                temp_vec[:len(batch)] = float(temperature)  # dstpu: noqa[DST001] scalar-mode temperature is a host python/np scalar per the method contract
-                topk_vec[:len(batch)] = int(top_k)  # dstpu: noqa[DST001] scalar-mode top_k is a host python int per the method contract
-            skw = {}
-            if seeds:
-                skw = self._seed_operands(batch, B, seeds, seed_positions)
-            toks, self.arena = self._programs.decode_tokens(
-                self.params, self.arena, self._host_in(tokens),
-                self._host_in(lens), self._host_in(tables),
-                self._host_in(active), rng, self._host_in(temp_vec),
-                self._host_in(max_lens), self._host_in(topk_vec),
-                n_steps=n_steps, mode="per_row", top_k=0, **skw, **lkw)
-        else:
-            # stage the sampling scalar explicitly as a 0-d ndarray: a
-            # python/np scalar would ride into the compiled program as an
-            # IMPLICIT host->device transfer every burst, which the
-            # transfer-guard sanitizer (analysis/transfer_guard.py)
-            # rightly rejects
-            temp_in = self._host_in(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] host scalar staged as 0-d array so the h2d transfer is explicit
-            toks, self.arena = self._programs.decode_tokens(
-                self.params, self.arena, self._host_in(tokens),
-                self._host_in(lens), self._host_in(tables),
-                self._host_in(active), rng, temp_in,
-                self._host_in(max_lens), n_steps=n_steps, mode=mode,
-                top_k=top_k, **lkw)
-        toks = jax.device_get(toks)  # dstpu: noqa[DST001] intended: THE once-per-burst fetch — n_steps sampled tokens per sequence, the only device->host traffic of burst decode
+                # stage the sampling scalar explicitly as a 0-d ndarray: a
+                # python/np scalar would ride into the compiled program as an
+                # IMPLICIT host->device transfer every burst, which the
+                # transfer-guard sanitizer (analysis/transfer_guard.py)
+                # rightly rejects
+                temp_in = self._host_in(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] host scalar staged as 0-d array so the h2d transfer is explicit
+                toks, self.arena = self._programs.decode_tokens(
+                    self.params, self.arena, self._host_in(tokens),
+                    self._host_in(lens), self._host_in(tables),
+                    self._host_in(active), rng, temp_in,
+                    self._host_in(max_lens), n_steps=n_steps, mode=mode,
+                    top_k=top_k, **lkw)
+        with span("engine.fetch", program="decode_tokens",
+                  bytes=toks.nbytes):
+            toks = jax.device_get(toks)  # dstpu: noqa[DST001] intended: THE once-per-burst fetch — n_steps sampled tokens per sequence, the only device->host traffic of burst decode
         self.profile["d2h_fetches"] += 1
         out: Dict[int, np.ndarray] = {}
         for i, d in enumerate(batch):
@@ -1128,80 +1158,85 @@ class InferenceEngineV2:
                 "program set (tp_ragged.TPServingPrograms has no "
                 "multi-step program) — use tp_collectives='xla' for "
                 "multi-step serving")
-        batch = [d for d in self.state.decode_batch() if d.generated
-                 and d.seen_tokens < len(d.prompt) + len(d.generated)]
-        if uids is not None:
-            sel = set(uids)
-            batch = [d for d in batch if d.uid in sel]
-        if not batch:
-            return {}
-        temperature = dict(temperature or {})
-        top_k = dict(top_k or {})
-        eos_ids = dict(eos_ids or {})
-        max_tokens = dict(max_tokens or {})
-        B = self.config.max_seqs
-        tokens = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        max_lens = np.ones(B, np.int32)
-        budget = np.zeros(B, np.int32)
-        eos_vec = np.full(B, -1, np.int32)
-        temp_vec = np.zeros(B, np.float32)
-        topk_vec = np.zeros(B, np.int32)
-        tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
-        active = np.zeros(B, bool)
-        for i, d in enumerate(batch):
-            pending = d.seen_tokens - len(d.prompt)
-            if pending != len(d.generated) - 1:
-                raise RuntimeError(
-                    f"sequence {d.uid} has {len(d.generated) - pending} "
-                    f"pending tokens; multi-step decode needs exactly 1 "
-                    f"(drive step() to drain extras first)")
-            tokens[i] = d.generated[pending]
-            lens[i] = d.seen_tokens
-            # full-k lease upfront, bounded by the row's token cap —
-            # identical discipline to decode_burst_step, except the
-            # budget ALSO terminates the row on device, so the program
-            # never even re-writes the last leased slot
-            capped = min(d.seen_tokens + k, self.max_tokens_per_seq)
-            capped = min(capped, int(max_tokens.get(d.uid, capped)))
-            capped = max(capped, d.seen_tokens)
-            max_lens[i] = capped
-            budget[i] = capped - d.seen_tokens
-            self.state.ensure_capacity(d, capped)
-            tables[i] = self.state.block_table(d)
-            active[i] = budget[i] > 0
-            eos_vec[i] = int(eos_ids.get(d.uid, -1))
-            temp_vec[i] = float(temperature.get(d.uid, 0.0))
-            topk_vec[i] = int(top_k.get(d.uid, 0))
-        if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
-        aids = self._batch_adapter_ids(batch, B)
-        lkw = ({} if aids is None else
-               dict(adapter_ids=self._host_in(aids), lora=self._lora))
-        skw = self._seed_operands(batch, B, seeds, seed_positions)
-        fkw = {}
-        if fsm is not None:
-            fsm_states = dict(fsm_states or {})
-            st = np.zeros(B, np.int32)
-            hf = np.zeros(B, bool)
+        with span("engine.plan") as plan:
+            batch = [d for d in self.state.decode_batch() if d.generated
+                     and d.seen_tokens < len(d.prompt) + len(d.generated)]
+            if uids is not None:
+                sel = set(uids)
+                batch = [d for d in batch if d.uid in sel]
+            if not batch:
+                return {}
+            temperature = dict(temperature or {})
+            top_k = dict(top_k or {})
+            eos_ids = dict(eos_ids or {})
+            max_tokens = dict(max_tokens or {})
+            B = self.config.max_seqs
+            tokens = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            max_lens = np.ones(B, np.int32)
+            budget = np.zeros(B, np.int32)
+            eos_vec = np.full(B, -1, np.int32)
+            temp_vec = np.zeros(B, np.float32)
+            topk_vec = np.zeros(B, np.int32)
+            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            active = np.zeros(B, bool)
             for i, d in enumerate(batch):
-                if d.uid in fsm_states:
-                    st[i] = int(fsm_states[d.uid])
-                    hf[i] = True
-            dt = fsm.device_tables()
-            fkw = dict(fsm_trans=dt["trans"], fsm_mask=dt["mask"],
-                       fsm_accept=dt["accept"],
-                       fsm_state=self._host_in(st),
-                       has_fsm=self._host_in(hf))
-        packed, self.arena = self._programs.decode_multi_step(
-            self.params, self.arena, self._host_in(tokens),
-            self._host_in(lens), self._host_in(tables),
-            self._host_in(active), rng, self._host_in(temp_vec),
-            self._host_in(max_lens), self._host_in(topk_vec),
-            self._host_in(eos_vec), self._host_in(budget),
-            skw["seed_hi"], skw["seed_lo"], skw["seed_pos"],
-            skw["has_seed"], k=k, **fkw, **lkw)
-        packed = jax.device_get(packed)  # dstpu: noqa[DST001] intended: THE once-per-group fetch — k pad-masked tokens + per-row emitted counts, the only device->host traffic of a step group
+                pending = d.seen_tokens - len(d.prompt)
+                if pending != len(d.generated) - 1:
+                    raise RuntimeError(
+                        f"sequence {d.uid} has {len(d.generated) - pending} "
+                        f"pending tokens; multi-step decode needs exactly 1 "
+                        f"(drive step() to drain extras first)")
+                tokens[i] = d.generated[pending]
+                lens[i] = d.seen_tokens
+                # full-k lease upfront, bounded by the row's token cap —
+                # identical discipline to decode_burst_step, except the
+                # budget ALSO terminates the row on device, so the program
+                # never even re-writes the last leased slot
+                capped = min(d.seen_tokens + k, self.max_tokens_per_seq)
+                capped = min(capped, int(max_tokens.get(d.uid, capped)))
+                capped = max(capped, d.seen_tokens)
+                max_lens[i] = capped
+                budget[i] = capped - d.seen_tokens
+                self.state.ensure_capacity(d, capped)
+                tables[i] = self.state.block_table(d)
+                active[i] = budget[i] > 0
+                eos_vec[i] = int(eos_ids.get(d.uid, -1))
+                temp_vec[i] = float(temperature.get(d.uid, 0.0))
+                topk_vec[i] = int(top_k.get(d.uid, 0))
+            plan.set_metadata(rows=len(batch))
+        with span("engine.dispatch", program="decode_multi_step"):
+            if rng is None:
+                self._rng, rng = jax.random.split(self._rng)
+            aids = self._batch_adapter_ids(batch, B)
+            lkw = ({} if aids is None else
+                   dict(adapter_ids=self._host_in(aids), lora=self._lora))
+            skw = self._seed_operands(batch, B, seeds, seed_positions)
+            fkw = {}
+            if fsm is not None:
+                fsm_states = dict(fsm_states or {})
+                st = np.zeros(B, np.int32)
+                hf = np.zeros(B, bool)
+                for i, d in enumerate(batch):
+                    if d.uid in fsm_states:
+                        st[i] = int(fsm_states[d.uid])
+                        hf[i] = True
+                dt = fsm.device_tables()
+                fkw = dict(fsm_trans=dt["trans"], fsm_mask=dt["mask"],
+                           fsm_accept=dt["accept"],
+                           fsm_state=self._host_in(st),
+                           has_fsm=self._host_in(hf))
+            packed, self.arena = self._programs.decode_multi_step(
+                self.params, self.arena, self._host_in(tokens),
+                self._host_in(lens), self._host_in(tables),
+                self._host_in(active), rng, self._host_in(temp_vec),
+                self._host_in(max_lens), self._host_in(topk_vec),
+                self._host_in(eos_vec), self._host_in(budget),
+                skw["seed_hi"], skw["seed_lo"], skw["seed_pos"],
+                skw["has_seed"], k=k, **fkw, **lkw)
+        with span("engine.fetch", program="decode_multi_step",
+                  bytes=packed.nbytes):
+            packed = jax.device_get(packed)  # dstpu: noqa[DST001] intended: THE once-per-group fetch — k pad-masked tokens + per-row emitted counts, the only device->host traffic of a step group
         self.profile["d2h_fetches"] += 1
         out: Dict[int, np.ndarray] = {}
         for i, d in enumerate(batch):
@@ -1248,109 +1283,114 @@ class InferenceEngineV2:
                 "the verify span accumulated (and any reroutes a demoted "
                 "expert caused inside the speculated span) cannot be "
                 "rolled back with it — serve MoE speculation unpaged")
-        batch = [d for d in self.state.decode_batch() if d.generated
-                 and d.seen_tokens < len(d.prompt) + len(d.generated)]
-        if uids is not None:
-            sel = set(uids)
-            batch = [d for d in batch if d.uid in sel]
-        if not batch:
-            return {}
-        B = self.config.max_seqs
-        S = int(draft_span)
-        fsm_states = dict(fsm_states or {})
-        fsm_eos = dict(fsm_eos or {})
-        tokens = np.zeros((B, S), np.int32)
-        lens = np.zeros(B, np.int32)
-        nval = np.ones(B, np.int32)
-        max_lens = np.ones(B, np.int32)
-        span_sts = np.zeros((B, S), np.int32)
-        hfv = np.zeros(B, bool)
-        eosv = np.full(B, -1, np.int32)
-        tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
-        active = np.zeros(B, bool)
-        for i, d in enumerate(batch):
-            pending = d.seen_tokens - len(d.prompt)
-            if pending != len(d.generated) - 1:
-                raise RuntimeError(
-                    f"sequence {d.uid} has {len(d.generated) - pending} "
-                    f"pending tokens; draft verify needs exactly 1 (drive "
-                    f"step() to drain extras first)")
-            tokens[i, 0] = d.generated[pending]
-            dr = np.asarray(drafts.get(d.uid, ()),  # dstpu: noqa[DST001] drafts are host token arrays per the method contract
-                            np.int32).ravel()[:S - 1]
-            tokens[i, 1:1 + len(dr)] = dr
-            nval[i] = 1 + len(dr)
-            lens[i] = d.seen_tokens
-            if fsm is not None and d.uid in fsm_states:
-                hfv[i] = True
-                eosv[i] = int(fsm_eos.get(d.uid, -1))
-                # state BEFORE each span position: walk the draft from
-                # the row's current state (same clamp as the device
-                # scan and TokenAutomaton.walk); the tail past the
-                # draft pins, masking the bonus position correctly
-                stw = int(fsm_states[d.uid])
-                for j in range(S):
-                    span_sts[i, j] = stw
-                    if j < len(dr):
-                        nt = int(fsm.trans[stw, int(dr[j])])  # dstpu: noqa[DST001] automaton tables + drafts are host numpy (TokenAutomaton contract) — no device sync
-                        if nt >= 0:
-                            stw = nt
-            # lease cap exactly as the sequential burst: span positions
-            # clamp to max_lens-1 in the program, overshot tokens are
-            # trimmed below, and capacity never exceeds what admission
-            # reserved
-            capped = min(d.seen_tokens + S, self.max_tokens_per_seq)
-            if max_tokens is not None and d.uid in max_tokens:
-                capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
-            capped = max(capped, d.seen_tokens)
-            max_lens[i] = capped
-            self.state.ensure_capacity(d, capped)
-            tables[i] = self.state.block_table(d)
-            active[i] = True
-        if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
-        fkw = {}
-        if fsm is not None:
-            dt = fsm.device_tables()
-            fkw = dict(fsm_mask=dt["mask"], fsm_accept=dt["accept"],
-                       span_states=self._host_in(span_sts),
-                       has_fsm=self._host_in(hfv),
-                       fsm_eos=self._host_in(eosv))
-        if mode == "greedy":
-            emitted, n_emitted, self.arena = self._programs.verify_tokens(
-                self.params, self.arena, self._host_in(tokens),
-                self._host_in(lens), self._host_in(nval),
-                self._host_in(tables), self._host_in(active), rng,
-                self._greedy_temp, self._host_in(max_lens),
-                mode="greedy", **fkw)
-        else:
-            # heterogeneous rows ("per_row" dicts) and uniform stochastic
-            # rows ("sample" scalars) share the per-row verify program —
-            # unlike the sequential burst there is no scalar "sample"
-            # variant to save a compile on: verification is one program
-            # per span width either way
-            temp_vec = np.zeros(B, np.float32)
-            topk_vec = np.zeros(B, np.int32)
-            if mode == "per_row":
-                temperature = dict(temperature or {})
-                top_k = dict(top_k or {})
-                for i, d in enumerate(batch):
-                    temp_vec[i] = float(temperature.get(d.uid, 0.0))
-                    topk_vec[i] = int(top_k.get(d.uid, 0))
-            elif mode == "sample":
-                temp_vec[:len(batch)] = float(temperature)
-                topk_vec[:len(batch)] = int(top_k)
+        with span("engine.plan") as plan:
+            batch = [d for d in self.state.decode_batch() if d.generated
+                     and d.seen_tokens < len(d.prompt) + len(d.generated)]
+            if uids is not None:
+                sel = set(uids)
+                batch = [d for d in batch if d.uid in sel]
+            if not batch:
+                return {}
+            B = self.config.max_seqs
+            S = int(draft_span)
+            fsm_states = dict(fsm_states or {})
+            fsm_eos = dict(fsm_eos or {})
+            tokens = np.zeros((B, S), np.int32)
+            lens = np.zeros(B, np.int32)
+            nval = np.ones(B, np.int32)
+            max_lens = np.ones(B, np.int32)
+            span_sts = np.zeros((B, S), np.int32)
+            hfv = np.zeros(B, bool)
+            eosv = np.full(B, -1, np.int32)
+            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            active = np.zeros(B, bool)
+            for i, d in enumerate(batch):
+                pending = d.seen_tokens - len(d.prompt)
+                if pending != len(d.generated) - 1:
+                    raise RuntimeError(
+                        f"sequence {d.uid} has {len(d.generated) - pending} "
+                        f"pending tokens; draft verify needs exactly 1 (drive "
+                        f"step() to drain extras first)")
+                tokens[i, 0] = d.generated[pending]
+                dr = np.asarray(drafts.get(d.uid, ()),  # dstpu: noqa[DST001] drafts are host token arrays per the method contract
+                                np.int32).ravel()[:S - 1]
+                tokens[i, 1:1 + len(dr)] = dr
+                nval[i] = 1 + len(dr)
+                lens[i] = d.seen_tokens
+                if fsm is not None and d.uid in fsm_states:
+                    hfv[i] = True
+                    eosv[i] = int(fsm_eos.get(d.uid, -1))
+                    # state BEFORE each span position: walk the draft from
+                    # the row's current state (same clamp as the device
+                    # scan and TokenAutomaton.walk); the tail past the
+                    # draft pins, masking the bonus position correctly
+                    stw = int(fsm_states[d.uid])
+                    for j in range(S):
+                        span_sts[i, j] = stw
+                        if j < len(dr):
+                            nt = int(fsm.trans[stw, int(dr[j])])  # dstpu: noqa[DST001] automaton tables + drafts are host numpy (TokenAutomaton contract) — no device sync
+                            if nt >= 0:
+                                stw = nt
+                # lease cap exactly as the sequential burst: span positions
+                # clamp to max_lens-1 in the program, overshot tokens are
+                # trimmed below, and capacity never exceeds what admission
+                # reserved
+                capped = min(d.seen_tokens + S, self.max_tokens_per_seq)
+                if max_tokens is not None and d.uid in max_tokens:
+                    capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
+                capped = max(capped, d.seen_tokens)
+                max_lens[i] = capped
+                self.state.ensure_capacity(d, capped)
+                tables[i] = self.state.block_table(d)
+                active[i] = True
+            plan.set_metadata(rows=len(batch))
+        with span("engine.dispatch", program="verify_tokens"):
+            if rng is None:
+                self._rng, rng = jax.random.split(self._rng)
+            fkw = {}
+            if fsm is not None:
+                dt = fsm.device_tables()
+                fkw = dict(fsm_mask=dt["mask"], fsm_accept=dt["accept"],
+                           span_states=self._host_in(span_sts),
+                           has_fsm=self._host_in(hfv),
+                           fsm_eos=self._host_in(eosv))
+            if mode == "greedy":
+                emitted, n_emitted, self.arena = self._programs.verify_tokens(
+                    self.params, self.arena, self._host_in(tokens),
+                    self._host_in(lens), self._host_in(nval),
+                    self._host_in(tables), self._host_in(active), rng,
+                    self._greedy_temp, self._host_in(max_lens),
+                    mode="greedy", **fkw)
             else:
-                raise ValueError(
-                    f"unknown sampling mode {mode!r} "
-                    f"(greedy | sample | per_row)")
-            emitted, n_emitted, self.arena = self._programs.verify_tokens(
-                self.params, self.arena, self._host_in(tokens),
-                self._host_in(lens), self._host_in(nval),
-                self._host_in(tables), self._host_in(active), rng,
-                self._host_in(temp_vec), self._host_in(max_lens),
-                self._host_in(topk_vec), mode="per_row", **fkw)
-        emitted, n_emitted = jax.device_get((emitted, n_emitted))  # dstpu: noqa[DST001] intended: THE once-per-dispatch fetch — emitted tokens + counts, the only device->host traffic of draft verify
+                # heterogeneous rows ("per_row" dicts) and uniform stochastic
+                # rows ("sample" scalars) share the per-row verify program —
+                # unlike the sequential burst there is no scalar "sample"
+                # variant to save a compile on: verification is one program
+                # per span width either way
+                temp_vec = np.zeros(B, np.float32)
+                topk_vec = np.zeros(B, np.int32)
+                if mode == "per_row":
+                    temperature = dict(temperature or {})
+                    top_k = dict(top_k or {})
+                    for i, d in enumerate(batch):
+                        temp_vec[i] = float(temperature.get(d.uid, 0.0))
+                        topk_vec[i] = int(top_k.get(d.uid, 0))
+                elif mode == "sample":
+                    temp_vec[:len(batch)] = float(temperature)
+                    topk_vec[:len(batch)] = int(top_k)
+                else:
+                    raise ValueError(
+                        f"unknown sampling mode {mode!r} "
+                        f"(greedy | sample | per_row)")
+                emitted, n_emitted, self.arena = self._programs.verify_tokens(
+                    self.params, self.arena, self._host_in(tokens),
+                    self._host_in(lens), self._host_in(nval),
+                    self._host_in(tables), self._host_in(active), rng,
+                    self._host_in(temp_vec), self._host_in(max_lens),
+                    self._host_in(topk_vec), mode="per_row", **fkw)
+        with span("engine.fetch", program="verify_tokens",
+                  bytes=emitted.nbytes + n_emitted.nbytes):
+            emitted, n_emitted = jax.device_get((emitted, n_emitted))  # dstpu: noqa[DST001] intended: THE once-per-dispatch fetch — emitted tokens + counts, the only device->host traffic of draft verify
         self.profile["d2h_fetches"] += 1
         out: Dict[int, tuple] = {}
         for i, d in enumerate(batch):
@@ -1374,20 +1414,22 @@ class InferenceEngineV2:
         (length N) with mode="per_row" (rows with temperature <= 0 take
         the argmax).  Returns [N] int32 on host."""
         from .ragged_ops import sample_tokens_compiled
-        self._rng, key = jax.random.split(self._rng)
-        stacked = jnp.asarray(np.asarray(logits_rows))  # dstpu: noqa[DST001] rows are host np logits the engine already fetched; this is h2d staging, not a sync
-        if mode == "per_row":
-            temperature = jnp.asarray(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] caller-provided host vector; explicit h2d staging
-            topk_vec = jnp.asarray(np.asarray(top_k, np.int32))  # dstpu: noqa[DST001] caller-provided host vector; explicit h2d staging
-            toks = sample_tokens_compiled(stacked, key, temperature,
-                                          topk_vec, mode="per_row")
-        else:
-            # 0-d ndarray staging, not a bare np scalar: scalar avals
-            # transfer implicitly, which the transfer guard rejects
-            temperature = jnp.asarray(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] host scalar staged as 0-d array so the h2d transfer is explicit
-            toks = sample_tokens_compiled(stacked, key, temperature,
-                                          mode=mode, top_k=int(top_k))
-        toks = jax.device_get(toks)  # dstpu: noqa[DST001] intended: one [N]-token fetch per batched first-token sample
+        with span("engine.dispatch", program="sample_tokens"):
+            self._rng, key = jax.random.split(self._rng)
+            stacked = jnp.asarray(np.asarray(logits_rows))  # dstpu: noqa[DST001] rows are host np logits the engine already fetched; this is h2d staging, not a sync
+            if mode == "per_row":
+                temperature = jnp.asarray(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] caller-provided host vector; explicit h2d staging
+                topk_vec = jnp.asarray(np.asarray(top_k, np.int32))  # dstpu: noqa[DST001] caller-provided host vector; explicit h2d staging
+                toks = sample_tokens_compiled(stacked, key, temperature,
+                                              topk_vec, mode="per_row")
+            else:
+                # 0-d ndarray staging, not a bare np scalar: scalar avals
+                # transfer implicitly, which the transfer guard rejects
+                temperature = jnp.asarray(np.asarray(temperature, np.float32))  # dstpu: noqa[DST001] host scalar staged as 0-d array so the h2d transfer is explicit
+                toks = sample_tokens_compiled(stacked, key, temperature,
+                                              mode=mode, top_k=int(top_k))
+        with span("engine.fetch", program="sample_tokens", bytes=toks.nbytes):
+            toks = jax.device_get(toks)  # dstpu: noqa[DST001] intended: one [N]-token fetch per batched first-token sample
         self.profile["d2h_fetches"] += 1
         return toks
 

@@ -129,6 +129,18 @@ def _dense(h, w, b=None):
     return out
 
 
+@jax.named_scope("kv_write")
+def _kv_write(ak_all, av_all, li, blk, off, k, v, merged: bool):
+    """Scatter layer `li`'s new keys and values into the arena at (block,
+    offset); a padded slot carries block == nb and drops.  A merged arena
+    keeps the NKV*D minor unpadded (init_arena)."""
+    if merged:
+        k = k.reshape(k.shape[:-2] + (-1,))
+        v = v.reshape(v.shape[:-2] + (-1,))
+    return (ak_all.at[li, blk, off].set(k, mode="drop"),
+            av_all.at[li, blk, off].set(v, mode="drop"))
+
+
 def _plain_mlp(cfg: TransformerConfig, lp, h):
     dt = h.dtype
     if cfg.activation == "swiglu":
@@ -141,6 +153,7 @@ def _plain_mlp(cfg: TransformerConfig, lp, h):
     return _dense(h, lp["w_down"], lp.get("b_down"))
 
 
+@jax.named_scope("mlp")
 def _mlp_delta(cfg: TransformerConfig, x, lp, pre_norm: bool = True,
                dense_flag=None):
     """norm -> MLP of `x`, WITHOUT the residual add (the caller places it:
@@ -162,6 +175,7 @@ def _mlp_delta(cfg: TransformerConfig, x, lp, pre_norm: bool = True,
     return _plain_mlp(cfg, lp, h)
 
 
+@jax.named_scope("mlp")
 def _mlp_delta_census(cfg: TransformerConfig, x, lp, dense_flag=None):
     """`_mlp_delta` (sequential pre-norm form) that also returns this
     layer's router census row [E+1] (see `_moe_inference`); a dense-
@@ -311,6 +325,7 @@ def _use_paged_prefill(cfg: TransformerConfig, D: int, bs: int, C: int,
                f"[got chunk {C}, heads {nh}])")
 
 
+@jax.named_scope("embed")
 def _embed(cfg: TransformerConfig, params, tokens, positions):
     x = _embed_in(cfg, params, tokens, cfg.dtype)
     if cfg.pos_emb == "learned":
@@ -326,6 +341,7 @@ def _embed(cfg: TransformerConfig, params, tokens, positions):
     return x
 
 
+@jax.named_scope("lm_head")
 def _lm_logits(cfg: TransformerConfig, params, x):
     if cfg.final_norm:
         x = _norm(x, params["final_norm_scale"],
@@ -428,113 +444,108 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
         la = xs[-1] if has_lora else None
         win = ex.get("window")
         dflag = ex.get("dense")
-        h = (x.reshape(NC * C, H) if cfg.post_norm
-             else _norm(x.reshape(NC * C, H), lp["attn_norm_scale"],
-                        lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps))
-        q = _dense(h, lp["wq"], lp.get("bq")).reshape(NC, C, NH, D)
-        k = _dense(h, lp["wk"], lp.get("bk")).reshape(NC, C, NKV, D)
-        v = _dense(h, lp["wv"], lp.get("bv")).reshape(NC, C, NKV, D)
-        if cfg.pos_emb == "rope":
-            q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling, regime_len=total_lens)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling, regime_len=total_lens)
+        with jax.named_scope("attention"):
+            h = (x.reshape(NC * C, H) if cfg.post_norm
+                 else _norm(x.reshape(NC * C, H), lp["attn_norm_scale"],
+                            lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps))
+            q = _dense(h, lp["wq"], lp.get("bq")).reshape(NC, C, NH, D)
+            k = _dense(h, lp["wk"], lp.get("bk")).reshape(NC, C, NKV, D)
+            v = _dense(h, lp["wv"], lp.get("bv")).reshape(NC, C, NKV, D)
+            if cfg.pos_emb == "rope":
+                q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling, regime_len=total_lens)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling, regime_len=total_lens)
 
-        # ONE batched scatter for every chunk of this layer, BEFORE the
-        # chunk scan: a chunk's keys can sit in the arena early because
-        # causality masks any key at a position a query cannot see (later
-        # chunks of the same prompt hold strictly higher positions, other
-        # sequences' blocks are not in this chunk's table).  Keeping the
-        # arena OUT of the inner scan's carry also stops XLA from holding
-        # a second full arena buffer for the nested loop — the 2x-arena
-        # peak that OOMed 32-seq serving.
-        if merged:
-            ak_all = ak_all.at[li, blk, off].set(
-                k.reshape(NC, C, NKV * D), mode="drop")
-            av_all = av_all.at[li, blk, off].set(
-                v.reshape(NC, C, NKV * D), mode="drop")
-        else:
-            ak_all = ak_all.at[li, blk, off].set(k, mode="drop")
-            av_all = av_all.at[li, blk, off].set(v, mode="drop")
+            # ONE batched scatter for every chunk of this layer, BEFORE the
+            # chunk scan: a chunk's keys can sit in the arena early because
+            # causality masks any key at a position a query cannot see (later
+            # chunks of the same prompt hold strictly higher positions, other
+            # sequences' blocks are not in this chunk's table).  Keeping the
+            # arena OUT of the inner scan's carry also stops XLA from holding
+            # a second full arena buffer for the nested loop — the 2x-arena
+            # peak that OOMed 32-seq serving.
+            ak_all, av_all = _kv_write(ak_all, av_all, li, blk, off, k, v,
+                                       merged)
 
-        def chunk_step(_, inp):
-            q_i, table_i, pos_i, p0_i, nv_i = inp
-            if use_kernel:
-                if merged:
-                    from ...ops.paged_merged import (
-                        merged_prefill_attention as _prefill_fn)
+            def chunk_step(_, inp):
+                q_i, table_i, pos_i, p0_i, nv_i = inp
+                if use_kernel:
+                    if merged:
+                        from ...ops.paged_merged import (
+                            merged_prefill_attention as _prefill_fn)
+                    else:
+                        from ...ops.paged_prefill import (
+                            paged_prefill_attention as _prefill_fn)
+                    if mesh is not None and n_tp > 1:
+                        kfn = _shard_mapped_tp(
+                            lambda q_, k_, v_, tb_, p0_, nv_, li_:
+                            _prefill_fn(
+                                q_, k_, v_, tb_, p0_, nv_,
+                                sliding_window=cfg.sliding_window,
+                                layer_idx=li_),
+                            mesh, 4, layered=True)
+                        attn = kfn(q_i, ak_all, av_all, table_i, p0_i, nv_i,
+                                   jnp.asarray(li))
+                    else:
+                        attn = _prefill_fn(
+                            q_i, ak_all, av_all, table_i, p0_i, nv_i,
+                            sliding_window=cfg.sliding_window, layer_idx=li)
                 else:
-                    from ...ops.paged_prefill import (
-                        paged_prefill_attention as _prefill_fn)
-                if mesh is not None and n_tp > 1:
-                    kfn = _shard_mapped_tp(
-                        lambda q_, k_, v_, tb_, p0_, nv_, li_:
-                        _prefill_fn(
-                            q_, k_, v_, tb_, p0_, nv_,
-                            sliding_window=cfg.sliding_window,
-                            layer_idx=li_),
-                        mesh, 4, layered=True)
-                    attn = kfn(q_i, ak_all, av_all, table_i, p0_i, nv_i,
-                               jnp.asarray(li))
-                else:
-                    attn = _prefill_fn(
-                        q_i, ak_all, av_all, table_i, p0_i, nv_i,
-                        sliding_window=cfg.sliding_window, layer_idx=li)
-            else:
-                idx = li * nb + jnp.clip(table_i, 0, nb - 1)
-                kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
-                              axis=0).reshape(max_kv, NKV, D)
-                vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
-                              axis=0).reshape(max_kv, NKV, D)
-                # (the L*nb flatten works for BOTH arena ranks)
-                if NKV != NH:
-                    kk = jnp.repeat(kk, NH // NKV, axis=1)
-                    vv = jnp.repeat(vv, NH // NKV, axis=1)
-                s = jnp.einsum(
-                    "cnd,mnd->ncm", q_i, kk,
-                    preferred_element_type=jnp.float32) / math.sqrt(D)
-                if cfg.pos_emb == "alibi":
-                    dist = (pos_i[None, :, None]
-                            - key_pos[None, None, :]).astype(jnp.float32)
-                    slopes = _alibi_slopes(NH)
-                    if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
-                        slopes = slopes / math.sqrt(D)
-                    s = s - slopes[:, None, None] * jnp.maximum(
-                        dist, 0.0)
-                mask = key_pos[None, None, :] <= pos_i[None, :, None]
-                if win is not None:
-                    w_eff = jnp.where(win > 0, win, max_kv)
-                    mask &= (key_pos[None, None, :]
-                             > pos_i[None, :, None] - w_eff)
-                elif cfg.sliding_window is not None:
-                    mask &= (key_pos[None, None, :]
-                             > pos_i[None, :, None] - cfg.sliding_window)
-                s = jnp.where(mask, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                attn = jnp.einsum("ncm,mnd->cnd", p.astype(dt), vv)
-            return (), attn.reshape(C, NH * D)
+                    idx = li * nb + jnp.clip(table_i, 0, nb - 1)
+                    kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
+                                  axis=0).reshape(max_kv, NKV, D)
+                    vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
+                                  axis=0).reshape(max_kv, NKV, D)
+                    # (the L*nb flatten works for BOTH arena ranks)
+                    if NKV != NH:
+                        kk = jnp.repeat(kk, NH // NKV, axis=1)
+                        vv = jnp.repeat(vv, NH // NKV, axis=1)
+                    s = jnp.einsum(
+                        "cnd,mnd->ncm", q_i, kk,
+                        preferred_element_type=jnp.float32) / math.sqrt(D)
+                    if cfg.pos_emb == "alibi":
+                        dist = (pos_i[None, :, None]
+                                - key_pos[None, None, :]).astype(jnp.float32)
+                        slopes = _alibi_slopes(NH)
+                        if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
+                            slopes = slopes / math.sqrt(D)
+                        s = s - slopes[:, None, None] * jnp.maximum(
+                            dist, 0.0)
+                    mask = key_pos[None, None, :] <= pos_i[None, :, None]
+                    if win is not None:
+                        w_eff = jnp.where(win > 0, win, max_kv)
+                        mask &= (key_pos[None, None, :]
+                                 > pos_i[None, :, None] - w_eff)
+                    elif cfg.sliding_window is not None:
+                        mask &= (key_pos[None, None, :]
+                                 > pos_i[None, :, None] - cfg.sliding_window)
+                    s = jnp.where(mask, s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1)
+                    attn = jnp.einsum("ncm,mnd->cnd", p.astype(dt), vv)
+                return (), attn.reshape(C, NH * D)
 
-        # Chunk attentions are data-independent (the scatter above
-        # already wrote EVERY chunk's keys; position masking provides
-        # causality even between chunks of one prompt), so a parallel
-        # vmap is semantically legal here — but MEASURED SLOWER (r5,
-        # v5e, 8k prompt, C=256): vmapping the scalar-prefetch pallas
-        # kernel halves prefill throughput (13.5k -> 7.5k tok/s; the
-        # batching rule's lowering serializes with per-instance arena
-        # handling), so the scan stays.  Prefill's distance from the
-        # training-forward bound (~9x at medium/8k) is the per-chunk
-        # kernel geometry, not the scan ordering; bigger chunks help
-        # modestly (C 256 -> 2048 measured +26%).
-        _, attn = jax.lax.scan(
-            chunk_step, (),
-            (q, block_tables, positions, pos0s, n_valids))
-        attn_out = _dense(attn.reshape(NC * C, NH * D), lp["wo"],
-                          lp.get("bo"))
-        if has_lora:
-            from ...ops.lora_matmul import lora_delta
-            attn_out = attn_out + lora_delta(
-                attn.reshape(NC * C, NH * D), la["a"], la["b"],
-                row_ids).astype(dt)
+            # Chunk attentions are data-independent (the scatter above
+            # already wrote EVERY chunk's keys; position masking provides
+            # causality even between chunks of one prompt), so a parallel
+            # vmap is semantically legal here — but MEASURED SLOWER (r5,
+            # v5e, 8k prompt, C=256): vmapping the scalar-prefetch pallas
+            # kernel halves prefill throughput (13.5k -> 7.5k tok/s; the
+            # batching rule's lowering serializes with per-instance arena
+            # handling), so the scan stays.  Prefill's distance from the
+            # training-forward bound (~9x at medium/8k) is the per-chunk
+            # kernel geometry, not the scan ordering; bigger chunks help
+            # modestly (C 256 -> 2048 measured +26%).
+            _, attn = jax.lax.scan(
+                chunk_step, (),
+                (q, block_tables, positions, pos0s, n_valids))
+            attn_out = _dense(attn.reshape(NC * C, NH * D), lp["wo"],
+                              lp.get("bo"))
+            if has_lora:
+                from ...ops.lora_matmul import lora_delta
+                attn_out = attn_out + lora_delta(
+                    attn.reshape(NC * C, NH * D), la["a"], la["b"],
+                    row_ids).astype(dt)
         x2 = x.reshape(NC * C, H)
         if cfg.parallel_residual:
             x2 = x2 + attn_out + _mlp_delta(cfg, x2, lp)
@@ -641,31 +652,26 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
         else:
             lp, li = xs
             ex = {}
-        h = _norm(x.reshape(NS * S, H), lp["attn_norm_scale"],
-                  lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
-        q = _dense(h, lp["wq"], lp.get("bq")).reshape(NS, S, NH, D)
-        k = _dense(h, lp["wk"], lp.get("bk")).reshape(NS, S, NKV, D)
-        v = _dense(h, lp["wv"], lp.get("bv")).reshape(NS, S, NKV, D)
-        if cfg.pos_emb == "rope":
-            q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling, regime_len=total_lens)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling, regime_len=total_lens)
-        if merged:
-            ak_all = ak_all.at[li, blk, off].set(
-                k.reshape(NS, S, NKV * D), mode="drop")
-            av_all = av_all.at[li, blk, off].set(
-                v.reshape(NS, S, NKV * D), mode="drop")
-        else:
-            ak_all = ak_all.at[li, blk, off].set(k, mode="drop")
-            av_all = av_all.at[li, blk, off].set(v, mode="drop")
-        # dense causal self-attention over the prompts — the training
-        # flash kernel (GQA handled inside); padded tails are masked by
-        # causality + the logits slice below
-        attn = causal_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                                impl=cfg.attn_impl)
-        attn_out = _dense(attn.reshape(NS * S, NH * D), lp["wo"],
-                          lp.get("bo"))
+        with jax.named_scope("attention"):
+            h = _norm(x.reshape(NS * S, H), lp["attn_norm_scale"],
+                      lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
+            q = _dense(h, lp["wq"], lp.get("bq")).reshape(NS, S, NH, D)
+            k = _dense(h, lp["wk"], lp.get("bk")).reshape(NS, S, NKV, D)
+            v = _dense(h, lp["wv"], lp.get("bv")).reshape(NS, S, NKV, D)
+            if cfg.pos_emb == "rope":
+                q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling, regime_len=total_lens)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling, regime_len=total_lens)
+            ak_all, av_all = _kv_write(ak_all, av_all, li, blk, off, k, v,
+                                       merged)
+            # dense causal self-attention over the prompts — the training
+            # flash kernel (GQA handled inside); padded tails are masked by
+            # causality + the logits slice below
+            attn = causal_attention(q.astype(dt), k.astype(dt), v.astype(dt),
+                                    impl=cfg.attn_impl)
+            attn_out = _dense(attn.reshape(NS * S, NH * D), lp["wo"],
+                              lp.get("bo"))
         x2 = x.reshape(NS * S, H) + attn_out
         x2 = x2 + _mlp_delta(cfg, x2, lp, dense_flag=ex.get("dense"))
         return (x2.reshape(NS, S, H), ak_all, av_all), None
@@ -701,6 +707,7 @@ def decode_step(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                         active, n_tp, mesh, adapter_ids, lora)
 
 
+@jax.named_scope("sample")
 def _sample_tokens(logits, key, mode: str, temperature, top_k):
     """On-device sampling (reference: the host-side sampler the v2 engine
     leaves to the client — moving it on-device removes the per-token
@@ -849,6 +856,7 @@ def _seeded_pick(scaled_logits, u):
     return jnp.minimum(idx, cdf.shape[-1] - 1).astype(jnp.int32)
 
 
+@jax.named_scope("sample")
 def _sample_per_row(logits, key, temperature, top_k_vec, seed_hi=None,
                     seed_lo=None, seed_pos=None, has_seed=None,
                     mask=None):
@@ -1108,6 +1116,7 @@ def decode_multi_step(cfg: TransformerConfig, params, arena, tokens,
     return packed, arena
 
 
+@jax.named_scope("sample")
 def _spec_accept(logits, tokens, n_valids, key, mode: str, temperature,
                  top_k_vec, fsm_mask=None, fsm_accept=None,
                  span_states=None, has_fsm=None, fsm_eos=None):
@@ -1334,97 +1343,92 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
             ex = {}
         win = ex.get("window")
         dflag = ex.get("dense")
-        h = (x.reshape(B * S, H) if cfg.post_norm
-             else _norm(x.reshape(B * S, H), lp["attn_norm_scale"],
-                        lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps))
-        q = _dense(h, lp["wq"], lp.get("bq")).reshape(B, S, NH, D)
-        k = _dense(h, lp["wk"], lp.get("bk")).reshape(B, S, NKV, D)
-        v = _dense(h, lp["wv"], lp.get("bv")).reshape(B, S, NKV, D)
-        if cfg.pos_emb == "rope":
-            q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
-                      cfg.rope_scaling)
-        if merged:
-            ak_all = ak_all.at[li, blk, off].set(
-                k.reshape(B, S, NKV * D), mode="drop")
-            av_all = av_all.at[li, blk, off].set(
-                v.reshape(B, S, NKV * D), mode="drop")
-        else:
-            ak_all = ak_all.at[li, blk, off].set(k, mode="drop")
-            av_all = av_all.at[li, blk, off].set(v, mode="drop")
+        with jax.named_scope("attention"):
+            h = (x.reshape(B * S, H) if cfg.post_norm
+                 else _norm(x.reshape(B * S, H), lp["attn_norm_scale"],
+                            lp.get("attn_norm_bias"), cfg.norm, cfg.norm_eps))
+            q = _dense(h, lp["wq"], lp.get("bq")).reshape(B, S, NH, D)
+            k = _dense(h, lp["wk"], lp.get("bk")).reshape(B, S, NKV, D)
+            v = _dense(h, lp["wv"], lp.get("bv")).reshape(B, S, NKV, D)
+            if cfg.pos_emb == "rope":
+                q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct,
+                          cfg.rope_scaling)
+            ak_all, av_all = _kv_write(ak_all, av_all, li, blk, off, k, v,
+                                       merged)
 
-        if use_kernel:
-            # per-row spans ride the blocked-prefill kernel (pos0 =
-            # seq_lens, nv = n_valids), scanned over rows exactly like
-            # prefill_chunks' chunk scan
-            if merged:
-                from ...ops.paged_merged import (
-                    merged_prefill_attention as _prefill_fn)
-            else:
-                from ...ops.paged_prefill import (
-                    paged_prefill_attention as _prefill_fn)
-
-            def row_step(_, inp):
-                q_i, table_i, p0_i, nv_i = inp
-                if mesh is not None and n_tp > 1:
-                    kfn = _shard_mapped_tp(
-                        lambda q_, k_, v_, tb_, p0_, nv_, li_:
-                        _prefill_fn(
-                            q_, k_, v_, tb_, p0_, nv_,
-                            sliding_window=cfg.sliding_window,
-                            layer_idx=li_),
-                        mesh, 4, layered=True)
-                    attn = kfn(q_i, ak_all, av_all, table_i, p0_i, nv_i,
-                               jnp.asarray(li))
+            if use_kernel:
+                # per-row spans ride the blocked-prefill kernel (pos0 =
+                # seq_lens, nv = n_valids), scanned over rows exactly like
+                # prefill_chunks' chunk scan
+                if merged:
+                    from ...ops.paged_merged import (
+                        merged_prefill_attention as _prefill_fn)
                 else:
-                    attn = _prefill_fn(
-                        q_i, ak_all, av_all, table_i, p0_i, nv_i,
-                        sliding_window=cfg.sliding_window, layer_idx=li)
-                return (), attn
+                    from ...ops.paged_prefill import (
+                        paged_prefill_attention as _prefill_fn)
 
-            _, attn = jax.lax.scan(
-                row_step, (),
-                (q, block_tables, seq_lens, n_valids))
-            attn = attn.reshape(B, S, NH, D)
-        else:
-            idx = li * nb + jnp.clip(block_tables, 0, nb - 1)
-            kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
-                          axis=0).reshape(B, max_kv, NKV, D)
-            vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
-                          axis=0).reshape(B, max_kv, NKV, D)
-            if NKV != NH:
-                kk = jnp.repeat(kk, NH // NKV, axis=2)
-                vv = jnp.repeat(vv, NH // NKV, axis=2)
-            # ONE gather serves all S queries of a row — S sequential
-            # decode steps would materialize this [B, max_kv] copy S
-            # times, the bandwidth the span forward amortizes
-            s = jnp.einsum("bsnd,bmnd->bnsm", q, kk,
-                           preferred_element_type=jnp.float32
-                           ) / math.sqrt(D)
-            if cfg.pos_emb == "alibi":
-                dist = (positions[:, None, :, None]
-                        - key_pos[None, None, None, :]).astype(jnp.float32)
-                slopes = _alibi_slopes(NH)
-                if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
-                    slopes = slopes / math.sqrt(D)
-                s = s - slopes[None, :, None, None] * jnp.maximum(
-                    dist, 0.0)
-            mask = key_pos[None, None, None, :] <= positions[:, None, :,
-                                                            None]
-            if win is not None:
-                w_eff = jnp.where(win > 0, win, max_kv)
-                mask &= (key_pos[None, None, None, :]
-                         > positions[:, None, :, None] - w_eff)
-            elif cfg.sliding_window is not None:
-                mask &= (key_pos[None, None, None, :]
-                         > positions[:, None, :, None]
-                         - cfg.sliding_window)
-            s = jnp.where(mask, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bnsm,bmnd->bsnd", p.astype(dt), vv)
-        attn_out = _dense(attn.reshape(B * S, NH * D), lp["wo"],
-                          lp.get("bo"))
+                def row_step(_, inp):
+                    q_i, table_i, p0_i, nv_i = inp
+                    if mesh is not None and n_tp > 1:
+                        kfn = _shard_mapped_tp(
+                            lambda q_, k_, v_, tb_, p0_, nv_, li_:
+                            _prefill_fn(
+                                q_, k_, v_, tb_, p0_, nv_,
+                                sliding_window=cfg.sliding_window,
+                                layer_idx=li_),
+                            mesh, 4, layered=True)
+                        attn = kfn(q_i, ak_all, av_all, table_i, p0_i, nv_i,
+                                   jnp.asarray(li))
+                    else:
+                        attn = _prefill_fn(
+                            q_i, ak_all, av_all, table_i, p0_i, nv_i,
+                            sliding_window=cfg.sliding_window, layer_idx=li)
+                    return (), attn
+
+                _, attn = jax.lax.scan(
+                    row_step, (),
+                    (q, block_tables, seq_lens, n_valids))
+                attn = attn.reshape(B, S, NH, D)
+            else:
+                idx = li * nb + jnp.clip(block_tables, 0, nb - 1)
+                kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
+                              axis=0).reshape(B, max_kv, NKV, D)
+                vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
+                              axis=0).reshape(B, max_kv, NKV, D)
+                if NKV != NH:
+                    kk = jnp.repeat(kk, NH // NKV, axis=2)
+                    vv = jnp.repeat(vv, NH // NKV, axis=2)
+                # ONE gather serves all S queries of a row — S sequential
+                # decode steps would materialize this [B, max_kv] copy S
+                # times, the bandwidth the span forward amortizes
+                s = jnp.einsum("bsnd,bmnd->bnsm", q, kk,
+                               preferred_element_type=jnp.float32
+                               ) / math.sqrt(D)
+                if cfg.pos_emb == "alibi":
+                    dist = (positions[:, None, :, None]
+                            - key_pos[None, None, None, :]).astype(jnp.float32)
+                    slopes = _alibi_slopes(NH)
+                    if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
+                        slopes = slopes / math.sqrt(D)
+                    s = s - slopes[None, :, None, None] * jnp.maximum(
+                        dist, 0.0)
+                mask = key_pos[None, None, None, :] <= positions[:, None, :,
+                                                                None]
+                if win is not None:
+                    w_eff = jnp.where(win > 0, win, max_kv)
+                    mask &= (key_pos[None, None, None, :]
+                             > positions[:, None, :, None] - w_eff)
+                elif cfg.sliding_window is not None:
+                    mask &= (key_pos[None, None, None, :]
+                             > positions[:, None, :, None]
+                             - cfg.sliding_window)
+                s = jnp.where(mask, s, -1e30)
+                p = jax.nn.softmax(s, axis=-1)
+                attn = jnp.einsum("bnsm,bmnd->bsnd", p.astype(dt), vv)
+            attn_out = _dense(attn.reshape(B * S, NH * D), lp["wo"],
+                              lp.get("bo"))
         x2 = x.reshape(B * S, H)
         if cfg.parallel_residual:
             x2 = x2 + attn_out + _mlp_delta(cfg, x2, lp)
@@ -1494,102 +1498,99 @@ def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
         la = xs[-1] if has_lora else None
         win = ex.get("window")
         dflag = ex.get("dense")
-        h = x if cfg.post_norm else _norm(x, lp["attn_norm_scale"],
-                                          lp.get("attn_norm_bias"),
-                                          cfg.norm, cfg.norm_eps)
-        q = _dense(h, lp["wq"], lp.get("bq")).reshape(B, NH, D)
-        k = _dense(h, lp["wk"], lp.get("bk")).reshape(B, NKV, D)
-        v = _dense(h, lp["wv"], lp.get("bv")).reshape(B, NKV, D)
-        if cfg.pos_emb == "rope":
-            q = _rope(q[:, None], positions[:, None], cfg.rope_theta,
-                      cfg.rope_pct, cfg.rope_scaling)[:, 0]
-            k = _rope(k[:, None], positions[:, None], cfg.rope_theta,
-                      cfg.rope_pct, cfg.rope_scaling)[:, 0]
-        if merged:
-            ak_all = ak_all.at[li, blk, off].set(
-                k.reshape(B, NKV * D), mode="drop")
-            av_all = av_all.at[li, blk, off].set(
-                v.reshape(B, NKV * D), mode="drop")
-        else:
-            ak_all = ak_all.at[li, blk, off].set(k, mode="drop")
-            av_all = av_all.at[li, blk, off].set(v, mode="drop")
+        with jax.named_scope("attention"):
+            h = x if cfg.post_norm else _norm(x, lp["attn_norm_scale"],
+                                              lp.get("attn_norm_bias"),
+                                              cfg.norm, cfg.norm_eps)
+            q = _dense(h, lp["wq"], lp.get("bq")).reshape(B, NH, D)
+            k = _dense(h, lp["wk"], lp.get("bk")).reshape(B, NKV, D)
+            v = _dense(h, lp["wv"], lp.get("bv")).reshape(B, NKV, D)
+            if cfg.pos_emb == "rope":
+                q = _rope(q[:, None], positions[:, None], cfg.rope_theta,
+                          cfg.rope_pct, cfg.rope_scaling)[:, 0]
+                k = _rope(k[:, None], positions[:, None], cfg.rope_theta,
+                          cfg.rope_pct, cfg.rope_scaling)[:, 0]
+            ak_all, av_all = _kv_write(ak_all, av_all, li, blk, off, k, v,
+                                       merged)
 
-        use_kernel = _use_paged_kernel(
-            cfg, D, bs, 1 if mesh is not None else n_tp)
-        if merged:
-            # merged arenas feed the packed-q kernel (ops/paged_merged) —
-            # the r3 gather fallback is gone where the layout qualifies
-            from ...ops.paged_merged import merged_kernels_supported
-            loc = n_tp if mesh is not None else 1
-            m_ok = merged_kernels_supported(NH // loc, NKV // loc, D)
-            if use_kernel and not m_ok and cfg.attn_impl == "pallas":
-                # keep _gate_fused's no-silent-fallback contract
-                raise ValueError(
-                    f"attn_impl='pallas' requested but the merged-arena "
-                    f"decode kernel cannot serve this layout (local heads "
-                    f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
-                    f"128-aligned packed stripes)")
-            use_kernel = use_kernel and m_ok
-        if use_kernel:
-            # fused Pallas paged attention: the block table is a scalar-
-            # prefetch operand whose index map DMAs arena blocks directly —
-            # the [B, max_kv] gathered K/V copy below never materializes
-            # (measured 1.2-2.9x vs the dense gather on v5e, 2026-07-30)
+            use_kernel = _use_paged_kernel(
+                cfg, D, bs, 1 if mesh is not None else n_tp)
             if merged:
-                from ...ops.paged_merged import (
-                    merged_decode_attention as _decode_fn)
+                # merged arenas feed the packed-q kernel (ops/paged_merged) —
+                # the r3 gather fallback is gone where the layout qualifies
+                from ...ops.paged_merged import merged_kernels_supported
+                loc = n_tp if mesh is not None else 1
+                m_ok = merged_kernels_supported(NH // loc, NKV // loc, D)
+                if use_kernel and not m_ok and cfg.attn_impl == "pallas":
+                    # keep _gate_fused's no-silent-fallback contract
+                    raise ValueError(
+                        f"attn_impl='pallas' requested but the merged-arena "
+                        f"decode kernel cannot serve this layout (local heads "
+                        f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
+                        f"128-aligned packed stripes)")
+                use_kernel = use_kernel and m_ok
+            if use_kernel:
+                # fused Pallas paged attention: the block table is a
+                # scalar-prefetch operand whose index map DMAs arena blocks
+                # directly — the [B, max_kv] gathered K/V copy below never
+                # materializes (measured 1.2-2.9x vs the dense gather on
+                # v5e, 2026-07-30)
+                if merged:
+                    from ...ops.paged_merged import (
+                        merged_decode_attention as _decode_fn)
+                else:
+                    from ...ops.paged_attention import (
+                        paged_decode_attention as _decode_fn)
+                lens = jnp.where(active, positions, -1)
+                if mesh is not None and n_tp > 1:
+                    kfn = _shard_mapped_tp(
+                        lambda q_, k_, v_, tb_, ln_, li_:
+                        _decode_fn(q_, k_, v_, tb_, ln_, layer_idx=li_),
+                        mesh, 3, layered=True)
+                    attn = kfn(q, ak_all, av_all, block_tables, lens,
+                               jnp.asarray(li)).reshape(B, NH * D)
+                else:
+                    attn = _decode_fn(
+                        q, ak_all, av_all, block_tables, lens,
+                        layer_idx=li).reshape(B, NH * D)
             else:
-                from ...ops.paged_attention import (
-                    paged_decode_attention as _decode_fn)
-            lens = jnp.where(active, positions, -1)
-            if mesh is not None and n_tp > 1:
-                kfn = _shard_mapped_tp(
-                    lambda q_, k_, v_, tb_, ln_, li_:
-                    _decode_fn(q_, k_, v_, tb_, ln_, layer_idx=li_),
-                    mesh, 3, layered=True)
-                attn = kfn(q, ak_all, av_all, block_tables, lens,
-                           jnp.asarray(li)).reshape(B, NH * D)
-            else:
-                attn = _decode_fn(
-                    q, ak_all, av_all, block_tables, lens,
-                    layer_idx=li).reshape(B, NH * D)
-        else:
-            idx = li * nb + jnp.clip(block_tables, 0, nb - 1)
-            kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
-                          axis=0).reshape(B, max_kv, NKV, D)
-            vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
-                          axis=0).reshape(B, max_kv, NKV, D)
-            if NKV != NH:
-                kk = jnp.repeat(kk, NH // NKV, axis=2)
-                vv = jnp.repeat(vv, NH // NKV, axis=2)
-            s = jnp.einsum("bnd,bmnd->bnm", q, kk,
-                           preferred_element_type=jnp.float32) / math.sqrt(D)
-            if cfg.pos_emb == "alibi":
-                dist = (positions[:, None, None]
-                        - key_pos[None, None, :]).astype(jnp.float32)
-                slopes = _alibi_slopes(NH)
-                if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
-                    slopes = slopes / math.sqrt(D)
-                s = s - slopes[None, :, None] * jnp.maximum(
-                    dist, 0.0)
-            mask = key_pos[None, None, :] <= positions[:, None, None]
-            if win is not None:
-                w_eff = jnp.where(win > 0, win, max_kv)
-                mask &= (key_pos[None, None, :]
-                         > positions[:, None, None] - w_eff)
-            elif cfg.sliding_window is not None:
-                mask &= (key_pos[None, None, :]
-                         > positions[:, None, None] - cfg.sliding_window)
-            s = jnp.where(mask, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bnm,bmnd->bnd", p.astype(dt),
-                              vv).reshape(B, NH * D)
-        attn_out = _dense(attn, lp["wo"], lp.get("bo"))
-        if has_lora:
-            from ...ops.lora_matmul import lora_delta
-            attn_out = attn_out + lora_delta(
-                attn, la["a"], la["b"],
-                jnp.asarray(adapter_ids, jnp.int32)).astype(dt)
+                idx = li * nb + jnp.clip(block_tables, 0, nb - 1)
+                kk = jnp.take(ak_all.reshape(L * nb, bs, NKV * D), idx,
+                              axis=0).reshape(B, max_kv, NKV, D)
+                vv = jnp.take(av_all.reshape(L * nb, bs, NKV * D), idx,
+                              axis=0).reshape(B, max_kv, NKV, D)
+                if NKV != NH:
+                    kk = jnp.repeat(kk, NH // NKV, axis=2)
+                    vv = jnp.repeat(vv, NH // NKV, axis=2)
+                s = jnp.einsum(
+                    "bnd,bmnd->bnm", q, kk,
+                    preferred_element_type=jnp.float32) / math.sqrt(D)
+                if cfg.pos_emb == "alibi":
+                    dist = (positions[:, None, None]
+                            - key_pos[None, None, :]).astype(jnp.float32)
+                    slopes = _alibi_slopes(NH)
+                    if cfg.alibi_scaled:   # falcon: (qk+alibi)*inv_norm
+                        slopes = slopes / math.sqrt(D)
+                    s = s - slopes[None, :, None] * jnp.maximum(
+                        dist, 0.0)
+                mask = key_pos[None, None, :] <= positions[:, None, None]
+                if win is not None:
+                    w_eff = jnp.where(win > 0, win, max_kv)
+                    mask &= (key_pos[None, None, :]
+                             > positions[:, None, None] - w_eff)
+                elif cfg.sliding_window is not None:
+                    mask &= (key_pos[None, None, :]
+                             > positions[:, None, None] - cfg.sliding_window)
+                s = jnp.where(mask, s, -1e30)
+                p = jax.nn.softmax(s, axis=-1)
+                attn = jnp.einsum("bnm,bmnd->bnd", p.astype(dt),
+                                  vv).reshape(B, NH * D)
+            attn_out = _dense(attn, lp["wo"], lp.get("bo"))
+            if has_lora:
+                from ...ops.lora_matmul import lora_delta
+                attn_out = attn_out + lora_delta(
+                    attn, la["a"], la["b"],
+                    jnp.asarray(adapter_ids, jnp.int32)).astype(dt)
         if cfg.parallel_residual:
             x = x + attn_out + _mlp_delta(cfg, x, lp)
         elif cfg.post_norm:

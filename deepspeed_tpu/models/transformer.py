@@ -856,51 +856,52 @@ def _layer(cfg: TransformerConfig, x, lp, positions, window=None,
 
     # -- attention --
     x_in = x
-    # post_norm (OPT-350m): no norm before the sublayer; the block norms
-    # move to after each residual add below
-    h = x if cfg.post_norm else _norm(x, lp["attn_norm_scale"],
-                                      lp.get("attn_norm_bias"), cfg.norm,
-                                      cfg.norm_eps)
-    # proj tags: residuals for the save_attn_proj* remat policies (identity
-    # under every other policy) — the remat backward then recomputes only
-    # norm/rope, not the q/k/v matmuls
-    from ..runtime.activation_checkpointing import proj_checkpoint_name
-    q = proj_checkpoint_name(dense(h, lp["wq"], lp.get("bq"))).reshape(
-        B, S, NH, D)
-    k = proj_checkpoint_name(dense(h, lp["wk"], lp.get("bk"))).reshape(
-        B, S, NKV, D)
-    v = proj_checkpoint_name(dense(h, lp["wv"], lp.get("bv"))).reshape(
-        B, S, NKV, D)
-    if cfg.pos_emb == "rope":
-        q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
-        k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
+    with jax.named_scope("attention"):
+        # post_norm (OPT-350m): no norm before the sublayer; the block norms
+        # move to after each residual add below
+        h = x if cfg.post_norm else _norm(x, lp["attn_norm_scale"],
+                                          lp.get("attn_norm_bias"), cfg.norm,
+                                          cfg.norm_eps)
+        # proj tags: residuals for the save_attn_proj* remat policies (identity
+        # under every other policy) — the remat backward then recomputes only
+        # norm/rope, not the q/k/v matmuls
+        from ..runtime.activation_checkpointing import proj_checkpoint_name
+        q = proj_checkpoint_name(dense(h, lp["wq"], lp.get("bq"))).reshape(
+            B, S, NH, D)
+        k = proj_checkpoint_name(dense(h, lp["wk"], lp.get("bk"))).reshape(
+            B, S, NKV, D)
+        v = proj_checkpoint_name(dense(h, lp["wv"], lp.get("bv"))).reshape(
+            B, S, NKV, D)
+        if cfg.pos_emb == "rope":
+            q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
+            k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
 
-    if cfg.sp_axis is not None:
-        if cfg.sp_mode == "ring":
-            from ..parallel.ring_attention import ring_attention
-            attn = ring_attention(q, k, v, axis_name=cfg.sp_axis)
+        if cfg.sp_axis is not None:
+            if cfg.sp_mode == "ring":
+                from ..parallel.ring_attention import ring_attention
+                attn = ring_attention(q, k, v, axis_name=cfg.sp_axis)
+            else:
+                # Ulysses all-to-all leaves each device with the FULL sequence
+                # for a head subset, so position-based masks (incl. the traced
+                # per-layer window) apply unchanged inside the wrapper
+                from ..parallel.ulysses import ulysses_attention
+                attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
+                                         attn_fn=partial(_attention, cfg=cfg,
+                                                         window=window))
+            # ring/ulysses run under shard_map where the flash custom_vjp's
+            # internal tags are not visible to the outer remat policy — tag
+            # the gathered output here so save_attn* at least saves it (their
+            # custom-vjp residuals still recompute; the single-path flash
+            # kernel is the fully-saved case)
+            from ..runtime.activation_checkpointing import attn_checkpoint_name
+            attn = attn_checkpoint_name(attn)
         else:
-            # Ulysses all-to-all leaves each device with the FULL sequence
-            # for a head subset, so position-based masks (incl. the traced
-            # per-layer window) apply unchanged inside the wrapper
-            from ..parallel.ulysses import ulysses_attention
-            attn = ulysses_attention(q, k, v, axis_name=cfg.sp_axis,
-                                     attn_fn=partial(_attention, cfg=cfg,
-                                                     window=window))
-        # ring/ulysses run under shard_map where the flash custom_vjp's
-        # internal tags are not visible to the outer remat policy — tag
-        # the gathered output here so save_attn* at least saves it (their
-        # custom-vjp residuals still recompute; the single-path flash
-        # kernel is the fully-saved case)
-        from ..runtime.activation_checkpointing import attn_checkpoint_name
-        attn = attn_checkpoint_name(attn)
-    else:
-        attn = _attention(q, k, v, cfg, window=window)
-    attn = attn.reshape(B, S, NH * D)
-    # single-path attention tags its own residuals (ops/flash_attention.py
-    # _fwd_res tags out+lse; ops/attention.py tags the jnp output) — a
-    # second tag on the reshaped copy would double-save under save_attn*
-    attn_out = proj_checkpoint_name(dense(attn, lp["wo"], lp.get("bo")))
+            attn = _attention(q, k, v, cfg, window=window)
+        attn = attn.reshape(B, S, NH * D)
+        # single-path attention tags its own residuals (ops/flash_attention.py
+        # _fwd_res tags out+lse; ops/attention.py tags the jnp output) — a
+        # second tag on the reshaped copy would double-save under save_attn*
+        attn_out = proj_checkpoint_name(dense(attn, lp["wo"], lp.get("bo")))
 
     # layer-boundary residual: the save/offload/partition remat policies key
     # off this tag (runtime/activation_checkpointing — maybe identity)
@@ -910,9 +911,11 @@ def _layer(cfg: TransformerConfig, x, lp, positions, window=None,
         # falcon/gpt-neox/phi block: attn and mlp both read the layer input;
         # one residual add at the end (reference: falcon/neox policies in
         # module_inject/containers)
-        h2 = _norm(x_in, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"),
-                   cfg.norm, cfg.norm_eps)
-        x = x_in + attn_out + _mlp_block(cfg, lp, h2, S)
+        with jax.named_scope("mlp"):
+            h2 = _norm(x_in, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"),
+                       cfg.norm, cfg.norm_eps)
+            mlp_out = _mlp_block(cfg, lp, h2, S)
+        x = x_in + attn_out + mlp_out
         return maybe_checkpoint_name(x), jnp.zeros((), jnp.float32)
 
     x = x_in + attn_out
@@ -922,38 +925,39 @@ def _layer(cfg: TransformerConfig, x, lp, positions, window=None,
     x = maybe_checkpoint_name(x)
 
     # -- mlp --
-    h = x if cfg.post_norm else _norm(x, lp["mlp_norm_scale"],
-                                      lp.get("mlp_norm_bias"), cfg.norm,
-                                      cfg.norm_eps)
-    if cfg.moe_experts > 1:
-        from ..moe.sharded import moe_layer
-        moe_params = {"gate": lp["moe_gate"], "w_up": lp["moe_w_up"],
-                      "w_down": lp["moe_w_down"]}
-        if cfg.activation == "swiglu":
-            moe_params["w_gate_proj"] = lp["moe_w_gate_proj"]
-        mlp_out, l_aux = moe_layer(
-            moe_params, h, top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor,
-            min_capacity=cfg.moe_min_capacity, activation=cfg.activation,
-            drop_tokens=cfg.moe_drop_tokens,
-            norm_topk=cfg.moe_norm_topk_prob,
-            dispatch=cfg.moe_dispatch,
-            dispatch_bits=cfg.moe_dispatch_bits)
-        if cfg.moe_shared_expert_ffn:
-            mlp_out = mlp_out + _shared_expert(cfg, lp, h)
-        if dense_flag is not None:
-            # dense-interleaved layer: both branches computed (collective-
-            # safe under EP sharding), the flag selects; a dense layer
-            # contributes no router aux
-            df = (dense_flag > 0)
-            mlp_out = jnp.where(df, _mlp_block(cfg, lp, h, S), mlp_out)
-            l_aux = jnp.where(df, 0.0, l_aux)
-        return x + mlp_out, l_aux
-    x = x + _mlp_block(cfg, lp, h, S)
-    if cfg.post_norm:
-        x = _norm(x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"),
-                  cfg.norm, cfg.norm_eps)
-    return x, jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        h = x if cfg.post_norm else _norm(x, lp["mlp_norm_scale"],
+                                          lp.get("mlp_norm_bias"), cfg.norm,
+                                          cfg.norm_eps)
+        if cfg.moe_experts > 1:
+            from ..moe.sharded import moe_layer
+            moe_params = {"gate": lp["moe_gate"], "w_up": lp["moe_w_up"],
+                          "w_down": lp["moe_w_down"]}
+            if cfg.activation == "swiglu":
+                moe_params["w_gate_proj"] = lp["moe_w_gate_proj"]
+            mlp_out, l_aux = moe_layer(
+                moe_params, h, top_k=cfg.moe_top_k,
+                capacity_factor=cfg.moe_capacity_factor,
+                min_capacity=cfg.moe_min_capacity, activation=cfg.activation,
+                drop_tokens=cfg.moe_drop_tokens,
+                norm_topk=cfg.moe_norm_topk_prob,
+                dispatch=cfg.moe_dispatch,
+                dispatch_bits=cfg.moe_dispatch_bits)
+            if cfg.moe_shared_expert_ffn:
+                mlp_out = mlp_out + _shared_expert(cfg, lp, h)
+            if dense_flag is not None:
+                # dense-interleaved layer: both branches computed (collective-
+                # safe under EP sharding), the flag selects; a dense layer
+                # contributes no router aux
+                df = (dense_flag > 0)
+                mlp_out = jnp.where(df, _mlp_block(cfg, lp, h, S), mlp_out)
+                l_aux = jnp.where(df, 0.0, l_aux)
+            return x + mlp_out, l_aux
+        x = x + _mlp_block(cfg, lp, h, S)
+        if cfg.post_norm:
+            x = _norm(x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"),
+                      cfg.norm, cfg.norm_eps)
+        return x, jnp.zeros((), jnp.float32)
 
 
 def _shared_expert(cfg: TransformerConfig, lp, h):
@@ -1156,12 +1160,16 @@ def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
     dt = cfg.dtype
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    x = _embed_in(cfg, params, input_ids, dt)
-    if cfg.pos_emb == "learned":
-        x = x + jnp.take(params["pos_embed"], positions, axis=0).astype(dt)
-    if cfg.embed_norm:
-        x = _norm(x, params["embed_norm_scale"], params["embed_norm_bias"],
-                  "layernorm", cfg.norm_eps)
+    # the scopes (embed, attention and mlp in `_layer`, lm_head, loss)
+    # name the layers' device work in a profiler trace: metadata only
+    with jax.named_scope("embed"):
+        x = _embed_in(cfg, params, input_ids, dt)
+        if cfg.pos_emb == "learned":
+            x = x + jnp.take(params["pos_embed"], positions,
+                             axis=0).astype(dt)
+        if cfg.embed_norm:
+            x = _norm(x, params["embed_norm_scale"],
+                      params["embed_norm_bias"], "layernorm", cfg.norm_eps)
 
     layer_fn = partial(_layer, cfg)
     if cfg.remat:
@@ -1200,17 +1208,18 @@ def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
             schedule=cfg.pp_schedule)
     else:
         x, moe_aux = stage(stack, x, positions)
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm_scale"],
-                  params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
-    if return_hidden:
-        return x, moe_aux
-    x = _head_hidden(params, x, dt)
-    head = _lm_head(params)
-    logits = jnp.einsum("bsh,hv->bsv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)
-    if "lm_head_bias" in params:
-        logits = logits + params["lm_head_bias"]
+    with jax.named_scope("lm_head"):
+        if cfg.final_norm:
+            x = _norm(x, params["final_norm_scale"],
+                      params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
+        if return_hidden:
+            return x, moe_aux
+        x = _head_hidden(params, x, dt)
+        head = _lm_head(params)
+        logits = jnp.einsum("bsh,hv->bsv", x, head.astype(dt),
+                            preferred_element_type=jnp.float32)
+        if "lm_head_bias" in params:
+            logits = logits + params["lm_head_bias"]
     return logits, moe_aux
 
 
@@ -1244,19 +1253,24 @@ def _lm_loss(cfg: TransformerConfig, params, batch, rng=None):
         # (reference: TiledFusedLogitsLoss ulysses_sp.py:898)
         from ..sequence.tiled import tiled_fused_logits_loss
         hidden, moe_aux = _forward(cfg, params, inputs, return_hidden=True)
-        loss = tiled_fused_logits_loss(hidden, _lm_head(params), labels,
-                                       shards=cfg.tiled_loss_shards, mask=mask,
-                                       bias=params.get("lm_head_bias"))
+        with jax.named_scope("lm_head"):    # head and loss fused, by tile
+            loss = tiled_fused_logits_loss(
+                hidden, _lm_head(params), labels,
+                shards=cfg.tiled_loss_shards, mask=mask,
+                bias=params.get("lm_head_bias"))
     else:
         logits, moe_aux = _forward(cfg, params, inputs)
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        if mask is not None:
-            maskf = mask.astype(jnp.float32)
-            loss = jnp.sum(nll * maskf) / jnp.maximum(jnp.sum(maskf), 1.0)
-        else:
-            loss = jnp.mean(nll)
+        with jax.named_scope("loss"):
+            logits = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None],
+                                       axis=-1)[..., 0]
+            if mask is not None:
+                maskf = mask.astype(jnp.float32)
+                loss = jnp.sum(nll * maskf) / jnp.maximum(jnp.sum(maskf),
+                                                          1.0)
+            else:
+                loss = jnp.mean(nll)
     aux = {"ppl_log": loss}
     if cfg.moe_experts > 1:
         aux["moe_aux"] = moe_aux

@@ -155,6 +155,7 @@ def evoformer_flash_forward(q, k, v, b1=None, b2=None,
                      jax.ShapeDtypeStruct((BN, H, L), jnp.float32)]
     out = pl.pallas_call(
         kernel,
+        name="evoformer_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -379,6 +380,7 @@ def evoformer_flash_backward(q, k, v, b1, b2, out, do, lse,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, sm_scale=sm_scale,
                           has_b1=has_b1, has_b2=has_b2, num_jk=L // bk),
+        name="evoformer_dq",
         grid=(BN, L // bq),
         in_specs=[
             pl.BlockSpec((1, H, bq, D), lambda bn, iq: (bn, 0, iq, 0)),
@@ -405,6 +407,7 @@ def evoformer_flash_backward(q, k, v, b1, b2, out, do, lse,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, sm_scale=sm_scale,
                           has_b1=has_b1, has_b2=has_b2),
+        name="evoformer_dkv",
         grid=(BN, L // bk, L // bq),
         in_specs=[
             pl.BlockSpec((1, H, bq, D), lambda bn, jk, iq: (bn, 0, iq, 0)),
@@ -443,6 +446,7 @@ def evoformer_flash_backward(q, k, v, b1, b2, out, do, lse,
             functools.partial(_bwd_db2_kernel, bq=bq, bk=bk,
                               sm_scale=sm_scale, has_b1=has_b1,
                               has_b2=True),
+            name="evoformer_db2",
             grid=(B, L // bq, L // bk, N),
             in_specs=[
                 pl.BlockSpec((1, H, bq, D),
@@ -480,6 +484,7 @@ def evoformer_flash_backward(q, k, v, b1, b2, out, do, lse,
             functools.partial(_bwd_db1_kernel, bq=bq, bk=bk,
                               sm_scale=sm_scale, has_b1=True,
                               has_b2=has_b2),
+            name="evoformer_db1",
             grid=(BN, L // bk, L // bq),
             in_specs=[
                 pl.BlockSpec((1, H, bq, D),
@@ -612,6 +617,7 @@ def evoformer_flash_forward_dmajor(q, k, v, b1=None, b2=None,
                      jax.ShapeDtypeStruct((BN, H, L), jnp.float32)]
     out = pl.pallas_call(
         kernel,
+        name="evoformer_fwd_dmajor",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -271,6 +271,7 @@ def _fwd(q, k, v, causal: bool, block_q: int, block_k: int):
         seq_len=S)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, n, i: (b, n, i, 0),
@@ -374,6 +375,7 @@ def _bwd_vjp(causal, block_q, block_k, res, do):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, sm_scale=sm_scale,
                           causal=causal, seq_len=S),
+        name="flash_attention_dq",
         grid=(B, Nq, S // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, n, i: (b, n, i, 0),
@@ -398,6 +400,7 @@ def _bwd_vjp(causal, block_q, block_k, res, do):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, sm_scale=sm_scale,
                           causal=causal, seq_len=S),
+        name="flash_attention_dkv",
         grid=(B, Nq, S // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, S, D), lambda b, n, i: (b, n, 0, 0),

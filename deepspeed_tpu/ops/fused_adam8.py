@@ -181,6 +181,7 @@ def fused_adam8_leaf(g, m_q, m_s, v_q, v_s, p, lr, gscale, c1, c2, *,
         bias_correction=bias_correction)
     outs = pl.pallas_call(
         kernel,
+        name="fused_adam8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -124,6 +124,7 @@ def _pallas_lora_delta(x, lora_a, lora_b, ids, interpret: bool):
         ids = jnp.pad(ids, (0, Sp - S), constant_values=-1)
     out = pl.pallas_call(
         functools.partial(_lora_kernel, num_slots=A),
+        name="lora_matmul",
         grid=(Sp // bm, A),
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),

@@ -207,6 +207,7 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
         kernel_fn = kernel
     return pl.pallas_call(
         kernel_fn,
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, D), q.dtype),
     )(*operands)

@@ -201,6 +201,7 @@ def merged_decode_attention(q, arena_k, arena_v, block_tables, lens,
     kernel_fn = (lambda li_ref, *rest: kernel(*rest)) if layered else kernel
     out = pl.pallas_call(
         kernel_fn,
+        name="paged_merged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, M), q.dtype),
         interpret=interpret,
@@ -399,6 +400,7 @@ def merged_prefill_attention(q, arena_k, arena_v, block_table, pos0, n_valid,
     kernel_fn = (lambda li_ref, *rest: kernel(*rest)) if layered else kernel
     out = pl.pallas_call(
         kernel_fn,
+        name="paged_merged_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_stripes, out_rows, 128), q.dtype),
         interpret=interpret,

@@ -308,6 +308,7 @@ def paged_prefill_attention(q, arena_k, arena_v, block_table, pos0, n_valid,
         kernel_fn = kernel
     out = pl.pallas_call(
         kernel_fn,
+        name="paged_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, NH, D), q.dtype),
     )(*operands)

@@ -148,6 +148,7 @@ def block_sparse_flash_attention(q, k, v, kb_idx, block: int,
                                sm_scale=sm_scale, with_lse=return_lse)
     out = pl.pallas_call(
         kernel,
+        name="sparse_flash_fwd",
         grid_spec=grid_spec,
         out_shape=out_shape,
     )(idx, qb, kb, vb)
@@ -299,6 +300,7 @@ def block_sparse_flash_backward(q, k, v, kb_idx, rev_idx, out, do, lse,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block=block, causal=causal,
                           sm_scale=sm_scale),
+        name="sparse_flash_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nqb, A),
@@ -329,6 +331,7 @@ def block_sparse_flash_backward(q, k, v, kb_idx, rev_idx, out, do, lse,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block=block, causal=causal,
                           sm_scale=sm_scale),
+        name="sparse_flash_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nb, R),

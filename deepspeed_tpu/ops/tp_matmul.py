@@ -117,6 +117,7 @@ def _pallas_matmul(x, w):
     grid = (M // bm, N // bn, nk)
     return pl.pallas_call(
         functools.partial(_mm_kernel, nk=nk),
+        name="tp_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

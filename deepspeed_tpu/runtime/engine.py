@@ -48,6 +48,7 @@ from ..config.config import ConfigError, DeepSpeedTPUConfig
 from ..parallel.mesh import MeshTopology, make_mesh
 from ..utils.device import on_tpu
 from ..utils.logging import log_dist, logger
+from ..utils.spans import span
 from ..utils import tree as tu
 from . import lr_schedules, optimizers
 from .zero.sharding import ZeroShardingRules, param_specs, opt_state_specs, grad_specs
@@ -506,200 +507,205 @@ class TrainEngine:
             g_specs = grad_specs(rules, params)
             o_specs = opt_state_specs(rules, params)
 
-            # ---- gradient accumulation over micro-batches (lax.scan) ----
-            # batch leaves: [gas, micro_global, ...]
-            accum0 = tu.tree_zeros_like(params, gad)
+            # the three scopes name the step's device work in a trace
+            # (docs/OBSERVABILITY.md): metadata only
+            with jax.named_scope("forward_backward"):
+                # ---- gradient accumulation over micro-batches (lax.scan) ----
+                # batch leaves: [gas, micro_global, ...]
+                accum0 = tu.tree_zeros_like(params, gad)
 
-            def body(carry, micro):
-                acc, aux_acc, loss_sum, i = carry
-                k = jax.random.fold_in(rng, i)
-                loss, aux, grads = micro_grads(params, micro, k, state.loss_scale,
-                                               comp_masks, state.step)
-                acc = jax.tree.map(lambda a, g: a + g.astype(gad), acc, grads)
-                aux_acc = jax.tree.map(
-                    lambda a, v: a + v.astype(jnp.float32), aux_acc, aux)
-                return (acc, aux_acc, loss_sum + loss.astype(jnp.float32),
-                        i + 1), loss.astype(jnp.float32)
-
-            if gas > 1 and overlap_micro:
-                # ---- T3 microstep double-buffering (overlap_mode=
-                # "microstep"): microstep 0 is peeled and its RAW grads
-                # ride the scan carry; each iteration issues the PREVIOUS
-                # microstep's reductions FIRST — no data dependency on
-                # this microstep's forward/backward, so XLA's async
-                # collective scheduler can hide them under its compute —
-                # then runs its own fwd/bwd and hands its raw grads to the
-                # next iteration.  The last microstep's reduction runs
-                # after the scan.  Costs one raw-grad tree of carry (the
-                # double buffer); reassociates the accumulation order, so
-                # it is opt-in (the default path stays bit-exact). ----
-                first_micro = jax.tree.map(lambda x: x[0], batch)
-                rest = jax.tree.map(lambda x: x[1:], batch)
-                # the accumulator adds FINISHED grads (already in the
-                # grad layout); pin it there so GSPMD does not reshard
-                # the carry against each iteration's addend
-                accum0 = jax.lax.with_sharding_constraint(
-                    accum0, self._named(g_specs))
-                k0 = jax.random.fold_in(rng, 0)
-                loss0, aux0v, raw0 = micro_grads.raw(
-                    params, first_micro, k0, state.loss_scale, comp_masks,
-                    state.step)
-                aux0 = jax.tree.map(
-                    lambda v: v.astype(jnp.float32), aux0v)
-                loss0 = loss0.astype(jnp.float32)
-
-                def body_overlap(carry, micro):
-                    acc, raw_prev, aux_acc, loss_sum, i = carry
-                    finished = micro_grads.finish(raw_prev)
-                    acc = jax.tree.map(
-                        lambda a, g: a + g.astype(gad), acc, finished)
+                def body(carry, micro):
+                    acc, aux_acc, loss_sum, i = carry
                     k = jax.random.fold_in(rng, i)
-                    loss, aux, raw = micro_grads.raw(
-                        params, micro, k, state.loss_scale, comp_masks,
-                        state.step)
+                    loss, aux, grads = micro_grads(params, micro, k, state.loss_scale,
+                                                   comp_masks, state.step)
+                    acc = jax.tree.map(lambda a, g: a + g.astype(gad), acc, grads)
                     aux_acc = jax.tree.map(
                         lambda a, v: a + v.astype(jnp.float32), aux_acc, aux)
-                    return (acc, raw, aux_acc,
-                            loss_sum + loss.astype(jnp.float32),
+                    return (acc, aux_acc, loss_sum + loss.astype(jnp.float32),
                             i + 1), loss.astype(jnp.float32)
 
-                (acc, raw_last, aux_sum, loss_sum, _), rest_losses = \
-                    jax.lax.scan(
-                        body_overlap,
-                        (accum0, raw0, aux0, loss0,
-                         jnp.ones((), jnp.int32)), rest)
-                grads = jax.tree.map(
-                    lambda a, g: a + g.astype(gad), acc,
-                    micro_grads.finish(raw_last))
-                micro_losses = jnp.concatenate([loss0[None], rest_losses])
-                aux = jax.tree.map(lambda a: a / gas, aux_sum)
-                loss = loss_sum / gas
-            elif gas > 1:
-                # aux accumulates in the carry (constant memory) — its
-                # structure comes from an abstract trace of one micro step
-                first_micro = jax.tree.map(lambda x: x[0], batch)
-                aux0 = aux_zeros(
-                    lambda p, m: micro_grads(p, m, rng, state.loss_scale,
-                                             comp_masks, state.step)[1],
-                    params, first_micro)
-                (grads, aux_sum, loss_sum, _), micro_losses = jax.lax.scan(
-                    body, (accum0, aux0, jnp.zeros((), jnp.float32),
-                           jnp.zeros((), jnp.int32)), batch)
-                aux = jax.tree.map(lambda a: a / gas, aux_sum)
-                loss = loss_sum / gas
-            else:
-                micro = jax.tree.map(lambda x: x[0], batch)
-                loss, aux, g = micro_grads(params, micro, rng, state.loss_scale,
-                                           comp_masks, state.step)
-                grads = jax.tree.map(lambda x: x.astype(gad), g)
-                loss = loss.astype(jnp.float32)
-                micro_losses = loss[None]
+                if gas > 1 and overlap_micro:
+                    # ---- T3 microstep double-buffering (overlap_mode=
+                    # "microstep"): microstep 0 is peeled and its RAW grads
+                    # ride the scan carry; each iteration issues the PREVIOUS
+                    # microstep's reductions FIRST — no data dependency on
+                    # this microstep's forward/backward, so XLA's async
+                    # collective scheduler can hide them under its compute —
+                    # then runs its own fwd/bwd and hands its raw grads to the
+                    # next iteration.  The last microstep's reduction runs
+                    # after the scan.  Costs one raw-grad tree of carry (the
+                    # double buffer); reassociates the accumulation order, so
+                    # it is opt-in (the default path stays bit-exact). ----
+                    first_micro = jax.tree.map(lambda x: x[0], batch)
+                    rest = jax.tree.map(lambda x: x[1:], batch)
+                    # the accumulator adds FINISHED grads (already in the
+                    # grad layout); pin it there so GSPMD does not reshard
+                    # the carry against each iteration's addend
+                    accum0 = jax.lax.with_sharding_constraint(
+                        accum0, self._named(g_specs))
+                    k0 = jax.random.fold_in(rng, 0)
+                    loss0, aux0v, raw0 = micro_grads.raw(
+                        params, first_micro, k0, state.loss_scale, comp_masks,
+                        state.step)
+                    aux0 = jax.tree.map(
+                        lambda v: v.astype(jnp.float32), aux0v)
+                    loss0 = loss0.astype(jnp.float32)
 
-            # ---- unscale + average over accumulation (reference:
-            # _backward_prologue scale_wrt_gas engine.py:2199).  When the
-            # optimizer supports grad_scale, the unscale AND the clip
-            # multiplies FOLD into its update pass as one scalar — the
-            # global norm is homogeneous (norm(raw)*inv == norm(unscaled))
-            # so nothing needs the rewritten grads, and two full
-            # read+write passes over the grad tree (~12 GB at the 1.3B
-            # bench) disappear from the step tail ----
-            # fp16 keeps the unscale BEFORE the cross-device reduction:
-            # folding would sum still-loss-scaled grads over dp, costing
-            # log2(dp_size) bits of fp16 headroom (overflow -> permanent
-            # step-skipping under a static scale).  bf16/fp32 have the
-            # exponent range to reduce first.
-            inv = 1.0 / (state.loss_scale * gas)
-            fold_scale = getattr(opt, "supports_grad_scale", False) \
-                and self.compression is None and not fp16
-            if not fold_scale:
-                grads = jax.tree.map(lambda g: g * inv, grads)
+                    def body_overlap(carry, micro):
+                        acc, raw_prev, aux_acc, loss_sum, i = carry
+                        finished = micro_grads.finish(raw_prev)
+                        acc = jax.tree.map(
+                            lambda a, g: a + g.astype(gad), acc, finished)
+                        k = jax.random.fold_in(rng, i)
+                        loss, aux, raw = micro_grads.raw(
+                            params, micro, k, state.loss_scale, comp_masks,
+                            state.step)
+                        aux_acc = jax.tree.map(
+                            lambda a, v: a + v.astype(jnp.float32), aux_acc, aux)
+                        return (acc, raw, aux_acc,
+                                loss_sum + loss.astype(jnp.float32),
+                                i + 1), loss.astype(jnp.float32)
 
-            # ---- ZeRO gradient sharding constraint: stage>=2 this forces a
-            # ReduceScatter; stage<2 an AllReduce (sharding.py docstring) ----
-            grads = jax.lax.with_sharding_constraint(grads, self._named(g_specs))
+                    (acc, raw_last, aux_sum, loss_sum, _), rest_losses = \
+                        jax.lax.scan(
+                            body_overlap,
+                            (accum0, raw0, aux0, loss0,
+                             jnp.ones((), jnp.int32)), rest)
+                    grads = jax.tree.map(
+                        lambda a, g: a + g.astype(gad), acc,
+                        micro_grads.finish(raw_last))
+                    micro_losses = jnp.concatenate([loss0[None], rest_losses])
+                    aux = jax.tree.map(lambda a: a / gas, aux_sum)
+                    loss = loss_sum / gas
+                elif gas > 1:
+                    # aux accumulates in the carry (constant memory) — its
+                    # structure comes from an abstract trace of one micro step
+                    first_micro = jax.tree.map(lambda x: x[0], batch)
+                    aux0 = aux_zeros(
+                        lambda p, m: micro_grads(p, m, rng, state.loss_scale,
+                                                 comp_masks, state.step)[1],
+                        params, first_micro)
+                    (grads, aux_sum, loss_sum, _), micro_losses = jax.lax.scan(
+                        body, (accum0, aux0, jnp.zeros((), jnp.float32),
+                               jnp.zeros((), jnp.int32)), batch)
+                    aux = jax.tree.map(lambda a: a / gas, aux_sum)
+                    loss = loss_sum / gas
+                else:
+                    micro = jax.tree.map(lambda x: x[0], batch)
+                    loss, aux, g = micro_grads(params, micro, rng, state.loss_scale,
+                                               comp_masks, state.step)
+                    grads = jax.tree.map(lambda x: x.astype(gad), g)
+                    loss = loss.astype(jnp.float32)
+                    micro_losses = loss[None]
 
-            # ---- overflow check (reference: CheckOverflow + DynamicLossScaler
-            # fp16/loss_scaler.py:93). bf16/fp32 skip the check — at TRACE
-            # time, not with a constant-True select: a traced
-            # where(finite, new, old) over master + every moment is an
-            # extra full read+select+write of ~9 GB of optimizer state at
-            # the 774M bench (XLA cannot fold a select on a runtime
-            # scalar), measured in the step-vs-grad decomposition gap ----
-            if fp16:
-                finite = tu.tree_finite(grads)
-            else:
-                finite = jnp.asarray(True)
+            with jax.named_scope("grad_reduce"):
+                # ---- unscale + average over accumulation (reference:
+                # _backward_prologue scale_wrt_gas engine.py:2199).  When the
+                # optimizer supports grad_scale, the unscale AND the clip
+                # multiplies FOLD into its update pass as one scalar — the
+                # global norm is homogeneous (norm(raw)*inv == norm(unscaled))
+                # so nothing needs the rewritten grads, and two full
+                # read+write passes over the grad tree (~12 GB at the 1.3B
+                # bench) disappear from the step tail ----
+                # fp16 keeps the unscale BEFORE the cross-device reduction:
+                # folding would sum still-loss-scaled grads over dp, costing
+                # log2(dp_size) bits of fp16 headroom (overflow -> permanent
+                # step-skipping under a static scale).  bf16/fp32 have the
+                # exponent range to reduce first.
+                inv = 1.0 / (state.loss_scale * gas)
+                fold_scale = getattr(opt, "supports_grad_scale", False) \
+                    and self.compression is None and not fp16
+                if not fold_scale:
+                    grads = jax.tree.map(lambda g: g * inv, grads)
 
-            # ---- grad clip by global norm (engine config gradient_clipping;
-            # reference: runtime/utils.py clip_grad_norm_) ----
-            if fold_scale:
-                gnorm = tu.global_norm(grads) * inv
-                gscale = inv
-                if clip and clip > 0:
-                    gscale = inv * jnp.minimum(1.0, clip / (gnorm + 1e-6))
-            else:
-                gnorm = tu.global_norm(grads)
-                gscale = None
-                if clip and clip > 0:
-                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                    grads = jax.tree.map(lambda g: g * scale, grads)
+                # ---- ZeRO gradient sharding constraint: stage>=2 this forces a
+                # ReduceScatter; stage<2 an AllReduce (sharding.py docstring) ----
+                grads = jax.lax.with_sharding_constraint(grads, self._named(g_specs))
 
-            # ---- optimizer update on fp32 master (BF16_Optimizer semantics,
-            # runtime/bf16_optimizer.py:274) ----
-            master = state.master if state.master is not None else params
-            step_num = state.step + 1
-            lr = lr_fn(state.step)
-            # fused single-pass update (Pallas; optimizers.update_fused)
-            # emits the compute-dtype params from the same VMEM pass —
-            # TPU only, and only when a cast is wanted (master mode)
-            use_fused = (opt.update_fused is not None
-                         and state.master is not None
-                         and on_tpu())
-            new_params_cast = None
-            fold_kw = {"grad_scale": gscale} if fold_scale else {}
-            if use_fused:
-                new_master, new_params_cast, new_opt = opt.update_fused(
-                    grads, state.opt_state, master, lr,
-                    step_num.astype(jnp.float32), self.compute_dtype,
-                    **fold_kw)
-            else:
-                new_master, new_opt = opt.update(
-                    grads, state.opt_state, master, lr,
-                    step_num.astype(jnp.float32), **fold_kw)
-            new_master = jax.lax.with_sharding_constraint(new_master, self._named(o_specs))
-            # the moments (and their scale trees) come back placed as
-            # _init_state placed them: left to propagation, the replicated
-            # scales returned sharded and the second step recompiled
-            new_opt = jax.lax.with_sharding_constraint(
-                new_opt, self._opt_tree_shardings(master, o_specs))
+                # ---- overflow check (reference: CheckOverflow + DynamicLossScaler
+                # fp16/loss_scaler.py:93). bf16/fp32 skip the check — at TRACE
+                # time, not with a constant-True select: a traced
+                # where(finite, new, old) over master + every moment is an
+                # extra full read+select+write of ~9 GB of optimizer state at
+                # the 774M bench (XLA cannot fold a select on a runtime
+                # scalar), measured in the step-vs-grad decomposition gap ----
+                if fp16:
+                    finite = tu.tree_finite(grads)
+                else:
+                    finite = jnp.asarray(True)
 
-            # skip update on overflow (reference: step skipping engine.py:2400)
-            if fp16:
-                new_master = tu.tree_where(finite, new_master, master)
-                new_opt = {k: tu.tree_where(finite, v, state.opt_state[k])
-                           for k, v in new_opt.items()}
-                if new_params_cast is not None:
-                    # params IS cast(master) from the previous step — no
-                    # per-step recast just to feed the overflow branch
-                    new_params_cast = tu.tree_where(
-                        finite, new_params_cast, params)
+                # ---- grad clip by global norm (engine config gradient_clipping;
+                # reference: runtime/utils.py clip_grad_norm_) ----
+                if fold_scale:
+                    gnorm = tu.global_norm(grads) * inv
+                    gscale = inv
+                    if clip and clip > 0:
+                        gscale = inv * jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                else:
+                    gnorm = tu.global_norm(grads)
+                    gscale = None
+                    if clip and clip > 0:
+                        scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                        grads = jax.tree.map(lambda g: g * scale, grads)
 
-            if state.master is not None:
-                p_specs = param_specs(rules, params)
-                cast = (new_params_cast if new_params_cast is not None
-                        else tu.tree_cast(new_master, self.compute_dtype))
-                new_params = jax.lax.with_sharding_constraint(
-                    cast, self._named(p_specs))
-                new_state_master = new_master
-            else:
-                # no master copy: params ARE the optimizer's target, but
-                # their resident layout must stay param_specs — under hpZ
-                # o_specs span dp×fsdp while the param gather domain is
-                # fsdp-only, and inheriting the opt layout here would
-                # silently widen every later gather to the full world
-                new_params = jax.lax.with_sharding_constraint(
-                    new_master, self._named(param_specs(rules, params)))
-                new_state_master = None
+            with jax.named_scope("optimizer"):
+                # ---- optimizer update on fp32 master (BF16_Optimizer semantics,
+                # runtime/bf16_optimizer.py:274) ----
+                master = state.master if state.master is not None else params
+                step_num = state.step + 1
+                lr = lr_fn(state.step)
+                # fused single-pass update (Pallas; optimizers.update_fused)
+                # emits the compute-dtype params from the same VMEM pass —
+                # TPU only, and only when a cast is wanted (master mode)
+                use_fused = (opt.update_fused is not None
+                             and state.master is not None
+                             and on_tpu())
+                new_params_cast = None
+                fold_kw = {"grad_scale": gscale} if fold_scale else {}
+                if use_fused:
+                    new_master, new_params_cast, new_opt = opt.update_fused(
+                        grads, state.opt_state, master, lr,
+                        step_num.astype(jnp.float32), self.compute_dtype,
+                        **fold_kw)
+                else:
+                    new_master, new_opt = opt.update(
+                        grads, state.opt_state, master, lr,
+                        step_num.astype(jnp.float32), **fold_kw)
+                new_master = jax.lax.with_sharding_constraint(new_master, self._named(o_specs))
+                # the moments (and their scale trees) come back placed as
+                # _init_state placed them: left to propagation, the replicated
+                # scales returned sharded and the second step recompiled
+                new_opt = jax.lax.with_sharding_constraint(
+                    new_opt, self._opt_tree_shardings(master, o_specs))
+
+                # skip update on overflow (reference: step skipping engine.py:2400)
+                if fp16:
+                    new_master = tu.tree_where(finite, new_master, master)
+                    new_opt = {k: tu.tree_where(finite, v, state.opt_state[k])
+                               for k, v in new_opt.items()}
+                    if new_params_cast is not None:
+                        # params IS cast(master) from the previous step — no
+                        # per-step recast just to feed the overflow branch
+                        new_params_cast = tu.tree_where(
+                            finite, new_params_cast, params)
+
+                if state.master is not None:
+                    p_specs = param_specs(rules, params)
+                    cast = (new_params_cast if new_params_cast is not None
+                            else tu.tree_cast(new_master, self.compute_dtype))
+                    new_params = jax.lax.with_sharding_constraint(
+                        cast, self._named(p_specs))
+                    new_state_master = new_master
+                else:
+                    # no master copy: params ARE the optimizer's target, but
+                    # their resident layout must stay param_specs — under hpZ
+                    # o_specs span dp×fsdp while the param gather domain is
+                    # fsdp-only, and inheriting the opt layout here would
+                    # silently widen every later gather to the full world
+                    new_params = jax.lax.with_sharding_constraint(
+                        new_master, self._named(param_specs(rules, params)))
+                    new_state_master = None
 
             # ---- dynamic loss scale update ----
             if fp16 and pc.loss_scale == 0:
@@ -810,20 +816,25 @@ class TrainEngine:
                 "train_batch() called inside no_sync(): the fused step "
                 "always syncs gradients at the boundary; no_sync only "
                 "affects the forward/backward/step compat loop")
-        if self.store_gradients != self._built_with_grads:
-            self._train_step = self._build_train_step()
-        sharded = self._shard_batch(batch)
-        comp_masks = {}
-        if self.compression is not None:
-            comp_masks = dict(
-                self.compression.step(self.state.params, self.global_steps).masks)
-        self.state, metrics = self._train_step(self.state, sharded,
-                                               self.next_rng(), comp_masks)
-        if self.store_gradients:
-            self._last_grads = metrics.pop("grads")
-        else:
-            self._last_grads = None  # never serve stale grads
-        self._finish_step(metrics)
+        # host spans in the profiler's trace (utils/spans.py): the step is
+        # dispatched, not awaited, so `train.step` is the host's share
+        with span("train.step", step=self.global_steps + 1):
+            if self.store_gradients != self._built_with_grads:
+                self._train_step = self._build_train_step()
+            with span("train.shard_batch"):
+                sharded = self._shard_batch(batch)
+            comp_masks = {}
+            if self.compression is not None:
+                comp_masks = dict(self.compression.step(
+                    self.state.params, self.global_steps).masks)
+            with span("train.dispatch"):
+                self.state, metrics = self._train_step(
+                    self.state, sharded, self.next_rng(), comp_masks)
+            if self.store_gradients:
+                self._last_grads = metrics.pop("grads")
+            else:
+                self._last_grads = None  # never serve stale grads
+            self._finish_step(metrics)
         return metrics
 
     def _finish_step(self, metrics: Dict[str, Any]) -> None:

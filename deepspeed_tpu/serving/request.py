@@ -75,7 +75,7 @@ class Request:
     uid: int
     prompt: np.ndarray                     # int32 prompt token ids
     max_new_tokens: int
-    arrival_time: float                    # clock() at submit
+    arrival_time: float                    # clock() at submit, or `due=`
     deadline: Optional[float] = None       # absolute clock() bound, or None
     priority: int = 0                      # lower admits first; FIFO within
     eos_token_id: Optional[int] = None

@@ -47,11 +47,48 @@ import numpy as np
 
 from ..config.config import ServingConfig
 from ..utils.logging import logger
+from ..utils.spans import span
 from .request import Request, RequestState
 from .scheduler import (AdmissionError, ContinuousBatchingScheduler)
 from .telemetry import ServingTelemetry
 
 __all__ = ["ServeLoop", "ThreadedServer"]
+
+
+class _StepPhases:
+    """The phases of one serve step follow one another on one thread, so
+    each boundary is written once, as one `enter(name)`: it ends the open
+    phase's profiler span and begins the next (always; utils/spans.py),
+    and with the step timeline on (`clock` given) it stamps the serve
+    clock there too (`at[name]`), which is all `StepTimeline` is fed
+    from.  `now` hands over a read the step makes anyway, so with the
+    timeline off the serve clock is touched exactly as without this.
+    Leaving the `with` ends the open phase, also when the step raises."""
+
+    __slots__ = ("_clock", "_open", "at")
+
+    def __init__(self, clock: Optional[Callable[[], float]]):
+        self._clock = clock
+        self._open = None
+        self.at: Dict[str, float] = {}
+
+    def enter(self, name: str, now: Optional[float] = None, **attrs):
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+        if self._clock is not None:
+            self.at[name] = self._clock() if now is None else now
+        self._open = span(name, **attrs)
+        self._open.__enter__()
+        return self._open
+
+    def __enter__(self) -> "_StepPhases":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._open is not None:
+            self._open.__exit__(*exc)
+            self._open = None
+        return False
 
 
 class ServeLoop:
@@ -460,10 +497,17 @@ class ServeLoop:
                temperature: float = 0.0, top_k: int = 0,
                seed: Optional[int] = None, tenant: str = "default",
                adapter_id: Optional[str] = None,
-               response_format=None) -> Request:
+               response_format=None,
+               due: Optional[float] = None) -> Request:
         """Queue one request.  Raises `AdmissionError` for a request the
         engine can never serve and `QueueFullError` when the bounded queue
         is full (backpressure — nothing is silently dropped).
+
+        `due` (serve-clock seconds) is when the caller says the request
+        arrived, where that is earlier than this call — a load generator
+        that was itself held up, a client whose previous request just
+        finished: `arrival_time`, and with it the `queued` span, the
+        queue wait, TTFT and the deadline, count from there.  None = now.
 
         `seed` pins the request's stochastic sampling to the counter-
         based stream (serving/streaming.seeded_sample) — required for
@@ -624,10 +668,12 @@ class ServeLoop:
                 f"{temperature}) needs a sampling seed for verifiable "
                 f"exactly-once replay: pass seed= or leave "
                 f"StreamingConfig.auto_seed on")
+        arrival = now if due is None else due
         req = Request(
             uid=self._next_uid, prompt=prompt,
-            max_new_tokens=max_new_tokens, arrival_time=now,
-            deadline=(now + timeout_s) if timeout_s is not None else None,
+            max_new_tokens=max_new_tokens, arrival_time=arrival,
+            deadline=(arrival + timeout_s) if timeout_s is not None
+            else None,
             priority=priority, eos_token_id=eos_token_id,
             temperature=temperature, top_k=top_k, seed=seed,
             tenant=tenant, adapter_id=adapter_id,
@@ -901,12 +947,21 @@ class ServeLoop:
         return out
 
     def _step(self) -> List[Request]:
+        # `step` is the number the timeline row of this step carries
+        # (`telemetry.steps` after its `record_step`): the join between
+        # the profiler's clock and the serve clock
+        phases = _StepPhases(
+            self.clock if self._timeline is not None else None)
+        with span("serve.step", step=self.telemetry.steps + 1), phases:
+            return self._step_phases(phases)
+
+    def _step_phases(self, phases: _StepPhases) -> List[Request]:
         now = self.clock()
-        # step timeline (observe-only): phase boundary reads happen only
-        # with the profiler on, so the off path touches the clock exactly
-        # as before
+        # step timeline (observe-only): the phase boundaries read the
+        # serve clock only with the timeline on (`_StepPhases`), so the
+        # off path touches the clock exactly as before
         timeline = self._timeline
-        t_start = now if timeline is not None else 0.0
+        phases.enter("serve.finalize", now=now)
         # promote-wall attribution (host KV tier): promotions run inside
         # the admission phase, so the timeline carries their wall as its
         # own sub-phase — real profiler seconds from the tier's
@@ -953,7 +1008,7 @@ class ServeLoop:
             # every expiry was still flushed (attempted) and reported;
             # the failure itself surfaces as this step's health signal
             raise flush_err
-        t_finalize = self.clock() if timeline is not None else 0.0
+        admission = phases.enter("serve.admission")
 
         # 2) admission: fold queued requests into free engine slots,
         #    gated on the KV blocks their WHOLE lifetime needs (minus
@@ -1142,7 +1197,10 @@ class ServeLoop:
         #    retry nor allowed to consume the fleet router's coverage
         #    expectation for an admission that never stuck.
         try:
-            t_admission = self.clock() if timeline is not None else 0.0
+            admission.set_metadata(admitted=len(admitted))
+            # the engine call, and the bookkeeping of what it admitted
+            # up to the clock's re-read below: the timeline's "prefill"
+            phases.enter("serve.engine")
             # prefill-chunk span attribution reads the clock only when
             # some live request is actually traced (admitted ones
             # already joined the active set above)
@@ -1219,6 +1277,8 @@ class ServeLoop:
         # finish stamps must charge it to THIS step's requests, not the
         # next step's bookkeeping
         now = self.clock()
+        # host work on what the engine returned: the timeline's "decode"
+        phases.enter("serve.sample", now=now, rows=len(out))
 
         # 4) measured per-step budget accounting: attribute each live
         #    sequence's progress to prefill or decode work.  (Burst-mode
@@ -1281,6 +1341,7 @@ class ServeLoop:
                     # staging generate_batch uses)
                     self.engine.state.seqs[uid].generated.append(tok)
 
+        phases.enter("serve.bookkeep")
         # census-driven expert rebalance: every Nth step, drain the
         # router census the decode programs accumulated (one tiny d2h),
         # fold it into the pool's LRU/demand ranking, and promote the
@@ -1307,21 +1368,23 @@ class ServeLoop:
             expert_pool=(self._expert_pool.stats()
                          if self._expert_pool is not None else None))
         if timeline is not None:
-            t_end = self.clock()
+            at = phases.at
             timeline.record(
                 self.telemetry.steps,
-                {"finalize": t_finalize - t_start,
-                 "admission": t_admission - t_finalize,
+                {"finalize": at["serve.admission"] - at["serve.finalize"],
+                 "admission": at["serve.engine"] - at["serve.admission"],
                  # host-tier promotions ran INSIDE the admission window
                  # above; this is their share of it (tier perf-counter
                  # wall — 0.0 without a tier)
                  "promote": (self._tier.promote_wall_s - promote_w0
                              if self._tier is not None else 0.0),
-                 # the engine's put/step call dominates this window; the
-                 # cheap host bookkeeping between it and the decode
-                 # phase rides along
-                 "prefill": now - t_admission,
-                 "decode": t_end - now},
+                 # the engine's put/step call dominates this window
+                 # (on the per-step path that is staging, prefill, the
+                 # decode program and the logits fetch); the cheap host
+                 # bookkeeping between it and the sampling rides along
+                 "prefill": at["serve.sample"] - at["serve.engine"],
+                 # host sampling (per-step path) or the compiled bursts
+                 "decode": at["serve.bookkeep"] - at["serve.sample"]},
                 admitted=len(admitted), finished=len(finished),
                 prefill_tokens=prefill_toks, decode_tokens=decode_toks,
                 queue_depth=self.scheduler.queue_depth,

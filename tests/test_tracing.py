@@ -563,3 +563,178 @@ def test_schema_covers_every_tag_literal_in_the_source():
             if not ok:
                 bad.append(f"{path.relative_to(root)}: {s!r}")
     assert bad == [], bad
+
+
+# -- program spans in the profiler's own trace (utils/spans.py) -------------
+def _program_spans(trace_dir):
+    """Every `serve.*`/`engine.*`/`train.*` event of the newest xplane
+    under `trace_dir`, per host line: [(start, end, name, stats)]."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    assert files, "the profiler session wrote no xplane file"
+    lines = []
+    for plane in ProfileData.from_file(files[-1]).planes:
+        for line in plane.lines:
+            evs = [(int(e.start_ns), int(e.start_ns) + int(e.duration_ns),
+                    e.name.split("#", 1)[0], dict(e.stats))
+                   for e in line.events
+                   if e.name.startswith(("serve.", "engine.", "train."))]
+            if evs:
+                lines.append(sorted(evs, key=lambda t: (t[0], -t[1])))
+    return lines
+
+
+def _traced(tmp_path):
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return jax.profiler.trace(str(tmp_path), profiler_options=options)
+
+
+def _inside(ev, outer):
+    return any(o[0] <= ev[0] and ev[1] <= o[1] for o in outer)
+
+
+def test_span_catalogue_holds_the_source_to_one_primitive():
+    """Every span name the package opens is in `SPAN_NAMES`, every host
+    span goes through `utils.spans.span`, and the nvtx-style leftovers
+    are gone."""
+    import re
+    from pathlib import Path
+    import deepspeed_tpu
+    from deepspeed_tpu.utils import spans
+
+    root = Path(deepspeed_tpu.__file__).parent
+    # `span(` itself, not a request trace's `.span(` (the serve clock's)
+    opened = re.compile(r'(?:(?<![\w.])span|phases\.enter)\(\s*"([^"]+)"')
+    found, direct = set(), []
+    for path in root.rglob("*.py"):
+        text = path.read_text(encoding="utf-8")
+        found |= set(opened.findall(text))
+        if "TraceAnnotation(" in text and path.name != "spans.py":
+            direct.append(str(path.relative_to(root)))
+        for gone in ("range_push", "range_pop", "instrument_w_nvtx"):
+            assert f"def {gone}" not in text, (path, gone)
+    assert direct == []
+    assert found == set(spans.SPAN_NAMES)
+    assert not (root / "utils" / "nvtx.py").exists()
+
+
+@pytest.mark.parametrize("burst", [1, 4], ids=["per_step", "burst"])
+def test_serve_spans_land_in_a_real_profiler_trace(tmp_path, burst):
+    """A tiny CPU engine driven for a few steps under a real
+    `jax.profiler` session: the xplane read back holds the serve step's
+    spans, named from the catalogue, nested inside `serve.step`, one
+    `engine.fetch` per explicit device-to-host fetch, and `serve.step`'s
+    `step` is the key of the step's timeline row."""
+    from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
+                                            build_engine)
+    from deepspeed_tpu.utils.spans import SPAN_NAMES
+    eng = build_engine("gpt2", "tiny",
+                       engine_config=RaggedInferenceEngineConfig(
+                           num_blocks=32, block_size=8, max_blocks_per_seq=8,
+                           max_seqs=4, prefill_chunk_size=8))
+    loop = ServeLoop(eng, ServingConfig(
+        decode_burst=burst,
+        tracing=TracingConfig(enabled=False, step_timeline=64)))
+    rng = np.random.RandomState(0)
+    for n in (5, 11, 3):
+        loop.submit(rng.randint(0, eng.cfg.vocab_size, n).astype(np.int32),
+                    max_new_tokens=6)
+    fetches0 = eng.profile["d2h_fetches"]
+    with _traced(tmp_path):
+        steps = 0
+        while loop.has_work and steps < 40:
+            loop.step()
+            steps += 1
+    assert not loop.has_work
+    lines = [ln for ln in _program_spans(tmp_path)
+             if any(e[2] == "serve.step" for e in ln)]
+    assert len(lines) == 1, "one serve thread"
+    evs = lines[0]
+    names = {e[2] for e in evs}
+    assert names <= set(SPAN_NAMES)
+    assert {"serve.step", "serve.finalize", "serve.admission",
+            "serve.engine", "serve.sample", "serve.bookkeep",
+            "engine.plan", "engine.dispatch", "engine.fetch"} <= names
+    step_spans = [e for e in evs if e[2] == "serve.step"]
+    assert len(step_spans) == steps
+    for e in evs:
+        if e[2] != "serve.step":
+            assert _inside(e, step_spans), e
+    # the engine's spans lie inside the phase that called it
+    callers = [e for e in evs if e[2] in ("serve.engine", "serve.sample")]
+    for e in evs:
+        if e[2].startswith("engine."):
+            assert _inside(e, callers), e
+    fetch = [e for e in evs if e[2] == "engine.fetch"]
+    assert len(fetch) == eng.profile["d2h_fetches"] - fetches0
+    assert all(e[3]["bytes"] > 0 and e[3]["program"] for e in fetch)
+    assert all("rows" in e[3] for e in evs if e[2] == "engine.plan")
+    # the join with the serve clock: the timeline rows carry the same key
+    assert [e[3]["step"] for e in step_spans] \
+        == [r["step"] for r in loop._timeline.rows]
+    admitted = [e[3]["admitted"] for e in evs if e[2] == "serve.admission"]
+    assert sum(admitted) == 3
+
+
+def test_train_spans_land_in_a_real_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as dstpu
+
+    def loss_fn(params, batch, rng):
+        return jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+
+    engine = dstpu.initialize(
+        loss_fn=loss_fn, params={"w": jnp.zeros((8, 4), jnp.float32)},
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 2,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-2}},
+                "zero_optimization": {"stage": 1}, "steps_per_print": 0})
+    n = engine.config.train_batch_size
+    batch = {"x": np.ones((n, 8), np.float32),
+             "y": np.ones((n, 4), np.float32)}
+    engine.train_batch(batch)                      # compiles
+    with _traced(tmp_path):
+        for _ in range(2):
+            jax.block_until_ready(engine.train_batch(batch)["loss"])
+    lines = [ln for ln in _program_spans(tmp_path)
+             if any(e[2] == "train.step" for e in ln)]
+    assert len(lines) == 1
+    evs = lines[0]
+    assert {e[2] for e in evs} == {"train.step", "train.shard_batch",
+                                   "train.dispatch"}
+    steps = [e for e in evs if e[2] == "train.step"]
+    assert [e[3]["step"] for e in steps] == [2, 3]
+    for name in ("train.shard_batch", "train.dispatch"):
+        inner = [e for e in evs if e[2] == name]
+        assert len(inner) == 2 and all(_inside(e, steps) for e in inner)
+
+
+def test_submit_due_starts_the_request_clock_when_the_caller_says():
+    """`submit(due=)`: arrival, and with it the queue wait, TTFT, the
+    `queued` span and the deadline, count from when the request was due;
+    the default is the clock at submit, as before."""
+    clock = FakeClock()
+    clock.advance(10.0)
+    loop = ServeLoop(FakeEngine(max_seqs=2, budget=8),
+                     ServingConfig(tracing=_tracing_cfg()), clock=clock)
+    late = loop.submit(np.asarray([1, 2], np.int32), max_new_tokens=2,
+                       timeout_s=5.0, due=7.5)
+    now = loop.submit(np.asarray([3], np.int32), max_new_tokens=2,
+                      timeout_s=5.0)
+    assert (late.arrival_time, late.deadline) == (7.5, 12.5)
+    assert (now.arrival_time, now.deadline) == (10.0, 15.0)
+    while loop.has_work:
+        loop.step()
+        clock.advance(1.0)
+    assert late.state is RequestState.DONE
+    assert late.admit_time - late.arrival_time == 2.5
+    assert late.ttft == now.ttft + 2.5
+    queued = [e for e in late.trace.entries if e.get("name") == "queued"]
+    assert queued and queued[0]["t0"] == 7.5
