@@ -454,13 +454,13 @@ def bench_decode_single(ctx: int, B: int = 8, steps: int = 50):
     eng, cfg = _engine(ctx, max_seqs=B)
     tokens, lens, tables, active = _fill(eng, cfg, B, ctx)
     arena = eng.arena
-    logits, arena = decode_step(eng.cfg, eng.params, arena, tokens, lens,
-                                tables, active)
+    logits, _, arena = decode_step(eng.cfg, eng.params, arena, tokens, lens,
+                                   tables, active)
     float(logits.sum())
     t0 = time.perf_counter()
     for _ in range(steps):
-        logits, arena = decode_step(eng.cfg, eng.params, arena, tokens,
-                                    lens, tables, active)
+        logits, _, arena = decode_step(eng.cfg, eng.params, arena, tokens,
+                                       lens, tables, active)
     float(logits.sum())
     dt = time.perf_counter() - t0
     tok_s = B * steps / dt

@@ -1418,8 +1418,11 @@ class ServingConfig:
     # into the engine's on-device decode program (logits never leave the
     # device; one host observation per burst), trading cancellation /
     # deadline granularity — expiry is checked at burst boundaries — for
-    # throughput.  1 = the per-step host-sampling path, bit-for-bit
-    # today's per-token behavior (the deterministic-test reference).
+    # throughput.  1 = the per-step path: one token per engine step,
+    # admission every step; a greedy row takes the argmax the step's
+    # program returns beside its logits, every other row is sampled on
+    # the host from its own logits row (serving/server.py step 5) —
+    # token for token the all-host sampler it replaced.
     decode_burst: int = 1
     # decode steps per compiled step-GROUP in ServeLoop: > 1 runs K
     # decode iterations in ONE dispatch with on-device per-row sampling
@@ -1538,8 +1541,8 @@ class ServingConfig:
                 f"{self.monitor_interval_steps}")
         if self.decode_burst < 1:
             raise ConfigError(
-                f"serving.decode_burst must be >= 1 (1 = per-step host "
-                f"sampling), got {self.decode_burst}")
+                f"serving.decode_burst must be >= 1 (1 = the per-step "
+                f"path), got {self.decode_burst}")
         if self.multi_step < 1:
             raise ConfigError(
                 f"serving.multi_step must be >= 1 (1 = multi-step "
@@ -1663,8 +1666,8 @@ class ServingConfig:
                     "serving.speculative needs decode_burst > 1: draft "
                     "verification rides the burst serve path (on-device "
                     "accept/reject in the compiled program); the "
-                    "decode_burst=1 host-sampling reference loop has no "
-                    "verify step to extend")
+                    "decode_burst=1 per-step loop has no verify step "
+                    "to extend")
 
     @classmethod
     def from_dict(cls, d: Optional[Dict[str, Any]]) -> "ServingConfig":

@@ -14,6 +14,7 @@ scheduling is host-side bookkeeping in DSStateManager.
 """
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
@@ -25,9 +26,90 @@ import jax.numpy as jnp
 from ...utils.spans import span
 from .ragged_manager import DSStateManager, SequenceDescriptor
 from .ragged_ops import (init_arena, prefill_chunks, decode_step,
-                         decode_tokens, decode_multi_step, verify_tokens)
+                         decode_tokens, decode_multi_step, verify_tokens,
+                         logits_row)
 
-__all__ = ["RaggedInferenceEngineConfig", "InferenceEngineV2"]
+__all__ = ["RaggedInferenceEngineConfig", "InferenceEngineV2", "LogitsRows"]
+
+
+class _Row:
+    """One sequence's last-token logits: row `i` of a program's [N, V]
+    output still on the device (`logits`), the row on the host once it
+    was read (`host`), and its argmax as the program took it (`token`).
+    The cell is shared by the step's result and the engine's own table,
+    so a row crosses once whoever reads it."""
+    __slots__ = ("logits", "i", "token", "host")
+
+    def __init__(self, logits, i: int, token: Optional[int], host=None):
+        self.logits, self.i, self.token, self.host = logits, i, token, host
+
+
+class LogitsRows(Mapping):
+    """{uid: last-token logits row [V]} over logits left on the device:
+    what `put`/`step` return and `query` reads.
+
+    The per-step programs hand back each row's greedy token beside the
+    logits (`ragged_ops.greedy_tokens`), and only those [N] int32 cross
+    when the step ends: `greedy(uid)` reads them.  A logits row crosses
+    when it is read, through the engine's explicit fetch (an
+    `engine.fetch` span, one count in `profile["d2h_fetches"]`):
+    `rows[uid]` brings that row alone, `items()` brings each program's
+    whole output once (the burst loops' batched first-token sampler
+    reads every row of a prefill, with the fetches it always made).
+    Keys, `in` and `len` fetch nothing.  `rows[uid] = row` puts a host
+    row in a row's place: it has no token of the program's, so
+    `greedy(uid)` is None and whoever wants its token samples that
+    row."""
+
+    def __init__(self, fetch):
+        self._fetch = fetch       # (device logits, row or None) -> host
+        self._rows: Dict[int, _Row] = {}
+
+    def _set(self, uid: int, logits, i: int, token: int) -> None:
+        self._rows[uid] = _Row(logits, i, token)
+
+    def __setitem__(self, uid: int, row: np.ndarray) -> None:
+        self._rows[uid] = _Row(None, 0, None, host=row)
+
+    def __getitem__(self, uid: int) -> np.ndarray:
+        row = self._rows[uid]
+        if row.host is None:
+            row.host, row.logits = self._fetch(row.logits, row.i), None
+        return row.host
+
+    def __iter__(self):
+        return iter(self._rows)
+
+    def __len__(self) -> int:
+        return len(self._rows)
+
+    def __contains__(self, uid) -> bool:
+        return uid in self._rows
+
+    def greedy(self, uid: int) -> Optional[int]:
+        """The argmax of `rows[uid]` as the program that made the
+        logits took it: already on the host, no row fetched.  The serve
+        loop probes this for the rows whose sampler is the plain
+        argmax."""
+        row = self._rows.get(uid)
+        return None if row is None else row.token
+
+    def items(self):
+        pending: Dict[int, List[_Row]] = {}
+        for row in self._rows.values():
+            if row.host is None:
+                pending.setdefault(id(row.logits), []).append(row)
+        for rows in pending.values():
+            host = self._fetch(rows[0].logits, None)
+            for row in rows:
+                row.host, row.logits = host[row.i], None
+        return super().items()
+
+    def update(self, other: "LogitsRows") -> None:
+        self._rows.update(other._rows)
+
+    def discard(self, uid: int) -> None:
+        self._rows.pop(uid, None)
 
 
 @dataclass
@@ -215,7 +297,7 @@ class InferenceEngineV2:
         self._use_prefill_full = (self.config.full_prompt_prefill
                                   and self.tp == 1
                                   and prefill_full_supported(self.cfg))
-        self._last_logits: Dict[int, np.ndarray] = {}
+        self._last_logits = LogitsRows(self._fetch_logits)
         self._rng = jax.random.PRNGKey(0)
         # host-sync ledger: every EXPLICIT device->host fetch the engine
         # performs bumps d2h_fetches (the implicit ones are what the
@@ -481,13 +563,14 @@ class InferenceEngineV2:
 
     # -- scheduling ------------------------------------------------------
     def put(self, uids: Sequence[int], tokens_list: Sequence[np.ndarray],
-            decode: bool = True, prefixes=None) -> Dict[int, np.ndarray]:
+            decode: bool = True, prefixes=None) -> LogitsRows:
         """Admit new sequences and advance the ragged batch one step
         (reference `put` :107).  Returns {uid: last-token logits} for every
-        sequence that produced fresh logits this call.  `decode=False`
+        sequence that produced fresh logits this call (`LogitsRows`: a
+        row crosses to the host when it is read).  `decode=False`
         runs only the prefill phase — the burst serve loop owns decode via
         `decode_burst_step` and must not have pending burst-chain tokens
-        consumed by the host-logits decode path here.
+        consumed by the per-step decode path here.
 
         `prefixes` maps a fresh uid to a PrefixLease the caller already
         acquired — or to None recording a known miss (the serve loop
@@ -549,8 +632,29 @@ class InferenceEngineV2:
                         self._prefix_leases[uid] = lease
         return self.step(decode=decode)
 
-    def step(self, decode: bool = True) -> Dict[int, np.ndarray]:
-        out: Dict[int, np.ndarray] = {}
+    def _fetch_tokens(self, program: str, toks) -> List[int]:
+        """What a per-step program's end costs the host: its [N] int32
+        greedy tokens (waiting for them is waiting for the program)."""
+        with span("engine.fetch", program=program, bytes=toks.nbytes):
+            toks = jax.device_get(toks)  # dstpu: noqa[DST001] intended: one [N]-token fetch per per-step program — the step's sync point; the [N, V] logits stay on the device (LogitsRows); explicit so the transfer guard admits it
+        self.profile["d2h_fetches"] += 1
+        return toks.tolist()
+
+    def _fetch_logits(self, logits, row: Optional[int]) -> np.ndarray:
+        """Row `row` of a program's [N, V] logits (None: all of it) on
+        the host — `LogitsRows`' reader.  The span's `bytes` tell it from
+        a step's token fetch."""
+        if row is not None:
+            logits = logits_row(
+                logits, self._host_in(np.asarray(row, np.int32)))
+        with span("engine.fetch", program="logits_rows",
+                  bytes=logits.nbytes):
+            logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: logits a caller reads (a host-sampled row, query(), the burst loops' first-token batch); explicit so the transfer guard admits it
+        self.profile["d2h_fetches"] += 1
+        return logits
+
+    def step(self, decode: bool = True) -> LogitsRows:
+        out = LogitsRows(self._fetch_logits)
         C = self.config.prefill_chunk_size
         # a zero/negative budget must still make 1 token of progress per
         # step, or in_prefill sequences (and generate()) would spin forever
@@ -656,17 +760,14 @@ class InferenceEngineV2:
                 plan.set_metadata(rows=len(fresh))
             if fresh:
                 with span("engine.dispatch", program="prefill_full"):
-                    logits, self.arena = prefill_full(
+                    logits, toks, self.arena = prefill_full(
                         self.cfg, self.params, self.arena,
                         self._host_in(ftokens), self._host_in(flens),
                         self._host_in(ftables), self._host_in(factive))
-                with span("engine.fetch", program="prefill_full",
-                          bytes=logits.nbytes):
-                    logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one prefill-logits fetch per fresh batch feeds first-token sampling; explicit so the transfer guard admits it
-                self.profile["d2h_fetches"] += 1
+                toks = self._fetch_tokens("prefill_full", toks)
                 for i, d in enumerate(fresh):
                     d.seen_tokens = len(d.prompt)
-                    out[d.uid] = logits[i]
+                    out._set(d.uid, logits, i, toks[i])
                 budget -= sum(len(d.prompt) for d in fresh)
                 budget = max(budget, 0)
         # slot bound: every full chunk consumes C budget and each sequence
@@ -726,19 +827,16 @@ class InferenceEngineV2:
                 aids = self._batch_adapter_ids([d for d, _, _ in planned], NC)
                 lkw = ({} if aids is None else
                        dict(adapter_ids=self._host_in(aids), lora=self._lora))
-                logits, self.arena = self._programs.prefill_chunks(
+                logits, toks, self.arena = self._programs.prefill_chunks(
                     self.params, self.arena, self._host_in(tokens[:NC]),
                     self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
                     self._host_in(tables[:NC]), self._host_in(active[:NC]),
                     self._host_in(tlens[:NC]), **lkw)
-            with span("engine.fetch", program="prefill_chunks",
-                      bytes=logits.nbytes):
-                logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: one chunk-logits fetch per prefill step (prompt-completion detection); explicit for the transfer guard
-            self.profile["d2h_fetches"] += 1
+            toks = self._fetch_tokens("prefill_chunks", toks)
             for i, (d, start, n) in enumerate(planned):
                 d.seen_tokens = start + n
                 if not d.in_prefill:
-                    out[d.uid] = logits[i]
+                    out._set(d.uid, logits, i, toks[i])
         # 2) decode: one token for every sequence with a pending input token
         #    (suppressed under decode=False: the burst serve path keeps one
         #    pending token per chained sequence, which must wait for the
@@ -767,17 +865,14 @@ class InferenceEngineV2:
                 aids = self._batch_adapter_ids(batch, B)
                 lkw = ({} if aids is None else
                        dict(adapter_ids=self._host_in(aids), lora=self._lora))
-                logits, self.arena = self._programs.decode_step(
+                logits, toks, self.arena = self._programs.decode_step(
                     self.params, self.arena, self._host_in(tokens),
                     self._host_in(lens), self._host_in(tables),
                     self._host_in(active), **lkw)
-            with span("engine.fetch", program="decode_step",
-                      bytes=logits.nbytes):
-                logits = jax.device_get(logits)  # dstpu: noqa[DST001] intended: the host-sampling path ships one [B, V] logits batch per decode token BY DESIGN — burst serving (decode_burst > 1) exists to avoid this
-            self.profile["d2h_fetches"] += 1
+            toks = self._fetch_tokens("decode_step", toks)
             for i, d in enumerate(batch):
                 d.seen_tokens += 1
-                out[d.uid] = logits[i]
+                out._set(d.uid, logits, i, toks[i])
         self._last_logits.update(out)
         return out
 
@@ -1074,7 +1169,7 @@ class InferenceEngineV2:
             d.seen_tokens = min(d.seen_tokens + n_steps, int(max_lens[i]))
             out[d.uid] = toks[i]
             # burst path produces tokens, not logits — drop stale logits
-            self._last_logits.pop(d.uid, None)
+            self._last_logits.discard(d.uid)
         return out
 
     def _seed_operands(self, batch, B: int,
@@ -1246,7 +1341,7 @@ class InferenceEngineV2:
             d.seen_tokens += n_e
             out[d.uid] = toks
             # multi-step produces tokens, not logits — drop stale logits
-            self._last_logits.pop(d.uid, None)
+            self._last_logits.discard(d.uid)
         return out
 
     def _verify_draft_step(self, uids: Optional[Sequence[int]], *,
@@ -1401,7 +1496,7 @@ class InferenceEngineV2:
             d.generated.extend(int(t) for t in toks)
             d.seen_tokens = min(d.seen_tokens + n, int(max_lens[i]))
             # verify path produces tokens, not logits — drop stale logits
-            self._last_logits.pop(d.uid, None)
+            self._last_logits.discard(d.uid)
             out[d.uid] = (toks, int(nval[i]) - 1, max(take - 1, 0))
         return out
 
@@ -1450,10 +1545,12 @@ class InferenceEngineV2:
         self.state.flush(uid)
         if lease is not None:
             self.prefix_cache.release(lease)
-        self._last_logits.pop(uid, None)
+        self._last_logits.discard(uid)
         self._adapter_slots.pop(uid, None)
 
     def query(self, uid: int) -> Optional[np.ndarray]:
+        """`uid`'s latest last-token logits row on the host (fetched
+        when first read), None until its prompt is complete."""
         return self._last_logits.get(uid)
 
     @property

@@ -382,7 +382,8 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     so a later chunk attends keys a former chunk just wrote, while QKV
     projections, MLP and logits batch over all NC*C tokens (better MXU
     shapes than NC separate calls, and NC fewer host dispatches).
-    Returns (logits [NC, V] — last valid token each, arena)."""
+    Returns (logits [NC, V] — last valid token each, their argmax
+    [NC] int32 (`greedy_tokens`), arena)."""
     NC, C = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -569,7 +570,7 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     last = jnp.clip(n_valids - 1, 0, C - 1)
     xl = x[jnp.arange(NC), last]                           # [NC, H]
     logits = _lm_logits(cfg, params, xl)                   # [NC, V]
-    return logits, _arena_out(arena, new_k, new_v)
+    return logits, greedy_tokens(logits), _arena_out(arena, new_k, new_v)
 
 
 def prefill_full_supported(cfg: TransformerConfig) -> bool:
@@ -607,7 +608,7 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
 
     tokens: [NS, S] int32 (zero-padded); lens: [NS]; block_tables:
     [NS, MB]; active: [NS].  Returns (logits [NS, V] at each prompt's
-    last token, arena).
+    last token, their argmax [NS] int32 (`greedy_tokens`), arena).
 
     Invariant: the padded bucket S may EXCEED cfg.max_seq_len (a
     513-token prompt with max_seq_len 768 pads to S=1024), so padded
@@ -684,7 +685,7 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
     last = jnp.clip(lens - 1, 0, S - 1)
     xl = x[jnp.arange(NS), last]                           # [NS, H]
     logits = _lm_logits(cfg, params, xl)                   # [NS, V]
-    return logits, _arena_out(arena, new_k, new_v)
+    return logits, greedy_tokens(logits), _arena_out(arena, new_k, new_v)
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,),
@@ -701,10 +702,12 @@ def decode_step(cfg: TransformerConfig, params, arena, tokens, seq_lens,
     from the operands' NamedShardings); adapter_ids [B] + `lora` stacked
     factors: the per-row gather-LoRA epilogue (see `prefill_chunks`),
     `lora=None` = the exact single-tenant program.  Returns
-    (logits [B, V], arena).
+    (logits [B, V], their argmax [B] int32 (`greedy_tokens`), arena).
     """
-    return _decode_core(cfg, params, arena, tokens, seq_lens, block_tables,
-                        active, n_tp, mesh, adapter_ids, lora)
+    logits, arena = _decode_core(cfg, params, arena, tokens, seq_lens,
+                                 block_tables, active, n_tp, mesh,
+                                 adapter_ids, lora)
+    return logits, greedy_tokens(logits), arena
 
 
 @jax.named_scope("sample")
@@ -736,6 +739,24 @@ def _sample_tokens(logits, key, mode: str, temperature, top_k):
     return jax.random.categorical(
         key, scale_topk(logits, temperature, top_k),
         axis=-1).astype(jnp.int32)
+
+
+def greedy_tokens(logits):
+    """The greedy token of every logits row, chosen by the program that
+    made the logits (the per-step programs return it beside them, so a
+    greedy row's token crosses to the host as 4 bytes and its logits
+    row stays on the device).  The same f32 row, and `jnp.argmax` like
+    `np.argmax` takes the first maximum: bit-for-bit the host
+    sampler's token for temperature <= 0 (`ServeLoop._sample`)."""
+    return _sample_tokens(logits, None, "greedy", None, 0)
+
+
+@jax.jit
+def logits_row(logits, i):
+    """Row `i` of a program's [N, V] logits, for a caller that reads one
+    (`engine_v2.LogitsRows`).  `i` is traced: one program per logits
+    shape, not per row."""
+    return logits[i]
 
 
 # -- counter-based sampling streams (Philox4x64-10 in uint32 lanes) --------

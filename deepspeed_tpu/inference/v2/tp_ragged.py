@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 from ...models.transformer import _norm, _rope
 from ...ops.tp_matmul import ag_matmul, matmul_rs, tile_matmul
 from ...parallel.mesh import AXIS_TP
+from .ragged_ops import greedy_tokens
 from jax import shard_map
 
 PyTree = Any
@@ -343,7 +344,10 @@ class TPServingPrograms:
         sm = shard_map(local, mesh=self.mesh,
                        in_specs=(self._pspecs, self._aspec) + (P(),) * 4,
                        out_specs=(P(), self._aspec), check_vma=False)
-        return sm(params, arena, tokens, seq_lens, block_tables, active)
+        logits, arena = sm(params, arena, tokens, seq_lens, block_tables,
+                           active)
+        # the replicated logits' argmax, as ragged_ops.decode_step returns
+        return logits, greedy_tokens(logits), arena
 
     def _decode_tokens_impl(self, params, arena, tokens, seq_lens,
                             block_tables, active, rng, temperature,
@@ -570,5 +574,6 @@ class TPServingPrograms:
         sm = shard_map(local, mesh=self.mesh,
                        in_specs=(self._pspecs, self._aspec) + (P(),) * 6,
                        out_specs=(P(), self._aspec), check_vma=False)
-        return sm(params, arena, tokens, pos0s, n_valids, block_tables,
-                  active, total_lens)
+        logits, arena = sm(params, arena, tokens, pos0s, n_valids,
+                           block_tables, active, total_lens)
+        return logits, greedy_tokens(logits), arena

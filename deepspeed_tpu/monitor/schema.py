@@ -44,6 +44,7 @@ SERVING_TAGS = frozenset(
         "prefix_hits", "prefix_misses", "drained_unserved",
         "rejected_draining", "evicted_in_flight", "spec_drafted",
         "spec_accepted", "handoff_parked",
+        "sampled_on_device", "sampled_on_host",
         # token streaming + SLO-aware preemption (ISSUE 15):
         # exactly-once delivery accounting and the swap-or-recompute
         # preemption lifecycle

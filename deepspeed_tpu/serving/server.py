@@ -10,12 +10,15 @@ with a fake clock.  `ThreadedServer` wraps the same core behind
 
 Two hot paths, selected by `ServingConfig.decode_burst`:
 
-- **decode_burst == 1** (the deterministic-test reference): one ServeLoop
-  step == one engine step; every decode token is sampled on HOST from the
-  full-vocab logits the engine ships back — one dispatch and a
-  [max_seqs, vocab] host materialization per token (bench_serve
-  `serve_closed_c8` recorded this at 0.9 tok/s vs the 63.5 the same
-  engine programs reach through their own burst path).
+- **decode_burst == 1** (the default): one ServeLoop step == one engine
+  step == one token per decoding request, with admission every step.
+  The step's programs return each row's argmax beside its logits and
+  the engine fetches only those [max_seqs] int32; a request with
+  temperature <= 0 and no response_format takes that token (it IS the
+  host sampler's result for such a row), and only the other rows —
+  stochastic, seeded, top-k, grammar-masked — have their own logits
+  row fetched and sampled on HOST (`_sample`), as every row of an
+  engine whose step returns plain host rows is (test fakes).
 - **decode_burst > 1** (burst serving): decode rides the engine's fused
   `decode_burst_step` — sample -> append-KV -> feed-back run as ONE
   compiled program per `decode_burst` tokens and logits never leave the
@@ -172,7 +175,7 @@ class ServeLoop:
                 f"ServingConfig.decode_burst={self._burst_n} needs an "
                 f"engine with decode_burst_step (on-device burst "
                 f"sampling); {type(engine).__name__} has none — use "
-                f"decode_burst=1 for the host-sampling path")
+                f"decode_burst=1 for the per-step path")
         # multi-step step groups (host-free steady-state decode): K
         # decode steps per compiled dispatch with ON-DEVICE sampling and
         # termination (engine decode_multi_step).  Everything host-side
@@ -1180,10 +1183,10 @@ class ServeLoop:
                 self._rollback_admission(admitted)
                 raise
         # 3) one ragged engine step (admissions ride the same put() call).
-        #    Burst mode suppresses the engine's host-logits decode phase:
+        #    Burst mode suppresses the engine's per-step decode phase:
         #    burst-chained sequences each hold one pending token that
-        #    belongs to the NEXT decode burst, and per-token logits must
-        #    never be materialized to host while bursts own decode.
+        #    belongs to the NEXT decode burst, which must not be decoded
+        #    one step at a time while bursts own decode.
         #    The whole admit->put window is crash-atomic: a raise before
         #    put() returns rolls the admissions back to the queue —
         #    without that, a supervised replica that recovers after the
@@ -1319,14 +1322,32 @@ class ServeLoop:
             self._first_tokens_batch(out, now, finished)
             decode_toks = self._decode_bursts(finished)
         else:
-            # 5) per-step path: host-sample a token for every sequence
-            #    that produced logits; finish or stage the token as the
-            #    next step's decode input
-            for uid, logits in out.items():
+            # 5) per-step path: a token for every sequence that produced
+            #    logits; finish or stage the token as the next step's
+            #    decode input.  A row whose sampler is the plain argmax
+            #    (temperature <= 0, no grammar mask: `_sample`) takes the
+            #    token the engine's program chose beside the logits —
+            #    the same f32 row, the same first maximum — and its
+            #    logits never leave the device.  Every other row is
+            #    sampled here from its own logits row: so is a row
+            #    somebody put a host row in the place of (no token), and
+            #    every row of an engine whose step returns plain host
+            #    rows (`engine_v2.LogitsRows.greedy` is the capability
+            #    probed; test fakes return dicts).
+            greedy = getattr(out, "greedy", None)
+            for uid in out:
                 req = self.scheduler.active.get(uid)
                 if req is None:
                     continue   # not ours (engine shared with other callers)
-                tok = self._sample(req, np.asarray(logits))  # dstpu: noqa[DST001] logits rows are host np — the engine fetches them explicitly (device_get) once per step
+                tok = None
+                if (greedy is not None and req.temperature <= 0.0
+                        and req.response_format is None):
+                    tok = greedy(uid)
+                if tok is None:
+                    tok = self._sample(req, np.asarray(out[uid]))  # dstpu: noqa[DST001] a host np row: the engine fetches it explicitly (device_get) when it is read
+                    self.telemetry.count("sampled_on_host")
+                else:
+                    self.telemetry.count("sampled_on_device")
                 if req.state is RequestState.PREFILL:
                     req.advance(RequestState.DECODE, now)
                     req.mark_first_token(now)
@@ -1380,10 +1401,11 @@ class ServeLoop:
                              if self._tier is not None else 0.0),
                  # the engine's put/step call dominates this window
                  # (on the per-step path that is staging, prefill, the
-                 # decode program and the logits fetch); the cheap host
-                 # bookkeeping between it and the sampling rides along
+                 # decode program and the fetch of its tokens); the cheap
+                 # host bookkeeping between it and the sampling rides along
                  "prefill": at["serve.sample"] - at["serve.engine"],
-                 # host sampling (per-step path) or the compiled bursts
+                 # bookkeeping and host sampling of the rows that need
+                 # it (per-step path), or the compiled bursts
                  "decode": at["serve.bookkeep"] - at["serve.sample"]},
                 admitted=len(admitted), finished=len(finished),
                 prefill_tokens=prefill_toks, decode_tokens=decode_toks,
@@ -2166,7 +2188,11 @@ class ServeLoop:
         return st
 
     def _sample(self, req: Request, logits: np.ndarray) -> int:
-        """Host-side reference sampler (the decode_burst == 1 path).
+        """Host-side reference sampler: on the decode_burst == 1 path,
+        every row whose token is not the plain argmax of its logits (a
+        greedy unconstrained row takes the engine's on-device argmax —
+        the same token, `LogitsRows.greedy`), and every row of an
+        engine without that capability.
         Same truncation semantics as the on-device samplers: temperature
         scale, entries below the top_k-th value dropped (ties at the kth
         value survive).  A seeded request draws from its counter-based

@@ -67,6 +67,11 @@ class ServingTelemetry:
             # this PREFILL-role replica ran to prompt completion and
             # parked for the cross-pool handoff
             "handoff_parked": 0,
+            # per-step path (serving/server.py step 5): tokens taken
+            # from the engine's on-device argmax, and tokens sampled on
+            # the host from a fetched logits row (temperature > 0,
+            # grammar-masked rows, engines that return host rows)
+            "sampled_on_device": 0, "sampled_on_host": 0,
             # token streaming (serving/streaming.py): tokens delivered
             # through request streams, tokens regenerated after a
             # failover and suppressed as verified replay (exactly-once

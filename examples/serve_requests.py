@@ -128,8 +128,9 @@ def main():
             num_blocks=128, block_size=32, max_blocks_per_seq=24,
             max_seqs=4, prefill_chunk_size=128))
     # decode_burst=8: decode runs as fused on-device bursts (sampling
-    # included — logits never leave the device); set 1 for the per-token
-    # host-sampling path.  prefix_cache_blocks: KV blocks the radix
+    # included — logits never leave the device); set 1 for the per-step
+    # path (one token and one admission pass per step; greedy rows take
+    # the program's own argmax).  prefix_cache_blocks: KV blocks the radix
     # prefix cache may keep for reuse across requests (0 = off)
     from deepspeed_tpu import SpeculativeConfig
     # with the host tier on, a deliberately small HBM budget (the shared
