@@ -32,6 +32,11 @@ from .ragged_ops import (init_arena, prefill_chunks, decode_step,
 __all__ = ["RaggedInferenceEngineConfig", "InferenceEngineV2", "LogitsRows"]
 
 
+# what the four block readers/writers refuse for a latent arena
+_PAGE_IO = ("arena page export/import (KV tiering, fleet migration, "
+            "disaggregated handoff)")
+
+
 class _Row:
     """One sequence's last-token logits: row `i` of a program's [N, V]
     output still on the device (`logits`), the row on the host once it
@@ -212,6 +217,14 @@ class InferenceEngineV2:
                 "tp_collectives='fused' requires tensor_parallel_size > 1 "
                 "(there is no collective to fuse at tp=1; the default "
                 "'xla' keeps tp=1 byte-identical)")
+        self._latent = bool(getattr(self.cfg, "latent", False))
+        if self._latent and (self.tp > 1
+                             or self.config.tp_collectives != "xla"):
+            raise ValueError(
+                "tensor parallelism (tensor_parallel_size > 1, "
+                "tp_collectives='fused') cannot serve the latent (MLA) "
+                "block: its cache has no head dimension to shard and its "
+                "kernel and expert share are not wrapped for a mesh")
         if self.tp > 1:
             if self.cfg.num_heads % self.tp or self.cfg.kv_heads % self.tp:
                 raise ValueError(
@@ -338,6 +351,9 @@ class InferenceEngineV2:
         (telemetry / invalidation handle)."""
         from ...serving.kv_tier import HostKVTier
         from ...serving.prefix_cache import PrefixCache
+        self._refuse_latent("the prefix cache and its host KV tier "
+                            "(shared blocks are attached and spilled as "
+                            "K/V pages)")
         scaling = getattr(self.cfg, "rope_scaling", None)
         if scaling and scaling[0] == "longrope":
             # phi3-style longrope picks short/long rope factors from the
@@ -372,9 +388,20 @@ class InferenceEngineV2:
             tier=tier)
         return self.prefix_cache
 
+    def _refuse_latent(self, what: str) -> None:
+        """Mechanisms written for a per-head K/V arena refuse the latent
+        (MLA) one where they are switched on."""
+        if self._latent:
+            raise NotImplementedError(
+                f"{what}: not wired for the latent (MLA) arena, which "
+                f"holds one [latent | rope key] row per token and "
+                f"attention and no K/V pages")
+
     # -- multi-LoRA adapter serving (serving/tenancy) ---------------------
     # the serving layer probes this before enabling an adapter pool
-    supports_lora = True
+    @property
+    def supports_lora(self) -> bool:
+        return not self._latent
 
     def attach_lora(self, lora) -> None:
         """Attach (None = detach) the stacked multi-LoRA factors the
@@ -387,6 +414,8 @@ class InferenceEngineV2:
         see these operands — their programs stay bit-for-bit
         single-tenant."""
         if lora is not None:
+            self._refuse_latent("LoRA adapters (the gather epilogue sits "
+                                "on the dense block's output projection)")
             a, b = lora["a"], lora["b"]
             if (a.ndim != 4 or b.ndim != 4 or a.shape[0] != b.shape[0]
                     or a.shape[1] != b.shape[1] or a.shape[3] != b.shape[2]):
@@ -443,6 +472,7 @@ class InferenceEngineV2:
         (jax.device_get): migration runs outside the serve step's
         transfer guard, but the same no-implicit-sync discipline
         applies."""
+        self._refuse_latent(_PAGE_IO)
         if not 0 <= block < self.config.num_blocks:
             raise ValueError(f"bad block id {block}")
         k = jax.device_get(self.arena["k"][:, block])
@@ -455,6 +485,7 @@ class InferenceEngineV2:
         arena.  The caller must own the block (a fresh allocator lease —
         see fleet/migration.py's insert-before-decref handoff); writing
         a block a live sequence reads would corrupt its KV."""
+        self._refuse_latent(_PAGE_IO)
         if not 0 <= block < self.config.num_blocks:
             raise ValueError(f"bad block id {block}")
         shape = self.arena["k"].shape         # [L, blocks, bs, ...minor]
@@ -482,6 +513,7 @@ class InferenceEngineV2:
         each, in ONE gather fetch per page tensor — the multi-block
         transfer unit of the disagg handoff path (one device round trip
         for the span instead of one per block)."""
+        self._refuse_latent(_PAGE_IO)
         blocks = [int(b) for b in blocks]
         for b in blocks:
             if not 0 <= b < self.config.num_blocks:
@@ -499,6 +531,7 @@ class InferenceEngineV2:
         the caller holds a fresh allocator lease on every target block,
         and the span's block ids must be distinct (a duplicated scatter
         index would silently keep only one page)."""
+        self._refuse_latent(_PAGE_IO)
         blocks = [int(b) for b in blocks]
         if len(set(blocks)) != len(blocks):
             raise ValueError(f"duplicate block ids in span {blocks}")
@@ -882,7 +915,9 @@ class InferenceEngineV2:
     supports_per_row_sampling = True
     # the serving layer probes this before enabling speculative decoding
     # (decode_burst_step drafts= runs the compiled verify program)
-    supports_draft_verify = True
+    @property
+    def supports_draft_verify(self) -> bool:
+        return not self._latent
     # per-request counter-based sampling streams (serving/streaming.
     # seeded_sample — the streaming layer's replayable stochastic
     # decode): the compiled burst and multi-step programs run the SAME
@@ -919,7 +954,27 @@ class InferenceEngineV2:
     # pre-sharded per rank — a host-side slot splice would corrupt them)
     @property
     def supports_moe(self) -> bool:
-        return self.cfg.moe_experts > 1 and self._tpp is None
+        # (a latent model holds a SHARE of its experts: nothing to page)
+        return (self.cfg.moe_experts > 1 and self._tpp is None
+                and not self._latent)
+
+    # the latent block's router counters (latent_ops.COUNT_NAMES), a
+    # rider of its arena that every program accumulates
+    @property
+    def supports_moe_counts(self) -> bool:
+        return "moe_counts" in self.arena
+
+    def drain_moe_counts(self) -> Dict[str, int]:
+        """Fetch-and-reset the router counters: ONE explicit d2h of a few
+        int32 per drain (the serve loop's interval), ledgered like every
+        other fetch."""
+        from .latent_ops import COUNT_NAMES
+        counts = self.arena["moe_counts"]
+        with span("engine.fetch", program="moe_counts", bytes=counts.nbytes):
+            out = jax.device_get(counts)  # dstpu: noqa[DST001] intended: the periodic counter drain (a few int32 per interval), explicit so the transfer guard admits it
+        self.profile["d2h_fetches"] += 1
+        self.arena["moe_counts"] = jnp.zeros_like(counts)
+        return dict(zip(COUNT_NAMES, out.tolist()))
 
     def enable_expert_paging(self, slots_per_layer: int,
                              spill: str = "none"):

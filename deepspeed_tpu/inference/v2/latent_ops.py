@@ -1,0 +1,439 @@
+"""The latent-attention (MLA) shortcut-MoE double block over a paged latent
+arena: what `ragged_ops`' four layer bodies run for a `TransformerConfig`
+with `kv_lora_rank > 0` (LongCat-Flash).
+
+A layer is two attention + dense-FFN sub-blocks with the MoE branching off
+the first sub-block's post-attention norm and joining after the second FFN:
+
+    a0 = x + MLA0(rms(x));  h0 = rms(a0);  m = MoE(h0);  y0 = a0 + FFN0(h0)
+    a1 = y0 + MLA1(rms(y0));  y1 = a1 + FFN1(rms(a1));  out = y1 + m
+
+The block is written once (`_forward`): the four bodies differ only in the
+rows they hand it and in the attention `form`:
+
+- "fresh"  (`prefill_full`): whole prompts from position 0; K/V are
+  decompressed from the tokens' own latents and go through the flash path
+  (`ops.attention.causal_attention`; head dims 192/128 zero-padded to the
+  next multiple of 128, the score scale folded into q);
+- "cached" (`prefill_chunks`): chunks against the arena, and "decode"
+  (`_decode_core`): one token a row.  Both in the ABSORBED form through
+  the paged kernel `ops/mla_paged.py` (tiles of 8 queries x all heads, or
+  one query's heads, against the row's arena blocks by block table).  On
+  the CPU only: the same absorbed mathematics as a dense gather
+  (`mla_paged_reference`).
+
+Chunk slots are padded (`[NC, C]` rows for at most the step's token budget
+of real tokens), so everything that works token by token (norms,
+projections, the dense FFNs, the router and the experts) runs over the real
+tokens only: a program of more than `ROW_TILE` rows moves the real ones to
+the front and takes them `ROW_TILE` at a time, for as many passes as they
+need (`_rows`).  Attention sees them back in their rows.
+
+Weights: `params["layers"]` holds, stacked over layers for the scan,
+`sub` (a list of the two sub-blocks' leaves), `moe_gate` and
+`moe_router_bias`; `params["experts"]` holds this chip's experts
+`[L, local, ...]` OUTSIDE the scan: the grouped matmuls take the whole
+stack with group sizes that are zero outside the layer at hand (a
+per-layer slice handed to a custom call is first copied, 1.2 GB a layer
+at the cell's size: measured 29 of a 51 ms decode step).
+
+The arena is ONE array `[2L, blocks, block_size, W]`: row `[c | rope(kr) |
+unused]` per token and attention (attention `2*layer + sub`), no V, nothing
+per head.  Block tables, the allocator and admission do not know: a block
+is still `block_size` tokens.  W is `kv_lora_rank + rope` rounded up to
+whole 128-lane tiles (576 -> 640), stated in the shape: the TPU tiles a
+576-wide minor dimension to 640 lanes anyway (as it would two arrays of 512
+and 64), and handed the unpadded shape XLA copies the whole arena into the
+tiled one before every kernel call (compiled for the v5e: 2.29 GB of
+temporaries per call at the cell's size, none with 640).
+
+The MoE holds a SHARE of the routed experts (`cfg.moe_expert_first`,
+`cfg.local_experts`): it routes over every router output, runs grouped
+matmuls over the assignments to its own experts only, adds the identity
+experts' part for every token, and leaves the absent experts' part out
+(another chip's work; nothing stands in for it).  Rider `moe_counts`
+([len(COUNT_NAMES)] int32) accumulates what the router did, for
+`InferenceEngineV2.drain_moe_counts`.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ...models.transformer import TransformerConfig
+from .ragged_ops import (_dense, _embed, _gate_fused, _lm_logits,
+                         _plain_mlp, greedy_tokens)
+
+__all__ = ["COUNT_NAMES", "COUNT_DRAIN_STEPS", "ROW_TILE",
+           "init_latent_arena", "prefill_full", "prefill_chunks",
+           "decode_core", "local_rows_cap", "refuse_lora"]
+
+# rows a token-wise pass takes at once (see `_rows`)
+ROW_TILE = 1024
+
+# what `moe_counts` holds, summed over layers and program calls: top-k
+# picks of valid tokens; those that fell on identity experts; those that
+# fell on the experts held here (rows of the grouped matmuls); the
+# busiest local expert's rows, summed; router calls (layers x programs)
+COUNT_NAMES = ("picks", "zero_picks", "local_rows", "busiest_rows",
+               "router_calls")
+# serve steps between two drains of it (`ServeLoop`: one 20-byte fetch)
+COUNT_DRAIN_STEPS = 16
+
+
+def init_latent_arena(cfg: TransformerConfig, num_blocks: int,
+                      block_size: int):
+    width = -(-cfg.latent_width // 128) * 128
+    return {"c": jnp.zeros((2 * cfg.num_layers, num_blocks, block_size,
+                            width), cfg.dtype),
+            "moe_counts": jnp.zeros((len(COUNT_NAMES),), jnp.int32)}
+
+
+def refuse_lora(lora) -> None:
+    if lora is not None:
+        raise NotImplementedError(
+            "LoRA adapters are not wired into the latent (MLA) block")
+
+
+def _rms(x, scale, eps: float, mult: float = 1.0):
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (out * (scale.astype(jnp.float32) * mult)).astype(x.dtype)
+
+
+def _rope_pairs(x, positions, theta: float):
+    """Rotate the pairs (2i, 2i+1) of x [T, ..., D] by positions [T] *
+    theta^(-2i/D) (the interleaved convention)."""
+    half = x.shape[-1] // 2
+    freqs = jnp.exp(-math.log(theta)
+                    * jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * freqs
+    ang = ang.reshape((ang.shape[0],) + (1,) * (x.ndim - 2) + (half,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+# ----------------------------------------------------------------------
+# attention
+# ----------------------------------------------------------------------
+def _use_latent_kernel(cfg: TransformerConfig, bs: int, queries: int) -> bool:
+    from ...ops.mla_paged import QUERIES_PER_STEP as tq
+    from ...utils.device import on_tpu
+    return _gate_fused(
+        cfg, on_tpu() and bs % 8 == 0 and queries % min(queries, tq) == 0,
+        reason=f"attn_impl='pallas' requested but the paged latent "
+               f"attention kernel cannot run here (needs TPU, block_size % "
+               f"8 == 0 [got {bs}] and whole tiles of {tq} queries [got "
+               f"{queries}])")
+
+
+def _attend_fresh(cfg, q, c, kr, w_kvb):
+    """Rows [R, S, ...] attend their own tokens, causally: decompressed
+    K/V through the flash path.  q [R, S, NH, dn + dr]."""
+    from ...ops.attention import causal_attention
+    R, S, NH, dqk = q.shape
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    kv = _dense(c.reshape(R * S, -1), w_kvb).reshape(R, S, NH, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(kr[:, :, None],
+                                        (R, S, NH, kr.shape[-1]))], -1)
+    width = -(-dqk // 128) * 128        # the flash kernel's head widths
+    pad = lambda t: jnp.pad(  # noqa: E731
+        t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
+    # the attention path scales by 1/sqrt(its head width): fold the rest in
+    q = (q.astype(jnp.float32) * math.sqrt(width / dqk)).astype(q.dtype)
+    out = causal_attention(pad(q), pad(k), pad(kv[..., dn:]),
+                           impl=cfg.attn_impl)
+    return out[..., :dv]
+
+
+def _attend_absorbed(cfg, q, arena_c, index, block_tables, pos0, n_valid,
+                     w_kvb):
+    """Rows of queries q [R, S, NH, dn + dr] against the arena, absorbed:
+    the latents are read and never decompressed.  Query i of row r stands
+    at pos0[r] + i; n_valid[r] of them are real."""
+    R, S, NH, _ = q.shape
+    dn = cfg.qk_nope_head_dim
+    w = w_kvb.astype(q.dtype).reshape(cfg.kv_lora_rank, NH, -1)
+    q_abs = jnp.einsum("rsnd,cnd->rsnc", q[..., :dn], w[..., :dn],
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+    from ...ops import mla_paged
+    fn = (mla_paged.mla_paged_attention
+          if _use_latent_kernel(cfg, arena_c.shape[2], S)
+          else mla_paged.mla_paged_reference)
+    u = fn(q_abs, q[..., dn:], arena_c, block_tables, pos0, n_valid, index,
+           sm_scale=1.0 / math.sqrt(q.shape[-1]))
+    return jnp.einsum("rsnc,cnd->rsnd", u, w[..., dn:],
+                      preferred_element_type=jnp.float32).astype(u.dtype)
+
+
+# ----------------------------------------------------------------------
+# the router and this chip's share of the experts
+# ----------------------------------------------------------------------
+def local_rows_cap(assignments: int, local: int, outputs: int) -> int:
+    """Rows of the compact buffer the grouped matmuls run over: four
+    times the share of `assignments` that even routing sends to `local`
+    of `outputs` router outputs, in steps of 16, never more than all of
+    them.  A step whose local assignments pass it runs the buffer again
+    for the rest (exact either way; absent experts never cost a row)."""
+    even = 4 * assignments * local / outputs
+    return min(assignments, max(16, -(-int(even) // 16) * 16))
+
+
+def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid):
+    """h [T, H] -> (MoE(h) [T, H] over the experts held here and the
+    identity experts, counts [len(COUNT_NAMES)] int32).  `experts`: the
+    whole `[L * local, ...]` stacks; `li`: the layer at hand."""
+    T, H = h.shape
+    dt, k = h.dtype, cfg.moe_top_k
+    E, first, El = cfg.moe_experts, cfg.moe_expert_first, cfg.local_experts
+    with jax.named_scope("router"):
+        logits = h.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
+        score = jax.nn.softmax(logits, axis=-1)               # [T, E + Z]
+        choose = score
+        if cfg.moe_router_bias:        # the bias picks, it does not weigh
+            choose = score + lp["moe_router_bias"].astype(jnp.float32)
+        _, topi = jax.lax.top_k(choose, k)                    # [T, k]
+        weight = jnp.take_along_axis(score, topi, axis=1)
+        if cfg.moe_norm_topk_prob:
+            weight = weight / jnp.maximum(
+                jnp.sum(weight, axis=1, keepdims=True), 1e-9)
+        weight = weight * cfg.moe_routed_scaling
+    with jax.named_scope("zero_experts"):
+        is_zero = topi >= E
+        zero_part = jnp.sum(jnp.where(is_zero, weight, 0.0), axis=1,
+                            keepdims=True) * h.astype(jnp.float32)
+    with jax.named_scope("experts"):
+        ids, wf = topi.reshape(-1), weight.reshape(-1)        # [T * k]
+        picked = jnp.repeat(tok_valid, k)
+        local = (ids >= first) & (ids < first + El) & picked
+        key = jnp.where(local, ids - first, El)
+        order = jnp.argsort(key, stable=True)   # local rows first, by expert
+        sizes = jnp.bincount(key, length=El + 1).astype(jnp.int32)[:El]
+        n_local = jnp.sum(sizes)
+        cap = local_rows_cap(T * k, El, E + cfg.moe_zero_experts)
+        ends = jnp.cumsum(sizes)
+        order = jnp.pad(order, (0, cap))     # a window never slides back
+        every = jnp.zeros((experts["w_up"].shape[0],), jnp.int32)
+
+        def piece(i, acc):
+            """Rows [i * cap, (i + 1) * cap) of the sorted assignments."""
+            lo = i * cap
+            sel = jax.lax.dynamic_slice(order, (lo,), (cap,))
+            part = (jnp.clip(ends, lo, lo + cap)
+                    - jnp.clip(ends - sizes, lo, lo + cap))   # per expert
+            # groups of the whole stack: empty outside this layer
+            groups = jax.lax.dynamic_update_slice(every, part, (li * El,))
+            tok = sel // k
+            xs = jnp.take(h, tok, axis=0)
+            g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
+                                   preferred_element_type=jnp.float32)
+            u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
+                                   preferred_element_type=jnp.float32)
+            act = (jax.nn.silu(g) * u).astype(dt)
+            down = jax.lax.ragged_dot(act, experts["w_down"], groups,
+                                      preferred_element_type=jnp.float32)
+            # rows past the last group belong to no expert held here
+            mine = (lo + jnp.arange(cap) < n_local)[:, None]
+            return acc.at[tok].add(
+                jnp.where(mine, down * wf[sel][:, None], 0.0))
+
+        # one piece unless routing piles more than `cap` rows on this share
+        routed = jax.lax.fori_loop(0, (n_local + cap - 1) // cap, piece,
+                                   jnp.zeros((T, H), jnp.float32))
+    picked = picked.reshape(T, k)
+    counts = jnp.stack([
+        jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
+        jnp.max(sizes), jnp.ones((), jnp.int32)]).astype(jnp.int32)
+    return (routed + zero_part).astype(dt), counts
+
+
+# ----------------------------------------------------------------------
+# the double layer, once
+# ----------------------------------------------------------------------
+def _rows(fn, n, ins, extra):
+    """Token-wise work over the first `n` rows of `ins` ([T, ...] arrays,
+    real rows in front), `ROW_TILE` rows at a time for as many passes as
+    `n` needs; rows no pass reached come out zero.  `fn(*tile_ins) ->
+    (row outputs, a summand for `extra`)`.  A program of at most
+    `ROW_TILE` rows (or not whole tiles) takes them all at once."""
+    T = ins[0].shape[0]
+    tile = ROW_TILE if T > ROW_TILE and T % ROW_TILE == 0 else T
+    if tile == T:
+        outs, e = fn(*ins)
+        return outs, extra + e
+    shapes, _ = jax.eval_shape(fn, *[
+        jax.ShapeDtypeStruct((tile,) + a.shape[1:], a.dtype) for a in ins])
+
+    def one(i, carry):
+        outs, extra = carry
+        lo = i * tile
+        part, e = fn(*[jax.lax.dynamic_slice_in_dim(a, lo, tile)
+                       for a in ins])
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, p, lo, 0)
+                     for o, p in zip(outs, part)), extra + e
+
+    return jax.lax.fori_loop(
+        0, (n + tile - 1) // tile, one,
+        (tuple(jnp.zeros((T,) + s.shape[1:], s.dtype) for s in shapes),
+         extra))
+
+
+def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
+             block_tables, form: str):
+    """tokens/positions/valid [R, S] (a row's real tokens first);
+    block_tables [R, MB]; `form` as the module docstring.  Returns (hidden
+    states [R, S, H], arena)."""
+    R, S = tokens.shape
+    H, T, NH, dt = cfg.hidden_size, R * S, cfg.num_heads, cfg.dtype
+    dn, dr, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    L, El = cfg.num_layers, cfg.local_experts
+    nb, bs, Wa = arena["c"].shape[1:]
+    MB = block_tables.shape[1]
+    s_q = math.sqrt(H / cfg.q_lora_rank) if cfg.mla_scale_q_lora else 1.0
+    s_kv = math.sqrt(H / rank) if cfg.mla_scale_kv_lora else 1.0
+    experts = {n: w.reshape((L * El,) + w.shape[2:]).astype(dt)
+               for n, w in params["experts"].items()}
+
+    blk = jnp.take_along_axis(block_tables,
+                              jnp.clip(positions // bs, 0, MB - 1), axis=1)
+    blk = jnp.where(valid, blk, nb).reshape(T)       # padded slots drop
+    off, pos, real = (positions % bs).reshape(T), positions.reshape(T), \
+        valid.reshape(T)
+    toks, n = tokens.reshape(T), jnp.sum(valid)
+    # more rows than a pass takes: the real ones go in front (`_rows`)
+    compact = T > ROW_TILE and T % ROW_TILE == 0
+    if compact:
+        order = jnp.argsort(~real, stable=True)
+        back = jnp.argsort(order)
+        toks, pos, real, blk, off = (a[order] for a in
+                                     (toks, pos, real, blk, off))
+    in_rows = lambda a: (a[back] if compact else a).reshape(  # noqa: E731
+        (R, S) + a.shape[1:])
+    in_line = lambda a: a.reshape((T,) + a.shape[2:])[order] \
+        if compact else a.reshape((T,) + a.shape[2:])  # noqa: E731
+    none = jnp.zeros((), jnp.int32)
+    x = _embed(cfg, params, toks, pos)                            # [T, H]
+
+    def project(sp, t, pos):
+        """Normed input t [tile, H] -> (queries with their rope part
+        rotated [tile, NH * (dn + dr)], cache rows [tile, Wa])."""
+        with jax.named_scope("mla_proj"):
+            # both scale factors ride the bottleneck norms (q = W_qb s_q cq)
+            cq = _rms(_dense(t, sp["wq_a"]), sp["q_a_norm_scale"],
+                      cfg.norm_eps, s_q)
+            q = _dense(cq, sp["wq_b"]).reshape(-1, NH, dn + dr)
+            q = jnp.concatenate(
+                [q[..., :dn], _rope_pairs(q[..., dn:], pos, cfg.rope_theta)],
+                -1)
+            ckv = _dense(t, sp["wkv_a"])
+            c = _rms(ckv[:, :rank], sp["kv_a_norm_scale"], cfg.norm_eps, s_kv)
+            kr = _rope_pairs(ckv[:, rank:], pos, cfg.rope_theta)
+            row = jnp.pad(jnp.concatenate([c, kr], -1).astype(dt),
+                          ((0, 0), (0, Wa - rank - dr)))
+        return q.reshape(-1, NH * (dn + dr)), row
+
+    def attend(sp, index, q, row, arena_c):
+        """The attention proper of one sub-block on projected queries and
+        rows [T, ...]: (heads' outputs [T, NH * dv], arena)."""
+        with jax.named_scope("latent_write"):
+            arena_c = arena_c.at[index, blk, off].set(row, mode="drop")
+        with jax.named_scope("mla_attention"):
+            q = in_rows(q).reshape(R, S, NH, dn + dr)
+            if form == "fresh":
+                row = in_rows(row)
+                o = _attend_fresh(cfg, q, row[..., :rank],
+                                  row[..., rank:rank + dr], sp["wkv_b"])
+            else:
+                o = _attend_absorbed(
+                    cfg, q, arena_c, index, block_tables, positions[:, 0],
+                    jnp.sum(valid, axis=1), sp["wkv_b"])
+        return in_line(o.reshape(R, S, NH * cfg.v_head_dim).astype(dt)), \
+            arena_c
+
+    def ffn(sp, h):
+        with jax.named_scope("dense_ffn"):
+            return _plain_mlp(cfg, sp, h)
+
+    def out_proj(sp, o):
+        with jax.named_scope("mla_proj"):
+            return _dense(o, sp["wo"])
+
+    def layer(carry, xs):
+        x, arena_c, counts = carry
+        lp, li = xs
+        sp0, sp1 = lp["sub"]
+
+        def before(x, pos):
+            return project(sp0, _rms(x, sp0["attn_norm_scale"],
+                                     cfg.norm_eps), pos), none
+
+        def between(x, o, pos, real):
+            a0 = x + out_proj(sp0, o)
+            h0 = _rms(a0, sp0["mlp_norm_scale"], cfg.norm_eps)
+            m, c = _moe(cfg, lp, experts, li, h0, real)
+            y0 = a0 + ffn(sp0, h0)
+            q, row = project(sp1, _rms(y0, sp1["attn_norm_scale"],
+                                       cfg.norm_eps), pos)
+            return (y0, m, q, row), c
+
+        def after(y0, m, o):
+            a1 = y0 + out_proj(sp1, o)
+            y1 = a1 + ffn(sp1, _rms(a1, sp1["mlp_norm_scale"], cfg.norm_eps))
+            return (y1 + m,), none
+
+        (q, row), _ = _rows(before, n, (x, pos), none)
+        o, arena_c = attend(sp0, 2 * li, q, row, arena_c)
+        (y0, m, q, row), counts = _rows(between, n, (x, o, pos, real),
+                                        counts)
+        o, arena_c = attend(sp1, 2 * li + 1, q, row, arena_c)
+        (x,), _ = _rows(after, n, (y0, m, o), none)
+        return (x, arena_c, counts), None
+
+    (x, arena_c, counts), _ = jax.lax.scan(
+        layer, (x, arena["c"], arena["moe_counts"]),
+        (params["layers"], jnp.arange(L)))
+    return in_rows(x), {**arena, "c": arena_c, "moe_counts": counts}
+
+
+def _last_logits(cfg, params, x, last):
+    xl = x[jnp.arange(x.shape[0]), jnp.clip(last, 0, x.shape[1] - 1)]
+    logits = _lm_logits(cfg, params, xl)
+    return logits, greedy_tokens(logits)
+
+
+def prefill_full(cfg, params, arena, tokens, lens, block_tables, active):
+    """`ragged_ops.prefill_full` for a latent model (same contract)."""
+    NS, S = tokens.shape
+    lens = jnp.where(active, lens, 0)
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
+                                 (NS, S))
+    x, arena = _forward(cfg, params, arena, tokens, positions,
+                        positions < lens[:, None], block_tables, "fresh")
+    return (*_last_logits(cfg, params, x, lens - 1), arena)
+
+
+def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
+                   block_tables, active):
+    """`ragged_ops.prefill_chunks` for a latent model (same contract)."""
+    C = tokens.shape[1]
+    pos0s = jnp.where(active, pos0s, 0)
+    n_valids = jnp.where(active, n_valids, 0)
+    positions = pos0s[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+    valid = jnp.arange(C)[None] < n_valids[:, None]
+    x, arena = _forward(cfg, params, arena, tokens, positions, valid,
+                        block_tables, "cached")
+    return (*_last_logits(cfg, params, x, n_valids - 1), arena)
+
+
+def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active):
+    """`ragged_ops._decode_core` for a latent model: (logits, arena)."""
+    x, arena = _forward(cfg, params, arena, tokens[:, None],
+                        seq_lens[:, None], active[:, None], block_tables,
+                        "decode")
+    return _lm_logits(cfg, params, x[:, 0]), arena

@@ -36,6 +36,7 @@ ARCH_REGISTRY = {
     "opt": "opt",
     "bloom": "bloom",
     "gptneox": "gptneox",
+    "longcat_flash": "longcat_flash",
 }
 
 
@@ -97,6 +98,12 @@ def check_serving_moe(model_config, serving_config) -> None:
     if moe is None or not moe.enabled:
         return
     E = model_config.moe_experts
+    if getattr(model_config, "latent", False):
+        raise ValueError(
+            "serving.moe (expert paging) pages whole experts of a model "
+            "that holds them all; the latent-attention MoE block holds a "
+            "fixed share of its experts (moe_expert_first/count) and "
+            "routes the rest to other chips — drop serving.moe")
     if E <= 1:
         raise ValueError(
             f"serving.moe needs an MoE model layout (moe_experts > 1); "

@@ -62,7 +62,17 @@ def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
     enough to pad AND the padded per-device 5-D footprint exceeds
     ~8 GiB (the serving programs need several GB of temps on top) —
     smaller arenas keep the 5-D layout the fused Pallas kernels consume
-    directly.  The serving programs branch on the arena rank."""
+    directly.  The serving programs branch on the arena rank.
+
+    A latent-attention model (`cfg.latent`) caches one row per token and
+    attention and no V: `latent_ops.init_latent_arena` (key "c")."""
+    if cfg.latent:
+        if (topology is not None and topology.tp_size > 1) or moe_census:
+            raise ValueError(
+                "the latent (MLA) arena has no head dimension to shard "
+                "over tp and no expert-paging census rider")
+        from .latent_ops import init_latent_arena
+        return init_latent_arena(cfg, num_blocks, block_size)
     D = cfg.head_dim
     logical = (cfg.num_layers * num_blocks * block_size
                * cfg.kv_heads * D * jnp.dtype(cfg.dtype).itemsize)
@@ -384,6 +394,11 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     shapes than NC separate calls, and NC fewer host dispatches).
     Returns (logits [NC, V] — last valid token each, their argmax
     [NC] int32 (`greedy_tokens`), arena)."""
+    if cfg.latent:
+        from . import latent_ops
+        latent_ops.refuse_lora(lora)
+        return latent_ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
+                                         n_valids, block_tables, active)
     NC, C = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -581,7 +596,10 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
     flash-capable too — otherwise causal_attention would SILENTLY serve
     the jnp reference here while the chunked path raises, violating the
     no-silent-fallback contract (_gate_fused); such configs stay chunked
-    (and get that loud error)."""
+    (and get that loud error).  The latent block pads its own head
+    widths for the flash path (latent_ops._attend_fresh)."""
+    if cfg.latent:
+        return True
     D = cfg.head_dim
     flash_ok = D % 128 == 0 or D == 64
     return (cfg.pos_emb in ("rope", "learned") and cfg.sliding_window is None
@@ -620,6 +638,10 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
     `blk -> nb` for invalid slots) discards padded K/V writes, and the
     logits slice reads only each prompt's LAST VALID token.
     """
+    if cfg.latent:
+        from . import latent_ops
+        return latent_ops.prefill_full(cfg, params, arena, tokens, lens,
+                                       block_tables, active)
     from ...ops.attention import causal_attention
     NS, S = tokens.shape
     bs = arena["k"].shape[2]
@@ -1293,6 +1315,10 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
     query may see, so position i attends its own draft prefix — the
     conditioning speculative verification needs.  Returns
     (logits [B, S, V] at every span position, arena)."""
+    if cfg.latent:
+        raise NotImplementedError(
+            "speculative verify spans are not wired into the latent (MLA) "
+            "block (the engine reports supports_draft_verify = False)")
     B, S = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1475,6 +1501,11 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
 def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                  block_tables, active, n_tp: int = 1, mesh=None,
                  adapter_ids=None, lora=None):
+    if cfg.latent:
+        from . import latent_ops
+        latent_ops.refuse_lora(lora)
+        return latent_ops.decode_core(cfg, params, arena, tokens, seq_lens,
+                                      block_tables, active)
     B = tokens.shape[0]
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]

@@ -17,6 +17,7 @@ from .transformer import (
     opt_config,
     bloom_config,
     gptneox_config,
+    longcat_flash_config,
 )
 
 from .hf_loader import load_hf_model, hf_to_config, convert_state_dict
@@ -34,6 +35,7 @@ MODEL_FAMILIES = {
     "opt": opt_config,
     "bloom": bloom_config,
     "gptneox": gptneox_config,
+    "longcat_flash": longcat_flash_config,
 }
 
 
@@ -53,5 +55,5 @@ __all__ = [
     "gpt2_config", "llama_config", "mistral_config", "mixtral_config",
     "qwen2_config", "qwen2_moe_config", "phi_config", "phi3_config",
     "falcon_config", "opt_config",
-    "bloom_config", "gptneox_config",
+    "bloom_config", "gptneox_config", "longcat_flash_config",
 ]

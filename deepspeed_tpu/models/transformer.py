@@ -44,7 +44,7 @@ __all__ = [
     "TransformerConfig", "Transformer", "gpt2_config", "llama_config",
     "mistral_config", "mixtral_config", "qwen2_config", "qwen2_moe_config",
     "phi_config", "phi3_config", "falcon_config", "opt_config",
-    "bloom_config", "gptneox_config",
+    "bloom_config", "gptneox_config", "longcat_flash_config",
 ]
 
 
@@ -143,6 +143,37 @@ class TransformerConfig:
     scan_unroll: int = 1            # lax.scan unroll factor over layers
                                     # (larger: XLA schedules across layer
                                     # boundaries; costs compile time)
+    # latent (MLA) attention, on when kv_lora_rank > 0: queries go through a
+    # q_lora_rank bottleneck, keys and values are up-projections of ONE
+    # kv_lora_rank-wide normed latent per token, and a qk_rope_head_dim-wide
+    # rotary key is shared by all heads.  The serving cache holds
+    # [latent | rotary key] per token and attention, nothing per head
+    # (inference/v2/latent_ops.py).  mla_scale_*: multiply q by
+    # sqrt(hidden/q_lora_rank) and the latent by sqrt(hidden/kv_lora_rank).
+    # A latent layer rotates the pairs (2i, 2i+1) and is the
+    # shortcut-connected double block: TWO attention + dense-FFN
+    # sub-blocks; the MoE reads the first sub-block's post-attention norm
+    # and its output joins the residual after the second FFN
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    moe_expert_ffn: int = 0         # routed experts' width (intermediate_size
+                                    # is the dense FFNs')
+    # router outputs past moe_experts that return their input ("identity"
+    # zero-compute experts); selection by score + a bias buffer, weights
+    # from the unbiased score times moe_routed_scaling
+    moe_zero_experts: int = 0
+    moe_router_bias: bool = False
+    moe_routed_scaling: float = 1.0
+    # the share of the routed experts THIS program holds: experts
+    # [first, first + count) (count 0: all).  The router still scores
+    # every expert; assignments to absent ones are another chip's work
+    moe_expert_first: int = 0
+    moe_expert_count: int = 0
 
     def __post_init__(self):
         # static feature-compat checks: fail at config time, not with silently
@@ -223,6 +254,37 @@ class TransformerConfig:
             raise ValueError(
                 "post_norm (OPT-350m block) supports only the sequential "
                 "dense block")
+        if self.latent:
+            if not (self.q_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            if not (self.moe_experts > 1
+                    and self.moe_expert_ffn and self.pos_emb == "rope"
+                    and self.norm == "rmsnorm"
+                    and self.activation == "swiglu"
+                    and self.sliding_window is None
+                    and self.sliding_window_layers is None
+                    and not self.qkv_bias and self.tie_embeddings is False):
+                raise ValueError(
+                    "latent attention is served inside the shortcut-connected "
+                    "MoE double block only (moe_experts > 1, "
+                    "moe_expert_ffn, rope, rmsnorm, swiglu, no window, no "
+                    "qkv bias, untied head)")
+            if not (0 <= self.moe_expert_first
+                    and self.moe_expert_first + self.local_experts
+                    <= self.moe_experts):
+                raise ValueError(
+                    f"experts [{self.moe_expert_first}, "
+                    f"{self.moe_expert_first + self.local_experts}) are not "
+                    f"among the {self.moe_experts} routed experts")
+        elif (self.moe_zero_experts or self.moe_router_bias
+              or self.moe_expert_count or self.moe_expert_first):
+            raise ValueError(
+                "moe_zero_experts, moe_router_bias and the expert share "
+                "(moe_expert_first/count) exist only in the "
+                "latent-attention double block (kv_lora_rank > 0)")
         if self.embed_proj_dim and self.tiled_loss_shards > 1:
             raise ValueError(
                 "tiled_loss_shards with embed_proj_dim is not supported: "
@@ -236,6 +298,19 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values cached per token and attention: [latent | rotary key]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def local_experts(self) -> int:
+        return self.moe_expert_count or self.moe_experts
 
     @property
     def ffn_dim(self) -> int:
@@ -452,10 +527,80 @@ def gptneox_config(size: str = "20b", **kw) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def longcat_flash_config(size: str = "chat", **kw) -> TransformerConfig:
+    """LongCat-Flash (meituan-longcat/LongCat-Flash-Chat config.json):
+    latent attention, shortcut-connected double layers, a router over
+    512 routed + 256 identity experts.  Serving only
+    (inference/v2/latent_ops.py)."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=2, num_heads=4,
+                     max_seq_len=512, vocab_size=512, intermediate_size=128,
+                     q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, moe_experts=32,
+                     moe_zero_experts=16, moe_top_k=4, moe_expert_ffn=32),
+        "chat": dict(hidden_size=6144, num_layers=28, num_heads=64,
+                     max_seq_len=131072, vocab_size=131072,
+                     intermediate_size=12288, q_lora_rank=1536,
+                     kv_lora_rank=512, qk_nope_head_dim=128,
+                     qk_rope_head_dim=64, v_head_dim=128, moe_experts=512,
+                     moe_zero_experts=256, moe_top_k=12,
+                     moe_expert_ffn=2048),
+    }
+    base = dict(pos_emb="rope", norm="rmsnorm", activation="swiglu",
+                tie_embeddings=False, rope_theta=1e7, norm_eps=1e-5,
+                mla_scale_q_lora=True, mla_scale_kv_lora=True,
+                moe_router_bias=True, moe_routed_scaling=6.0,
+                moe_norm_topk_prob=False)
+    base.update(presets[size])
+    base.update(kw)
+    return TransformerConfig(**base)
+
+
 # ----------------------------------------------------------------------
 # init
 # ----------------------------------------------------------------------
+def _init_latent_params(key, cfg: TransformerConfig) -> PyTree:
+    """Random weights in the latent double block's layout (the leaves
+    `inference/v2/latent_ops.py` reads): per layer the two sub-blocks'
+    leaves (`sub`), the router and its bias; this chip's experts apart."""
+    H, L, NH, F = (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
+                   cfg.ffn_dim)
+    rq, rkv, Fe = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.moe_expert_ffn
+    dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    dkv = cfg.qk_nope_head_dim + cfg.v_head_dim
+    E, El = cfg.moe_experts + cfg.moe_zero_experts, cfg.local_experts
+    out_std = 0.02 / math.sqrt(2 * L)
+    keys = iter(jax.random.split(key, 32))
+
+    def rnd(shape, std=0.02):
+        return jax.random.normal(next(keys), shape, jnp.float32) * std
+
+    def sub_block():
+        ones = lambda n: jnp.ones((L, n), jnp.float32)  # noqa: E731
+        return {"attn_norm_scale": ones(H), "mlp_norm_scale": ones(H),
+                "q_a_norm_scale": ones(rq), "kv_a_norm_scale": ones(rkv),
+                "wq_a": rnd((L, H, rq)), "wq_b": rnd((L, rq, NH * dqk)),
+                "wkv_a": rnd((L, H, cfg.latent_width)),
+                "wkv_b": rnd((L, rkv, NH * dkv)),
+                "wo": rnd((L, NH * cfg.v_head_dim, H), out_std),
+                "w_gate": rnd((L, H, F)), "w_up": rnd((L, H, F)),
+                "w_down": rnd((L, F, H), out_std)}
+
+    return {
+        "tok_embed": rnd((cfg.vocab_size, H)),
+        "lm_head": rnd((H, cfg.vocab_size)),
+        "final_norm_scale": jnp.ones((H,), jnp.float32),
+        "layers": {"sub": [sub_block(), sub_block()],
+                   "moe_gate": rnd((L, H, E)),
+                   "moe_router_bias": jnp.zeros((L, E), jnp.float32)},
+        "experts": {"w_gate_proj": rnd((L, El, H, Fe)),
+                    "w_up": rnd((L, El, H, Fe)),
+                    "w_down": rnd((L, El, Fe, H), out_std)}}
+
+
 def _init_params(key, cfg: TransformerConfig) -> PyTree:
+    if cfg.latent:
+        return _init_latent_params(key, cfg)
     H, L = cfg.hidden_size, cfg.num_layers
     D, NH, NKV = cfg.head_dim, cfg.num_heads, cfg.kv_heads
     F, V = cfg.ffn_dim, cfg.vocab_size
@@ -1467,13 +1612,23 @@ class Transformer:
     def init_params(self, key) -> PyTree:
         return _init_params(key, self.cfg)
 
+    def _refuse_latent(self, what: str) -> None:
+        if self.cfg.latent:
+            raise NotImplementedError(
+                f"{what} has no latent-attention (MLA) double block: this "
+                f"configuration is served through inference.v2 "
+                f"(build_engine -> ServeLoop) only")
+
     def loss_fn(self, params, batch, rng=None):
+        self._refuse_latent("Transformer.loss_fn (training, initialize())")
         return _lm_loss(self.cfg, params, batch, rng)
 
     def init_cache(self, batch: int, max_len: int):
+        self._refuse_latent("Transformer.init_cache (the dense K/V cache)")
         return init_kv_cache(self.cfg, batch, max_len)
 
     def forward_with_cache(self, params, input_ids, cache):
+        self._refuse_latent("Transformer.forward_with_cache")
         return forward_with_cache(self.cfg, params, input_ids, cache)
 
     def tp_rules(self, path, shape):
@@ -1490,6 +1645,7 @@ class Transformer:
         return spec
 
     def forward(self, params, input_ids, positions=None):
+        self._refuse_latent("Transformer.forward")
         logits, _ = _forward(self.cfg, params, input_ids, positions)
         return logits
 

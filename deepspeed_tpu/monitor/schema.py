@@ -45,6 +45,8 @@ SERVING_TAGS = frozenset(
         "rejected_draining", "evicted_in_flight", "spec_drafted",
         "spec_accepted", "handoff_parked",
         "sampled_on_device", "sampled_on_host",
+        "moe_picks", "moe_zero_picks", "moe_local_rows",
+        "moe_busiest_rows", "moe_router_calls",
         # token streaming + SLO-aware preemption (ISSUE 15):
         # exactly-once delivery accounting and the swap-or-recompute
         # preemption lifecycle

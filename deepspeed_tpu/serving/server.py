@@ -436,6 +436,14 @@ class ServeLoop:
                      or engine.cfg.moe_experts)  # 0 = full residency
             self._expert_pool = engine.enable_expert_paging(
                 slots, spill=self._moe.spill)
+        # the latent MoE block's router counters (an engine with
+        # supports_moe_counts): drained every COUNT_DRAIN_STEPS steps
+        # into the telemetry counters and a `serve.moe_census` span;
+        # 0 = an engine without them, never asked
+        self._moe_counts_every = 0
+        if getattr(engine, "supports_moe_counts", False):
+            from ..inference.v2.latent_ops import COUNT_DRAIN_STEPS
+            self._moe_counts_every = COUNT_DRAIN_STEPS
         # observability (serving/tracing.py): per-request span traces +
         # the per-step timeline profiler.  Both default off (tracing is
         # None) and every hook below guards on None — the untraced loop
@@ -1374,6 +1382,15 @@ class ServeLoop:
                 % self._moe.census_interval_steps == 0):
             self._expert_pool.ingest_census(self.engine.drain_moe_census())
             self._expert_pool.rebalance(self._moe.max_promotes_per_step)
+        # the latent MoE block's router counters: drained on the same
+        # kind of interval, into the counters and a span a trace carries
+        if (self._moe_counts_every and (self.telemetry.steps + 1)
+                % self._moe_counts_every == 0):
+            with span("serve.moe_census") as census:
+                counts = self.engine.drain_moe_counts()
+                census.set_metadata(**counts)
+            for name, n in counts.items():
+                self.telemetry.count("moe_" + name, n)
 
         self.telemetry.record_step(
             queue_depth=self.scheduler.queue_depth,

@@ -72,6 +72,13 @@ class ServingTelemetry:
             # the host from a fetched logits row (temperature > 0,
             # grammar-masked rows, engines that return host rows)
             "sampled_on_device": 0, "sampled_on_host": 0,
+            # latent MoE block (inference/v2/latent_ops.COUNT_NAMES),
+            # drained from the device every COUNT_DRAIN_STEPS serve steps:
+            # top-k picks, those on identity experts, those on the
+            # experts held here, the busiest local expert's rows
+            # (summed over router calls), router calls
+            "moe_picks": 0, "moe_zero_picks": 0, "moe_local_rows": 0,
+            "moe_busiest_rows": 0, "moe_router_calls": 0,
             # token streaming (serving/streaming.py): tokens delivered
             # through request streams, tokens regenerated after a
             # failover and suppressed as verified replay (exactly-once

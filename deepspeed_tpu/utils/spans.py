@@ -21,7 +21,7 @@ __all__ = ["span", "SPAN_NAMES"]
 # the train step's
 SPAN_NAMES = (
     "serve.step", "serve.finalize", "serve.admission", "serve.engine",
-    "serve.sample", "serve.bookkeep",
+    "serve.sample", "serve.bookkeep", "serve.moe_census",
     "engine.plan", "engine.dispatch", "engine.fetch",
     "train.step", "train.shard_batch", "train.dispatch",
 )

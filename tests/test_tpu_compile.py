@@ -127,6 +127,35 @@ def test_merged_arena_decode(chip):
                    chip((), jnp.int32)) == 1
 
 
+# (B, Q, NH, rank, rope, attentions, nb, bs, MB): the LongCat-Flash cell's
+# decode (one query's 64 heads against one 576-wide row, 33 blocks in 5
+# steps of 7, arena minor padded to 640 lanes) and its chunk slots (tiles
+# of 8 queries); then one block a step and 2 heads
+@pytest.mark.parametrize("B,Q,NH,R,Dr,A,nb,bs,MB", [
+    pytest.param(96, 1, 64, 512, 64, 8, 3488, 64, 33, id="longcat-decode"),
+    pytest.param(4, 1024, 64, 512, 64, 8, 3488, 64, 33, id="longcat-chunks"),
+    pytest.param(4, 1, 2, 128, 64, 2, 16, 16, 1, id="one-block"),
+])
+def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
+    """The latent attention kernel at the cell's widths: one Mosaic
+    kernel, and no relayout of the arena (an arena whose minor dimension
+    is not whole 128-lane tiles is copied whole before every call)."""
+    from deepspeed_tpu.ops.mla_paged import mla_paged_attention
+    W = -(-(R + Dr) // 128) * 128
+
+    def attend(qa, qr, arena, tables, pos0, n_valid, index):
+        return mla_paged_attention(qa, qr, arena, tables, pos0, n_valid,
+                                   index, sm_scale=0.07)
+
+    args = (chip((B, Q, NH, R)), chip((B, Q, NH, Dr)), chip((A, nb, bs, W)),
+            chip((B, MB), jnp.int32), chip((B,), jnp.int32),
+            chip((B,), jnp.int32), chip((), jnp.int32))
+    assert kernels(attend, *args) == 1
+    with jax.default_matmul_precision("default"):
+        mem = jax.jit(attend).lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("M,K,N", [(256, 2048, 5632), (8, 2048, 2048)])
 def test_tile_matmul(chip, M, K, N):
     """The fused-TP ring's per-hop GEMM (ops.tp_matmul; `tile_matmul`
