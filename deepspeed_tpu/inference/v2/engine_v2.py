@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from collections.abc import Mapping
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Collection, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -49,14 +49,26 @@ class _Row:
         self.logits, self.i, self.token, self.host = logits, i, token, host
 
 
+class _Program:
+    """One dispatched per-step program: its outputs still on the device
+    and the (sequence, row) pairs it computed a token for.  The row is
+    bound to the sequence object, not the uid: a preempted request comes
+    back under its uid as another sequence."""
+    __slots__ = ("name", "logits", "toks", "rows")
+
+    def __init__(self, name: str, logits, toks, rows: List[tuple]):
+        self.name, self.logits, self.toks, self.rows = (name, logits, toks,
+                                                        rows)
+
+
 class LogitsRows(Mapping):
     """{uid: last-token logits row [V]} over logits left on the device:
     what `put`/`step` return and `query` reads.
 
     The per-step programs hand back each row's greedy token beside the
     logits (`ragged_ops.greedy_tokens`), and only those [N] int32 cross
-    when the step ends: `greedy(uid)` reads them.  A logits row crosses
-    when it is read, through the engine's explicit fetch (an
+    when the step is collected: `greedy(uid)` reads them.  A logits row
+    crosses when it is read, through the engine's explicit fetch (an
     `engine.fetch` span, one count in `profile["d2h_fetches"]`):
     `rows[uid]` brings that row alone, `items()` brings each program's
     whole output once (the burst loops' batched first-token sampler
@@ -64,11 +76,40 @@ class LogitsRows(Mapping):
     Keys, `in` and `len` fetch nothing.  `rows[uid] = row` puts a host
     row in a row's place: it has no token of the program's, so
     `greedy(uid)` is None and whoever wants its token samples that
-    row."""
+    row.
 
-    def __init__(self, fetch):
+    A step dispatched with `collect=False` returns its rows `pending`:
+    the programs that will fill them are listed (`prefill`, `decode`),
+    their tokens still on the device, and the mapping is empty until
+    `engine.collect` fetches them.  `items()` alone collects what is
+    pending before it reads: a compatibility route for a caller that
+    wraps `step` and reads every row of whatever it returned (the
+    benchmark's altered-token test, `HostRowsOnly`), which must see
+    rows at once; the serve loop never reads rows it has not
+    collected."""
+
+    def __init__(self, fetch, collect=None):
         self._fetch = fetch       # (device logits, row or None) -> host
+        self._collect = collect   # (these rows) -> their tokens fetched
         self._rows: Dict[int, _Row] = {}
+        self.prefill: List[_Program] = []
+        self.decode: Optional[_Program] = None
+        # of the step's decode rows, how many took their input token on
+        # the device from the decode program of the step before
+        self.decode_rows = self.fed_rows = 0
+        # what `engine.collect` returns: the rows it left out because
+        # their sequence had been flushed (or replaced under its uid)
+        self.overrun = 0
+
+    @property
+    def pending(self) -> bool:
+        return bool(self.prefill) or self.decode is not None
+
+    @property
+    def awaited(self) -> int:
+        """The rows of the programs not collected yet."""
+        return sum(len(prog.rows) for prog in self.prefill) + (
+            0 if self.decode is None else len(self.decode.rows))
 
     def _set(self, uid: int, logits, i: int, token: int) -> None:
         self._rows[uid] = _Row(logits, i, token)
@@ -100,6 +141,8 @@ class LogitsRows(Mapping):
         return None if row is None else row.token
 
     def items(self):
+        if self.pending:
+            self._collect(self)
         pending: Dict[int, List[_Row]] = {}
         for row in self._rows.values():
             if row.host is None:
@@ -115,6 +158,13 @@ class LogitsRows(Mapping):
 
     def discard(self, uid: int) -> None:
         self._rows.pop(uid, None)
+
+
+def _feed_tokens(staged, fed, source):
+    """A decode step's input tokens: row i takes row `source[i]` of the
+    step before's tokens, still on the device (`fed`), or, where
+    `source[i]` < 0, the token the host staged."""
+    return jnp.where(source >= 0, fed[jnp.maximum(source, 0)], staged)
 
 
 @dataclass
@@ -245,7 +295,13 @@ class InferenceEngineV2:
             self._replicated = NamedSharding(mesh, PartitionSpec())
             self._param_specs = specs
         else:
-            self._replicated = None
+            # weights somebody committed to a device make every program's
+            # outputs committed; host inputs are then staged the same
+            # way, so a program sees one kind of operand whether it came
+            # from the host or from the program before (`dispatch`)
+            held = [x.sharding for x in jax.tree.leaves(self.params)
+                    if x.committed and len(x.sharding.device_set) == 1]
+            self._replicated = held[0] if held else None
             self._param_specs = None
 
         self.state = DSStateManager(
@@ -302,6 +358,17 @@ class InferenceEngineV2:
         # (mode="greedy" ignores it; a fresh per-dispatch staging would
         # put one needless h2d transfer on the hot path)
         self._greedy_temp = self._host_in(np.zeros((), np.float32))
+        # the select in front of every decode_step (`dispatch`): built
+        # and run once here, so no later step compiles it; `_no_tokens`
+        # stands in for the step before's tokens where no row is fed
+        self._feed_tokens = jax.jit(
+            _feed_tokens, **({} if self._replicated is None
+                             else dict(out_shardings=self._replicated)))
+        self._no_tokens = self._host_in(
+            np.zeros(self.config.max_seqs, np.int32))
+        self._feed_tokens(self._no_tokens, self._no_tokens,
+                          self._host_in(np.full(self.config.max_seqs, -1,
+                                                np.int32)))
         # fresh-full-prompt fast path (ragged_ops.prefill_full): dense
         # causal flash for whole prompts — gated off under tp (no
         # shard_map wiring) and for archs whose masks live in the chunk
@@ -588,7 +655,8 @@ class InferenceEngineV2:
 
     def _host_in(self, x):
         """Stage a host array as a replicated device array under tp (so jit
-        sees consistent NamedShardings); pass through otherwise."""
+        sees consistent NamedShardings), or beside weights committed to
+        one device; pass through otherwise."""
         x = jnp.asarray(x)
         if self._replicated is not None:
             x = jax.device_put(x, self._replicated)
@@ -596,7 +664,7 @@ class InferenceEngineV2:
 
     # -- scheduling ------------------------------------------------------
     def put(self, uids: Sequence[int], tokens_list: Sequence[np.ndarray],
-            decode: bool = True, prefixes=None) -> LogitsRows:
+            decode: bool = True, prefixes=None, **step_kw) -> LogitsRows:
         """Admit new sequences and advance the ragged batch one step
         (reference `put` :107).  Returns {uid: last-token logits} for every
         sequence that produced fresh logits this call (`LogitsRows`: a
@@ -604,6 +672,10 @@ class InferenceEngineV2:
         runs only the prefill phase — the burst serve loop owns decode via
         `decode_burst_step` and must not have pending burst-chain tokens
         consumed by the per-step decode path here.
+
+        Further keywords (`ahead`, `hold`, `collect`) go to `step` as
+        given: a caller that gives none reaches `step(decode=...)` as
+        ever (tests and tools replace `step` under that signature).
 
         `prefixes` maps a fresh uid to a PrefixLease the caller already
         acquired — or to None recording a known miss (the serve loop
@@ -663,7 +735,7 @@ class InferenceEngineV2:
                             self.prefix_cache.abandon(lease)
                             raise
                         self._prefix_leases[uid] = lease
-        return self.step(decode=decode)
+        return self.step(decode=decode, **step_kw)
 
     def _fetch_tokens(self, program: str, toks) -> List[int]:
         """What a per-step program's end costs the host: its [N] int32
@@ -686,8 +758,66 @@ class InferenceEngineV2:
         self.profile["d2h_fetches"] += 1
         return logits
 
-    def step(self, decode: bool = True) -> LogitsRows:
+    def step(self, decode: bool = True, ahead: Optional[LogitsRows] = None,
+             hold: Collection[int] = (), collect: bool = True
+             ) -> LogitsRows:
+        """One ragged step: dispatch its programs, then collect their
+        tokens.  `collect=False` leaves that to the caller, who goes on
+        while the programs run and calls `collect(rows)` later (the
+        serve loop dispatches its next step first); `ahead` and `hold`
+        are `dispatch`'s."""
+        rows = self.dispatch(decode=decode, ahead=ahead, hold=hold)
+        if collect:
+            self.collect(rows)
+        return rows
+
+    def collect(self, rows: LogitsRows,
+                part: Optional[str] = None) -> LogitsRows:
+        """Fetch the tokens of a dispatched step's programs (`part`:
+        "prefill" or "decode" alone; None = all that is left) and fill
+        in their rows — waiting for the tokens is waiting for the
+        programs.  Returns the rows this call filled in.  A row whose
+        sequence was flushed since the dispatch (or replaced under its
+        uid: a preempted request comes back as another sequence) is left
+        out and counted in the result's `overrun`: it was computed for
+        nothing.  The programs leave `rows` only once their tokens are
+        here: after a fetch that raised they can be collected again."""
+        programs = [] if part == "decode" else list(rows.prefill)
+        if part != "prefill" and rows.decode is not None:
+            programs.append(rows.decode)
+        fetched = [self._fetch_tokens(prog.name, prog.toks)
+                   for prog in programs]
         out = LogitsRows(self._fetch_logits)
+        for prog, toks in zip(programs, fetched):
+            if prog is rows.decode:
+                rows.decode = None
+            else:
+                rows.prefill.remove(prog)
+            for d, i in prog.rows:
+                if self.state.seqs.get(d.uid) is d:
+                    out._set(d.uid, prog.logits, i, toks[i])
+                else:
+                    out.overrun += 1
+        rows.update(out)
+        self._last_logits.update(out)
+        return out
+
+    def dispatch(self, decode: bool = True,
+                 ahead: Optional[LogitsRows] = None,
+                 hold: Collection[int] = ()) -> LogitsRows:
+        """`step`'s first half: plan the step and launch its programs
+        (`prefill_full`, `prefill_chunks`, `decode_step`), fetching
+        nothing.  `seen_tokens`, block leases and lengths advance here;
+        the tokens stay on the device, and the rows returned `pending`,
+        until `collect`.
+
+        `ahead` is an earlier step whose decode tokens are still
+        uncollected: a sequence with a row there (and its uid not in
+        `hold`) decodes again, its input token taken from that row on
+        the device (`_feed_tokens`); the host's `generated` list catches
+        up when `ahead` is collected.  Every other decode row takes the
+        pending token the host staged, as ever."""
+        pending = LogitsRows(self._fetch_logits, self.collect)
         C = self.config.prefill_chunk_size
         # a zero/negative budget must still make 1 token of progress per
         # step, or in_prefill sequences (and generate()) would spin forever
@@ -797,10 +927,11 @@ class InferenceEngineV2:
                         self.cfg, self.params, self.arena,
                         self._host_in(ftokens), self._host_in(flens),
                         self._host_in(ftables), self._host_in(factive))
-                toks = self._fetch_tokens("prefill_full", toks)
-                for i, d in enumerate(fresh):
+                for d in fresh:
                     d.seen_tokens = len(d.prompt)
-                    out._set(d.uid, logits, i, toks[i])
+                pending.prefill.append(_Program(
+                    "prefill_full", logits, toks,
+                    [(d, i) for i, d in enumerate(fresh)]))
                 budget -= sum(len(d.prompt) for d in fresh)
                 budget = max(budget, 0)
         # slot bound: every full chunk consumes C budget and each sequence
@@ -865,29 +996,41 @@ class InferenceEngineV2:
                     self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
                     self._host_in(tables[:NC]), self._host_in(active[:NC]),
                     self._host_in(tlens[:NC]), **lkw)
-            toks = self._fetch_tokens("prefill_chunks", toks)
-            for i, (d, start, n) in enumerate(planned):
+            for d, start, n in planned:
                 d.seen_tokens = start + n
-                if not d.in_prefill:
-                    out._set(d.uid, logits, i, toks[i])
+            rows = [(d, i) for i, (d, _, _) in enumerate(planned)
+                    if not d.in_prefill]
+            if rows:      # chunks that end no prompt leave nothing to fetch
+                pending.prefill.append(
+                    _Program("prefill_chunks", logits, toks, rows))
         # 2) decode: one token for every sequence with a pending input token
         #    (suppressed under decode=False: the burst serve path keeps one
         #    pending token per chained sequence, which must wait for the
         #    next decode_burst_step, not be host-decoded here)
         with span("engine.plan") as plan:
-            batch = [d for d in self.state.decode_batch() if d.generated
-                     and d.seen_tokens < len(d.prompt) + len(d.generated)
+            # id(sequence) -> its row of `ahead`'s decode program
+            fed = {id(d): i for d, i in ahead.decode.rows
+                   if d.uid not in hold and self.state.seqs.get(d.uid) is d
+                   } if ahead is not None and ahead.decode is not None \
+                else {}
+            batch = [d for d in self.state.decode_batch() if id(d) in fed
+                     or (d.generated and d.seen_tokens
+                         < len(d.prompt) + len(d.generated))
                      ] if decode else []
             if batch:
                 B = self.config.max_seqs
                 tokens = np.zeros(B, np.int32)
+                source = np.full(B, -1, np.int32)
                 lens = np.zeros(B, np.int32)
                 tables = np.zeros((B, self.config.max_blocks_per_seq),
                                   np.int32)
                 active = np.zeros(B, bool)
                 for i, d in enumerate(batch):
-                    pending_idx = d.seen_tokens - len(d.prompt)
-                    tokens[i] = d.generated[pending_idx]
+                    if id(d) in fed:
+                        source[i] = fed[id(d)]
+                    else:
+                        tokens[i] = d.generated[d.seen_tokens
+                                                - len(d.prompt)]
                     lens[i] = d.seen_tokens
                     self.state.ensure_capacity(d, d.seen_tokens + 1)
                     tables[i] = self.state.block_table(d)
@@ -899,15 +1042,20 @@ class InferenceEngineV2:
                 lkw = ({} if aids is None else
                        dict(adapter_ids=self._host_in(aids), lora=self._lora))
                 logits, toks, self.arena = self._programs.decode_step(
-                    self.params, self.arena, self._host_in(tokens),
+                    self.params, self.arena,
+                    self._feed_tokens(
+                        self._host_in(tokens),
+                        ahead.decode.toks if fed else self._no_tokens,
+                        self._host_in(source)),
                     self._host_in(lens), self._host_in(tables),
                     self._host_in(active), **lkw)
-            toks = self._fetch_tokens("decode_step", toks)
-            for i, d in enumerate(batch):
+            for d in batch:
                 d.seen_tokens += 1
-                out._set(d.uid, logits, i, toks[i])
-        self._last_logits.update(out)
-        return out
+            pending.decode = _Program("decode_step", logits, toks,
+                                      list(zip(batch, range(len(batch)))))
+            pending.decode_rows = len(batch)
+            pending.fed_rows = sum(id(d) in fed for d in batch)
+        return pending
 
     # -- burst decode: on-device sampling, one host dispatch per K tokens
     # the serving layer probes this before merging heterogeneous sampling

@@ -45,6 +45,7 @@ SERVING_TAGS = frozenset(
         "rejected_draining", "evicted_in_flight", "spec_drafted",
         "spec_accepted", "handoff_parked",
         "sampled_on_device", "sampled_on_host",
+        "steps_run_ahead", "steps_collected_at_once", "rows_overrun",
         "moe_picks", "moe_zero_picks", "moe_local_rows",
         "moe_busiest_rows", "moe_router_calls",
         # token streaming + SLO-aware preemption (ISSUE 15):

@@ -62,24 +62,32 @@ class _StepPhases:
     """The phases of one serve step follow one another on one thread, so
     each boundary is written once, as one `enter(name)`: it ends the open
     phase's profiler span and begins the next (always; utils/spans.py),
-    and with the step timeline on (`clock` given) it stamps the serve
-    clock there too (`at[name]`), which is all `StepTimeline` is fed
-    from.  `now` hands over a read the step makes anyway, so with the
-    timeline off the serve clock is touched exactly as without this.
+    and with the step timeline on (`clock` given) it reads the serve
+    clock there too and adds the seconds to the phase that ended
+    (`spent[name]`: a step that collects one engine step and dispatches
+    the next enters `serve.engine` and `serve.sample` twice), which is
+    all `StepTimeline` is fed from.  `now` hands over a read the step
+    makes anyway, so with the timeline off the serve clock is touched
+    exactly as without this.
     Leaving the `with` ends the open phase, also when the step raises."""
 
-    __slots__ = ("_clock", "_open", "at")
+    __slots__ = ("_clock", "_open", "_name", "_since", "spent")
 
     def __init__(self, clock: Optional[Callable[[], float]]):
         self._clock = clock
         self._open = None
-        self.at: Dict[str, float] = {}
+        self._name, self._since = None, 0.0
+        self.spent: Dict[str, float] = {}
 
     def enter(self, name: str, now: Optional[float] = None, **attrs):
         if self._open is not None:
             self._open.__exit__(None, None, None)
         if self._clock is not None:
-            self.at[name] = self._clock() if now is None else now
+            now = self._clock() if now is None else now
+            if self._name is not None:
+                self.spent[self._name] = (self.spent.get(self._name, 0.0)
+                                          + now - self._since)
+            self._name, self._since = name, now
         self._open = span(name, **attrs)
         self._open.__enter__()
         return self._open
@@ -358,6 +366,16 @@ class ServeLoop:
         # router's error handler) reports them, so a mid-step engine
         # failure can never drop a terminal-state notification
         self._finished_backlog: List[Request] = []
+        # the per-step path keeps at most one dispatched engine step
+        # uncollected (its `engine_v2.LogitsRows`, still `pending`): its
+        # programs run on the device while the next call admits and
+        # dispatches, and its tokens are fetched a call late
+        # (`_step_phases` 3).  The capability probed is the engine's
+        # `collect`; an engine without it (the test fakes) collects
+        # inside its own `put`/`step`, as ever.  Oldest first; a second
+        # entry only after a call raised while it waited for the first
+        self._in_flight: List = []
+        self._splits_step = hasattr(engine, "collect")
         self.clock = clock or time.monotonic
         self.scheduler = ContinuousBatchingScheduler(
             max_queue_len=self.config.max_queue_len)
@@ -866,6 +884,9 @@ class ServeLoop:
         retry (`Request.reset_for_retry` + adoption elsewhere) vs
         `Request.fail`."""
         taken = list(self.scheduler.active.values())
+        # a step still in flight is theirs: its uncollected tokens go
+        # (the resumed replica re-prefills prompt + `generated`)
+        self.drop_pending()
         # parked handoff-ready requests (prefill role) are in-flight too:
         # they hold engine sequences and PREFILL state, so a failover off
         # this replica must evict and re-home them like any active request
@@ -934,7 +955,9 @@ class ServeLoop:
         # one more time to collect them even when the crash emptied the
         # scheduler — without a supervisor around to call
         # take_finished_backlog(), they would otherwise vanish
-        return self.scheduler.has_work or bool(self._finished_backlog)
+        # so is a dispatched step whose tokens are not collected yet
+        return (self.scheduler.has_work or bool(self._finished_backlog)
+                or bool(self._in_flight))
 
     # -- the serve step ---------------------------------------------------
     def step(self) -> List[Request]:
@@ -963,10 +986,11 @@ class ServeLoop:
         # the profiler's clock and the serve clock
         phases = _StepPhases(
             self.clock if self._timeline is not None else None)
-        with span("serve.step", step=self.telemetry.steps + 1), phases:
-            return self._step_phases(phases)
+        with span("serve.step", step=self.telemetry.steps + 1) as whole, \
+                phases:
+            return self._step_phases(phases, whole)
 
-    def _step_phases(self, phases: _StepPhases) -> List[Request]:
+    def _step_phases(self, phases: _StepPhases, whole) -> List[Request]:
         now = self.clock()
         # step timeline (observe-only): the phase boundaries read the
         # serve clock only with the timeline on (`_StepPhases`), so the
@@ -1195,6 +1219,28 @@ class ServeLoop:
         #    burst-chained sequences each hold one pending token that
         #    belongs to the NEXT decode burst, which must not be decoded
         #    one step at a time while bursts own decode.
+        #    On the per-step path a serve step is a dispatch and a
+        #    collect (`engine.step(collect=False)` / `engine.collect`),
+        #    and the collect of the step before comes after this step's
+        #    dispatch, so the device holds its next programs while the
+        #    host waits for tokens: first that step's PREFILL tokens
+        #    (its prefill programs ended before the decode program
+        #    queued behind them; a first token is stamped when it is
+        #    here, not at the end of the call), then this step's
+        #    dispatch, then that step's DECODE tokens.  A decode row
+        #    whose input is among those takes it on the device.  This
+        #    step in turn stays uncollected while every active request
+        #    takes the program's own token (temperature <= 0, no grammar
+        #    mask, no adapter row); a token sampled on the host has to
+        #    be here before the next dispatch, so then the same call
+        #    collects what it dispatched before it returns (depth 0).
+        #    A step stays in `_in_flight` until its tokens are here
+        #    (`engine.collect` takes a program off it only with its
+        #    tokens), so a raise loses none: after one in the dispatch
+        #    the next call feeds from and collects the step before as
+        #    if this call had not been; after one in the wait for the
+        #    step before's decode tokens, two steps are left, and the
+        #    next call settles the older before anything else.
         #    The whole admit->put window is crash-atomic: a raise before
         #    put() returns rolls the admissions back to the queue —
         #    without that, a supervised replica that recovers after the
@@ -1207,11 +1253,22 @@ class ServeLoop:
         #    rolled-back admission is neither double-counted on its
         #    retry nor allowed to consume the fleet router's coverage
         #    expectation for an admission that never stuck.
+        split = self._splits_step and not no_decode
+        in_flight = self._in_flight
+        taken = 0
         try:
             admission.set_metadata(admitted=len(admitted))
-            # the engine call, and the bookkeeping of what it admitted
-            # up to the clock's re-read below: the timeline's "prefill"
+            # the engine calls, and the bookkeeping of what they
+            # admitted: the timeline's "prefill"
             phases.enter("serve.engine")
+            while len(in_flight) > 1:
+                taken += self._collect(in_flight[0], None, phases, finished)
+                del in_flight[0]
+                phases.enter("serve.engine")
+            earlier = in_flight[0] if in_flight else None
+            if earlier is not None and earlier.prefill:
+                taken += self._collect(earlier, "prefill", phases, finished)
+                phases.enter("serve.engine")
             # prefill-chunk span attribution reads the clock only when
             # some live request is actually traced (admitted ones
             # already joined the active set above)
@@ -1224,8 +1281,14 @@ class ServeLoop:
             prefill_before = {uid for uid, d
                               in self.engine.state.seqs.items()
                               if d.seen_tokens < len(d.prompt)}
+            # how the engine is to step: dispatch only, a row whose
+            # input token is among `earlier`'s taking it on the device
+            step_kw = (dict(ahead=earlier,
+                            hold=self._ending_by_count(earlier),
+                            collect=False)
+                       if split else dict(decode=False) if no_decode
+                       else {})
             if admitted:
-                put_kw = {}
                 if self._cache is not None:
                     # hand the admission-time lookups to the engine —
                     # hits AND known misses (None), so put() never
@@ -1233,24 +1296,24 @@ class ServeLoop:
                     # until put() RETURNS, so a put that raises leaves
                     # them findable for the rollback (and take_active)
                     # instead of orphaned in a dead local
-                    put_kw["prefixes"] = {
+                    step_kw["prefixes"] = {
                         r.uid: self._prefix_pending.get(r.uid)
                         for r in admitted}
-                if no_decode:
-                    put_kw["decode"] = False
                 out = self.engine.put(
                     [r.uid for r in admitted],
                     [self._effective_tokens(r) for r in admitted],
-                    **put_kw)
+                    **step_kw)
             elif self.scheduler.active and (not no_decode
                                             or prefill_before):
-                out = self.engine.step(decode=False) if no_decode \
-                    else self.engine.step()
+                out = self.engine.step(**step_kw)
             else:
                 out = {}
         except BaseException:
             self._rollback_admission(admitted)
             raise
+        whole.set_metadata(
+            decode_rows=getattr(out, "decode_rows", 0),
+            fed_on_device_rows=getattr(out, "fed_rows", 0))
         self.telemetry.count("admitted", len(admitted))
         if self._tenancy is not None:
             for r in admitted:
@@ -1283,18 +1346,13 @@ class ServeLoop:
             # ACTUALLY got (put() above consumed the leases)
             for r in admitted:
                 self.admit_hook(r, covered_by_uid[r.uid])
-        # re-read the clock: the engine call above is where the step's
-        # time actually goes (compiles, device work), and first-token /
-        # finish stamps must charge it to THIS step's requests, not the
-        # next step's bookkeeping
-        now = self.clock()
-        # host work on what the engine returned: the timeline's "decode"
-        phases.enter("serve.sample", now=now, rows=len(out))
 
         # 4) measured per-step budget accounting: attribute each live
-        #    sequence's progress to prefill or decode work.  (Burst-mode
+        #    sequence's progress to prefill or decode work (the engine
+        #    advances `seen_tokens` as it dispatches).  (Burst-mode
         #    decode tokens are counted in _decode_bursts below — the
         #    engine state read here predates the bursts.)
+        t_engine1 = self.clock() if tracing_step else 0.0
         prefill_toks = decode_toks = 0
         for uid, d in self.engine.state.seqs.items():
             # a fresh prefix-attached sequence starts at seen_tokens ==
@@ -1311,64 +1369,62 @@ class ServeLoop:
                     if req is not None and req.trace is not None:
                         # one span per serve step the prompt advanced:
                         # the chunked-prefill progress a TTFT debug needs
-                        req.trace.span("prefill_chunk", t_engine0, now,
-                                       tokens=delta)
+                        req.trace.span("prefill_chunk", t_engine0,
+                                       t_engine1, tokens=delta)
             else:
                 decode_toks += delta
 
-        if prefill_only:
+        pending = getattr(out, "pending", False)
+        if pending:
+            in_flight.append(out)
+        if earlier is not None:
+            # the step before's decode tokens: the device already holds
+            # this step's programs.  A row whose request ended
+            # meanwhile (EOS seen late, cancel, deadline, preemption)
+            # was computed for nothing and its token is dropped
+            if earlier.decode is not None:
+                taken += self._collect(earlier, "decode", phases,
+                                       finished)
+            in_flight.remove(earlier)
+        if not no_decode:
+            # 5) per-step path: this step's tokens wait on the device
+            #    for the next call while every active request takes the
+            #    program's own token; a row sampled here has to be on
+            #    the host before the next dispatch, so then they are
+            #    collected now.  (An engine that collects inside its own
+            #    put()/step() hands back rows that are never `pending`.)
+            if pending and all(
+                    r.temperature <= 0.0 and r.response_format is None
+                    and r.adapter_id is None
+                    for r in self.scheduler.active.values()):
+                self.telemetry.count("steps_run_ahead")
+            else:
+                self.telemetry.count("steps_collected_at_once",
+                                     1 if pending or out else 0)
+                if pending:
+                    taken += self._collect(out, None, phases, finished)
+                    in_flight.remove(out)
+                else:
+                    taken += self._take_tokens(out, phases, finished)
+        elif prefill_only:
             # 5) prefill pool (disagg): a request whose prompt just
             #    finished is PARKED for the cross-pool handoff — no
             #    first token here (it is sampled on the decode replica
             #    after the KV migrates, so the token stream has exactly
             #    one author), no decode phase ever
+            phases.enter("serve.sample", rows=len(out))
             self._park_handoffs(out)
         elif burst:
             # 5) burst path: batched first tokens from the prefill logits
             #    (TTFT semantics unchanged), then one compiled burst per
-            #    sampling group with on-device sampling
+            #    sampling group with on-device sampling.  The clock is
+            #    re-read: the engine call above is where the step's time
+            #    went, and first-token / finish stamps must charge it to
+            #    THIS step's requests
+            now = self.clock()
+            phases.enter("serve.sample", now=now, rows=len(out))
             self._first_tokens_batch(out, now, finished)
             decode_toks = self._decode_bursts(finished)
-        else:
-            # 5) per-step path: a token for every sequence that produced
-            #    logits; finish or stage the token as the next step's
-            #    decode input.  A row whose sampler is the plain argmax
-            #    (temperature <= 0, no grammar mask: `_sample`) takes the
-            #    token the engine's program chose beside the logits —
-            #    the same f32 row, the same first maximum — and its
-            #    logits never leave the device.  Every other row is
-            #    sampled here from its own logits row: so is a row
-            #    somebody put a host row in the place of (no token), and
-            #    every row of an engine whose step returns plain host
-            #    rows (`engine_v2.LogitsRows.greedy` is the capability
-            #    probed; test fakes return dicts).
-            greedy = getattr(out, "greedy", None)
-            for uid in out:
-                req = self.scheduler.active.get(uid)
-                if req is None:
-                    continue   # not ours (engine shared with other callers)
-                tok = None
-                if (greedy is not None and req.temperature <= 0.0
-                        and req.response_format is None):
-                    tok = greedy(uid)
-                if tok is None:
-                    tok = self._sample(req, np.asarray(out[uid]))  # dstpu: noqa[DST001] a host np row: the engine fetches it explicitly (device_get) when it is read
-                    self.telemetry.count("sampled_on_host")
-                else:
-                    self.telemetry.count("sampled_on_device")
-                if req.state is RequestState.PREFILL:
-                    req.advance(RequestState.DECODE, now)
-                    req.mark_first_token(now)
-                req.generated.append(tok)
-                self._emit_stream(req, now)
-                hit_eos = (req.eos_token_id is not None
-                           and tok == req.eos_token_id)
-                if hit_eos or len(req.generated) >= req.max_new_tokens:
-                    self._finish(req, now, finished)
-                else:
-                    # pending input of the next decode step (the same
-                    # staging generate_batch uses)
-                    self.engine.state.seqs[uid].generated.append(tok)
 
         phases.enter("serve.bookkeep")
         # census-driven expert rebalance: every Nth step, drain the
@@ -1406,24 +1462,24 @@ class ServeLoop:
             expert_pool=(self._expert_pool.stats()
                          if self._expert_pool is not None else None))
         if timeline is not None:
-            at = phases.at
+            spent = phases.spent
             timeline.record(
                 self.telemetry.steps,
-                {"finalize": at["serve.admission"] - at["serve.finalize"],
-                 "admission": at["serve.engine"] - at["serve.admission"],
+                {"finalize": spent.get("serve.finalize", 0.0),
+                 "admission": spent.get("serve.admission", 0.0),
                  # host-tier promotions ran INSIDE the admission window
                  # above; this is their share of it (tier perf-counter
                  # wall — 0.0 without a tier)
                  "promote": (self._tier.promote_wall_s - promote_w0
                              if self._tier is not None else 0.0),
-                 # the engine's put/step call dominates this window
-                 # (on the per-step path that is staging, prefill, the
-                 # decode program and the fetch of its tokens); the cheap
-                 # host bookkeeping between it and the sampling rides along
-                 "prefill": at["serve.sample"] - at["serve.engine"],
+                 # the engine's calls dominate this window (on the
+                 # per-step path that is staging, the launches, and the
+                 # waits for the tokens of the step before); the cheap
+                 # host bookkeeping of what was admitted rides along
+                 "prefill": spent.get("serve.engine", 0.0),
                  # bookkeeping and host sampling of the rows that need
                  # it (per-step path), or the compiled bursts
-                 "decode": at["serve.bookkeep"] - at["serve.sample"]},
+                 "decode": spent.get("serve.sample", 0.0)},
                 admitted=len(admitted), finished=len(finished),
                 prefill_tokens=prefill_toks, decode_tokens=decode_toks,
                 queue_depth=self.scheduler.queue_depth,
@@ -1456,10 +1512,95 @@ class ServeLoop:
         # when this is set
         self._step_worked = (bool(finished) or bool(admitted)
                              or prefill_toks > 0 or decode_toks > 0
+                             or taken > 0
                              or self._preempted_this_step > 0)
         self._preempted_this_step = 0
         self._finished_backlog = []
         return finished
+
+    def _collect(self, step, part: Optional[str], phases: _StepPhases,
+                 finished: List[Request]) -> int:
+        """Wait for a dispatched step's tokens (`engine.collect`) and
+        take them; a row whose sequence is gone was an overrun."""
+        got = self.engine.collect(step, part)
+        self.telemetry.count("rows_overrun", got.overrun)
+        return self._take_tokens(got, phases, finished)
+
+    def _take_tokens(self, out, phases: _StepPhases,
+                     finished: List[Request]) -> int:
+        """Per-step path: a token for every sequence of ours in `out`
+        (the rows an engine step produced), stamped with the clock read
+        now; finish the request or stage the token as its next decode
+        input.  Returns how many tokens were taken.
+
+        A row whose sampler is the plain argmax (temperature <= 0, no
+        grammar mask: `_sample`) takes the token the engine's program
+        chose beside the logits — the same f32 row, the same first
+        maximum — and its logits never leave the device.  Every other
+        row is sampled here from its own logits row: so is a row
+        somebody put a host row in the place of (no token), and every
+        row of an engine whose step returns plain host rows
+        (`engine_v2.LogitsRows.greedy` is the capability probed; test
+        fakes return dicts)."""
+        # the clock is read after the wait for these rows: first-token
+        # and finish stamps charge it to the requests that waited
+        now = self.clock()
+        # host work on what the engine returned: the timeline's "decode"
+        phases.enter("serve.sample", now=now, rows=len(out))
+        greedy = getattr(out, "greedy", None)
+        taken = 0
+        for uid in out:
+            req = self.scheduler.active.get(uid)
+            if req is None:
+                continue   # not ours (engine shared with other callers)
+            tok = None
+            if (greedy is not None and req.temperature <= 0.0
+                    and req.response_format is None):
+                tok = greedy(uid)
+            if tok is None:
+                tok = self._sample(req, np.asarray(out[uid]))  # dstpu: noqa[DST001] a host np row: the engine fetches it explicitly (device_get) when it is read
+                self.telemetry.count("sampled_on_host")
+            else:
+                self.telemetry.count("sampled_on_device")
+            taken += 1
+            if req.state is RequestState.PREFILL:
+                req.advance(RequestState.DECODE, now)
+                req.mark_first_token(now)
+            req.generated.append(tok)
+            self._emit_stream(req, now)
+            hit_eos = (req.eos_token_id is not None
+                       and tok == req.eos_token_id)
+            if hit_eos or len(req.generated) >= req.max_new_tokens:
+                self._finish(req, now, finished)
+            else:
+                # pending input of the next decode step (the same
+                # staging generate_batch uses); where that step is
+                # dispatched already and took the token on the device,
+                # this is the host's list catching up
+                self.engine.state.seqs[uid].generated.append(tok)
+        return taken
+
+    def _ending_by_count(self, earlier) -> List[int]:
+        """The uids whose uncollected decode token in `earlier` will be
+        their last by count: they are left out of the next dispatch, so
+        a greedy stream with no stop token computes no row for nothing.
+        (What the host learns late — EOS, cancel, deadline, preemption —
+        costs one row, dropped when it is collected.)"""
+        if earlier is None or earlier.decode is None:
+            return []
+        active = self.scheduler.active
+        return [d.uid for d, _ in earlier.decode.rows
+                if d.uid not in active
+                or len(active[d.uid].generated) + 1
+                >= active[d.uid].max_new_tokens]
+
+    def drop_pending(self) -> None:
+        """Forget the dispatched steps whose tokens were never collected
+        (failover, crash containment, shutdown): their rows were
+        computed for nothing."""
+        for step in self._in_flight:
+            self.telemetry.count("rows_overrun", step.awaited)
+        self._in_flight.clear()
 
     def _rollback_admission(self, admitted: List[Request]) -> None:
         """Undo admission for requests whose engine put() never
@@ -2267,6 +2408,7 @@ class ThreadedServer:
                 while not self._stop and not self.loop.has_work:
                     self._cond.wait()
                 if self._stop:
+                    self.loop.drop_pending()
                     return
                 try:
                     self.loop.step()

@@ -72,6 +72,15 @@ class ServingTelemetry:
             # the host from a fetched logits row (temperature > 0,
             # grammar-masked rows, engines that return host rows)
             "sampled_on_device": 0, "sampled_on_host": 0,
+            # per-step path, one of the two per serve step that launched
+            # engine work: its tokens left uncollected on the device
+            # until the next step has been dispatched, or collected
+            # before the step returned; and rows whose token was dropped
+            # when collected, their sequence flushed since the dispatch
+            # (`engine.collect`: the request had ended or was preempted),
+            # plus the rows of a step dropped whole (`drop_pending`)
+            "steps_run_ahead": 0, "steps_collected_at_once": 0,
+            "rows_overrun": 0,
             # latent MoE block (inference/v2/latent_ops.COUNT_NAMES),
             # drained from the device every COUNT_DRAIN_STEPS serve steps:
             # top-k picks, those on identity experts, those on the

@@ -576,13 +576,24 @@ def test_client_lr_scheduler_and_training_data():
 
 
 @pytest.mark.parametrize("fsdp", [1, 4])
-def test_train_step_compiles_once(fsdp, devices8, caplog):
+def test_train_step_compiles_once(fsdp, devices8, caplog, monkeypatch):
     """The state `_init_state` builds is placed exactly as the compiled
     step returns it — scalars on the mesh, canonical PartitionSpecs,
     constrained optimizer state (int8 moments carry replicated scale
     trees).  Otherwise the SECOND train_batch recompiles the whole step:
     17 s at 1.1B on one chip, 11.8 s under ZeRO-3 fsdp=4 (PR 23)."""
     import logging
+    # XLA:CPU alone: it schedules a program's independent collectives
+    # side by side, the virtual devices start them in different orders,
+    # and under load (xdist's other workers) this four-device step's
+    # all-gather and all-reduce wait for each other in the in-process
+    # rendezvous until the process aborts 40 s later, in whatever test
+    # runs then (beside five copies of itself: 7 of 24 runs; 0 of 24
+    # compiled like this: PR 30).  A TPU orders collectives itself.
+    jit = jax.jit
+    monkeypatch.setattr(jax, "jit", lambda f, **kw: jit(
+        f, compiler_options={
+            "xla_cpu_enable_concurrency_optimized_scheduler": False}, **kw))
     from deepspeed_tpu.models import Transformer, llama_config
     model = Transformer(llama_config("tiny", max_seq_len=32,
                                      dtype=jnp.bfloat16))
