@@ -96,38 +96,12 @@ def free(*trees) -> None:
     gc.collect()
 
 
-class CompileCounter:
-    """Counts what JAX compiled and what its persistent cache served."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.requests = self.hits = 0
-        self.compile_s = 0.0
-        mon.register_event_listener(self._event)
-        mon.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name, **kw):
-        if name == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def _duration(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def snapshot(self) -> Dict[str, float]:
-        return {"compiled": self.requests - self.hits,
-                "from_cache": self.hits,
-                "backend_compile_s": round(self.compile_s, 2)}
-
-
 # ----------------------------------------------------------------------
 # train
 # ----------------------------------------------------------------------
 def train_config(micro: int, stage: int, gas: int = 1) -> dict:
-    # the bench.py recipe: int8 Adam moments + bf16 grad accumulation +
-    # save_attn remat is what fits a 1B-class state in 16 GB
+    # int8 Adam moments + bf16 grad accumulation + save_attn remat is
+    # what fits a 1B-class state in 16 GB
     return {
         "train_micro_batch_size_per_gpu": micro,
         "gradient_accumulation_steps": gas,
@@ -528,7 +502,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    from deepspeed_tpu.utils.device import place_compile_cache
+    from deepspeed_tpu.utils.device import (CompileCounter,
+                                            place_compile_cache)
     from deepspeed_tpu.utils.tpu_claim import require_tpu
     require_tpu()
     devices = jax.devices()

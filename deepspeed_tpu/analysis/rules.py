@@ -14,8 +14,8 @@ Rule catalog (docs/ANALYSIS.md has the long form):
   `np.asarray`/`np.array`, `float()`/`int()`/`bool()` on a
   possibly-device value) inside a function reachable from the serving
   hot roots (`ServeLoop.step`, the engine's prefill/decode surface) or
-  inside any `@jax.jit`-decorated function.  This is the bug class that
-  cost ~70x in `serve_closed_c8` (PR 2): one accidental materialization
+  inside any `@jax.jit`-decorated function.  This is the bug class PR 2
+  removed from the decode loop: one accidental materialization
   in the decode loop ships [max_seqs, vocab] logits to the host every
   token.
 - **DST002 traced-control-flow**: Python `if`/`while`/`assert` on a

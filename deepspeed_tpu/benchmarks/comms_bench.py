@@ -306,8 +306,7 @@ def run_tp_inference_sweep(hidden: int = 1024, ffn: int = 4096,
     Each row reports measured wall time AND `hlo_census` wire bytes per
     step, so "fused is free on the wire and hides the hops" is a
     number, not a schedule claim.  On a 1-hop CPU mesh wall times mostly
-    document parity — the overlap shows on ICI (tpu_hlo_check asserts it
-    structurally).  `decode_rows` defaults to 64 so per-chunk GEMMs keep
+    document parity — the overlap would show on ICI.  `decode_rows` defaults to 64 so per-chunk GEMMs keep
     rows/world >= 8 on an 8-wide mesh — below the 8-row sublane tile the
     Pallas kernel auto-falls back to jnp.dot and the decode rows would
     time the wrong GEMM on TPU."""
@@ -384,24 +383,9 @@ def run_tp_inference_sweep(hidden: int = 1024, ffn: int = 4096,
 def main(argv=None) -> int:
     import sys
     argv = list(sys.argv[1:]) if argv is None else list(argv)
-    if "--history" in argv:
-        # perf-regression ledger mode (ISSUE 13): everything after the
-        # flag goes to bench_history's own CLI (--rebuild / --check /
-        # --tol / --root) — no device init, no collective sweep.  Sweep
-        # arguments BEFORE the flag are refused loudly: the two CLIs
-        # share no options, so mixing them is always a mistake.
-        from .bench_history import main as history_main
-        i = argv.index("--history")
-        if argv[:i]:
-            raise SystemExit(
-                f"dstpu_bench: arguments before --history "
-                f"({argv[:i]}) are sweep options; ledger mode takes "
-                f"only bench_history arguments after the flag")
-        return history_main(argv[i + 1:])
     p = argparse.ArgumentParser(
-        "dstpu_bench", description="XLA collective bandwidth sweep "
-        "(ds_bench); `--history` switches to the perf-regression "
-        "ledger over BENCH_*.json (see benchmarks/bench_history.py)")
+        "dstpu_bench",
+        description="XLA collective bandwidth sweep (ds_bench)")
     p.add_argument("--ops", nargs="*", default=None,
                    help="subset of: all_reduce all_gather reduce_scatter "
                         "all_to_all broadcast")

@@ -1,8 +1,7 @@
 """Collective census + wire-byte accounting over compiled HLO text.
 
-Shared by comms_bench (--quant rows), bench.py (the collective-share
-line), tpu_hlo_check (overlap verdict), and the lowering tests — one
-parser instead of four regex forks.
+Shared by comms_bench (--quant rows), tpu_hlo_check and the lowering
+tests — one parser instead of a regex fork each.
 
 Handles both SYNC collectives (`%all-reduce.3 = ...`) and the ASYNC
 start/done pairs a latency-hiding backend emits (`%all-reduce-start.3 =

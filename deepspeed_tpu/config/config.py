@@ -1468,7 +1468,7 @@ class ServingConfig:
     # serve step under jax's device->host transfer guard.  The hot paths
     # make every INTENDED fetch explicit (jax.device_get), so "disallow"
     # turns any accidental logits/array materialization — the bug class
-    # behind the ~70x serve_closed_c8 cliff — into a loud error at the
+    # of full logits fetched on every token — into a loud error at the
     # offending call ("log" just reports it).  "off" = no guard.  NOTE:
     # CPU-backend d2h is zero-copy and invisible to the guard; this has
     # full teeth on real accelerators (tests force the h2d direction for

@@ -151,7 +151,8 @@ class DSElasticAgent:
 
 
 class PodElasticAgent:
-    """Pod-level elastic supervision (VERDICT r3 weak #8): rank-0's host
+    """Pod-level elastic supervision (the local agent alone restarts
+    one host's workers; a pod needs its membership re-formed): rank-0's host
     runs this agent; it fans the training command out over the pod's
     hosts (launcher.multinode_runner.SSHRunner) and, when a host dies,
     restarts the WHOLE fan-out over the surviving membership with the

@@ -1,6 +1,5 @@
 """Pipelined inference: stage-sharded layers + collective-permute token
-relay (VERDICT r3 missing #3 / reference `InferenceSchedule`,
-runtime/pipe/schedule.py:135).
+relay (reference `InferenceSchedule`, runtime/pipe/schedule.py:135).
 
 Why this exists: TP serving covers one slice, but a model whose weights
 exceed a slice's HBM must also split LAYERS across devices.  The

@@ -199,8 +199,8 @@ class RaggedInferenceEngineConfig:
     #           escape hatch — serves every arch/layout tp=1 serves)
     # "fused" — the whole serving program runs in one shard_map region
     #           with ring compute-collective matmuls (ops/tp_matmul.py:
-    #           all-gather-producer + matmul-reduce-scatter, overlap
-    #           asserted by tpu_hlo_check.check_tp_fused_overlap);
+    #           all-gather-producer + matmul-reduce-scatter, each hop
+    #           issued while the previous chunk's matmul runs);
     #           refuses unsupported layouts loudly (inference/v2/
     #           tp_ragged.tp_fused_unsupported_reason)
     tp_collectives: str = "xla"
@@ -382,7 +382,7 @@ class InferenceEngineV2:
         # host-sync ledger: every EXPLICIT device->host fetch the engine
         # performs bumps d2h_fetches (the implicit ones are what the
         # transfer guard + DST001 forbid, so this IS the engine's total).
-        # The bench rows divide deltas by tokens generated to report
+        # Tests divide deltas by tokens generated to read
         # host syncs per token — the number multi-step decode amortizes.
         self.profile: Dict[str, int] = {"d2h_fetches": 0}
         # radix prefix KV cache (serving/prefix_cache.py), off until
@@ -839,7 +839,7 @@ class InferenceEngineV2:
         #      prompt is mid-prefill the suspension guard takes over.
         #      Without the reservation, a sustained stream of short fresh
         #      arrivals totalling >= budget/step could defer a long fresh
-        #      prompt indefinitely (ADVICE r5 finding 1);
+        #      prompt indefinitely;
         #    - one batch holds only prompts from ONE power-of-2 length
         #      bucket, and its PADDED slot count is capped at
         #      max(2x the budget's bucket, max_seqs * 128) — a lone long
@@ -878,7 +878,7 @@ class InferenceEngineV2:
                     # any length, and not yet protected by the mid-prefill
                     # suspension above).  Without it, a sustained stream of
                     # fresh arrivals totalling >= budget/step could defer
-                    # either indefinitely (ADVICE r5 finding 1).
+                    # either indefinitely.
                     full_budget = max(budget - C, 0)
                 fresh: List = []
                 S = 128

@@ -22,8 +22,8 @@ expressed as a fused ring matmul from `ops/tp_matmul.py`:
 
 Comm volume per block is identical to the one-reduce-per-block Megatron
 layout (ring AR == RS + AG), but each hop is issued while the previous
-chunk's matmul runs — `tpu_hlo_check.check_tp_fused_overlap` asserts
-the async start/done interleaving structurally.  Extra collectives
+chunk's matmul runs (the hop carries no dependency on that matmul, so
+the scheduler may overlap them).  Extra collectives
 outside the blocks: one [rows, H] psum at the vocab-sharded embedding,
 and one vocab all-gather of the final logits.
 

@@ -331,7 +331,8 @@ def gpt2_config(size: str = "small", **kw) -> TransformerConfig:
         "medium": dict(hidden_size=1024, num_layers=24, num_heads=16),
         "large": dict(hidden_size=1280, num_layers=36, num_heads=20),
         "xl": dict(hidden_size=1600, num_layers=48, num_heads=25),
-        # the north-star benchmark model (BASELINE.json: GPT-2-1.3B ZeRO-2)
+        # a 1.3B-class shape of this repository's own (16 heads of 128: no
+        # published GPT-2 has it); tests size memory and kernels with it
         "1.3b": dict(hidden_size=2048, num_layers=24, num_heads=16, max_seq_len=2048),
     }
     base = dict(vocab_size=50304, pos_emb="learned", norm="layernorm",

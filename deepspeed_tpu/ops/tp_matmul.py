@@ -23,11 +23,10 @@ a shard_map region.  The permute of step k carries no data dependency
 on step k's matmul, so XLA's latency-hiding scheduler issues
 collective-permute-start, runs the matmul, then waits on
 collective-permute-done — the overlap is STRUCTURAL in the scheduled
-executable and `benchmarks/tpu_hlo_check.check_tp_fused_overlap`
-asserts exactly that (async start/done pairs with MXU compute between)
-against the real TPU compiler.  Each per-chunk matmul runs as a Pallas
-MXU kernel on TPU (`tile_matmul`), with `jnp.dot` as the portable
-escape (and the CPU-test path).
+executable (async start/done pairs with MXU compute between); no test
+or cell reads it off a TPU executable yet.  Each per-chunk matmul runs
+as a Pallas MXU kernel on TPU (`tile_matmul`), with `jnp.dot` as the
+portable escape (and the CPU-test path).
 
 Two fused primitives, mirroring the papers' pair:
 

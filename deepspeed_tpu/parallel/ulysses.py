@@ -5,7 +5,7 @@ Reference: sequence/layer.py — `_SeqAllToAll`:277 and
 ranks; before attention, all-to-all Q/K/V so each rank holds the FULL
 sequence for 1/P of the heads; run any local attention (flash); all-to-all
 back.  Comm volume O(M/P) per rank vs O(M) for an allgather — the property
-the reference's blog benchmarks (>175 TFLOPs/GPU, BASELINE.md).
+the reference's long-sequence results rest on.
 
 TPU-native: `_SeqAllToAll` becomes `jax.lax.all_to_all` over a mesh axis
 inside a `shard_map` region; XLA lowers it to an ICI AllToAll and overlaps it

@@ -1,7 +1,7 @@
 """Trace-time handoff of per-layer qwZ gathers from the ZeRO++ quantized
 path to scan-over-layers models.
 
-Problem (VERDICT r4 Missing #3): `runtime/zero/quantized.py` gathered every
+Problem: `runtime/zero/quantized.py` gathered every
 sharded leaf at the top of the loss, so qwZ peak memory was ZeRO-1/2-like —
 a model that NEEDS stage-3 residency couldn't use qwZ.  The reference
 quantizes the same per-module gathers stage 3 already does

@@ -6,9 +6,10 @@ cannot produce), bounded per-tick metric time series on the existing
 step seams, and a recompile flight recorder that turns mid-serve XLA
 compiles into counted, timestamped, trace-visible events.
 
-The perf-regression ledger that reads the bench artifacts this package
-helps produce lives in `deepspeed_tpu.benchmarks.bench_history`
-(`dstpu_bench --history`).
+The workload generator and the open-loop driver have no caller in the
+package or the benchmark yet (tests/test_observatory.py and
+examples/serve_requests.py drive them): the benchmark's traffic lives
+under `benchmark/traffic_kinds/`.
 """
 from .workload import ARRIVAL_PROCESSES, WorkloadGenerator, WorkloadItem
 from .driver import (OpenLoopDriver, OpenLoopResult, VirtualClock,

@@ -1,8 +1,8 @@
 """Open-loop driver: submit on the arrival schedule, no matter what.
 
-The closed-loop drivers in bench_serve.py submit a client's next
-request when its previous one COMPLETES — the server can never be
-offered more load than it serves.  `OpenLoopDriver` submits each
+A closed loop (the benchmark's `closed_loop` traffic kind) submits a
+client's next request when its previous one COMPLETES — the server can
+never be offered more load than it serves.  `OpenLoopDriver` submits each
 `WorkloadItem` the moment the serve clock reaches its `arrival_s`,
 regardless of completions: under-capacity the queue stays shallow,
 past capacity it grows without bound, and the knee between the two is
@@ -17,11 +17,11 @@ anything with the loop contract (`submit`/`step`/`has_work` — a bare
   queueing simulation with REAL serving mechanics (admission gate, KV
   ledger, bursts, prefix cache, handoffs) and real model tokens.
   Offered load ρ is then exact: `rate_rps` against a service rate
-  measured by `calibrate_service_rate`.  This is what the seeded
-  `serve_openloop_*` bench rows run.
+  measured by `calibrate_service_rate`.  This is what
+  tests/test_observatory.py's ramp runs.
 - **measured** (`step_dt=None`): the clock must be real
   (`time.monotonic`-like); each step costs its actual wall time.  Same
-  driver, real latencies — the mode a chip-attached re-measure uses.
+  driver, real latencies — the mode a run on the chip would use.
 
 Backpressure is part of the measurement: a submit rejected by the
 bounded queue (`QueueFullError`) is counted in `rejected`, never

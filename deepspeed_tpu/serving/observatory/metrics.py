@@ -151,8 +151,8 @@ class MetricsSampler:
 
     def sample_loop(self, loop, now: float) -> Dict[str, Any]:
         """One row from a just-completed serve step.  Pure host reads —
-        no device sync anywhere (the < 5% overhead contract measured on
-        the serve_closed_c8 bench row)."""
+        no device sync anywhere (sampling must stay cheap beside a serve
+        step)."""
         t = loop.telemetry
         recompiles = 0
         if self.recorder is not None:

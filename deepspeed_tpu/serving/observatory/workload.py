@@ -14,7 +14,7 @@ goodput degrade as the offered load ρ approaches and passes 1.
 `WorkloadGenerator` draws that schedule deterministically: one seeded
 `numpy.random.RandomState`, a fixed draw order, and explicit arrival /
 length distributions, so the same seed replays the same workload
-bit-for-bit (locked by test) and a bench row's "ρ = 1.3 arm" means the
+bit-for-bit (locked by test) and a sweep's "ρ = 1.3 arm" means the
 same thing on every run.
 
 Arrival processes:
@@ -78,8 +78,8 @@ class WorkloadGenerator:
     an independent child stream per quantity (arrivals, prompt
     lengths, output lengths, prefix membership, priorities, prompt
     tokens).  `generate(n)` is therefore a pure function of the
-    constructor arguments — the determinism contract the bench rows
-    and the regression ledger lean on — and the streams are
+    constructor arguments — the determinism contract a sweep's arms
+    lean on — and the streams are
     PREFIX-stable: `generate(m)[:n] == generate(n)` for m >= n (a
     longer run extends the schedule; with one shared stream the later
     draws' offsets would depend on n and every prompt would reshuffle).
@@ -330,9 +330,8 @@ class WorkloadGenerator:
         return items
 
     def describe(self) -> Dict[str, Any]:
-        """The generator's full parameterization — recorded alongside
-        bench rows so a trajectory entry names the workload it
-        measured."""
+        """The generator's full parameterization, so that a result can
+        name the workload it ran."""
         return {
             "seed": self.seed, "arrival": self.arrival,
             "rate_rps": self.rate_rps, "burst_size": self.burst_size,
@@ -346,8 +345,8 @@ class WorkloadGenerator:
             "tenant_zipf_a": self.tenant_zipf_a,
             "adapter_frac": self.adapter_frac,
             "structured_frac": self.structured_frac,
-            # (kind, spec) pairs, not objects: describe() rows land in
-            # JSON bench records
+            # (kind, spec) pairs, not objects: the description must
+            # serialize as JSON
             "structured_formats": (
                 [(f.kind, f.spec) for f in self.structured_formats]
                 if self.structured_formats else None),

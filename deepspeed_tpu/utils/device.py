@@ -1,13 +1,12 @@
 """The device this process runs on: one question, one answer.
 
-Every kernel gate, the accelerator auto-detect and the bench scripts ask
+Every kernel gate, the accelerator auto-detect and `chip_smoke.py` ask
 `platform()`; nothing else in the package probes the backend.  Exceptions
 from `jax.devices()` propagate: a process that cannot reach its device
 must fail there, not run a slower path that looks plausible.
 
-Also here, because they are decided once per process the same way: the
-peak-rate table (keyed by `device_kind`, an unknown kind is an error) and
-the persistent compile cache's location.
+Also here, because they are process-wide the same way: where the
+persistent compile cache lives, and the count of what JAX compiled.
 """
 from __future__ import annotations
 
@@ -15,7 +14,7 @@ import os
 
 import jax
 
-__all__ = ["platform", "on_tpu", "device_peaks", "place_compile_cache"]
+__all__ = ["platform", "on_tpu", "place_compile_cache", "CompileCounter"]
 
 
 def platform() -> str:
@@ -25,26 +24,6 @@ def platform() -> str:
 
 def on_tpu() -> bool:
     return platform() == "tpu"
-
-
-# Published per-chip peaks.  Source: Google Cloud documentation, "TPU v5e"
-# (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s).
-_PEAKS = {
-    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
-                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
-}
-
-
-def device_peaks(kind: str | None = None) -> dict:
-    """Peak rates of `kind` (default: the attached device's `device_kind`).
-    A device that is not in the table is an error, never a default."""
-    kind = kind if kind is not None else jax.devices()[0].device_kind
-    if kind not in _PEAKS:
-        raise KeyError(
-            f"no published peak rates for device kind {kind!r} "
-            f"(known: {sorted(_PEAKS)}); add it to "
-            f"deepspeed_tpu.utils.device._PEAKS with its source")
-    return dict(_PEAKS[kind])
 
 
 def place_compile_cache(min_compile_secs: float = 0.0) -> str:
@@ -67,3 +46,29 @@ def place_compile_cache(min_compile_secs: float = 0.0) -> str:
                       min_compile_secs)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+class CompileCounter:
+    """Counts what JAX compiled and what its persistent cache served."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.requests = self.hits = 0
+        self.compile_s = 0.0
+        mon.register_event_listener(self._event)
+        mon.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, name, **kw):
+        if name == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def _duration(self, name, secs, **kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.compile_s += secs
+
+    def snapshot(self) -> dict:
+        return {"compiled": self.requests - self.hits,
+                "from_cache": self.hits,
+                "backend_compile_s": round(self.compile_s, 2)}

@@ -1,6 +1,7 @@
-"""TPU guard for the benchmark drivers and `chip_smoke.py`: a measurement
-path that finds no chip fails — a CPU run would print a plausible-looking
-but wrong metric."""
+"""TPU guard of `chip_smoke.py`, its one caller: a path that exists to run
+on the chip fails where it finds none — a CPU run would look plausible and
+prove nothing.  (The benchmark refuses the same way, on its own:
+`benchmark/harness.py`.)"""
 from __future__ import annotations
 
 from . import device

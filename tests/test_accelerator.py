@@ -122,7 +122,7 @@ class TestDiffusionWrappers:
     model_implementations/diffusers/{unet,vae,clip_encoder}.py) exercised
     against a REAL tiny diffusion stack written in jax — the diffusers
     package is absent from this environment, so torch-diffusers weight
-    conversion is explicitly out of scope (COVERAGE.md notes the descope);
+    conversion is explicitly out of scope;
     what the reference wrappers ADD — capture-once-per-shape, replay
     thereafter — is what these tests pin down."""
 

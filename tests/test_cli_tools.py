@@ -93,15 +93,19 @@ def test_dstpu_ssh_fanout(tmp_path):
     assert out.returncode == 0 and "local-ok" in out.stdout
 
 
-def test_bench_scripts_importable():
-    """bench.py / bench_serve.py are driver entry points; a syntax or
-    import-path break must fail in-suite, not on the TPU run."""
-    import importlib.util
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for name in ("bench", "bench_serve"):
-        spec = importlib.util.spec_from_file_location(
-            name, os.path.join(root, f"{name}.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)          # module-level code only
-        assert callable(mod.main)
+def _console_scripts():
+    import tomllib
+    with open(os.path.join(os.path.dirname(BIN), "pyproject.toml"), "rb") as f:
+        scripts = tomllib.load(f)["project"]["scripts"]
+    return [pytest.param(target, id=name)
+            for name, target in sorted(scripts.items())]
+
+
+@pytest.mark.parametrize("target", _console_scripts())
+def test_console_scripts_resolve(target):
+    """Every `[project.scripts]` entry of pyproject.toml names a module
+    that imports and an attribute that can be called: what `pip install`
+    would wire the script to exists."""
+    import importlib
+    module, _, attr = target.partition(":")
+    assert callable(getattr(importlib.import_module(module), attr)), target

@@ -19,6 +19,7 @@ import pytest
 
 from test_fleet import (BS, PrefixFakeEngine, _FakeClock, _prompt,
                         _real_prompts, _replica_of, _tiny_engine)
+from test_serving import _closed_loop
 
 from deepspeed_tpu.config.config import (ConfigError, DeepSpeedTPUConfig,
                                          DisaggConfig, FleetConfig,
@@ -776,40 +777,45 @@ def test_disagg_config_validation_and_json_wiring():
         FleetRouter(loops, cfg2)
 
 
-# -- the bench driver ------------------------------------------------------
-def test_bench_disagg_row_driver_on_tiny_engine(monkeypatch):
-    """The serve_disagg_c8x3 row's driver — identical-stream unified vs
-    disaggregated, bit-for-bit / zero-loss / zero-leak asserts —
-    end-to-end on tiny CPU engines.  The strict TPOT-interference win
-    is a real-hardware claim and is not asserted at this toy scale."""
-    import jax
-    import jax.numpy as jnp
+# -- unified against disaggregated, one stream --------------------------------
+def test_bench_disagg_row_driver_on_tiny_engine():
+    """One mixed stream (long and short prompts, each decoding 6 tokens,
+    bursts of 16) on three real tiny replicas, unified and then as 1
+    prefill + 2 decode replicas.  Prompt lengths are a multiple of the
+    prefill chunk plus one, so the handoff boundary (the last whole KV
+    block) is chunk-aligned and the decode side's tail re-prefill
+    computes the same logits bit for bit: the handoff is invisible in
+    the tokens.  Every request finishes, the disaggregated fleet hands
+    requests off (the unified one never), and no replica of either
+    fleet leaks a block.  (That decode TPOT falls is a claim about a
+    chip, and is not made at this size.)"""
+    rng = np.random.RandomState(29)
+    prompts = {(client, 0): rng.randint(0, 128, 65 if client % 2 == 0
+                                        else 33).astype(np.int32)
+               for client in range(3)}
+    results = {}
+    for label, disagg in (("unified", None),
+                          ("disagg", DisaggConfig(prefill_replicas=1,
+                                                  decode_replicas=2))):
+        cfg = ServingConfig(
+            max_queue_len=8, prefix_cache_blocks=12, decode_burst=16,
+            audit_blocks=True,
+            fleet=FleetConfig(replicas=3, snapshot_interval_steps=1,
+                              disagg=disagg))
+        clock = _FakeClock()
+        fleet = FleetRouter(
+            [ServeLoop(_tiny_engine(num_blocks=96, block_size=16,
+                                    max_seqs=2), cfg, clock=clock)
+             for _ in range(3)], cfg)
+        outputs, reqs = _closed_loop(fleet, prompts, new_tokens=6)
+        assert not fleet.has_work and all(r.finished for r in reqs)
+        fleet.audit()
+        results[label] = (outputs, fleet.summary())
 
-    import bench_serve
-    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                            RaggedInferenceEngineConfig)
-    from deepspeed_tpu.models import Transformer, TransformerConfig
-
-    def tiny_engine(ctx_budget, max_seqs=8, decode_burst=16,
-                    full_prompt_prefill=True, **kw):
-        cfg = TransformerConfig(vocab_size=128, hidden_size=64,
-                                num_layers=2, num_heads=4,
-                                max_seq_len=1024, dtype=jnp.float32)
-        model = Transformer(cfg)
-        if not hasattr(tiny_engine, "_params"):
-            tiny_engine._params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
-            num_blocks=96, block_size=16, max_blocks_per_seq=16,
-            max_seqs=max_seqs, prefill_chunk_size=32,
-            full_prompt_prefill=full_prompt_prefill)
-        return InferenceEngineV2(model, params=tiny_engine._params,
-                                 config=ecfg), cfg
-
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    goodput, extras = bench_serve.bench_serving_disagg(
-        clients=3, requests_per_client=1, new_tokens=6,
-        long_prompt_len=65, short_prompt_len=33, max_seqs=2,
-        prefix_cache_blocks=12, replicas=3, require_tpot_win=False)
-    assert goodput > 0
-    assert extras["handoffs"] > 0
-    assert extras["lost_requests"] == 0
+    outs_u, s_u = results["unified"]
+    outs_d, s_d = results["disagg"]
+    assert len(outs_d) == 3 and outs_d == outs_u
+    assert all(len(toks) == 6 for toks in outs_d.values())
+    assert s_u["handoffs"] == 0
+    assert s_d["handoffs"] > 0 and s_d["handoff_blocks"] > 0
+    assert s_d["handoff_failures"] == s_d["handoff_expired"] == 0

@@ -24,6 +24,8 @@ from deepspeed_tpu.serving import (AdmissionError, FleetRouter,
 from deepspeed_tpu.serving.fleet.migration import (NullBlockTransport,
                                                    _quant_roundtrip_int8)
 
+from test_serving import _closed_loop
+
 pytestmark = pytest.mark.serving
 
 BS = 4          # KV block size of the fake replicas
@@ -572,40 +574,55 @@ def test_real_engine_migration_int8_quant_completes_and_accounts_bytes():
     assert raw_bytes["int8"] < raw_bytes["none"] * 0.6
 
 
-def test_bench_fleet_row_driver_on_tiny_engine(monkeypatch):
-    """The serve_fleet_c8x2 row's driver — identical-stream cache-aware
-    vs round-robin, hit-rate / prefill / bit-for-bit / zero-loss /
-    audit asserts — end-to-end on tiny CPU engines."""
-    import jax
-    import jax.numpy as jnp
+def test_bench_fleet_row_driver_on_tiny_engine():
+    """Cache-aware against round-robin routing over one shared-system-
+    prompt stream on two real tiny replicas (one sequence at a time
+    each), the shared prefix heated by one primer request: round robin
+    pays a cold shared-prefix prefill on every replica the stream
+    touches, cache-aware routing steers the stream to the replica that
+    holds the prefix.  Placement is invisible (the same tokens), every
+    request finishes, the cache-aware fleet's hit rate is strictly
+    higher and its prefill tokens strictly fewer, and no replica leaks
+    a block."""
+    shared_len, unique_len, new_tokens = 64, 16, 3
+    rng = np.random.RandomState(13)
+    shared = rng.randint(0, 128, shared_len).astype(np.int32)
 
-    import bench_serve
-    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                            RaggedInferenceEngineConfig)
-    from deepspeed_tpu.models import Transformer, TransformerConfig
+    def prompt():
+        return np.concatenate(
+            [shared, rng.randint(0, 128, unique_len).astype(np.int32)])
 
-    def tiny_engine(ctx_budget, max_seqs=8, decode_burst=16,
-                    full_prompt_prefill=True, **kw):
-        cfg = TransformerConfig(vocab_size=128, hidden_size=64,
-                                num_layers=2, num_heads=4,
-                                max_seq_len=1024, dtype=jnp.float32)
-        model = Transformer(cfg)
-        if not hasattr(tiny_engine, "_params"):
-            tiny_engine._params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
-            num_blocks=64, block_size=16, max_blocks_per_seq=16,
-            max_seqs=max_seqs, prefill_chunk_size=32,
-            full_prompt_prefill=full_prompt_prefill)
-        return InferenceEngineV2(model, params=tiny_engine._params,
-                                 config=ecfg), cfg
+    primer_prompt = prompt()
+    prompts = {(client, 0): prompt() for client in range(3)}
+    results = {}
+    for routing in ("round_robin", "cache_aware"):
+        cfg = ServingConfig(
+            max_queue_len=8, prefix_cache_blocks=8, decode_burst=16,
+            audit_blocks=True,
+            fleet=FleetConfig(replicas=2, snapshot_interval_steps=1,
+                              routing=routing, prefix_weight=4.0,
+                              load_weight=0.25))
+        clock = _FakeClock()
+        fleet = FleetRouter(
+            [ServeLoop(_tiny_engine(num_blocks=64, block_size=16,
+                                    max_seqs=1), cfg, clock=clock)
+             for _ in range(2)], cfg)
+        primer = fleet.submit(primer_prompt, max_new_tokens=new_tokens)
+        fleet.run_until_idle(max_steps=300)
+        assert primer.state is RequestState.DONE
+        outputs, _ = _closed_loop(fleet, prompts, new_tokens)
+        fleet.audit()
+        s = fleet.summary()
+        # every prompt token was either prefilled or covered by shared KV
+        prefill = ((len(prompts) + 1) * (shared_len + unique_len)
+                   - s["fleet_prefill_tokens_saved"])
+        results[routing] = (outputs, s["fleet_prefix_hit_rate"], prefill)
 
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    goodput, extras = bench_serve.bench_serving_fleet(
-        clients=3, requests_per_client=1, new_tokens=3, shared_len=64,
-        unique_len=16, max_seqs=1, prefix_cache_blocks=8, replicas=2)
-    assert goodput > 0
-    assert extras["hit_rate"] > extras["hit_rate_round_robin"] > 0
-    assert extras["prefill_tokens"] < extras["prefill_tokens_round_robin"]
+    outs_rr, hit_rr, prefill_rr = results["round_robin"]
+    outs_ca, hit_ca, prefill_ca = results["cache_aware"]
+    assert outs_ca == outs_rr
+    assert hit_ca > hit_rr > 0
+    assert prefill_ca < prefill_rr
 
 
 # -- config ----------------------------------------------------------------

@@ -1138,40 +1138,80 @@ def test_supervisor_autoscale_config_validation_and_json_wiring():
         FleetConfig(migration_backoff_steps=-1).validate()
 
 
-def test_chaos_bench_row_driver_on_tiny_engine(monkeypatch):
-    """The serve_fleet_chaos_c8x3 row's driver end-to-end on tiny CPU
-    engines: replica death mid-stream, automatic failover, zero
-    accepted-request loss, every waiter resolved, zero leaked blocks on
-    the survivors, hit rate above round-robin."""
-    import jax
-    import jax.numpy as jnp
+def test_chaos_bench_row_driver_on_tiny_engine():
+    """One replica of three real tiny engines dies mid-stream, holding
+    work, under a closed loop of 3 clients x 2 requests (a shared-
+    system-prompt request, then a stranger), once per routing policy.
+    Nobody calls drain: the supervisor fails the dead replica over
+    exactly once, all 6 requests finish DONE with every waiter
+    released, the survivors leak no block, death and retries are
+    invisible in the tokens, and cache-aware routing keeps its hit rate
+    above round robin's through the death."""
+    from test_fleet import _tiny_engine
+    from test_serving import _closed_loop
 
-    import bench_serve
-    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                            RaggedInferenceEngineConfig)
-    from deepspeed_tpu.models import Transformer, TransformerConfig
+    shared_len, unique_len, new_tokens = 64, 16, 6
+    rng = np.random.RandomState(17)
+    shared = rng.randint(0, 128, shared_len).astype(np.int32)
 
-    def tiny_engine(ctx_budget, max_seqs=8, decode_burst=16,
-                    full_prompt_prefill=True, **kw):
-        cfg = TransformerConfig(vocab_size=128, hidden_size=64,
-                                num_layers=2, num_heads=4,
-                                max_seq_len=1024, dtype=jnp.float32)
-        model = Transformer(cfg)
-        if not hasattr(tiny_engine, "_params"):
-            tiny_engine._params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
-            num_blocks=64, block_size=16, max_blocks_per_seq=16,
-            max_seqs=max_seqs, prefill_chunk_size=32,
-            full_prompt_prefill=full_prompt_prefill)
-        return InferenceEngineV2(model, params=tiny_engine._params,
-                                 config=ecfg), cfg
+    def prompt(stranger=False):
+        head = (rng.randint(0, 128, shared_len).astype(np.int32)
+                if stranger else shared)
+        return np.concatenate(
+            [head, rng.randint(0, 128, unique_len).astype(np.int32)])
 
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    goodput, extras = bench_serve.bench_serving_fleet_chaos(
-        clients=3, requests_per_client=2, new_tokens=6, shared_len=64,
-        unique_len=16, max_seqs=1, prefix_cache_blocks=8, replicas=3,
-        decode_burst=2, heartbeat_timeout_s=0.1, failover_after_s=0.1)
-    assert goodput > 0
-    assert extras["failovers"] == 1
-    assert extras["requests"] == 6
-    assert extras["hit_rate"] > extras["hit_rate_round_robin"]
+    primer_prompt = prompt()
+    prompts = {(client, k): prompt(stranger=bool(k % 2))
+               for client in range(3) for k in range(2)}
+    results = {}
+    for routing in ("round_robin", "cache_aware"):
+        clock = FakeClock()
+        cfg = ServingConfig(
+            max_queue_len=8, prefix_cache_blocks=8, decode_burst=2,
+            audit_blocks=True,
+            fleet=FleetConfig(replicas=3, snapshot_interval_steps=1,
+                              routing=routing, prefix_weight=4.0,
+                              load_weight=0.25,
+                              supervisor=_sup(max_request_retries=2)))
+        fleet = FleetRouter(
+            [ServeLoop(_tiny_engine(num_blocks=64, block_size=16,
+                                    max_seqs=1), cfg, clock=clock)
+             for _ in range(3)], cfg)
+        primer = fleet.submit(primer_prompt, max_new_tokens=new_tokens)
+        while fleet.has_work:
+            _tick(fleet, clock)
+        assert primer.state is RequestState.DONE
+        # the primer heated the prefix on replica 0 (ties go to the
+        # lowest id), so replica 1 serves strangers under cache-aware
+        # routing and a third of everything under round robin: it dies
+        # holding work either way.  The death is armed by the first
+        # victim step that RETURNS with admitted work still in flight,
+        # so the next one raises over a request stranded mid-decode
+        # however fast the model steps.
+        victim = fleet.replicas[1]
+        inner_step = victim.loop.step
+
+        def step_then_arm():
+            out = inner_step()
+            if victim.loop.scheduler.active:
+                victim.loop.step = inner_step
+                FaultInjector(victim.loop, FaultPlan.replica_death(0))
+            return out
+
+        victim.loop.step = step_then_arm
+        outputs, reqs = _closed_loop(fleet, prompts, new_tokens,
+                                     after_step=lambda: clock.advance(1.0))
+        s = fleet.summary()
+        assert s["health"][victim.id] == "drained"
+        assert s["health_events"]["failovers"] == 1
+        assert s["failover_requeued"] >= 1 and s["failover_failed"] == 0
+        assert all(r.finished for r in reqs)
+        for rep in fleet.replicas:
+            if rep.id != victim.id:
+                rep.loop.engine.audit_blocks()
+        results[routing] = (outputs, s["fleet_prefix_hit_rate"])
+
+    outs_rr, hit_rr = results["round_robin"]
+    outs_ca, hit_ca = results["cache_aware"]
+    assert len(outs_ca) == 6 and outs_ca == outs_rr
+    assert hit_ca > hit_rr

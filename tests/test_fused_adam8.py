@@ -1,6 +1,7 @@
 """Fused 8-bit-Adam Pallas kernel parity vs the jnp int8 path
 (runtime/optimizers._make_adam_int8).  Runs in interpret mode on the CPU
-mesh; the TPU lowering is exercised by bench.py.
+mesh; tests/test_tpu_compile.py compiles the kernel for the described
+chip.
 """
 import jax
 import jax.numpy as jnp

@@ -254,9 +254,8 @@ class TestQuantizedOverlapLowering:
         (more iterArgs than the serialized build) and (b) on a backend
         with async collectives, schedule compute between start/done
         pairs.  The CPU backend is synchronous, so (b) is asserted only
-        when pairs exist — the TPU-side hard assertion lives in
-        benchmarks/tpu_hlo_check.check_quantized_overlap, which bench.py
-        runs against the real compiler."""
+        when pairs exist (what the TPU's scheduler does with them waits
+        for a four-chip cell)."""
         from deepspeed_tpu.benchmarks.hlo_census import (
             async_overlap_report, collective_census)
         ser = self._quant_engine("none", gas=3)
@@ -283,7 +282,7 @@ class TestQuantizedOverlapLowering:
 
 # ----------------------------------------------------------------------
 # slow-tier env-rot gating (ROADMAP): the container's jaxlib regressed
-# between MULTICHIP_r05 (2026-08-01, all green) and 08-02 — its SPMD
+# between 2026-08-01 (all green) and 08-02 — its SPMD
 # partitioner now refuses the PartitionId instruction that
 # partial-manual shard_map programs (pp pipeline, ring-CP) lower to
 # ("UNIMPLEMENTED: PartitionId instruction is not supported"), and

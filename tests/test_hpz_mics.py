@@ -10,8 +10,8 @@ Reference semantics being tested:
   optimizer state shard within the size-k sub-group, replicate across
   groups; grads still sum over the replica (dp) axis.
 
-Round-4 VERDICT Missing #1/#2: these flags parsed and silently no-oped.
-These tests fail if that regresses.
+These flags once parsed and silently no-oped.  These tests fail if that
+regresses.
 """
 import jax
 import numpy as np

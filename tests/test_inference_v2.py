@@ -585,7 +585,7 @@ def test_prefill_full_does_not_starve_fresh_long_prompt():
     the fast path reserves it one chunk of budget (it can never ride
     prefill_full itself, and the suspension guard only protects
     mid-prefill sequences), so a sustained stream of short fresh
-    arrivals must not defer it indefinitely (ADVICE r5 finding 1)."""
+    arrivals must not defer it indefinitely."""
     model, params = _model()
     eng = _engine(model, params, max_prefill_tokens_per_step=16,
                   prefill_chunk_size=8, max_seqs=4, num_blocks=64,
@@ -891,7 +891,7 @@ def test_small_budget_engine_serves_kernel_class(monkeypatch):
 
 
 def test_prefill_full_learned_pos_513_prompt_past_bucket(monkeypatch):
-    """ADVICE#4 regression: a 513-token prompt pads prefill_full's bucket
+    """Regression: a 513-token prompt pads prefill_full's bucket
     to S=1024 > max_seq_len=768, so padded TAIL positions index past the
     learned pos_embed table.  `_embed` clips them explicitly
     (ragged_ops.py) — this drives the exact corner end-to-end and checks

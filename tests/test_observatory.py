@@ -1,9 +1,7 @@
 """Tests: the serving observatory (ISSUE 13) — seeded open-loop
 workload generation, the open-loop driver against bare loops / fleets /
-disagg pools, the bounded metric time series + its schema gate, the
-recompile flight recorder (positive AND negative control), and the
-cross-run perf-regression ledger (ingest of the committed BENCH_*
-artifacts, the classification table, the tier-1 ledger-schema gate).
+disagg pools, the bounded metric time series + its schema gate, and the
+recompile flight recorder (positive AND negative control).
 
 Determinism discipline matches the rest of the serving tier: fake
 engines where blocks don't matter, a real DSStateManager fake where
@@ -11,7 +9,6 @@ they do, one tiny REAL engine for the ramp integration test, shared
 FakeClocks, zero sleeps.
 """
 import json
-import os
 
 import numpy as np
 import pytest
@@ -29,11 +26,8 @@ from deepspeed_tpu.serving.fleet.faults import FakeClock
 from deepspeed_tpu.serving.observatory import (
     MetricRing, OpenLoopDriver, RecompileFlightRecorder,
     WorkloadGenerator, calibrate_service_rate, program_cache_census)
-from deepspeed_tpu.benchmarks import bench_history
 
 pytestmark = pytest.mark.serving
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _items_equal(a, b):
@@ -428,240 +422,78 @@ def test_calibrate_service_rate_is_deterministic():
 
 
 # -- the ramp, on a tiny real engine ---------------------------------------
-def test_open_loop_ramp_detects_collapse_knee_on_real_engine(monkeypatch):
-    """Integration (ISSUE 13 acceptance): the bench sweep row's driver
-    — calibration, ρ ramp, bit-stability across arms + replay,
-    monotone utilization/queue series, SLA-violation onset at the
-    overloaded arm, zero loss / zero leaked blocks — end-to-end on a
-    tiny REAL engine under the fake clock."""
+def test_open_loop_ramp_detects_collapse_knee_on_real_engine():
+    """The queueing-collapse knee on a tiny REAL engine under the fake
+    clock (1 serve step = 1 virtual second): one seeded heavy-tailed
+    workload, its arrival rate set to rho x the calibrated service rate,
+    at rho 0.3 / 1.0 / 5.0.  Arrival timing changes scheduling and never
+    results (the same greedy tokens in every arm, and the overloaded arm
+    replays bit-identically); nothing is lost, rejected or leaked;
+    occupancy and the queue's peak never fall as rho rises; the
+    overloaded arm queues and waits where the light arm idles; and
+    against a TTFT target anchored to the light arm, violations are 0
+    there and > 0 past the knee."""
     import jax
     import jax.numpy as jnp
 
-    import bench_serve
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
     from deepspeed_tpu.models import Transformer, TransformerConfig
 
-    def tiny_engine(ctx_budget, max_seqs=4, decode_burst=8, **kw):
-        cfg = TransformerConfig(vocab_size=96, hidden_size=32,
-                                num_layers=2, num_heads=2,
-                                max_seq_len=512, dtype=jnp.float32)
-        model = Transformer(cfg)
-        params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
+    cfg = TransformerConfig(vocab_size=96, hidden_size=32, num_layers=2,
+                            num_heads=2, max_seq_len=512,
+                            dtype=jnp.float32)
+    model = Transformer(cfg)
+    eng = InferenceEngineV2(
+        model, params=model.init_params(jax.random.PRNGKey(0)),
+        config=RaggedInferenceEngineConfig(
             num_blocks=96, block_size=16, max_blocks_per_seq=24,
-            max_seqs=max_seqs, prefill_chunk_size=64)
-        return InferenceEngineV2(model, params=params, config=ecfg), cfg
+            max_seqs=2, prefill_chunk_size=64))
 
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    value, extras = bench_serve.bench_serving_openloop_sweep(
-        n_requests=16, seed=3, rhos=(0.3, 1.0, 5.0), max_seqs=2,
-        decode_burst=8)
-    arms = extras["arms"]
-    assert value > 0 and len(arms) == 3
-    assert extras["lost_requests"] == 0 and extras["rejected"] == 0
-    # the knee: the overloaded arm queues where the light arm idles
-    assert arms[-1]["queue_depth_peak"] > arms[0]["queue_depth_peak"]
-    assert arms[-1]["ttft_p95_vs"] > arms[0]["ttft_p95_vs"]
-    assert arms[0]["sla_ttft_violations"] == 0
-    assert arms[-1]["sla_ttft_violations"] > 0
-    assert extras["sla_onset_rho"] == arms[-1]["rho"]
+    def make_loop():
+        # one engine under every arm; a fresh loop and clock for each
+        clock = FakeClock()
+        return ServeLoop(eng, ServingConfig(
+            max_queue_len=512, decode_burst=8, audit_blocks=True,
+            tracing=TracingConfig(enabled=False, metrics_ring=8192)),
+            clock=clock), clock
 
+    n = 16
+    gen = WorkloadGenerator(
+        vocab_size=cfg.vocab_size, seed=3, arrival="poisson",
+        rate_rps=1.0, prompt_len_mean=48.0, prompt_len_sigma=0.9,
+        prompt_len_min=8, prompt_len_max=320, output_len_mean=12.0,
+        output_len_sigma=0.6, output_len_min=2, output_len_max=48)
+    mu = calibrate_service_rate(make_loop, gen.generate(n), step_dt=1.0)
+    assert mu > 0
 
-# -- perf-regression ledger ------------------------------------------------
-def _train_artifact(n: int, value: float) -> dict:
-    """What the driver's bench.py capture looks like (synthetic: the
-    committed training rounds described an installation that is gone)."""
-    parsed = {"metric": "tokens/sec/chip (GPT-2-large 774M, ZeRO bf16, "
-                        "seq 1024)",
-              "value": value, "unit": "tokens/s/chip", "vs_baseline": 1.0}
-    return {"n": n, "cmd": "python bench.py", "rc": 0,
-            "tail": json.dumps(parsed) + "\n", "parsed": parsed}
+    def arm(rho):
+        loop, clock = make_loop()
+        res = OpenLoopDriver(loop, clock,
+                             gen.with_rate(rho * mu).generate(n),
+                             step_dt=1.0).run()
+        assert res.lost == res.rejected == res.rejected_invalid == 0
+        assert len(res.requests) == n
+        eng.audit_blocks()
+        s = loop.telemetry.summary(elapsed_s=res.elapsed_s)
+        return {"tokens": [list(r.output_tokens) for r in res.requests],
+                "ttft": list(loop.telemetry.ttft),
+                "ttft_p95": s["ttft_p95_s"],
+                "occupancy": s["batch_occupancy_mean"],
+                "queue_peak": max(loop.metrics.ring.series("queue_depth"))}
 
-
-def test_ledger_ingests_the_committed_artifacts(tmp_path):
-    """The committed BENCH_SERVE_r* artifacts plus a training series
-    (synthetic BENCH_r01–r05 beside copies of them) all validate and
-    build one trajectory with the expected series."""
-    import glob
-    import shutil
-    for path in glob.glob(os.path.join(REPO_ROOT, "BENCH_SERVE_r*.json")):
-        shutil.copy(path, tmp_path)
-    for n, value in enumerate((16764.0, 17435.0, 17560.3, 17429.0,
-                               17610.0), start=1):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps(_train_artifact(n, value)))
-    doc = bench_history.build_trajectory(str(tmp_path))
-    rows = doc["rows"]
-    for key in ("serve_spec_c8", "serve_disagg_c8x3",
-                "serve_smallctx_c8", "serve_closed_c8",
-                "serve_fleet_chaos_c8x3", "serve_tp_c2"):
-        assert key in rows, f"serve row {key} missing from trajectory"
-        assert rows[key]["unit"] == "tokens/s"
-        assert all(e["backend"] == "cpu" for e in rows[key]["series"])
-    # the 774M train metric repeated across rounds -> a real series
-    train = [k for k in rows if k.startswith("tokens/sec/chip")]
-    assert train and any(len(rows[k]["series"]) >= 3 for k in train)
-    assert len(doc["sources"]["serve"]) >= 5
-    assert len(doc["sources"]["train"]) >= 5
-
-
-def test_committed_trajectory_is_current_and_valid():
-    """Tier-1 ledger-schema gate: BENCH_TRAJECTORY.json is committed,
-    schema-valid, and exactly what a rebuild from the committed
-    artifacts produces — a hand-added or malformed BENCH_*.json fails
-    HERE, at commit time, instead of silently dropping out of the
-    trajectory."""
-    committed = bench_history.load_trajectory(REPO_ROOT)
-    rebuilt = bench_history.build_trajectory(REPO_ROOT)
-    assert committed == rebuilt, (
-        "BENCH_TRAJECTORY.json is stale: rebuild it with "
-        "`dstpu_bench --history --rebuild` (bench_serve.py does this "
-        "automatically unless --no-history)")
-    # and the committed trajectory passes its own gate
-    report, rc = bench_history.check_latest(REPO_ROOT)
-    assert rc == 0, f"committed trajectory fails its own gate: {report}"
-
-
-def test_ledger_rejects_malformed_artifacts(tmp_path):
-    p = tmp_path / "BENCH_SERVE_r01.json"
-    p.write_text("{not json")
-    with pytest.raises(bench_history.LedgerError, match="r01"):
-        bench_history.build_trajectory(str(tmp_path))
-    p.write_text(json.dumps({"round": 1, "date": "d", "backend": "cpu",
-                             "rows": [{"key": "x", "unit": "tokens/s"}]}))
-    with pytest.raises(bench_history.LedgerError, match="value"):
-        bench_history.build_trajectory(str(tmp_path))
-    q = tmp_path / "BENCH_r01.json"
-    p.write_text(json.dumps({"round": 1, "date": "d", "backend": "cpu",
-                             "rows": []}))
-    q.write_text(json.dumps({"n": 1}))
-    with pytest.raises(bench_history.LedgerError, match="parsed"):
-        bench_history.build_trajectory(str(tmp_path))
-
-
-def _write_round(tmp_path, n, value, backend="cpu", key="row_a",
-                 unit="tokens/s"):
-    doc = {"round": n, "date": f"2026-08-{n:02d}", "backend": backend,
-           "note": "", "rows": [{"key": key, "value": value,
-                                 "unit": unit, "backend": backend}]}
-    (tmp_path / f"BENCH_SERVE_r{n:02d}.json").write_text(
-        json.dumps(doc))
-
-
-def test_regression_gate_classification_table(tmp_path):
-    """The classification table: ok / improved / regressed / new /
-    unit_mismatch, lower-better units inverted, backends never
-    pooled."""
-    for n, v in ((1, 100.0), (2, 110.0), (3, 95.0)):
-        _write_round(tmp_path, n, v)
-    traj = bench_history.build_trajectory(str(tmp_path))
-    rows = [
-        {"key": "row_a", "value": 100.0, "unit": "tokens/s"},   # in band
-        {"key": "row_a", "value": 50.0, "unit": "tokens/s"},    # regress
-        {"key": "row_a", "value": 200.0, "unit": "tokens/s"},   # improve
-        {"key": "row_b", "value": 1.0, "unit": "tokens/s"},     # new
-        {"key": "row_a", "value": 100.0, "unit": "ms/token"},   # unit
-    ]
-    out = bench_history.classify(traj, rows, backend="cpu",
-                                 rel_tol=0.2)
-    assert [r["verdict"] for r in out] == [
-        "ok", "regressed", "improved", "new", "unit_mismatch"]
-    assert out[0]["prior_points"] == 3 and not out[0]["thin_history"]
-    # lower-is-better inversion: a LOWER ms/token is an improvement
-    for n in (1, 2, 3):
-        os.remove(tmp_path / f"BENCH_SERVE_r{n:02d}.json")
-    _write_round(tmp_path, 1, 10.0, key="lat", unit="ms/token")
-    traj = bench_history.build_trajectory(str(tmp_path))
-    out = bench_history.classify(
-        traj, [{"key": "lat", "value": 50.0, "unit": "ms/token"},
-               {"key": "lat", "value": 2.0, "unit": "ms/token"}],
-        backend="cpu", rel_tol=0.2)
-    assert [r["verdict"] for r in out] == ["regressed", "improved"]
-    assert out[0]["thin_history"] is True
-    # cross-backend history never pools: a tpu row against cpu-only
-    # history is NEW, not compared against the wrong band
-    out = bench_history.classify(
-        traj, [{"key": "lat", "value": 50.0, "unit": "ms/token"}],
-        backend="tpu")
-    assert out[0]["verdict"] == "new"
-
-
-def test_regression_gate_exits_nonzero_on_injected_regression(tmp_path):
-    """End-to-end gate contract (ISSUE 13 acceptance): a synthetic
-    regressed round exits nonzero via `dstpu_bench --history --check`;
-    the healthy trajectory passes."""
-    from deepspeed_tpu.benchmarks.comms_bench import main as bench_main
-
-    for n, v in ((1, 100.0), (2, 108.0)):
-        _write_round(tmp_path, n, v)
-    bench_history.rebuild(str(tmp_path))
-    assert bench_main(["--history", "--root", str(tmp_path),
-                       "--check"]) == 0
-    # inject the regression as the latest round and re-gate
-    _write_round(tmp_path, 3, 40.0)
-    bench_history.rebuild(str(tmp_path))
-    assert bench_main(["--history", "--root", str(tmp_path),
-                       "--check"]) == 1
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 1
-    assert report[0]["verdict"] == "regressed"
-    # the check excludes the checked round from its own band: round 3's
-    # own 40.0 must not have widened the band it is judged against
-    assert report[0]["prior_points"] == 2
-    # a unit rename is a gate FAILURE too (the row was never compared;
-    # exit 0 would let a regression hide behind the rename).  No
-    # rebuild here: the --check-only flow gates the renamed round
-    # against the trajectory on disk (a rebuild would itself refuse
-    # the mid-trajectory unit change, the other loud path)
-    _write_round(tmp_path, 4, 100.0, unit="tok/s")
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 1 and report[0]["verdict"] == "unit_mismatch"
-    with pytest.raises(bench_history.LedgerError, match="unit"):
-        bench_history.rebuild(str(tmp_path))
-    os.remove(tmp_path / "BENCH_SERVE_r04.json")
-    # ...and a row carrying its OWN backend stamp classifies against
-    # THAT backend's band, not the document's (a tpu row over cpu-only
-    # history is new, never a false cpu-band verdict)
-    doc = {"round": 4, "date": "2026-08-04", "backend": "cpu",
-           "note": "", "rows": [{"key": "row_a", "value": 1.0,
-                                 "unit": "tokens/s", "backend": "tpu"}]}
-    (tmp_path / "BENCH_SERVE_r04.json").write_text(json.dumps(doc))
-    bench_history.rebuild(str(tmp_path))
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 0
-    assert report[0]["verdict"] == "new"
-    assert report[0]["backend"] == "tpu"
-
-
-def test_gate_failed_rounds_never_self_heal_into_the_band(tmp_path):
-    """A round that failed the gate is stamped `gate_failed`
-    (persist_rows does this before raising) and its values are
-    excluded from every future noise band — an unfixed regression
-    keeps failing on re-runs instead of becoming its own precedent."""
-    for n, v in ((1, 100.0), (2, 108.0)):
-        _write_round(tmp_path, n, v)
-    _write_round(tmp_path, 3, 40.0)                 # the regression
-    bench_history.rebuild(str(tmp_path))
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 1
-    # the stamp (what bench_serve's auto-gate applies on failure)
-    bench_history.mark_gate_failed(
-        str(tmp_path / "BENCH_SERVE_r03.json"))
-    bench_history.rebuild(str(tmp_path))
-    # the unfixed re-run at the same regressed value STILL fails: round
-    # 3's 40.0 did not widen the band it is judged against
-    _write_round(tmp_path, 4, 40.0)
-    bench_history.rebuild(str(tmp_path))
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 1 and report[0]["verdict"] == "regressed"
-    assert report[0]["prior_points"] == 2           # r01 + r02 only
-    # the failed re-run gets stamped too; a genuinely recovered round
-    # then passes against the healthy band
-    bench_history.mark_gate_failed(
-        str(tmp_path / "BENCH_SERVE_r04.json"))
-    _write_round(tmp_path, 5, 104.0)
-    bench_history.rebuild(str(tmp_path))
-    report, rc = bench_history.check_latest(str(tmp_path))
-    assert rc == 0 and report[0]["verdict"] == "ok"
-    assert report[0]["prior_points"] == 2
+    light, at_capacity, overloaded = arms = [arm(r) for r in (0.3, 1.0, 5.0)]
+    assert at_capacity["tokens"] == overloaded["tokens"] == light["tokens"]
+    replay = arm(5.0)
+    assert (replay["tokens"], replay["ttft"]) == (overloaded["tokens"],
+                                                  overloaded["ttft"])
+    for series in ("occupancy", "queue_peak"):
+        xs = [a[series] for a in arms]
+        assert all(b >= a - 1e-9 for a, b in zip(xs, xs[1:])), (series, xs)
+    assert overloaded["queue_peak"] > light["queue_peak"]
+    assert overloaded["ttft_p95"] > light["ttft_p95"]
+    # virtual time counts whole steps, so an uncontended TTFT can be 0:
+    # anchor the target one step above the light arm's p95
+    target = 3.0 * (light["ttft_p95"] + 1.0)
+    assert sum(t > target for t in light["ttft"]) == 0
+    assert sum(t > target for t in overloaded["ttft"]) > 0

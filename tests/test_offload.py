@@ -292,7 +292,7 @@ class TestOffloadStatesAPI:
 
 
 def test_1p3b_zero2_8dev_memory_fits(devices8):
-    """North-star scale check (VERDICT r2 #4): the GPT-2-1.3B config under
+    """Scale check: the 1.3B-class GPT-2 config under
     ZeRO-2 on 8 devices must COMPILE and its per-device memory accounting
     (XLA memory_analysis — static, nothing runs) must fit a 16 GB v5e
     chip: fp32 master + bf16 moments reduce-scattered 8 ways, bf16

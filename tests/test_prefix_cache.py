@@ -316,6 +316,29 @@ def _shared_prompt_stream(n, shared_len=32, unique_len=11, seed=7):
             for _ in range(n)]
 
 
+def _serve_stream(prompts, pcb, new_tokens=5, decode_burst=1, **engine_kw):
+    """Serve `prompts` (all submitted at once) on a fresh tiny engine
+    with a `pcb`-block prefix cache (0: off), counting the prefill
+    tokens of every step.  -> (tokens per request, prefill tokens,
+    telemetry summary, engine)."""
+    eng = _tiny_engine(**engine_kw)
+    loop = ServeLoop(eng, ServingConfig(prefix_cache_blocks=pcb,
+                                        decode_burst=decode_burst,
+                                        audit_blocks=True),
+                     clock=_FakeClock())
+    reqs = [loop.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    prefill_total = 0
+    steps = 0
+    while loop.has_work:
+        loop.step()
+        prefill_total += loop.telemetry.prefill_tokens_step
+        steps += 1
+        assert steps < 300
+    assert all(r.state is RequestState.DONE for r in reqs)
+    return ([list(r.output_tokens) for r in reqs], prefill_total,
+            loop.telemetry.summary(), eng)
+
+
 def test_serve_loop_prefix_parity_and_savings():
     """The serve-loop parity contract: `prefix_cache_blocks=0` is today's
     behavior, cache-on produces IDENTICAL tokens with measurably fewer
@@ -323,21 +346,7 @@ def test_serve_loop_prefix_parity_and_savings():
     prompts = _shared_prompt_stream(4)
 
     def run(pcb):
-        eng = _tiny_engine()
-        loop = ServeLoop(eng, ServingConfig(prefix_cache_blocks=pcb,
-                                            audit_blocks=True),
-                         clock=_FakeClock())
-        reqs = [loop.submit(p, max_new_tokens=5) for p in prompts]
-        prefill_total = 0
-        steps = 0
-        while loop.has_work:
-            loop.step()
-            prefill_total += loop.telemetry.prefill_tokens_step
-            steps += 1
-            assert steps < 300
-        assert all(r.state is RequestState.DONE for r in reqs)
-        return ([list(r.output_tokens) for r in reqs], prefill_total,
-                loop.telemetry.summary(), eng)
+        return _serve_stream(prompts, pcb)
 
     outs_off, prefill_off, s_off, eng_off = run(0)
     outs_on, prefill_on, s_on, eng_on = run(24)
@@ -674,36 +683,25 @@ def test_serving_config_prefix_validation_and_json_wiring():
         ServingConfig(prefix_cache_blocks=-1).validate()
 
 
-def test_bench_prefix_row_driver_on_tiny_engine(monkeypatch):
-    """The serve_prefix_c8 row's driver — identical-stream cache-off vs
-    cache-on comparison, hit-rate / >= 50%-prefill-reduction /
-    bit-for-bit / audit asserts — end-to-end on the tiny CPU engine."""
-    import jax
-    import jax.numpy as jnp
-
-    import bench_serve
-    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                            RaggedInferenceEngineConfig)
-    from deepspeed_tpu.models import Transformer, TransformerConfig
-
-    def tiny_engine(ctx_budget, max_seqs=8, decode_burst=16,
-                    full_prompt_prefill=True, **kw):
-        cfg = TransformerConfig(vocab_size=128, hidden_size=64,
-                                num_layers=2, num_heads=4,
-                                max_seq_len=1024, dtype=jnp.float32)
-        model = Transformer(cfg)
-        params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
-            num_blocks=64, block_size=16, max_blocks_per_seq=16,
-            max_seqs=max_seqs, prefill_chunk_size=32,
-            full_prompt_prefill=full_prompt_prefill)
-        return InferenceEngineV2(model, params=params, config=ecfg), cfg
-
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    goodput, extras = bench_serve.bench_serving_prefix(
-        clients=3, requests_per_client=1, new_tokens=3, shared_len=64,
-        unique_len=16, max_seqs=1, prefix_cache_blocks=8)
-    assert goodput > 0
-    assert extras["hit_rate"] > 0
-    assert extras["prefill_saved_frac"] >= 0.5
-    assert extras["ttft_p50_ms"] >= 0
+def test_bench_prefix_row_driver_on_tiny_engine():
+    """A shared-system-prompt stream served one sequence at a time, its
+    decode in bursts, behind a cache smaller than the stream (8 blocks:
+    the unique tails churn out, the shared prefix stays): the same
+    tokens as with the cache off, every request after the first a hit,
+    at least half of all prompt tokens never prefilled, and no block
+    leaked (the audit runs after every finishing step and once more
+    after the drain)."""
+    shared_len, unique_len = 64, 16
+    prompts = _shared_prompt_stream(3, shared_len, unique_len, seed=9)
+    geometry = dict(new_tokens=3, decode_burst=16, num_blocks=64,
+                    block_size=16, max_seqs=1)
+    outs_off, prefill_off, s_off, _ = _serve_stream(prompts, 0, **geometry)
+    outs_on, prefill_on, s_on, eng = _serve_stream(prompts, 8, **geometry)
+    assert outs_on == outs_off
+    assert s_off["prefix_hit_rate"] is None
+    assert s_on["prefix_hit_rate"] == pytest.approx(2 / 3)
+    assert s_on["prefill_tokens_saved"] == prefill_off - prefill_on
+    assert (s_on["prefill_tokens_saved"]
+            >= 0.5 * len(prompts) * (shared_len + unique_len))
+    report = eng.audit_blocks()
+    assert report["live"] == 0 and 0 < report["cached"] <= 8

@@ -254,8 +254,7 @@ def test_microstep_overlap_carries_double_buffer(devices8):
         f"iterArgs {a_ref} -> {a_ov}")
     # the deferred reductions still happen — and on a backend with a
     # latency-hiding scheduler they show up as async start/done pairs
-    # with compute between (asserted hard on TPU by tpu_hlo_check's
-    # check_quantized_overlap; the CPU backend schedules synchronously)
+    # with compute between (the CPU backend schedules synchronously)
     compiled = ov_l.compile().as_text()
     census = collective_census(compiled)
     assert census["all-to-all"] > 0, census

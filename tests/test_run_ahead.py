@@ -18,11 +18,11 @@ import pytest
 
 import jax
 
-from chip_smoke import CompileCounter
 from deepspeed_tpu.config.config import (PreemptionConfig, ServingConfig,
                                          StreamingConfig, StructuredConfig)
 from deepspeed_tpu.serving import RequestState, ServeLoop, ThreadedServer
 from deepspeed_tpu.serving.structured import ResponseFormat
+from deepspeed_tpu.utils.device import CompileCounter
 
 from test_device_sampling import EOS, VOCAB, _engine, _prompts, tiny  # noqa: F401
 from test_serving import FakeClock

@@ -635,43 +635,82 @@ def test_threaded_server_concurrent_submitters():
         server.shutdown(drain=True, timeout=10.0)
 
 
-# -- bench driver ---------------------------------------------------------
-def test_bench_closed_loop_driver_runs_on_tiny_engine(monkeypatch):
-    """The bench_serve closed-loop row's driver logic (fixed staggered
-    arrivals, closed-loop resubmission, zero-loss accounting) runs
-    end-to-end on the tiny CPU engine."""
+# -- closed loop -----------------------------------------------------------
+def _closed_loop(target, prompts, new_tokens, after_step=None,
+                 max_steps=2000):
+    """Serve `prompts` ({(client, k): tokens}) through a `ServeLoop` or a
+    `FleetRouter` as a closed loop: every client's request 0 at once, its
+    request k+1 the moment its request k finishes.  Every request must
+    end DONE.  -> ({(client, k): output tokens}, the requests in
+    submission order)."""
+    owner, outputs, reqs = {}, {}, []
+
+    def submit(key):
+        req = target.submit(prompts[key], max_new_tokens=new_tokens)
+        owner[id(req)] = key
+        reqs.append(req)
+
+    for client in sorted({c for c, _ in prompts}):
+        submit((client, 0))
+    steps = 0
+    while len(outputs) < len(prompts):
+        steps += 1
+        assert steps < max_steps, "closed loop wedged"
+        for req in target.step():
+            key = owner.pop(id(req), None)
+            if key is None:                  # not of this stream
+                continue
+            assert req.state is RequestState.DONE, (key, req.state)
+            outputs[key] = list(req.output_tokens)
+            if (key[0], key[1] + 1) in prompts:
+                submit((key[0], key[1] + 1))
+        if after_step is not None:
+            after_step()
+    return outputs, reqs
+
+
+def test_bench_closed_loop_driver_runs_on_tiny_engine():
+    """A closed loop over `ServeLoop` on the tiny CPU engine: two clients,
+    each submitting its next request the moment its previous one
+    completes (one short-prompt client, one long, so prefill and decode
+    interleave in the ragged batch) — per step, then with
+    `decode_burst=2`.  Every request finishes DONE with all its tokens,
+    none is lost, timed out or cancelled, and the telemetry's TTFT / e2e
+    percentiles are ordered."""
     import jax
     import jax.numpy as jnp
 
-    import bench_serve
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
     from deepspeed_tpu.models import Transformer, TransformerConfig
 
-    def tiny_engine(ctx_budget, max_seqs=8, decode_burst=32, **kw):
-        cfg = TransformerConfig(vocab_size=128, hidden_size=64,
-                                num_layers=2, num_heads=4, max_seq_len=1024,
-                                dtype=jnp.float32)
-        model = Transformer(cfg)
-        params = model.init_params(jax.random.PRNGKey(0))
-        ecfg = RaggedInferenceEngineConfig(
-            num_blocks=128, block_size=16, max_blocks_per_seq=40,
-            max_seqs=max_seqs, prefill_chunk_size=128)
-        return InferenceEngineV2(model, params=params, config=ecfg), cfg
+    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
+                            num_heads=4, max_seq_len=1024,
+                            dtype=jnp.float32)
+    model = Transformer(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    ecfg = RaggedInferenceEngineConfig(
+        num_blocks=128, block_size=16, max_blocks_per_seq=40, max_seqs=2,
+        prefill_chunk_size=128)
+    rng = np.random.RandomState(5)
+    prompts = {(client, k): rng.randint(0, 128, 512 if client else 128)
+               .astype(np.int32) for client in range(2) for k in range(2)}
 
-    monkeypatch.setattr(bench_serve, "_engine", tiny_engine)
-    goodput, extras = bench_serve.bench_serving_closed_loop(
-        clients=2, requests_per_client=1, new_tokens=3, stagger_s=0.0)
-    assert goodput > 0
-    assert extras["requests"] == 2
-    assert extras["ttft_p95_ms"] >= extras["ttft_p50_ms"] >= 0
-    assert extras["e2e_p95_ms"] >= extras["e2e_p50_ms"] > 0
-    # the serve_burst_c8 row's configuration: same driver, burst loop
-    goodput_b, extras_b = bench_serve.bench_serving_closed_loop(
-        clients=2, requests_per_client=1, new_tokens=3, stagger_s=0.0,
-        decode_burst=2)
-    assert goodput_b > 0 and extras_b["decode_burst"] == 2
-    assert extras_b["tpot_burst_p50_ms"] >= 0
+    for decode_burst in (1, 2):
+        eng = InferenceEngineV2(model, params=params, config=ecfg)
+        loop = ServeLoop(eng, ServingConfig(max_queue_len=len(prompts) + 1,
+                                            decode_burst=decode_burst))
+        outputs, _ = _closed_loop(loop, prompts, new_tokens=3)
+        assert all(len(toks) == 3 for toks in outputs.values())
+        assert not loop.has_work and eng.state.seqs == {}
+        s = loop.telemetry.summary()
+        assert s["completed"] == s["admitted"] == len(prompts)
+        assert s["timed_out"] == 0 and s["cancelled"] == 0
+        assert s["ttft_p95_s"] >= s["ttft_p50_s"] >= 0
+        assert s["e2e_p95_s"] >= s["e2e_p50_s"] > 0
+        # a burst's tokens come in one host observation: the burst
+        # percentiles exist exactly on the burst loop
+        assert (s["tpot_burst_p50_s"] is not None) == (decode_burst > 1)
 
 
 # -- real-engine integration ---------------------------------------------

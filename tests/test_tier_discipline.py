@@ -30,6 +30,7 @@ DEFAULT_TIER = {
     "test_chip_smoke.py",
     "test_data_pipeline.py",
     "test_device.py",
+    "test_docs.py",
     "test_domino_zenflow.py",
     "test_engine.py",
     "test_hpz_mics.py",

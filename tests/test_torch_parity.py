@@ -1,7 +1,6 @@
 """Loss-curve parity vs an independent PyTorch implementation.
 
-The reference's north-star requirement (BASELINE.md) is throughput at
-*identical loss curves*.  This test builds the same tiny GPT-2-style model
+What the reference promises is throughput at *identical loss curves*.  This test builds the same tiny GPT-2-style model
 in torch (CPU), copies our init weights in, trains both with plain SGD in
 fp32 on the same token stream, and demands per-step loss agreement — any
 divergence in forward math, autodiff, loss reduction, or the engine's

@@ -56,7 +56,7 @@ def kernels(fn, *args) -> int:
             "tpu_custom_call")
 
 
-# (B, S, heads, kv heads, head_dim): the smoke's model and bench.py's
+# (B, S, heads, kv heads, head_dim): the smoke's model and a D=128 one
 @pytest.mark.parametrize("B,S,NH,NKV,D", [
     pytest.param(4, 2048, 32, 4, 64, id="tinyllama-1.1b"),
     pytest.param(4, 2048, 16, 16, 128, id="gpt2-1.3b"),

@@ -107,8 +107,8 @@ def test_qgz_bits_validated():
 
 def test_flags_change_wire_dtype(devices8):
     """The collectives the step lowers to must carry int8 payloads when
-    the flags are on — the CommsLogger/HLO-volume check VERDICT r3 asked
-    for (flags that parse but drive nothing would fail this)."""
+    the flags are on (flags that parse but drive nothing would fail
+    this)."""
     def collect_lines(eng):
         b = eng._shard_batch(_batch())
         txt = eng._train_step.lower(
@@ -157,7 +157,7 @@ def _temp_bytes(eng, cfg, seq=32):
 
 
 def test_qwz_per_layer_gather_composes_with_stage3_memory(devices8):
-    """VERDICT r4 Missing #3: qwZ used to gather EVERY sharded leaf at the
+    """qwZ used to gather EVERY sharded leaf at the
     top of the loss, so its peak memory was ZeRO-1/2-like.  With the
     per-layer gather (layer_gather.py + the model scan hook) the compiled
     step's temp memory must sit near plain stage 3, far below the eager
@@ -229,7 +229,7 @@ def _collective_wire_bytes(eng, batch, n=8):
 
 
 def test_zeropp_wire_bytes_measured(devices8):
-    """VERDICT r4 Weak #5: the qwZ/qgZ byte saving must be MEASURED, not
+    """The qwZ/qgZ byte saving must be MEASURED, not
     asserted by dtype alone.  Census the compiled step's collectives:
     int8 wire must at least halve stage-3 param+grad traffic; int4 qgZ
     must cut strictly deeper.  (Reference quantifies 4x for the full
