@@ -97,6 +97,10 @@ class LogitsRows(Mapping):
         # of the step's decode rows, how many took their input token on
         # the device from the decode program of the step before
         self.decode_rows = self.fed_rows = 0
+        # of the decode program's block table (rows x width), the entries
+        # that hold keys a decode row attends to: what the paged decode
+        # kernel's time follows
+        self.kv_live_blocks = self.kv_table_blocks = 0
         # what `engine.collect` returns: the rows it left out because
         # their sequence had been flushed (or replaced under its uid)
         self.overrun = 0
@@ -1049,6 +1053,9 @@ class InferenceEngineV2:
                         self._host_in(source)),
                     self._host_in(lens), self._host_in(tables),
                     self._host_in(active), **lkw)
+            pending.kv_live_blocks = sum(
+                d.seen_tokens // self.config.block_size + 1 for d in batch)
+            pending.kv_table_blocks = tables.size
             for d in batch:
                 d.seen_tokens += 1
             pending.decode = _Program("decode_step", logits, toks,

@@ -206,27 +206,21 @@ def _use_paged_kernel(cfg: TransformerConfig, D: int, bs: int,
                       n_tp: int = 1) -> bool:
     """Gate the fused Pallas decode kernel: capability only.
 
-    Measurements (v5e, 2026-07-30, GPT-2-medium geometry, ctx 2048):
-    - attention alone: kernel 1.3-3.1x faster at 2k-4k context (bigger win
-      at GQA), incl. reproduced inside a 24-layer scan with the arena
-      scatter and donation (46 vs 65 ms).
-    - the full compiled decode_step, timed directly with chained calls:
-      kernel 60.9 ms vs dense 75.4 ms (temp memory also smaller).
-    The kernel serves the FULL key range: the 2048-key auto-gate that
-    routed small budgets onto the ~25x-slower dense XLA gather (and the
-    774M-class crash guard that gate needed) was retired in r7 — small
-    arenas run a short k-block grid (degenerate single-block walks
-    included), which is strictly cheaper than materializing the gathered
-    copy.  attn_impl="pallas" forces it (raising if the shapes or
-    platform cannot run it — no silent fallback), "jnp" is the explicit
-    dense escape hatch.
+    What the kernel costs on the chip, alone and inside the decode
+    program, and how it compares with the dense gather, is in `PERF.md`
+    (sections 5 and 6: the qwen2-7b cell and the kernel-alone sweep).
+    The kernel serves the FULL key range and every table width: it walks
+    a row's live blocks (`ops/paged_attention.py`), so a small arena is a
+    short walk (a single block included), which is strictly cheaper than
+    materializing the gathered copy.  attn_impl="pallas" forces it
+    (raising if the shapes or platform cannot run it — no silent
+    fallback), "jnp" is the explicit dense escape hatch.
 
-    No kv-head-count gate is needed: the K/V block's sublane dim is NKV,
-    and a v5e sweep (2026-07-30) of NKV in {1,2,3,4,5} x D in {64,128} —
-    odd counts, GQA and MHA — all compile under Mosaic and match the dense
-    reference to bf16 tolerance.  Small-budget shapes are additionally
-    AOT-compile-asserted against the real TPU compiler by
-    tests/test_tpu_compile.py."""
+    No kv-head-count gate is needed: odd counts, one local head of a
+    tensor-parallel shard, GQA and MHA at D 64 and 128 all compile under
+    Mosaic (`tests/test_tpu_compile.py` holds the real TPU compiler to
+    it, the cell's own shape and a 32k table among them) and match the
+    dense reference (`tests/test_paged_attention.py`)."""
     supported = (_kernel_capable(cfg, D, bs, n_tp)
                  and cfg.sliding_window is None)
     return _gate_fused(

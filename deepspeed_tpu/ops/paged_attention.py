@@ -6,20 +6,42 @@ block table; also the fused softmax_context decode path of
 csrc/transformer/inference/pt_binding.cpp).
 
 One query token per sequence attends to that sequence's KV blocks scattered
-through the shared arena.  The TPU-native trick: the block table rides the
-grid as a *scalar-prefetch* operand, and the K/V BlockSpec index maps read
-it — grid step (b, j) DMAs arena block `table[b, j]` straight into VMEM.
-The gathered [B, max_kv, ...] K/V copy the dense path materializes in HBM
-never exists; online softmax accumulates across table blocks in VMEM
-scratch (flash-attention style), so per-step HBM traffic is exactly one
-visit of the live KV blocks.
+through the shared arena.  The kernel's time follows the LIVE blocks of the
+batch, not rows x table width:
 
-GQA runs without a KV repeat: scores are computed per kv-head with the
-grouped q heads batched ([NKV, G, D] x [NKV, bs, D]).
+- the grid is ONE dimension over the batch's live tiles.  A tile is
+  `per_step` consecutive table entries of one row (about 512 keys: 8
+  blocks of 64; `_blocks_per_step` derives it from the static shapes).  A
+  row of `n` live blocks (`len // bs + 1`) is `ceil(n / per_step)` tiles,
+  an inactive row none.  The wrapper lists the tiles in row order
+  (`_walk`: a handful of integer ops on [B] and [B * steps] vectors, the
+  same for every layer) and the list rides the grid as scalar-prefetch
+  operands; its length is the grid's (dynamic) size, so a table entry
+  past a row's last live block is no grid step at all, whatever the
+  table's width;
+- each of a tile's `per_step` blocks is its own in_spec, whose index map
+  reads the list: the pipeline has the next tile's blocks in flight,
+  across rows, while this tile computes.  A slot of a row's last tile
+  past its last live block keeps the index it had a tile earlier, so the
+  pipeline sees no change and issues no copy: a dead table entry costs
+  neither a step, nor bytes, nor compute (its keys are masked);
+- inside a tile there is no float32 copy of K or V and no transpose.  The
+  blocks are read as `[bs * NKV, D]` rows (key-major, kv head minor) and
+  joined into one `[keys * NKV, D]` operand in the cache's dtype; every
+  query head is scored against every (key, kv head) row in one matmul
+  with float32 accumulation, and the columns of another kv head's rows
+  are masked with the keys past the row's length.  That is NKV times the
+  products the group structure needs, on an MXU with nothing else to do:
+  decode is bound by the bytes.  Where the arena's `[bs, NKV, D]` block
+  and `[bs * NKV, D]` are the same bytes in HBM (`_rows_view_is_free`)
+  the wrapper hands the arena over in that shape, a bitcast; elsewhere
+  the kernel reshapes the block in VMEM.  The online softmax (`m`, `l`,
+  `acc`) is float32; `p` goes to the cache's dtype for PV as in
+  `paged_decode_reference`.
 
 Masking: block j of a table holds key positions [j*bs, (j+1)*bs); keys with
-position > lens[b] (and whole blocks past the sequence) contribute exp(-inf)
-= 0.  lens[b] < 0 marks an inactive (padded) row — output zeros.
+position > lens[b] contribute exp(-inf) = 0.  lens[b] < 0 marks an inactive
+(padded) row — output zeros.
 """
 from __future__ import annotations
 
@@ -28,12 +50,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_decode_attention", "paged_decode_reference"]
 
 NEG_INF = -1e30
+# what a tile may take of the 16 MiB of VMEM a kernel gets on a v5e: its K
+# and V blocks, double-buffered by the pipeline
+_TILE_VMEM_BYTES = 8 << 20
 
 
 def paged_decode_reference(q, arena_k, arena_v, block_tables, lens):
@@ -63,74 +89,130 @@ def paged_decode_reference(q, arena_k, arena_v, block_tables, lens):
     return jnp.where(zero, 0.0, out).astype(q.dtype)
 
 
-def _compute_block(tables_ref, lens_ref, q_ref, k, v,
-                   m_s, l_s, acc_s, b, j, *, bs, groups, sm_scale):
-    # k/v: [bs, NKV, D] arrays already read from their (possibly layered)
-    # blocks — Mosaic rejects sub-ref views whose minor dim is narrower
-    # than the 128 tiling, so the kernel reads with leading indices
+def _tile_rows(NKV: int, itemsize: int) -> int:
+    """Rows of the TPU's HBM tile over a block's (NKV, D) minor dims: the
+    smallest power of two that holds NKV, from one 32-bit sublane's worth
+    of rows up to 8 (bfloat16: T(2,128)(2,1) to T(8,128)(2,1))."""
+    rows = max(1, 4 // itemsize)
+    while rows < min(NKV, 8):
+        rows *= 2
+    return rows
+
+
+def _rows_view_is_free(NKV: int, D: int, itemsize: int) -> bool:
+    """Whether an arena block `[bs, NKV, D]` and its rows `[bs * NKV, D]`
+    are the same bytes in HBM, so that XLA's reshape is a bitcast: the
+    head dim is one lane tile (a wider one is split into tiles that the
+    two shapes order differently) and the kv heads fill their tile with
+    no padding row.  `tests/test_tpu_compile.py` holds the compiler to it:
+    a reshape that is not free would copy the arena."""
+    return D <= 128 and NKV % _tile_rows(NKV, itemsize) == 0
+
+
+def _block_vmem_bytes(bs: int, NKV: int, D: int, itemsize: int) -> int:
+    """An arena block in VMEM, with the padding its (NKV, D) tile gives."""
+    tile = _tile_rows(NKV, itemsize)
+    return bs * (-(-NKV // tile) * tile) * (-(-D // 128) * 128) * itemsize
+
+
+def _blocks_per_step(bs: int, NKV: int, D: int, itemsize: int,
+                     MB: int) -> int:
+    """Arena blocks joined into one grid step's tile, from the static
+    shapes alone: about 512 keys, at most 8 blocks (each is an in_spec,
+    and compile time follows their number), at most 4096 (key, kv head)
+    rows of scores, K and V double-buffered inside `_TILE_VMEM_BYTES`.
+    The table's width only caps it: tables of 10 and of 32 entries walk
+    a row alike."""
+    return max(1, min(MB, 8, 512 // bs, 4096 // (bs * NKV),
+                      _TILE_VMEM_BYTES
+                      // (4 * _block_vmem_bytes(bs, NKV, D, itemsize))))
+
+
+@jax.named_scope("paged_attention_walk")
+def _walk(tables, lens, bs: int, per_step: int):
+    """The batch's live tiles in row order, for the grid to walk.
+
+    tables [B, MB] (clipped), lens [B].  Returns (count, rows [N], tiles
+    [N], blocks [per_step * N]) int32, N = B * ceil(MB / per_step): item
+    i < count is tile `tiles[i]` of row `rows[i]`, and slot s of it
+    reads arena block `blocks[s * N + i]` — the table's entry where that
+    is live, else the block the slot held an item earlier (no copy)."""
+    B, MB = tables.shape
+    N = B * -(-MB // per_step)
+    n_blocks = jnp.where(lens >= 0, lens // bs + 1, 0)
+    n_tiles = -(-n_blocks // per_step)
+    ends = jnp.cumsum(n_tiles)
+    item = jnp.arange(N, dtype=jnp.int32)
+    rows = jnp.minimum(
+        jnp.sum(ends[None, :] <= item[:, None], axis=1), B - 1)
+    tiles = item - (ends - n_tiles)[rows]
+    entry = tiles[:, None] * per_step + jnp.arange(per_step)[None]  # [N, P]
+    live = (entry < n_blocks[rows][:, None]) & (item < ends[-1])[:, None]
+    held = jax.lax.cummax(jnp.where(live, item[:, None], -1), axis=0)
+    blocks = jnp.take_along_axis(
+        tables[rows[:, None], jnp.minimum(entry, MB - 1)],
+        jnp.maximum(held, 0), axis=0)
+    blocks = jnp.where(held >= 0, blocks, 0)
+    i32 = lambda x: x.astype(jnp.int32)                      # noqa: E731
+    return (i32(ends[-1]), i32(rows), i32(tiles),
+            i32(blocks.T.reshape(-1)))
+
+
+def _kernel(*refs, bs: int, per_step: int, kv_heads: int, groups: int,
+            sm_scale: float, rows_view: bool):
+    # scalars: rows [N], tiles [N], blocks [P*N], lens [B] (and the layer
+    # index [1], if any).  q_ref/o_ref [1, NH, D]; col_key/col_head [1,
+    # keys*NKV] int32 (the key within the tile and the kv head of a score
+    # column); per_step K then V blocks, each [1(, 1), bs*NKV, D]
+    # (`rows_view`) or [1(, 1), bs, NKV, D]; scratch m/l [NH, 128], acc
+    # [NH, D] float32
+    rows_ref, tiles_ref, _, lens_ref = refs[:4]
+    refs = refs[-(2 * per_step + 7):]
+    q_ref, col_key_ref, col_head_ref = refs[:3]
+    k_refs, v_refs = refs[3:3 + per_step], refs[3 + per_step:-4]
+    o_ref, m_s, l_s, acc_s = refs[-4:]
+    i = pl.program_id(0)
+    tile = tiles_ref[i]
+    length = lens_ref[rows_ref[i]]
     NH, D = q_ref.shape[1], q_ref.shape[2]
-    NKV = k.shape[1]
-    qg = q_ref[0].astype(jnp.float32).reshape(NKV, groups, D) * sm_scale
-    k = k.astype(jnp.float32)                           # [bs, NKV, D]
-    v = v.astype(jnp.float32)
-    kt = jnp.swapaxes(k, 0, 1)                          # [NKV, bs, D]
-    vt = jnp.swapaxes(v, 0, 1)
+    keys = per_step * bs
 
-    # scores per kv head, grouped q heads batched: [NKV, G, bs]
-    s = jax.lax.dot_general(qg, kt, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    key_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-    s = jnp.where(key_pos <= lens_ref[b], s, NEG_INF)
-    s2 = s.reshape(NH, bs)
+    def joined(block_refs):
+        """The tile's blocks as one [keys * NKV, D] operand."""
+        if rows_view:
+            blocks = [r[(0,) * (len(r.shape) - 2)] for r in block_refs]
+        else:
+            blocks = [r.reshape(bs * kv_heads, D)[...] for r in block_refs]
+        return jnp.concatenate(blocks, axis=0)
 
-    m_prev = m_s[:, :1]                                 # [NH, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-    # explicit re-mask: when every key is masked m_new == NEG_INF and
-    # exp(s - m) would be exp(0) = 1 for the masked entries
-    p2 = jnp.where(s2 > NEG_INF * 0.5, jnp.exp(s2 - m_new), 0.0)  # [NH, bs]
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_s[:, :1] + jnp.sum(p2, axis=1, keepdims=True)
-
-    # weighted values: [NKV, G, bs] x [NKV, bs, D] -> [NKV, G, D]
-    pv = jax.lax.dot_general(p2.reshape(NKV, groups, bs), vt,
-                             (((2,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-    acc_s[:] = acc_s[:] * alpha + pv.reshape(NH, D)
-    m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-    l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
-
-
-def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-            m_s, l_s, acc_s, *, bs: int, groups: int, sm_scale: float,
-            layered: bool = False):
-    # q_ref: [1, NH, D]; k_ref/v_ref: [1, bs, NKV, D] (or [1, 1, bs, NKV,
-    # D] when `layered` — the arena keeps its leading layer dim and the
-    # BlockSpec index map picks the layer); o_ref: [1, NH, D]
-    # scratch: m_s/l_s [NH, 128] f32, acc_s [NH, D] f32
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_j = pl.num_programs(1)
-
-    @pl.when(j == 0)
+    @pl.when(tile == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
 
-    # skip whole blocks past the sequence end (their DMA is already paid;
-    # the compute is not)
-    @pl.when(j * bs <= lens_ref[b])
-    def _compute():
-        k = k_ref[0, 0] if layered else k_ref[0]
-        v = v_ref[0, 0] if layered else v_ref[0]
-        _compute_block(tables_ref, lens_ref, q_ref, k, v,
-                       m_s, l_s, acc_s, b, j, bs=bs, groups=groups,
-                       sm_scale=sm_scale)
+    k, v = joined(k_refs), joined(v_refs)
+    nt = (((1,), (1,)), ((), ()))                   # contract minor dims
+    s = jax.lax.dot_general(q_ref[0].astype(k.dtype), k, nt,
+                            preferred_element_type=jnp.float32) * sm_scale
+    head = jax.lax.broadcasted_iota(jnp.int32, (NH, 1), 0)
+    first = col_head_ref[...] * groups           # a kv head's first q head
+    live = ((tile * keys + col_key_ref[...] <= length)
+            & (first <= head) & (head < first + groups))
+    s = jnp.where(live, s, NEG_INF)                  # [NH, keys * NKV]
+    m_prev = m_s[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_s[...] = jnp.broadcast_to(
+        alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True), l_s.shape)
+    acc_s[...] = acc_s[...] * alpha + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
 
-    @pl.when(j == num_j - 1)
+    @pl.when((tile + 1) * keys > length)             # the row's last tile
     def _finish():
-        l = jnp.maximum(l_s[:, :1], 1e-9)   # all-masked (inactive) -> zeros
-        o_ref[0] = (acc_s[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l_s[:, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
@@ -138,7 +220,7 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
     """Fused paged decode attention (see module docstring).
 
     Shapes as in `paged_decode_reference`; block_tables entries may be
-    garbage past a sequence's live blocks (clamped + masked).
+    garbage past a sequence's live blocks (never read).
 
     `layer_idx`: when given, arena_k/v keep their FULL [L, nb, bs, NKV, D]
     shape and the (traced) scalar layer index rides the grid as a scalar-
@@ -148,66 +230,59 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
     arenas are served by the packed-q variant in ops/paged_merged.py."""
     B, NH, D = q.shape
     layered = layer_idx is not None
-    if layered:
-        _, nb, bs, NKV, _ = arena_k.shape
-    else:
-        nb, bs, NKV, _ = arena_k.shape
+    nb, bs, NKV, _ = arena_k.shape[-4:]
     MB = block_tables.shape[1]
-    groups = NH // NKV
-    sm_scale = 1.0 / math.sqrt(D)
+    itemsize = jnp.dtype(arena_k.dtype).itemsize
+    per_step = _blocks_per_step(bs, NKV, D, itemsize, MB)
+    N = B * -(-MB // per_step)
+    keys = per_step * bs
 
+    lens = jnp.minimum(lens.astype(jnp.int32), MB * bs - 1)
     tables = jnp.clip(block_tables, 0, nb - 1).astype(jnp.int32)
-    lens = lens.astype(jnp.int32)
-
+    count, rows, tiles, blocks = _walk(tables, lens, bs, per_step)
+    scalars = (rows, tiles, blocks, lens)
     if layered:
-        li = jnp.asarray(layer_idx, jnp.int32).reshape(1)
-        in_specs = [
-            pl.BlockSpec((1, NH, D), lambda b, j, li_, tb, ln: (b, 0, 0)),
-            pl.BlockSpec((1, 1, bs, NKV, D),
-                         lambda b, j, li_, tb, ln:
-                         (li_[0], tb[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, 1, bs, NKV, D),
-                         lambda b, j, li_, tb, ln:
-                         (li_[0], tb[b, j], 0, 0, 0)),
-        ]
-        out_specs = pl.BlockSpec((1, NH, D),
-                                 lambda b, j, li_, tb, ln: (b, 0, 0))
-        num_prefetch = 3
-        operands = (li, tables, lens, q, arena_k, arena_v)
-    else:
-        in_specs = [
-            pl.BlockSpec((1, NH, D), lambda b, j, tb, ln: (b, 0, 0)),
-            pl.BlockSpec((1, bs, NKV, D),
-                         lambda b, j, tb, ln: (tb[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, NKV, D),
-                         lambda b, j, tb, ln: (tb[b, j], 0, 0, 0)),
-        ]
-        out_specs = pl.BlockSpec((1, NH, D), lambda b, j, tb, ln: (b, 0, 0))
-        num_prefetch = 2
-        operands = (tables, lens, q, arena_k, arena_v)
+        scalars += (jnp.asarray(layer_idx, jnp.int32).reshape(1),)
 
+    rows_view = _rows_view_is_free(NKV, D, itemsize)
+    block = (bs * NKV, D) if rows_view else (bs, NKV, D)
+    if rows_view:
+        arena_k = arena_k.reshape(arena_k.shape[:-3] + block)
+        arena_v = arena_v.reshape(arena_v.shape[:-3] + block)
+
+    def kv_map(slot):
+        def index_map(i, rows, tiles, blocks, lens, *layer):
+            return (tuple(ref[0] for ref in layer)
+                    + (blocks[slot * N + i],) + (0,) * len(block))
+        return index_map
+
+    q_map = lambda i, rows, *_: (rows[i], 0, 0)              # noqa: E731
+    col_map = lambda i, *_: (0, 0)                           # noqa: E731
+    kv_spec = [pl.BlockSpec((1,) * (1 + layered) + block, kv_map(slot))
+               for slot in range(per_step)]
+    col = np.arange(keys * NKV, dtype=np.int32)[None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
-        grid=(B, MB),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        num_scalar_prefetch=len(scalars),
+        grid=(count,),
+        in_specs=[pl.BlockSpec((1, NH, D), q_map),
+                  pl.BlockSpec((1, keys * NKV), col_map),
+                  pl.BlockSpec((1, keys * NKV), col_map)] + kv_spec * 2,
+        out_specs=pl.BlockSpec((1, NH, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((NH, 128), jnp.float32),
             pltpu.VMEM((NH, 128), jnp.float32),
             pltpu.VMEM((NH, D), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, bs=bs, groups=groups,
-                               sm_scale=sm_scale, layered=layered)
-    if layered:
-        # kernel positional refs: (li, tables, lens, q, k, v, o, scratch);
-        # adapt to the shared (tables, lens, ...) signature
-        kernel_fn = lambda li_ref, *rest: kernel(*rest)
-    else:
-        kernel_fn = kernel
-    return pl.pallas_call(
-        kernel_fn,
+    kernel = functools.partial(
+        _kernel, bs=bs, per_step=per_step, kv_heads=NKV, groups=NH // NKV,
+        sm_scale=1.0 / math.sqrt(D), rows_view=rows_view)
+    out = pl.pallas_call(
+        kernel,
         name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, D), q.dtype),
-    )(*operands)
+    )(*scalars, q, col // NKV, col % NKV,
+      *([arena_k] * per_step), *([arena_v] * per_step))
+    # an inactive row is no tile of the walk: nothing wrote its output
+    return jnp.where((lens < 0)[:, None, None], 0, out).astype(q.dtype)

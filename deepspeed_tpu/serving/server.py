@@ -1313,7 +1313,9 @@ class ServeLoop:
             raise
         whole.set_metadata(
             decode_rows=getattr(out, "decode_rows", 0),
-            fed_on_device_rows=getattr(out, "fed_rows", 0))
+            fed_on_device_rows=getattr(out, "fed_rows", 0),
+            kv_live_blocks=getattr(out, "kv_live_blocks", 0),
+            kv_table_blocks=getattr(out, "kv_table_blocks", 0))
         self.telemetry.count("admitted", len(admitted))
         if self._tenancy is not None:
             for r in admitted:

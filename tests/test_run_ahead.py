@@ -589,3 +589,53 @@ def test_serve_step_spans_carry_the_rows_fed_on_the_device(tiny, tmp_path):
     assert json.dumps(spec)       # plain data
     assert os.path.isfile(os.path.join(
         harness.BENCH_DIR, "metrics", "decode_fed_on_device_share.closed.json"))
+
+
+def test_serve_step_spans_carry_the_live_share_of_the_block_table(
+        tiny, tmp_path):
+    """`kv_live_blocks` / `kv_table_blocks`: of the decode program's block
+    table (max_seqs x max_blocks_per_seq a step), the entries that hold
+    keys a row attends to (`pos // block_size + 1` a decode row)."""
+    from benchmark import harness
+    from benchmark.readers import span_attr_ratio
+    from test_tracing import _program_spans, _traced
+    eng = _engine(tiny)
+    bs = eng.config.block_size
+    width = eng.config.max_seqs * eng.config.max_blocks_per_seq
+    loop = ServeLoop(eng, ServingConfig(), clock=FakeClock())
+    lengths, new = (9, 21, 5), (6, 9, 12)
+    reqs = [loop.submit(p, max_new_tokens=n)
+            for p, n in zip(_prompts(3, lengths), new)]
+    trace_dir = tmp_path / ".cache" / "bench_trace"
+    with _traced(trace_dir):
+        loop.run_until_idle(max_steps=100)
+    assert all(r.state is RequestState.DONE for r in reqs)
+    steps = [e[3] for line in _program_spans(str(trace_dir))
+             for e in line if e[2] == "serve.step"]
+    assert steps and all({"kv_live_blocks", "kv_table_blocks"} <= set(s)
+                         for s in steps)
+    for s in steps:
+        rows, live = int(s["decode_rows"]), int(s["kv_live_blocks"])
+        # a row holds at least one block and at most its table's width
+        assert rows <= live <= int(s["kv_table_blocks"])
+        assert int(s["kv_table_blocks"]) == (width if rows else 0)
+    # a request of p prompt tokens decodes at positions p .. p + n - 2
+    want = sum((p + k) // bs + 1 for p, r in zip(lengths, reqs)
+               for k in range(len(r.output_tokens) - 1))
+    live = sum(int(s["kv_live_blocks"]) for s in steps)
+    table = sum(int(s["kv_table_blocks"]) for s in steps)
+    assert live == want
+    spec = harness.load_json(harness.BENCH_DIR, "metrics",
+                             "paged_live_block_share.closed.json")
+    entry = [m for m in harness.load_json(harness.ROOT, "BENCHMARK.json")
+             ["per_layer"] if m["name"] == "paged_live_block_share.closed"]
+    assert len(entry) == 1 and entry[0]["moves"] == "out_tok_s"
+    assert entry[0]["workloads"] == ["qwen2-7b.decode_closed"]
+    assert spec == {"reader": "span_attr_ratio", "params": {
+        "span": "serve.step", "num": "kv_live_blocks",
+        "den": "kv_table_blocks", "scale": 100.0}}
+    span_attr_ratio.attributes.cache_clear()
+    value = span_attr_ratio.read(
+        {"trace": True, "bench_dir": str(tmp_path / "benchmark")},
+        **spec["params"])
+    assert value == pytest.approx(100.0 * live / table)

@@ -75,21 +75,47 @@ def test_flash_attention_fwd_bwd(chip, B, S, NH, NKV, D):
     assert kernels(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
 
 
-# (B, NH, NKV, nb, bs, MB).  First the serving geometry (32/4 heads, D=64,
-# block 64); then the small budgets interpret mode cannot vouch for: MB=1
-# is the degenerate single-block walk, odd head counts, 1024-key GQA
-@pytest.mark.parametrize("B,NH,NKV,nb,bs,MB", [
-    (32, 32, 4, 256, 64, 32),
-    (3, 8, 2, 4, 8, 1),
-    (2, 6, 3, 8, 16, 2),
-    (8, 16, 4, 128, 64, 16),
+# (B, NH, NKV, D, nb, bs, MB, L; L: a layered arena).  First the serving
+# geometry (32/4 heads, D=64, block 64); then the small budgets interpret
+# mode cannot vouch for: MB=1 is the degenerate single-block walk, odd head
+# counts, 1024-key GQA; then the qwen2-7b cell's own decode (64 rows, 28/4
+# heads of 128, the 704-block arena of 16 layers), the same under a 32k
+# table (512 entries: 4096 tiles listed in SMEM), and a tensor-parallel
+# shard's one local kv head
+@pytest.mark.parametrize("B,NH,NKV,D,nb,bs,MB,L", [
+    (32, 32, 4, 64, 256, 64, 32, None),
+    (3, 8, 2, 64, 4, 8, 1, None),
+    (2, 6, 3, 64, 8, 16, 2, None),
+    (8, 16, 4, 64, 128, 64, 16, None),
+    pytest.param(64, 28, 4, 128, 704, 64, 32, 16, id="qwen2-7b-cell"),
+    pytest.param(64, 28, 4, 128, 704, 64, 512, 16, id="qwen2-7b-32k-table"),
+    pytest.param(16, 7, 1, 128, 128, 64, 32, 4, id="one-local-kv-head"),
 ])
-def test_paged_decode(chip, B, NH, NKV, nb, bs, MB):
-    from deepspeed_tpu.ops.paged_attention import paged_decode_attention
-    D = 64
-    arena = chip((nb, bs, NKV, D))
-    assert kernels(paged_decode_attention, chip((B, NH, D)), arena, arena,
-                   chip((B, MB), jnp.int32), chip((B,), jnp.int32)) == 1
+def test_paged_decode(chip, B, NH, NKV, D, nb, bs, MB, L):
+    """One Mosaic kernel; its tile inside the VMEM a kernel gets (a tile
+    over it is refused by the compile itself); and no copy of the arena:
+    the kernel takes a block's rows as `[bs * NKV, D]` where that is a
+    bitcast, and a reshape that was not would copy the arena whole."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    arena = chip((nb, bs, NKV, D) if L is None else (L, nb, bs, NKV, D))
+    args = (chip((B, NH, D)), arena, arena, chip((B, MB), jnp.int32),
+            chip((B,), jnp.int32))
+    if L is None:
+        attend = pa.paged_decode_attention
+    else:
+        args += (chip((), jnp.int32),)
+
+        def attend(q, ak, av, tables, lens, layer):
+            return pa.paged_decode_attention(q, ak, av, tables, lens,
+                                             layer_idx=layer)
+
+    assert kernels(attend, *args) == 1
+    tile = (4 * pa._blocks_per_step(bs, NKV, D, 2, MB)
+            * pa._block_vmem_bytes(bs, NKV, D, 2))
+    assert tile <= pa._TILE_VMEM_BYTES < 16 << 20
+    with jax.default_matmul_precision("default"):
+        mem = jax.jit(attend).lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20
 
 
 # (C, NH, NKV, nb, bs, MB).  The serving chunk, then the padded tiles: C=4
