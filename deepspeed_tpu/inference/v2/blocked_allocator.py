@@ -19,7 +19,53 @@ from __future__ import annotations
 
 from typing import Iterable, List
 
-__all__ = ["BlockedAllocator"]
+__all__ = ["BlockedAllocator", "KindCounts"]
+
+
+class KindCounts(tuple):
+    """Block counts of a cache that holds several KINDS of block side by
+    side (global layers' and window layers'), one number a kind.  They add
+    and subtract kind by kind (a plain int counts for every kind), so the
+    reservation ledger of `serving/server.py` computes with them as it
+    does with the one-kind cache's ints.  Kind by kind is a PARTIAL order
+    ((5, 4) and (9, 3) are each short of the other), so `<`, `>`, `max`
+    and sorting refuse: ask `short_of`."""
+
+    def _with(self, other, op):
+        if isinstance(other, tuple):
+            return KindCounts(op(a, b) for a, b in zip(self, other))
+        return KindCounts(op(a, other) for a in self)
+
+    def __add__(self, other):
+        return self._with(other, lambda a, b: a + b)
+
+    __radd__ = __add__
+
+    def __sub__(self, other):
+        return self._with(other, lambda a, b: a - b)
+
+    def _no_order(self, other):
+        raise TypeError("KindCounts are ordered kind by kind, a partial "
+                        "order: use need.short_of(have)")
+
+    __lt__ = __le__ = __gt__ = __ge__ = _no_order
+
+    def short_of(self, have) -> bool:
+        """`have` (counts, or one number for every kind) holds fewer
+        blocks than this of ANY kind."""
+        return any(self._with(have, lambda a, b: a > b))
+
+    def short_kind(self, have, names) -> str:
+        """The first kind of `names` of which `have` holds fewer than
+        this."""
+        return next(n for n, a, b in zip(names, self, have) if a > b)
+
+    def floor0(self) -> "KindCounts":
+        return KindCounts(max(a, 0) for a in self)
+
+    def __int__(self) -> int:
+        """As one number (a gauge, a timeline row): the scarcest kind's."""
+        return min(self)
 
 
 class BlockedAllocator:

@@ -101,6 +101,9 @@ class LogitsRows(Mapping):
         # that hold keys a decode row attends to: what the paged decode
         # kernel's time follows
         self.kv_live_blocks = self.kv_table_blocks = 0
+        # a two-kind cache's account of the decode rows, by counter name
+        # (`InferenceEngineV2._count_kinds`); one kind: nothing
+        self.kv_kinds: Dict[str, int] = {}
         # what `engine.collect` returns: the rows it left out because
         # their sequence had been flushed (or replaced under its uid)
         self.overrun = 0
@@ -279,6 +282,15 @@ class InferenceEngineV2:
                 "tp_collectives='fused') cannot serve the latent (MLA) "
                 "block: its cache has no head dimension to shard and its "
                 "kernel and expert share are not wrapped for a mesh")
+        # a static-kind stack (window + global layers): two kinds of cache
+        self._kinds = bool(getattr(self.cfg, "static_kinds", False))
+        if self._kinds and (self.tp > 1
+                            or self.config.tp_collectives != "xla"):
+            raise ValueError(
+                "tensor parallelism (tensor_parallel_size > 1, "
+                "tp_collectives='fused') cannot serve the static-kind "
+                "stack: its two-kind arena, its chunk attention kernel "
+                "and its experts are not wrapped for a mesh")
         if self.tp > 1:
             if self.cfg.num_heads % self.tp or self.cfg.kv_heads % self.tp:
                 raise ValueError(
@@ -308,18 +320,26 @@ class InferenceEngineV2:
             self._replicated = held[0] if held else None
             self._param_specs = None
 
+        self.arena = init_arena(self.cfg, self.config.num_blocks,
+                                self.config.block_size, self.topology,
+                                merged=self.config.arena_merged,
+                                max_seqs=self.config.max_seqs)
+        # a two-kind arena has divided `num_blocks`, its byte budget, by
+        # the model's kinds and `max_seqs` (hybrid_ops.kind_pools): the
+        # ledger counts the pools it made
+        nb, window = self.config.num_blocks, None
+        if self._kinds:
+            nb = self.arena["gk"].shape[1]
+            window = (self.cfg.window, self.arena["wk"].shape[1])
         self.state = DSStateManager(
-            self.config.num_blocks, self.config.block_size,
-            self.config.max_blocks_per_seq, self.config.max_seqs)
+            nb, self.config.block_size, self.config.max_blocks_per_seq,
+            self.config.max_seqs, window=window)
         # per-sequence token ceiling: arena lease AND model context — learned
         # position embeddings clip silently past max_seq_len, so enforce it
         # here with a loud error instead
         self.max_tokens_per_seq = min(
             self.config.max_blocks_per_seq * self.config.block_size,
             self.cfg.max_seq_len)
-        self.arena = init_arena(self.cfg, self.config.num_blocks,
-                                self.config.block_size, self.topology,
-                                merged=self.config.arena_merged)
         # fused kernels under tp run per-shard via shard_map; the mesh is a
         # static arg of the serving programs (hashable)
         self._kernel_mesh = (self.topology.mesh if self.tp > 1 else None)
@@ -382,6 +402,7 @@ class InferenceEngineV2:
                                   and self.tp == 1
                                   and prefill_full_supported(self.cfg))
         self._last_logits = LogitsRows(self._fetch_logits)
+        self._window_released_seen = 0
         self._rng = jax.random.PRNGKey(0)
         # host-sync ledger: every EXPLICIT device->host fetch the engine
         # performs bumps d2h_fetches (the implicit ones are what the
@@ -460,19 +481,29 @@ class InferenceEngineV2:
         return self.prefix_cache
 
     def _refuse_latent(self, what: str) -> None:
-        """Mechanisms written for a per-head K/V arena refuse the latent
-        (MLA) one where they are switched on."""
+        """Mechanisms written for ONE kind of per-head K/V block pair
+        refuse the latent (MLA) arena and the two-kind cache where they
+        are switched on."""
         if self._latent:
             raise NotImplementedError(
                 f"{what}: not wired for the latent (MLA) arena, which "
                 f"holds one [latent | rope key] row per token and "
                 f"attention and no K/V pages")
+        if self._kinds:
+            raise NotImplementedError(
+                f"{what}: not wired for the two-kind cache, where a block "
+                f"id names a block of ONE kind of layer (global or "
+                f"window), a sequence's window-kind blocks are handed "
+                f"back as it advances (a cached prefix could not be "
+                f"re-attached under them), and the static-kind stack's "
+                f"programs take no LoRA, draft-span or expert-page "
+                f"operands")
 
     # -- multi-LoRA adapter serving (serving/tenancy) ---------------------
     # the serving layer probes this before enabling an adapter pool
     @property
     def supports_lora(self) -> bool:
-        return not self._latent
+        return not (self._latent or self._kinds)
 
     def attach_lora(self, lora) -> None:
         """Attach (None = detach) the stacked multi-LoRA factors the
@@ -822,6 +853,10 @@ class InferenceEngineV2:
         up when `ahead` is collected.  Every other decode row takes the
         pending token the host staged, as ever."""
         pending = LogitsRows(self._fetch_logits, self.collect)
+        if self._kinds:     # every step of a two-kind cache gives its account
+            pending.kv_kinds = dict.fromkeys(
+                ("kv_blocks_held", "kv_blocks_full_cache",
+                 "kv_window_released"), 0)
         C = self.config.prefill_chunk_size
         # a zero/negative budget must still make 1 token of progress per
         # step, or in_prefill sequences (and generate()) would spin forever
@@ -914,8 +949,7 @@ class InferenceEngineV2:
                         NS *= 2
                     ftokens = np.zeros((NS, S), np.int32)
                     flens = np.zeros(NS, np.int32)
-                    ftables = np.zeros((NS, self.config.max_blocks_per_seq),
-                                       np.int32)
+                    ftables = np.zeros((NS,) + self.state.table_shape, np.int32)
                     factive = np.zeros(NS, bool)
                     for i, d in enumerate(fresh):
                         n = len(d.prompt)
@@ -962,8 +996,7 @@ class InferenceEngineV2:
             pos0s = np.zeros(cap_alloc, np.int32)
             nvalids = np.zeros(cap_alloc, np.int32)
             tlens = np.zeros(cap_alloc, np.int32)
-            tables = np.zeros((cap_alloc, self.config.max_blocks_per_seq),
-                              np.int32)
+            tables = np.zeros((cap_alloc,) + self.state.table_shape, np.int32)
             active = np.zeros(cap_alloc, bool)
             while budget > 0 and len(planned) < cap:
                 d = next((s for s in self.state.seqs.values()
@@ -972,8 +1005,14 @@ class InferenceEngineV2:
                 if d is None:
                     break
                 start = pseen[d.uid]
-                n = min(C, len(d.prompt) - start, budget)
-                self.state.ensure_capacity(d, start + n)
+                n = self.state.chunk_room(
+                    d, start, min(C, len(d.prompt) - start, budget))
+                if n == 0:     # a two-kind cache short of the window kind
+                    break
+                # (the row's first query of this STEP: an earlier chunk of
+                # it in this program still reads what lies behind `start`)
+                self.state.ensure_capacity(d, start + n,
+                                           first_query=d.seen_tokens)
                 i = len(planned)
                 tokens[i, :n] = d.prompt[start:start + n]
                 pos0s[i] = start
@@ -1002,6 +1041,9 @@ class InferenceEngineV2:
                     self._host_in(tlens[:NC]), **lkw)
             for d, start, n in planned:
                 d.seen_tokens = start + n
+                if self._kinds:
+                    # the blocks the chunk read and no later query will
+                    self.state.release_behind(d, d.seen_tokens)
             rows = [(d, i) for i, (d, _, _) in enumerate(planned)
                     if not d.in_prefill]
             if rows:      # chunks that end no prompt leave nothing to fetch
@@ -1026,8 +1068,7 @@ class InferenceEngineV2:
                 tokens = np.zeros(B, np.int32)
                 source = np.full(B, -1, np.int32)
                 lens = np.zeros(B, np.int32)
-                tables = np.zeros((B, self.config.max_blocks_per_seq),
-                                  np.int32)
+                tables = np.zeros((B,) + self.state.table_shape, np.int32)
                 active = np.zeros(B, bool)
                 for i, d in enumerate(batch):
                     if id(d) in fed:
@@ -1056,6 +1097,8 @@ class InferenceEngineV2:
             pending.kv_live_blocks = sum(
                 d.seen_tokens // self.config.block_size + 1 for d in batch)
             pending.kv_table_blocks = tables.size
+            if self._kinds:
+                self._count_kinds(pending, batch)
             for d in batch:
                 d.seen_tokens += 1
             pending.decode = _Program("decode_step", logits, toks,
@@ -1063,6 +1106,26 @@ class InferenceEngineV2:
             pending.decode_rows = len(batch)
             pending.fed_rows = sum(id(d) in fed for d in batch)
         return pending
+
+    def _count_kinds(self, pending: LogitsRows, batch) -> None:
+        """A decode step's account of the two-kind cache, in block x layer
+        units: what the step's rows hold of both kinds, what one kind over
+        all layers would hold for them, the window-kind blocks handed back
+        since the last account; the live entries of BOTH kinds' tables."""
+        Lg, Lw = self.arena["gk"].shape[0], self.arena["wk"].shape[0]
+        bs, W = self.config.block_size, self.cfg.window
+        pending.kv_live_blocks += sum(
+            d.seen_tokens // bs - max(0, d.seen_tokens - W + 1) // bs + 1
+            for d in batch)
+        pending.kv_kinds.update(
+            kv_blocks_held=sum(
+                Lg * len(d.blocks) + Lw * len(d.window_blocks)
+                for d in batch),
+            kv_blocks_full_cache=sum(
+                (Lg + Lw) * len(d.blocks) for d in batch),
+            kv_window_released=(
+                self.state.window_released - self._window_released_seen))
+        self._window_released_seen = self.state.window_released
 
     # -- burst decode: on-device sampling, one host dispatch per K tokens
     # the serving layer probes this before merging heterogeneous sampling
@@ -1072,7 +1135,7 @@ class InferenceEngineV2:
     # (decode_burst_step drafts= runs the compiled verify program)
     @property
     def supports_draft_verify(self) -> bool:
-        return not self._latent
+        return not (self._latent or self._kinds)
     # per-request counter-based sampling streams (serving/streaming.
     # seeded_sample — the streaming layer's replayable stochastic
     # decode): the compiled burst and multi-step programs run the SAME
@@ -1109,9 +1172,11 @@ class InferenceEngineV2:
     # pre-sharded per rank — a host-side slot splice would corrupt them)
     @property
     def supports_moe(self) -> bool:
-        # (a latent model holds a SHARE of its experts: nothing to page)
+        # (a latent model holds a SHARE of its experts: nothing to page;
+        # the static-kind stack's experts lie outside `params["layers"]`,
+        # where the slot stacks would ride)
         return (self.cfg.moe_experts > 1 and self._tpp is None
-                and not self._latent)
+                and not self._latent and not self._kinds)
 
     # the latent block's router counters (latent_ops.COUNT_NAMES), a
     # rider of its arena that every program accumulates
@@ -1296,7 +1361,7 @@ class InferenceEngineV2:
             tokens = np.zeros(B, np.int32)
             lens = np.zeros(B, np.int32)
             max_lens = np.ones(B, np.int32)
-            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            tables = np.zeros((B,) + self.state.table_shape, np.int32)
             active = np.zeros(B, bool)
             for i, d in enumerate(batch):
                 pending = d.seen_tokens - len(d.prompt)
@@ -1317,7 +1382,8 @@ class InferenceEngineV2:
                     capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
                 capped = max(capped, d.seen_tokens)
                 max_lens[i] = capped
-                self.state.ensure_capacity(d, capped)
+                self.state.ensure_capacity(d, capped,
+                                           first_query=d.seen_tokens)
                 tables[i] = self.state.block_table(d)
                 active[i] = True
             plan.set_metadata(rows=len(batch))
@@ -1483,7 +1549,7 @@ class InferenceEngineV2:
             eos_vec = np.full(B, -1, np.int32)
             temp_vec = np.zeros(B, np.float32)
             topk_vec = np.zeros(B, np.int32)
-            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            tables = np.zeros((B,) + self.state.table_shape, np.int32)
             active = np.zeros(B, bool)
             for i, d in enumerate(batch):
                 pending = d.seen_tokens - len(d.prompt)
@@ -1503,7 +1569,8 @@ class InferenceEngineV2:
                 capped = max(capped, d.seen_tokens)
                 max_lens[i] = capped
                 budget[i] = capped - d.seen_tokens
-                self.state.ensure_capacity(d, capped)
+                self.state.ensure_capacity(d, capped,
+                                           first_query=d.seen_tokens)
                 tables[i] = self.state.block_table(d)
                 active[i] = budget[i] > 0
                 eos_vec[i] = int(eos_ids.get(d.uid, -1))
@@ -1607,7 +1674,7 @@ class InferenceEngineV2:
             span_sts = np.zeros((B, S), np.int32)
             hfv = np.zeros(B, bool)
             eosv = np.full(B, -1, np.int32)
-            tables = np.zeros((B, self.config.max_blocks_per_seq), np.int32)
+            tables = np.zeros((B,) + self.state.table_shape, np.int32)
             active = np.zeros(B, bool)
             for i, d in enumerate(batch):
                 pending = d.seen_tokens - len(d.prompt)
@@ -1645,7 +1712,8 @@ class InferenceEngineV2:
                     capped = min(capped, int(max_tokens[d.uid]))  # dstpu: noqa[DST001] max_tokens is a host dict of python ints per the method contract
                 capped = max(capped, d.seen_tokens)
                 max_lens[i] = capped
-                self.state.ensure_capacity(d, capped)
+                self.state.ensure_capacity(d, capped,
+                                           first_query=d.seen_tokens)
                 tables[i] = self.state.block_table(d)
                 active[i] = True
             plan.set_metadata(rows=len(batch))
@@ -1764,8 +1832,23 @@ class InferenceEngineV2:
         return self._last_logits.get(uid)
 
     @property
-    def free_blocks(self) -> int:
-        return self.state.allocator.free_blocks
+    def free_blocks(self):
+        """Free blocks: an int, or one count a kind of a two-kind cache
+        (`blocked_allocator.KindCounts`, in `kind_names`' order)."""
+        return self.state.free_blocks
+
+    # what the serving layer books a request's blocks with: ints, or one
+    # count a kind (`DSStateManager.blocks_needed` / `blocks_leased`)
+    @property
+    def kind_names(self):
+        from .ragged_manager import KIND_NAMES
+        return KIND_NAMES if self._kinds else None
+
+    def blocks_needed(self, tokens: int):
+        return self.state.blocks_needed(tokens)
+
+    def blocks_leased(self, d):
+        return self.state.blocks_leased(d)
 
     @property
     def free_slots(self) -> int:

@@ -94,7 +94,8 @@ def init_latent_arena(cfg: TransformerConfig, num_blocks: int,
 def refuse_lora(lora) -> None:
     if lora is not None:
         raise NotImplementedError(
-            "LoRA adapters are not wired into the latent (MLA) block")
+            "LoRA adapters are not wired into the latent (MLA) block or "
+            "the static-kind stack")
 
 
 def _rms(x, scale, eps: float, mult: float = 1.0):
@@ -185,15 +186,20 @@ def local_rows_cap(assignments: int, local: int, outputs: int) -> int:
     return min(assignments, max(16, -(-int(even) // 16) * 16))
 
 
-def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid):
+def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
+         router_in=None):
     """h [T, H] -> (MoE(h) [T, H] over the experts held here and the
     identity experts, counts [len(COUNT_NAMES)] int32).  `experts`: the
-    whole `[L * local, ...]` stacks; `li`: the layer at hand."""
+    whole `[L * local, ...]` stacks; `li`: the layer at hand; `router_in`
+    [T, H]: what the router scores where that is not `h` (a router on the
+    layer's input).  The experts' gate is ReLU for `reglu`, else SiLU."""
     T, H = h.shape
     dt, k = h.dtype, cfg.moe_top_k
     E, first, El = cfg.moe_experts, cfg.moe_expert_first, cfg.local_experts
+    gate_act = jax.nn.relu if cfg.activation == "reglu" else jax.nn.silu
     with jax.named_scope("router"):
-        logits = h.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
+        logits = (h if router_in is None else router_in).astype(
+            jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
         score = jax.nn.softmax(logits, axis=-1)               # [T, E + Z]
         choose = score
         if cfg.moe_router_bias:        # the bias picks, it does not weigh
@@ -204,10 +210,13 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid):
             weight = weight / jnp.maximum(
                 jnp.sum(weight, axis=1, keepdims=True), 1e-9)
         weight = weight * cfg.moe_routed_scaling
-    with jax.named_scope("zero_experts"):
-        is_zero = topi >= E
-        zero_part = jnp.sum(jnp.where(is_zero, weight, 0.0), axis=1,
-                            keepdims=True) * h.astype(jnp.float32)
+    if cfg.moe_zero_experts:
+        with jax.named_scope("zero_experts"):
+            is_zero = topi >= E
+            zero_part = jnp.sum(jnp.where(is_zero, weight, 0.0), axis=1,
+                                keepdims=True) * h.astype(jnp.float32)
+    else:
+        is_zero, zero_part = jnp.zeros_like(topi, bool), 0.0
     with jax.named_scope("experts"):
         ids, wf = topi.reshape(-1), weight.reshape(-1)        # [T * k]
         picked = jnp.repeat(tok_valid, k)
@@ -235,7 +244,7 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid):
                                    preferred_element_type=jnp.float32)
             u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
                                    preferred_element_type=jnp.float32)
-            act = (jax.nn.silu(g) * u).astype(dt)
+            act = (gate_act(g) * u).astype(dt)
             down = jax.lax.ragged_dot(act, experts["w_down"], groups,
                                       preferred_element_type=jnp.float32)
             # rows past the last group belong to no expert held here
@@ -256,14 +265,15 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid):
 # ----------------------------------------------------------------------
 # the double layer, once
 # ----------------------------------------------------------------------
-def _rows(fn, n, ins, extra):
+def _rows(fn, n, ins, extra, row_tile: int = 0):
     """Token-wise work over the first `n` rows of `ins` ([T, ...] arrays,
-    real rows in front), `ROW_TILE` rows at a time for as many passes as
-    `n` needs; rows no pass reached come out zero.  `fn(*tile_ins) ->
-    (row outputs, a summand for `extra`)`.  A program of at most
-    `ROW_TILE` rows (or not whole tiles) takes them all at once."""
+    real rows in front), `row_tile` (`ROW_TILE`) rows at a time for as many
+    passes as `n` needs; rows no pass reached come out zero.  `fn(*tile_ins)
+    -> (row outputs, a summand for `extra`)`.  A program of at most
+    a tile's rows (or not whole tiles) takes them all at once."""
     T = ins[0].shape[0]
-    tile = ROW_TILE if T > ROW_TILE and T % ROW_TILE == 0 else T
+    row_tile = row_tile or ROW_TILE
+    tile = row_tile if T > row_tile and T % row_tile == 0 else T
     if tile == T:
         outs, e = fn(*ins)
         return outs, extra + e

@@ -37,6 +37,7 @@ ARCH_REGISTRY = {
     "bloom": "bloom",
     "gptneox": "gptneox",
     "longcat_flash": "longcat_flash",
+    "smallthinker": "smallthinker",
 }
 
 
@@ -104,6 +105,12 @@ def check_serving_moe(model_config, serving_config) -> None:
             "that holds them all; the latent-attention MoE block holds a "
             "fixed share of its experts (moe_expert_first/count) and "
             "routes the rest to other chips — drop serving.moe")
+    if getattr(model_config, "static_kinds", False):
+        raise ValueError(
+            "serving.moe (expert paging) swaps experts in the slot stacks "
+            "of `params['layers']`; the static-kind stack keeps its "
+            "experts apart, outside the layer scan, and holds them all — "
+            "drop serving.moe")
     if E <= 1:
         raise ValueError(
             f"serving.moe needs an MoE model layout (moe_experts > 1); "

@@ -46,7 +46,8 @@ __all__ = ["init_arena", "prefill_chunks", "prefill_full",
 
 
 def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
-               topology=None, merged="auto", moe_census: bool = False):
+               topology=None, merged="auto", moe_census: bool = False,
+               max_seqs: int = 0):
     """KV arena pytree (reference: ragged/kv_cache.py blocked arena).
 
     Under tensor parallelism the arena is sharded over tp on the kv-head
@@ -65,7 +66,19 @@ def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
     directly.  The serving programs branch on the arena rank.
 
     A latent-attention model (`cfg.latent`) caches one row per token and
-    attention and no V: `latent_ops.init_latent_arena` (key "c")."""
+    attention and no V: `latent_ops.init_latent_arena` (key "c").  A
+    static-kind stack (`cfg.static_kinds`) holds its global and its window
+    layers apart, each kind with its own blocks:
+    `hybrid_ops.init_kinds_arena` (keys "gk"/"gv", "wk"/"wv"), which takes
+    `num_blocks` as the byte budget and sizes the window kind by
+    `max_seqs`."""
+    if cfg.static_kinds:
+        if (topology is not None and topology.tp_size > 1) or moe_census:
+            raise ValueError(
+                "the two-kind arena is not sharded over tp and has no "
+                "expert-paging census rider")
+        from .hybrid_ops import init_kinds_arena
+        return init_kinds_arena(cfg, num_blocks, block_size, max_seqs)
     if cfg.latent:
         if (topology is not None and topology.tp_size > 1) or moe_census:
             raise ValueError(
@@ -220,16 +233,17 @@ def _use_paged_kernel(cfg: TransformerConfig, D: int, bs: int,
     tensor-parallel shard, GQA and MHA at D 64 and 128 all compile under
     Mosaic (`tests/test_tpu_compile.py` holds the real TPU compiler to
     it, the cell's own shape and a 32k table among them) and match the
-    dense reference (`tests/test_paged_attention.py`)."""
-    supported = (_kernel_capable(cfg, D, bs, n_tp)
-                 and cfg.sliding_window is None)
+    dense reference (`tests/test_paged_attention.py`).  A uniform
+    `sliding_window` is the kernel's static `window` (its walk starts at
+    the window's first block); a window that rides the layer scan as a
+    traced scalar (`sliding_window_layers` without static kinds) is not."""
     return _gate_fused(
-        cfg, supported,
+        cfg, _kernel_capable(cfg, D, bs, n_tp),
         reason=f"attn_impl='pallas' requested but the paged decode kernel "
                f"cannot run here (needs TPU, a mesh when tp > 1, "
                f"head_dim % 64 == 0 [got {D}], block_size % 8 == 0 "
-               f"[got {bs}], no alibi, no sliding_window, no per-layer "
-               f"sliding_window_layers)")
+               f"[got {bs}], no alibi, no traced per-layer window "
+               f"(sliding_window_layers without rope_layers))")
 
 
 def _kernel_capable(cfg: TransformerConfig, D: int, bs: int,
@@ -244,7 +258,8 @@ def _kernel_capable(cfg: TransformerConfig, D: int, bs: int,
     from ...utils.device import on_tpu
     return (on_tpu() and n_tp == 1 and D % 64 == 0 and bs % 8 == 0
             and cfg.pos_emb != "alibi"
-            and cfg.sliding_window_layers is None)
+            # a window the kernels can take is a Python value
+            and (cfg.sliding_window_layers is None or cfg.static_kinds))
 
 
 def _shard_mapped_tp(fn, mesh, n_in_specs_headed, layered=False):
@@ -311,8 +326,8 @@ def _use_paged_prefill(cfg: TransformerConfig, D: int, bs: int, C: int,
     is unreachable under auto and the guard is gone).  attn_impl="pallas"
     forces it wherever *capable* (raising otherwise — no silent
     fallback), "jnp" is the explicit dense escape hatch.
-    Unlike the decode kernel, sliding windows are supported (masked in-
-    kernel); alibi is not."""
+    A uniform sliding window is masked in the kernel, which skips the
+    key blocks wholly outside it; alibi is not supported."""
     from ...ops.paged_prefill import prefill_plan
     # under a tp mesh the kernel runs per-shard, so the VMEM-fit check must
     # size the LOCAL head count
@@ -324,8 +339,8 @@ def _use_paged_prefill(cfg: TransformerConfig, D: int, bs: int, C: int,
         reason=f"attn_impl='pallas' requested but the blocked-flash "
                f"prefill kernel cannot run here (needs TPU, a mesh when "
                f"tp > 1, head_dim % 64 == 0 [got {D}], block_size "
-               f"% 8 == 0 [got {bs}], no alibi, no per-layer "
-               f"sliding_window_layers, and a VMEM-fitting query tile "
+               f"% 8 == 0 [got {bs}], no alibi, no traced per-layer "
+               f"window, and a VMEM-fitting query tile "
                f"[got chunk {C}, heads {nh}])")
 
 
@@ -388,11 +403,12 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     shapes than NC separate calls, and NC fewer host dispatches).
     Returns (logits [NC, V] — last valid token each, their argmax
     [NC] int32 (`greedy_tokens`), arena)."""
-    if cfg.latent:
-        from . import latent_ops
+    if cfg.latent or cfg.static_kinds:
+        from . import hybrid_ops, latent_ops
         latent_ops.refuse_lora(lora)
-        return latent_ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
-                                         n_valids, block_tables, active)
+        ops = latent_ops if cfg.latent else hybrid_ops
+        return ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
+                                  n_valids, block_tables, active)
     NC, C = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -591,9 +607,14 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
     the jnp reference here while the chunked path raises, violating the
     no-silent-fallback contract (_gate_fused); such configs stay chunked
     (and get that loud error).  The latent block pads its own head
-    widths for the flash path (latent_ops._attend_fresh)."""
+    widths for the flash path (latent_ops._attend_fresh).  A static-kind
+    stack has one prefill program: a fresh prompt is a chunk at position 0
+    of `ops/chunk_attention.py`, which has the window the flash kernel
+    lacks and holds no whole sequence of keys in VMEM."""
     if cfg.latent:
         return True
+    if cfg.static_kinds:
+        return False
     D = cfg.head_dim
     flash_ok = D % 128 == 0 or D == 64
     return (cfg.pos_emb in ("rope", "learned") and cfg.sliding_window is None
@@ -636,6 +657,10 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
         from . import latent_ops
         return latent_ops.prefill_full(cfg, params, arena, tokens, lens,
                                        block_tables, active)
+    if cfg.static_kinds:
+        raise NotImplementedError(
+            "a static-kind stack prefills through prefill_chunks alone "
+            "(prefill_full_supported is False)")
     from ...ops.attention import causal_attention
     NS, S = tokens.shape
     bs = arena["k"].shape[2]
@@ -1309,10 +1334,11 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
     query may see, so position i attends its own draft prefix — the
     conditioning speculative verification needs.  Returns
     (logits [B, S, V] at every span position, arena)."""
-    if cfg.latent:
+    if cfg.latent or cfg.static_kinds:
         raise NotImplementedError(
             "speculative verify spans are not wired into the latent (MLA) "
-            "block (the engine reports supports_draft_verify = False)")
+            "block or the static-kind stack (the engine reports "
+            "supports_draft_verify = False)")
     B, S = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1495,11 +1521,12 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
 def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                  block_tables, active, n_tp: int = 1, mesh=None,
                  adapter_ids=None, lora=None):
-    if cfg.latent:
-        from . import latent_ops
+    if cfg.latent or cfg.static_kinds:
+        from . import hybrid_ops, latent_ops
         latent_ops.refuse_lora(lora)
-        return latent_ops.decode_core(cfg, params, arena, tokens, seq_lens,
-                                      block_tables, active)
+        ops = latent_ops if cfg.latent else hybrid_ops
+        return ops.decode_core(cfg, params, arena, tokens, seq_lens,
+                               block_tables, active)
     B = tokens.shape[0]
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1566,14 +1593,16 @@ def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                 # the r3 gather fallback is gone where the layout qualifies
                 from ...ops.paged_merged import merged_kernels_supported
                 loc = n_tp if mesh is not None else 1
-                m_ok = merged_kernels_supported(NH // loc, NKV // loc, D)
+                # (the packed-q kernel has no window)
+                m_ok = (merged_kernels_supported(NH // loc, NKV // loc, D)
+                        and cfg.sliding_window is None)
                 if use_kernel and not m_ok and cfg.attn_impl == "pallas":
                     # keep _gate_fused's no-silent-fallback contract
                     raise ValueError(
                         f"attn_impl='pallas' requested but the merged-arena "
                         f"decode kernel cannot serve this layout (local heads "
                         f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
-                        f"128-aligned packed stripes)")
+                        f"128-aligned packed stripes and no sliding_window)")
                 use_kernel = use_kernel and m_ok
             if use_kernel:
                 # fused Pallas paged attention: the block table is a
@@ -1585,8 +1614,11 @@ def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                     from ...ops.paged_merged import (
                         merged_decode_attention as _decode_fn)
                 else:
-                    from ...ops.paged_attention import (
-                        paged_decode_attention as _decode_fn)
+                    from ...ops.paged_attention import paged_decode_attention
+                    _decode_fn = paged_decode_attention
+                    if cfg.sliding_window is not None:
+                        _decode_fn = partial(paged_decode_attention,
+                                             window=cfg.sliding_window)
                 lens = jnp.where(active, positions, -1)
                 if mesh is not None and n_tp > 1:
                     kfn = _shard_mapped_tp(

@@ -318,8 +318,8 @@ class TPServingPrograms:
                 from ...ops.paged_attention import paged_decode_attention
                 lens = jnp.where(active, positions, -1)
                 attn = paged_decode_attention(
-                    q, ak_all, av_all, block_tables, lens,
-                    layer_idx=li).reshape(B, NHl * D)
+                    q, ak_all, av_all, block_tables, lens, layer_idx=li,
+                    window=cfg.sliding_window).reshape(B, NHl * D)
             else:
                 attn = self._gather_attn(
                     q[:, None], ak_all, av_all, block_tables,

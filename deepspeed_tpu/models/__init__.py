@@ -18,6 +18,7 @@ from .transformer import (
     bloom_config,
     gptneox_config,
     longcat_flash_config,
+    smallthinker_config,
 )
 
 from .hf_loader import load_hf_model, hf_to_config, convert_state_dict
@@ -36,6 +37,7 @@ MODEL_FAMILIES = {
     "bloom": bloom_config,
     "gptneox": gptneox_config,
     "longcat_flash": longcat_flash_config,
+    "smallthinker": smallthinker_config,
 }
 
 
@@ -56,4 +58,5 @@ __all__ = [
     "qwen2_config", "qwen2_moe_config", "phi_config", "phi3_config",
     "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
+    "smallthinker_config",
 ]

@@ -43,6 +43,7 @@ PyTree = Any
 __all__ = [
     "TransformerConfig", "Transformer", "gpt2_config", "llama_config",
     "mistral_config", "mixtral_config", "qwen2_config", "qwen2_moe_config",
+    "smallthinker_config",
     "phi_config", "phi3_config", "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
 ]
@@ -84,11 +85,21 @@ class TransformerConfig:
     final_norm: bool = True
     parallel_residual: bool = False             # attn+mlp from same x (falcon/neox/phi)
     sliding_window: Optional[int] = None        # local attention (mistral)
-    # qwen2-style heterogeneous stacks: per-layer window sizes (0 = full
-    # attention), length num_layers.  The window rides the layer scan as a
-    # traced scalar, so attention uses the masked jnp path (the fused
-    # kernels take static windows only)
+    # heterogeneous stacks: per-layer window sizes (0 = full attention),
+    # length num_layers.  Alone (qwen2's use_sliding_window) the window
+    # rides the layer scan as a traced scalar and attention takes the masked
+    # jnp path.  With `rope_layers` the layer kinds are STATIC: serving
+    # unrolls one period of the pattern inside the layer scan, every kernel
+    # sees its window as a Python value, and the cache holds the two kinds
+    # apart (inference/v2/hybrid_ops.py)
     sliding_window_layers: Optional[Tuple[int, ...]] = None
+    # per-layer rotary flags (1 = rotate q and k, 0 = no position encoding
+    # at all), length num_layers, pos_emb "rope": stacks that rotate their
+    # window layers and leave their global layers position-free (NoPE)
+    rope_layers: Optional[Tuple[int, ...]] = None
+    # attention head width where it is not hidden_size / num_heads (0: it
+    # is); read through `head_dim`.  The static-kind stack only
+    attn_head_dim: int = 0
     norm_eps: float = 1e-5
     dropout: float = 0.0
     dtype: Any = jnp.bfloat16                   # compute dtype for activations
@@ -285,6 +296,31 @@ class TransformerConfig:
                 "moe_zero_experts, moe_router_bias and the expert share "
                 "(moe_expert_first/count) exist only in the "
                 "latent-attention double block (kv_lora_rank > 0)")
+        if self.rope_layers is not None:
+            wins = set(self.sliding_window_layers or ())
+            if not (len(self.rope_layers) == self.num_layers
+                    and self.sliding_window_layers is not None
+                    and 0 in wins and len(wins) == 2
+                    and self.pos_emb == "rope" and self.norm == "rmsnorm"
+                    and self.activation in ("swiglu", "reglu")
+                    and self.moe_experts > 1 and self.moe_expert_ffn
+                    and not self.latent and not self.qkv_bias
+                    and self.tie_embeddings is False
+                    and self.moe_dense_layers is None
+                    and not self.moe_shared_expert_ffn
+                    and self.rope_scaling is None and self.rope_pct == 1.0):
+                raise ValueError(
+                    "rope_layers (static layer kinds) is served as the "
+                    "window + global MoE stack only: one flag a layer, "
+                    "sliding_window_layers with one window size and at "
+                    "least one full-attention layer, rope, rmsnorm, a gated "
+                    "activation, moe_experts > 1 with moe_expert_ffn, no "
+                    "qkv bias, untied head, no dense or shared-expert "
+                    "layers, no rope scaling")
+        elif self.attn_head_dim or self.activation == "reglu":
+            raise ValueError(
+                "attn_head_dim and the 'reglu' experts exist only in the "
+                "static-kind stack (rope_layers)")
         if self.embed_proj_dim and self.tiled_loss_shards > 1:
             raise ValueError(
                 "tiled_loss_shards with embed_proj_dim is not supported: "
@@ -297,7 +333,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
     @property
     def latent(self) -> bool:
@@ -311,6 +347,30 @@ class TransformerConfig:
     @property
     def local_experts(self) -> int:
         return self.moe_expert_count or self.moe_experts
+
+    @property
+    def static_kinds(self) -> bool:
+        """Layers of statically known kinds (window or global, rotary or
+        not), each with a router that scores the layer's INPUT (before the
+        input norm and attention): served over a two-kind paged cache."""
+        return self.rope_layers is not None
+
+    @property
+    def layer_period(self) -> Tuple[Tuple[int, int], ...]:
+        """(window, rope flag) of the layers of ONE period of a static-kind
+        stack: the shortest prefix of the pattern that, repeated, gives the
+        whole stack."""
+        kinds = tuple(zip(self.sliding_window_layers, self.rope_layers))
+        for p in range(1, self.num_layers + 1):
+            if self.num_layers % p == 0 \
+                    and kinds == kinds[:p] * (self.num_layers // p):
+                return kinds[:p]
+        return kinds
+
+    @property
+    def window(self) -> int:
+        """The window layers' size of a static-kind stack (0: none)."""
+        return max(self.sliding_window_layers or (0,))
 
     @property
     def ffn_dim(self) -> int:
@@ -557,9 +617,70 @@ def longcat_flash_config(size: str = "chat", **kw) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def smallthinker_config(size: str = "21b-a3b", **kw) -> TransformerConfig:
+    """SmallThinker (PowerInfer/SmallThinker-21BA3B-Instruct config.json):
+    window layers with rope and global layers with no position encoding
+    (one global in every four), a router that reads the layer's input,
+    ReLU-gated experts and no dense FFN.  Serving only
+    (inference/v2/hybrid_ops.py).  `num_layers` cuts whole periods: the
+    two layouts follow it."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=8, num_heads=4,
+                     num_kv_heads=2, max_seq_len=512, vocab_size=512,
+                     attn_head_dim=32, moe_experts=8, moe_top_k=2,
+                     moe_expert_ffn=32, sliding_window=16),
+        "21b-a3b": dict(hidden_size=2560, num_layers=52, num_heads=28,
+                        num_kv_heads=4, attn_head_dim=128,
+                        max_seq_len=16384, vocab_size=151936, moe_experts=64, moe_top_k=6,
+                        moe_expert_ffn=768, sliding_window=4096),
+    }
+    base = dict(pos_emb="rope", norm="rmsnorm", activation="reglu",
+                tie_embeddings=False, rope_theta=1.5e6, norm_eps=1e-6,
+                moe_norm_topk_prob=True)
+    base.update(presets[size])
+    base.update(kw)
+    # the published layouts: layer 4n global without rope, the others
+    # window with rope (`sliding_window` names the window's size here)
+    L, W = base["num_layers"], base.pop("sliding_window")
+    # (no dense FFN anywhere: the experts' width is the model's FFN width)
+    base.setdefault("intermediate_size", base["moe_expert_ffn"])
+    base.setdefault("rope_layers", tuple(int(l % 4 != 0) for l in range(L)))
+    base.setdefault("sliding_window_layers",
+                    tuple(W * (l % 4 != 0) for l in range(L)))
+    return TransformerConfig(**base)
+
+
 # ----------------------------------------------------------------------
 # init
 # ----------------------------------------------------------------------
+def _init_kinds_params(key, cfg: TransformerConfig) -> PyTree:
+    """Random weights in the static-kind stack's layout (the leaves
+    `inference/v2/hybrid_ops.py` reads): attention, the two norms and the
+    router per layer; the experts apart, outside the layer scan."""
+    H, L, NH, NKV, D = (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
+                        cfg.kv_heads, cfg.head_dim)
+    E, Fe = cfg.moe_experts, cfg.moe_expert_ffn
+    out_std = 0.02 / math.sqrt(2 * L)
+    keys = iter(jax.random.split(key, 16))
+
+    def rnd(shape, std=0.02):
+        return jax.random.normal(next(keys), shape, jnp.float32) * std
+
+    return {
+        "tok_embed": rnd((cfg.vocab_size, H)),
+        "lm_head": rnd((H, cfg.vocab_size)),
+        "final_norm_scale": jnp.ones((H,), jnp.float32),
+        "layers": {"attn_norm_scale": jnp.ones((L, H), jnp.float32),
+                   "mlp_norm_scale": jnp.ones((L, H), jnp.float32),
+                   "wq": rnd((L, H, NH * D)), "wk": rnd((L, H, NKV * D)),
+                   "wv": rnd((L, H, NKV * D)),
+                   "wo": rnd((L, NH * D, H), out_std),
+                   "moe_gate": rnd((L, H, E))},
+        "experts": {"w_gate_proj": rnd((L, E, H, Fe)),
+                    "w_up": rnd((L, E, H, Fe)),
+                    "w_down": rnd((L, E, Fe, H), out_std)}}
+
+
 def _init_latent_params(key, cfg: TransformerConfig) -> PyTree:
     """Random weights in the latent double block's layout (the leaves
     `inference/v2/latent_ops.py` reads): per layer the two sub-blocks'
@@ -602,6 +723,8 @@ def _init_latent_params(key, cfg: TransformerConfig) -> PyTree:
 def _init_params(key, cfg: TransformerConfig) -> PyTree:
     if cfg.latent:
         return _init_latent_params(key, cfg)
+    if cfg.static_kinds:
+        return _init_kinds_params(key, cfg)
     H, L = cfg.hidden_size, cfg.num_layers
     D, NH, NKV = cfg.head_dim, cfg.num_heads, cfg.kv_heads
     F, V = cfg.ffn_dim, cfg.vocab_size
@@ -1613,23 +1736,33 @@ class Transformer:
     def init_params(self, key) -> PyTree:
         return _init_params(key, self.cfg)
 
-    def _refuse_latent(self, what: str) -> None:
+    def refuse_serving_only(self, what: str) -> None:
+        """The serving-only blocks: refused with what is missing."""
         if self.cfg.latent:
             raise NotImplementedError(
                 f"{what} has no latent-attention (MLA) double block: this "
                 f"configuration is served through inference.v2 "
                 f"(build_engine -> ServeLoop) only")
+        if self.cfg.static_kinds:
+            raise NotImplementedError(
+                f"{what} has no static-kind stack: `_layer` has no "
+                f"per-layer rope flag, no router on the layer's input and "
+                f"no ReLU-gated experts, the training MoE (moe/sharded.py) "
+                f"no such expert either, and nothing gives the window "
+                f"layers' attention a backward pass; this configuration "
+                f"is served through inference.v2 (build_engine -> "
+                f"ServeLoop) only")
 
     def loss_fn(self, params, batch, rng=None):
-        self._refuse_latent("Transformer.loss_fn (training, initialize())")
+        self.refuse_serving_only("Transformer.loss_fn (training, initialize())")
         return _lm_loss(self.cfg, params, batch, rng)
 
     def init_cache(self, batch: int, max_len: int):
-        self._refuse_latent("Transformer.init_cache (the dense K/V cache)")
+        self.refuse_serving_only("Transformer.init_cache (the dense K/V cache)")
         return init_kv_cache(self.cfg, batch, max_len)
 
     def forward_with_cache(self, params, input_ids, cache):
-        self._refuse_latent("Transformer.forward_with_cache")
+        self.refuse_serving_only("Transformer.forward_with_cache")
         return forward_with_cache(self.cfg, params, input_ids, cache)
 
     def tp_rules(self, path, shape):
@@ -1646,7 +1779,7 @@ class Transformer:
         return spec
 
     def forward(self, params, input_ids, positions=None):
-        self._refuse_latent("Transformer.forward")
+        self.refuse_serving_only("Transformer.forward")
         logits, _ = _forward(self.cfg, params, input_ids, positions)
         return logits
 

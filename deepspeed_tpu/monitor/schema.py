@@ -48,6 +48,11 @@ SERVING_TAGS = frozenset(
         "steps_run_ahead", "steps_collected_at_once", "rows_overrun",
         "moe_picks", "moe_zero_picks", "moe_local_rows",
         "moe_busiest_rows", "moe_router_calls",
+        # two-kind cache (a window + global stack): block x layer units
+        # held and what one kind would hold, window-kind blocks handed
+        # back, admissions refused by the kind that was short
+        "kv_blocks_held", "kv_blocks_full_cache", "kv_window_released",
+        "admit_blocked_by_kind_global", "admit_blocked_by_kind_window",
         # token streaming + SLO-aware preemption (ISSUE 15):
         # exactly-once delivery accounting and the swap-or-recompute
         # preemption lifecycle

@@ -42,6 +42,13 @@ batch, not rows x table width:
 Masking: block j of a table holds key positions [j*bs, (j+1)*bs); keys with
 position > lens[b] contribute exp(-inf) = 0.  lens[b] < 0 marks an inactive
 (padded) row — output zeros.
+
+A sliding window (`window`, static) moves the START of a row's walk: its
+tiles are listed from table entry `max(0, lens - window + 1) // bs` on, so
+a row past the window costs the window's blocks whatever its length, and
+the table entries before them are never read (a cache that keeps only the
+window's blocks leaves them dead).  Keys at or before `lens - window` in
+the walk's first block are masked like the keys past the row's length.
 """
 from __future__ import annotations
 
@@ -62,11 +69,13 @@ NEG_INF = -1e30
 _TILE_VMEM_BYTES = 8 << 20
 
 
-def paged_decode_reference(q, arena_k, arena_v, block_tables, lens):
+def paged_decode_reference(q, arena_k, arena_v, block_tables, lens,
+                           window=None):
     """Dense-gather reference (the ragged engine's fallback math).
 
     q: [B, NH, D]; arena_k/v: [nb, bs, NKV, D]; block_tables: [B, MB];
-    lens: [B] current token position (inclusive key bound; <0 = inactive).
+    lens: [B] current token position (inclusive key bound; <0 = inactive);
+    `window`: keys at or before `lens - window` are masked.
     Returns [B, NH, D] in q.dtype.
     """
     B, NH, D = q.shape
@@ -82,7 +91,10 @@ def paged_decode_reference(q, arena_k, arena_v, block_tables, lens):
     s = jnp.einsum("bnd,bmnd->bnm", q, kk,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
     key_pos = jnp.arange(MB * bs)[None, None, :]
-    s = jnp.where(key_pos <= lens[:, None, None], s, NEG_INF)
+    seen = key_pos <= lens[:, None, None]
+    if window is not None:
+        seen &= key_pos > lens[:, None, None] - window
+    s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bnm,bmnd->bnd", p.astype(vv.dtype), vv)
     zero = (lens < 0)[:, None, None]
@@ -129,10 +141,11 @@ def _blocks_per_step(bs: int, NKV: int, D: int, itemsize: int,
 
 
 @jax.named_scope("paged_attention_walk")
-def _walk(tables, lens, bs: int, per_step: int):
+def _walk(tables, lens, bs: int, per_step: int, first=None):
     """The batch's live tiles in row order, for the grid to walk.
 
-    tables [B, MB] (clipped), lens [B].  Returns (count, rows [N], tiles
+    tables [B, MB] (clipped), lens [B]; `first` [B] (a window's walk): the
+    table entry a row's tiles start at.  Returns (count, rows [N], tiles
     [N], blocks [per_step * N]) int32, N = B * ceil(MB / per_step): item
     i < count is tile `tiles[i]` of row `rows[i]`, and slot s of it
     reads arena block `blocks[s * N + i]` — the table's entry where that
@@ -140,6 +153,8 @@ def _walk(tables, lens, bs: int, per_step: int):
     B, MB = tables.shape
     N = B * -(-MB // per_step)
     n_blocks = jnp.where(lens >= 0, lens // bs + 1, 0)
+    if first is not None:
+        n_blocks = n_blocks - jnp.where(lens >= 0, first, 0)
     n_tiles = -(-n_blocks // per_step)
     ends = jnp.cumsum(n_tiles)
     item = jnp.arange(N, dtype=jnp.int32)
@@ -148,6 +163,8 @@ def _walk(tables, lens, bs: int, per_step: int):
     tiles = item - (ends - n_tiles)[rows]
     entry = tiles[:, None] * per_step + jnp.arange(per_step)[None]  # [N, P]
     live = (entry < n_blocks[rows][:, None]) & (item < ends[-1])[:, None]
+    if first is not None:
+        entry = entry + first[rows][:, None]
     held = jax.lax.cummax(jnp.where(live, item[:, None], -1), axis=0)
     blocks = jnp.take_along_axis(
         tables[rows[:, None], jnp.minimum(entry, MB - 1)],
@@ -159,14 +176,16 @@ def _walk(tables, lens, bs: int, per_step: int):
 
 
 def _kernel(*refs, bs: int, per_step: int, kv_heads: int, groups: int,
-            sm_scale: float, rows_view: bool):
-    # scalars: rows [N], tiles [N], blocks [P*N], lens [B] (and the layer
-    # index [1], if any).  q_ref/o_ref [1, NH, D]; col_key/col_head [1,
+            sm_scale: float, rows_view: bool, window=None):
+    # scalars: rows [N], tiles [N], blocks [P*N], lens [B] (with a window
+    # the walks' first table entries [B]; the layer index [1], if any).
+    # q_ref/o_ref [1, NH, D]; col_key/col_head [1,
     # keys*NKV] int32 (the key within the tile and the kv head of a score
     # column); per_step K then V blocks, each [1(, 1), bs*NKV, D]
     # (`rows_view`) or [1(, 1), bs, NKV, D]; scratch m/l [NH, 128], acc
     # [NH, D] float32
     rows_ref, tiles_ref, _, lens_ref = refs[:4]
+    first_ref = refs[4] if window is not None else None
     refs = refs[-(2 * per_step + 7):]
     q_ref, col_key_ref, col_head_ref = refs[:3]
     k_refs, v_refs = refs[3:3 + per_step], refs[3 + per_step:-4]
@@ -176,6 +195,14 @@ def _kernel(*refs, bs: int, per_step: int, kv_heads: int, groups: int,
     length = lens_ref[rows_ref[i]]
     NH, D = q_ref.shape[1], q_ref.shape[2]
     keys = per_step * bs
+
+    def position(n):
+        """The position of the first key of tile `n` of this row's walk
+        (computed where it is used: without a window the kernel's ops are
+        the ones they were before it took one)."""
+        if window is None:
+            return n * keys
+        return n * keys + first_ref[rows_ref[i]] * bs
 
     def joined(block_refs):
         """The tile's blocks as one [keys * NKV, D] operand."""
@@ -197,8 +224,10 @@ def _kernel(*refs, bs: int, per_step: int, kv_heads: int, groups: int,
                             preferred_element_type=jnp.float32) * sm_scale
     head = jax.lax.broadcasted_iota(jnp.int32, (NH, 1), 0)
     first = col_head_ref[...] * groups           # a kv head's first q head
-    live = ((tile * keys + col_key_ref[...] <= length)
+    live = ((position(tile) + col_key_ref[...] <= length)
             & (first <= head) & (head < first + groups))
+    if window is not None:
+        live &= position(tile) + col_key_ref[...] > length - window
     s = jnp.where(live, s, NEG_INF)                  # [NH, keys * NKV]
     m_prev = m_s[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -210,17 +239,18 @@ def _kernel(*refs, bs: int, per_step: int, kv_heads: int, groups: int,
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
 
-    @pl.when((tile + 1) * keys > length)             # the row's last tile
+    @pl.when(position(tile + 1) > length)            # the row's last tile
     def _finish():
         o_ref[0] = (acc_s[...] / l_s[:, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
-                           layer_idx=None):
+                           layer_idx=None, window=None):
     """Fused paged decode attention (see module docstring).
 
     Shapes as in `paged_decode_reference`; block_tables entries may be
-    garbage past a sequence's live blocks (never read).
+    garbage past a sequence's live blocks and, with a `window` (static),
+    before the window's first block (never read).
 
     `layer_idx`: when given, arena_k/v keep their FULL [L, nb, bs, NKV, D]
     shape and the (traced) scalar layer index rides the grid as a scalar-
@@ -239,8 +269,13 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
 
     lens = jnp.minimum(lens.astype(jnp.int32), MB * bs - 1)
     tables = jnp.clip(block_tables, 0, nb - 1).astype(jnp.int32)
-    count, rows, tiles, blocks = _walk(tables, lens, bs, per_step)
-    scalars = (rows, tiles, blocks, lens)
+    if window is None:
+        count, rows, tiles, blocks = _walk(tables, lens, bs, per_step)
+        scalars = (rows, tiles, blocks, lens)
+    else:
+        first = (jnp.maximum(lens - window + 1, 0) // bs).astype(jnp.int32)
+        count, rows, tiles, blocks = _walk(tables, lens, bs, per_step, first)
+        scalars = (rows, tiles, blocks, lens, first)
     if layered:
         scalars += (jnp.asarray(layer_idx, jnp.int32).reshape(1),)
 
@@ -251,8 +286,9 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
         arena_v = arena_v.reshape(arena_v.shape[:-3] + block)
 
     def kv_map(slot):
-        def index_map(i, rows, tiles, blocks, lens, *layer):
-            return (tuple(ref[0] for ref in layer)
+        def index_map(i, rows, tiles, blocks, *rest):
+            # rest: lens (a window's first entries) and the layer index
+            return (tuple(ref[0] for ref in rest[-1:] if layered)
                     + (blocks[slot * N + i],) + (0,) * len(block))
         return index_map
 
@@ -276,7 +312,7 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lens,
     )
     kernel = functools.partial(
         _kernel, bs=bs, per_step=per_step, kv_heads=NKV, groups=NH // NKV,
-        sm_scale=1.0 / math.sqrt(D), rows_view=rows_view)
+        sm_scale=1.0 / math.sqrt(D), rows_view=rows_view, window=window)
     out = pl.pallas_call(
         kernel,
         name="paged_attention_decode",

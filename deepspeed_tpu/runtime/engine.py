@@ -1099,6 +1099,9 @@ def initialize(
     otherwise pass `loss_fn` + `params` explicitly.
     """
     if model is not None:
+        # a block that is served only says here what training would take
+        getattr(model, "refuse_serving_only", lambda what: None)(
+            "deepspeed_tpu.initialize (training)")
         if loss_fn is None:
             loss_fn = model.loss_fn
             if getattr(model, "supports_layer_gather", False):

@@ -166,7 +166,7 @@ class MetricsSampler:
             "active_seqs": len(loop.scheduler.active),
             "parked": len(loop._handoff_ready),
             "free_slots": loop.engine.free_slots,
-            "free_blocks": loop.engine.free_blocks,
+            "free_blocks": int(loop.engine.free_blocks),  # dstpu: noqa[DST001] a host count: an int, or a two-kind cache's KindCounts (its scarcest kind)
             "batch_occupancy": t.batch_occupancy,
             "prefill_tokens_step": t.prefill_tokens_step,
             "decode_tokens_step": t.decode_tokens_step,
@@ -214,7 +214,7 @@ class FleetMetricsSampler:
             "parked_total": sum(
                 len(rep.loop._handoff_ready) for rep in fleet.replicas),
             "free_blocks_total": sum(
-                rep.loop.engine.free_blocks for rep in fleet.replicas),
+                int(rep.loop.engine.free_blocks) for rep in fleet.replicas),
             "load_mean": (sum(loads) / len(loads)) if loads else 0.0,
             "load_max": max(loads) if loads else 0.0,
             "routed_total": sum(t.routed.values()),

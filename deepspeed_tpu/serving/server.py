@@ -49,6 +49,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from ..config.config import ServingConfig
+from ..inference.v2.blocked_allocator import KindCounts
 from ..utils.logging import logger
 from ..utils.spans import span
 from .request import Request, RequestState
@@ -56,6 +57,21 @@ from .scheduler import (AdmissionError, ContinuousBatchingScheduler)
 from .telemetry import ServingTelemetry
 
 __all__ = ["ServeLoop", "ThreadedServer"]
+
+
+# Block counts are ints, or one count a kind where the engine's cache holds
+# two kinds of block (`inference/v2/blocked_allocator.KindCounts`): both add
+# and subtract, and these two are every comparison the ledger makes
+def _short(need, have) -> bool:
+    """`have` blocks do not cover `need` (two kinds: of ANY kind)."""
+    return need.short_of(have) if isinstance(need, KindCounts) \
+        else need > have
+
+
+def _floor0(blocks):
+    """max(blocks, 0), kind by kind."""
+    return blocks.floor0() if isinstance(blocks, KindCounts) \
+        else max(blocks, 0)
 
 
 class _StepPhases:
@@ -511,6 +527,14 @@ class ServeLoop:
         self._rng = np.random.RandomState(rng_seed)
         self._next_uid = 0
         self._block_size = getattr(engine.state, "block_size", 1)
+        # how the engine counts a sequence's blocks (`DSStateManager.
+        # blocks_needed` / `blocks_leased`: ints, or one count a kind); an
+        # engine that states no count holds whole blocks of `block_size`
+        self._count_needed = getattr(
+            engine, "blocks_needed",
+            lambda tokens: -(-tokens // self._block_size))
+        self._count_leased = getattr(engine, "blocks_leased",
+                                     lambda d: len(d.blocks))
         # KV reservation ledger: uid -> total blocks the request's WHOLE
         # lifetime needs.  The engine leases blocks lazily as sequences
         # grow, so "free_blocks" alone over-reports headroom: blocks an
@@ -1069,7 +1093,7 @@ class ServeLoop:
                     held = sum(self._reserved.get(uid, 0)
                                for uid, r in self.scheduler.active.items()
                                if r.tenant == req.tenant)
-                    if held + self._blocks_needed(req) > quota:
+                    if _short(held + self._blocks_needed(req), quota):
                         self.telemetry.count("quota_deferred")
                         self.telemetry.count_tenant(req.tenant,
                                                     "quota_deferred")
@@ -1133,7 +1157,7 @@ class ServeLoop:
             try:
                 need = total - (len(lease.blocks)
                                 if lease is not None else 0)
-                if need > headroom[0] and self._cache is not None:
+                if _short(need, headroom[0]) and self._cache is not None:
                     # cached-but-unreferenced blocks are reclaimable
                     # headroom, not spent capacity: evict LRU prefixes
                     # to fit the head of the queue (never skipped —
@@ -1144,7 +1168,11 @@ class ServeLoop:
                     short = need - headroom[0]
                     if self._cache.evictable_blocks() >= short:
                         headroom[0] += self._cache.reclaim(short)
-                if need > headroom[0]:
+                if _short(need, headroom[0]):
+                    if isinstance(need, KindCounts):
+                        self.telemetry.count(
+                            "admit_blocked_by_kind_" + need.short_kind(
+                                headroom[0], self.engine.kind_names))
                     if lease is not None:
                         self._cache.abandon(lease)
                     elif self._cache is not None:
@@ -1311,11 +1339,16 @@ class ServeLoop:
         except BaseException:
             self._rollback_admission(admitted)
             raise
+        # a two-kind cache's account of this step's decode rows (block x
+        # layer units: `InferenceEngineV2._count_kinds`); else nothing
+        kinds = getattr(out, "kv_kinds", {})
         whole.set_metadata(
             decode_rows=getattr(out, "decode_rows", 0),
             fed_on_device_rows=getattr(out, "fed_rows", 0),
             kv_live_blocks=getattr(out, "kv_live_blocks", 0),
-            kv_table_blocks=getattr(out, "kv_table_blocks", 0))
+            kv_table_blocks=getattr(out, "kv_table_blocks", 0), **kinds)
+        for name, n in kinds.items():
+            self.telemetry.count(name, n)
         self.telemetry.count("admitted", len(admitted))
         if self._tenancy is not None:
             for r in admitted:
@@ -1485,7 +1518,7 @@ class ServeLoop:
                 admitted=len(admitted), finished=len(finished),
                 prefill_tokens=prefill_toks, decode_tokens=decode_toks,
                 queue_depth=self.scheduler.queue_depth,
-                free_blocks=self.engine.free_blocks)
+                free_blocks=int(self.engine.free_blocks))  # dstpu: noqa[DST001] a host count: an int, or a two-kind cache's KindCounts (its scarcest kind)
         if self._metrics is not None:
             # one time-series row per tick (serving/observatory): pure
             # host reads on state this step already computed
@@ -2143,23 +2176,25 @@ class ServeLoop:
         return self._expert_pool
 
     # -- KV reservation ---------------------------------------------------
-    def _blocks_needed(self, req: Request) -> int:
+    def _blocks_needed(self, req: Request):
+        """Blocks the request's whole lifetime holds at most, as the
+        engine counts them (an int, or one count a kind)."""
         if self._role == "prefill":
             # disagg prefill pool: decode runs on ANOTHER replica's
             # arena after the handoff, so only the prompt's blocks are
             # ever leased here — reserving the decode budget too would
             # just shrink the admission batch (the "large prefill
             # batches" lever of disaggregated serving)
-            return -(-len(req.prompt) // self._block_size)
-        return -(-(len(req.prompt) + req.max_new_tokens)
-                 // self._block_size)
+            return self._count_needed(len(req.prompt))
+        return self._count_needed(len(req.prompt) + req.max_new_tokens)
 
-    def _unleased_reserve(self) -> int:
+    def _unleased_reserve(self):
         """Blocks promised to active requests but not leased yet."""
         out = 0
         for uid, need in self._reserved.items():
             d = self.engine.state.seqs.get(uid)
-            out += max(0, need - (len(d.blocks) if d is not None else 0))
+            out = out + _floor0(
+                need - (self._count_leased(d) if d is not None else 0))
         return out
 
     def _effective_tokens(self, req: Request) -> np.ndarray:
@@ -2256,7 +2291,7 @@ class ServeLoop:
                                           r._arrival_seq or 0),
                            reverse=True)
             need = self._blocks_needed(head)
-            avail = (max(headroom[0], 0)
+            avail = (_floor0(headroom[0])
                      + sum(self._reserved.get(r.uid, 0) for r in
                            cands[:cfg.max_victims_per_step - victims]))
             if self._cache is not None:
@@ -2268,7 +2303,7 @@ class ServeLoop:
                 avail += (self._cache.covered_tokens(
                     self._effective_tokens(head)) // self._block_size)
                 avail += self._cache.evictable_blocks()
-            if need > avail:
+            if _short(need, avail):
                 break      # preemption cannot make the head fit
             victim = cands[0]
             self._preempt_victim(victim, now)

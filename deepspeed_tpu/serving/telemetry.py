@@ -88,6 +88,15 @@ class ServingTelemetry:
             # (summed over router calls), router calls
             "moe_picks": 0, "moe_zero_picks": 0, "moe_local_rows": 0,
             "moe_busiest_rows": 0, "moe_router_calls": 0,
+            # two-kind cache (a window + global stack): summed over decode
+            # steps, in block x layer units, what the step's rows held of
+            # both kinds, what one kind over all layers would have held
+            # for them, and the window-kind blocks handed back since the
+            # step before; admissions refused for want of blocks, by the
+            # kind that was short
+            "kv_blocks_held": 0, "kv_blocks_full_cache": 0,
+            "kv_window_released": 0, "admit_blocked_by_kind_global": 0,
+            "admit_blocked_by_kind_window": 0,
             # token streaming (serving/streaming.py): tokens delivered
             # through request streams, tokens regenerated after a
             # failover and suppressed as verified replay (exactly-once

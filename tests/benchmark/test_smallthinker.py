@@ -1,0 +1,338 @@
+"""The SmallThinker cell at a tiny size on the CPU: the reference against
+the program through the harness, its four controls, its counts by hand, and
+the readers of the two-kind cache's span attributes."""
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import harness
+
+from bench_testlib import DATA
+
+CELL = "smallthinker-tiny.closed"
+REAL_CELL = "smallthinker-21b-a3b.decode_closed_long"
+REF = harness.load_module(harness.BENCH_DIR, "references", "smallthinker")
+PEAKS = harness.load_json(harness.BENCH_DIR, "peaks.json")["TPU v5 lite"]
+REAL_CFG = harness.load_json(harness.BENCH_DIR, "configs",
+                             "smallthinker-21b-a3b.json")
+
+
+def real():
+    return REF.sizes(REAL_CFG)
+
+
+@pytest.fixture
+def run_smallthinker(bench_root, run_tiny):
+    """The tiny cell added to the temporary root as entries (its files are
+    in tests/benchmark/data): every metric the real cell lists."""
+    path = os.path.join(bench_root, "BENCHMARK.json")
+    bench = json.load(open(path))
+    bench["workloads"].append({"name": CELL, "config": "smallthinker-tiny",
+                               "traffic": "closed_tiny", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if REAL_CELL in m.get("workloads", []):
+            m["workloads"].append(CELL)
+    json.dump(bench, open(path, "w"))
+    return lambda **kw: run_tiny(CELL, **kw)
+
+
+def test_program_agrees_with_the_reference(run_smallthinker):
+    """A whole closed-loop run: prompts through chunk slots into both kinds
+    of cache (rows of up to 52 tokens under a window of 20), decode through
+    the windowed walk; every served token is the float32 reference's
+    best."""
+    res = run_smallthinker()
+    assert res["correct"], res["compared"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert res["compiles_in_window"] == 0
+    assert res["metrics"]["ttft_p50_ms"]["value"] > 0
+    assert "out_tok_s" not in res["metrics"]      # not this cell's to report
+    assert tuple(res["notes"]["free_blocks"]) == (132, 20)
+
+
+@pytest.mark.parametrize("control", REF.CONTROLS)
+def test_each_control_comes_out_incorrect(run_smallthinker, control):
+    res = run_smallthinker(seconds=2.0, control=control)
+    assert res["correct"] is False and res["control"] == control
+    c = res["compared"]["greedy_gap"]
+    assert c["value"] > c["limit"]
+
+
+def test_an_unknown_control_is_an_error():
+    with pytest.raises(ValueError, match="unknown control"):
+        REF._how("fp4")
+    assert REF._how(None) == (None, ()) and REF._how("int8") == ("int8", ())
+    assert REF._how("rope_on_global") == (None, ("rope_on_global",))
+
+
+def test_weights_one_call_equals_layer_by_layer():
+    """The program's stacked tree (experts apart) and the reference's
+    layer-by-layer leaves are the same numbers, at a seed past 2**31."""
+    s = REF.sizes(harness.load_json(DATA, "configs",
+                                    "smallthinker-tiny.json"))
+    whole = REF.make_params(2**31 + 9, s, jnp.float32)
+    key = REF.seed_key(REF.seed_arg(2**31 + 9))
+    assert set(whole) == {"tok_embed", "final_norm_scale", "lm_head",
+                          "layers", "experts"}
+    for l in range(s.layers):
+        lp = REF.layer_params(key, np.uint32(l), s, jnp.float32)
+        ex = lp.pop("experts")
+        # (two compiled forms of one formula: a unit in the last place)
+        close = lambda a, b: np.testing.assert_allclose(  # noqa: E731
+            a[l], b, rtol=1e-6, atol=1e-8)
+        jax.tree.map(close, whole["layers"], lp)
+        jax.tree.map(close, whole["experts"], ex)
+    assert whole["experts"]["w_up"].shape == (8, 8, 64, 32)
+    assert whole["layers"]["moe_gate"].shape == (8, 64, 8)
+    assert s.rope_layout == s.window_layout == (0, 1, 1, 1, 0, 1, 1, 1)
+    assert s.global_layers == 2
+
+
+def test_the_seeded_spreads_are_what_the_configuration_states():
+    """`seeded_weights`: q.k / sqrt(D) of a normed input spreads by
+    `qk_logit_std`, the router's logits by `router_logit_std` per unit RMS
+    of the stream it reads (numpy, at the published widths, one layer's
+    projections made here as `_leaf` makes them)."""
+    s = real()
+    rng = np.random.RandomState(0)
+    H, D = s.hidden, s.head_dim
+    h = rng.randn(64, H).astype(np.float32)
+    h /= np.sqrt((h * h).mean(-1, keepdims=True))
+    std = np.sqrt(s.qk_logit_std / H)
+    wq, wk = (rng.randn(H, D).astype(np.float32) * std for _ in range(2))
+    logits = (h @ wq) @ (h @ wk).T / np.sqrt(D)
+    assert logits.std() == pytest.approx(s.qk_logit_std, rel=0.1)
+    router = rng.randn(H, s.experts).astype(np.float32) \
+        * s.router_logit_std / np.sqrt(H)
+    assert (h @ router).std() == pytest.approx(s.router_logit_std, rel=0.1)
+    assert (s.qk_logit_std, s.router_logit_std) == (2.5, 2.0)
+
+
+def test_the_cut_weighs_7_93_gb_and_the_two_kinds_3_15_gib():
+    s = real()
+    attention = 2 * 2560 * 3584 + 2 * 2560 * 512
+    assert attention == 20_971_520
+    layer = attention + 2560 * 64 + 2 * 2560             # router, two norms
+    expert = 3 * 2560 * 768
+    assert expert == 5_898_240
+    per_layer = layer + 64 * expert
+    assert per_layer / 1e6 == pytest.approx(398.6, abs=0.05)
+    total = 8 * per_layer + 2 * 151_936 * 2560 + 2560
+    assert REF.weight_bytes(s, "bfloat16") == 2 * total
+    assert 2 * total / 1e9 == pytest.approx(7.93, abs=0.005)
+    # a cached token's K and V in one layer; a 64-token block of one layer
+    assert REF.kv_bytes_per_token_layer(s, "bfloat16") == 2048
+    block = 64 * 2048
+    assert block == 128 << 10
+    # 32 rows of 209 blocks on 2 global layers, 32 rows of 65 on 6 window
+    # layers; one kind over 8 layers
+    two_kinds = (32 * 209 * 2 + 32 * 65 * 6) * block
+    assert two_kinds / 2**30 == pytest.approx(3.156, abs=0.001)
+    assert 32 * 209 * 8 * block / 2**30 == pytest.approx(6.53, abs=0.005)
+    engine = REAL_CFG["program"]["engine"]
+    assert engine["max_blocks_per_seq"] == 209 and engine["max_seqs"] == 32
+    assert engine["prefill_chunk_size"] \
+        == engine["max_prefill_tokens_per_step"] == 12_288
+    # the file states the published depth beside the cut
+    assert REAL_CFG["num_hidden_layers"] == 8 == len(REAL_CFG["rope_layout"])
+    assert REAL_CFG["published"]["num_hidden_layers"] == 52
+    assert REAL_CFG["published"]["rope_layout"] == [0, 1, 1, 1] * 13
+    assert REAL_CFG["published"]["sliding_window_layout"] == [0, 1, 1, 1] * 13
+    assert REAL_CFG["reduced"] == ["num_hidden_layers", "rope_layout",
+                                   "sliding_window_layout"]
+
+
+def test_decode_step_bytes_by_hand():
+    s = real()
+    rows, ctx = 32, 32 * 8600
+    # a token picks a given expert with probability 6/64: of 64 experts
+    # 64 (1 - (58/64)^32) = 61.26 get a row; the other 2.74 are not read
+    reached = 64 * (1 - (58 / 64) ** 32)
+    assert REF.experts_with_a_row(s, rows) == pytest.approx(reached)
+    assert reached == pytest.approx(61.26, abs=0.005)
+    # keys: every live key on the 2 global layers, 4096 on the 6 window ones
+    keys = rows * (2 * (8600 + 1) + 6 * 4096)
+    assert REF.keys_read(s, rows, ctx) == pytest.approx(keys)
+    outside = 2 * 2560 * 3584 + 2 * 2560 * 512 + 2560 * 64 + 2 * 2560
+    weights = (8 * (outside + reached * 3 * 2560 * 768)
+               + 151_936 * 2560 + 2560) * 2
+    want = weights + rows * 2560 * 2 + (keys + 8 * rows) * 2048 \
+        + rows * 151_936 * 4
+    assert REF.decode_step_bytes(s, "bfloat16", rows, ctx) \
+        == pytest.approx(want, rel=1e-12)
+    # 9.67 GB: 6.90 of weights (5.78 of them experts), 2.74 of keys and
+    # values: 11.6 ms at 819 GB/s, the floor of a decode step
+    assert weights / 1e9 == pytest.approx(6.90, abs=0.01)
+    assert keys * 2048 / 1e9 == pytest.approx(2.74, abs=0.01)
+    assert want / 1e9 == pytest.approx(9.67, abs=0.02)
+    assert 1e3 * want / PEAKS["hbm_bytes_per_s"] == pytest.approx(11.8,
+                                                                  abs=0.05)
+    # a row shorter than the window reads what it has
+    assert REF.keys_read(s, 4, 4 * 999) == 4 * 8 * 1000
+
+
+def test_paged_decode_counts_by_hand():
+    s = real()
+    rows, ctx = 32, 32 * 8600
+    keys = rows * (2 * 8601 + 6 * 4096)
+    q_and_out = 8 * rows * 2 * 28 * 128 * 2
+    assert REF.paged_decode_bytes(s, "bfloat16", rows, ctx) \
+        == pytest.approx(keys * 2048 + q_and_out)
+    assert REF.paged_decode_flops(s, rows, ctx) \
+        == pytest.approx(keys * 2 * 2 * 28 * 128)
+    # bandwidth bounds the kernel on this chip: 3.35 ms against 0.09 ms
+    t_bytes = REF.paged_decode_bytes(s, "bfloat16", rows, ctx) / 819e9
+    t_flops = REF.paged_decode_flops(s, rows, ctx) / 197e12
+    assert t_bytes == pytest.approx(3.35e-3, rel=0.01)
+    assert t_flops == pytest.approx(0.097e-3, rel=0.02)
+    # one kind with no window would read 8 x 8601 keys a row: 1.64 x these
+    assert 8 * 8601 / (2 * 8601 + 6 * 4096) == pytest.approx(1.645, abs=0.01)
+
+
+def test_the_kernel_roofline_reader_adds_up_both_kinds(monkeypatch):
+    """`paged_window_roofline.closed`: the `paged_attention_decode` ops
+    under BOTH scopes (not the walk's list) per run of the decode program
+    against the floor of `paged_decode_bytes`; the two scope shares read
+    their own scope."""
+    from benchmark import span_reduce
+    from benchmark.readers import kernel_roofline, scope_share
+    body = "jit(decode_step)/while/body/closed_call/"
+    reduced = {"programs": {"jit_decode_step": {"device_s": 0.1, "ops": {
+        body + "attn_window/paged_attention_decode "
+               "paged_attention_decode.57": 0.012,
+        body + "attn_global/paged_attention_decode "
+               "paged_attention_decode.56": 0.008,
+        body + "attn_window/paged_attention_walk fusion.3": 0.001,
+        body + "attn_window/sh,hd->sd fusion.9": 0.004,
+        body + "attn_global/kv_write fusion.4": 0.002,
+        "jit(decode_step)/ragged-dot-none ragged-dot-none.1": 0.05}},
+        "jit_prefill_chunks": {"device_s": 0.3, "ops": {
+            "jit(prefill_chunks)/while/body/attn_window/chunk_attention "
+            "chunk_attention.3": 0.04,
+            "jit(prefill_chunks)/while/body/attn_global/chunk_attention "
+            "chunk_attention.2": 0.02,
+            "jit(prefill_chunks)/ragged-dot-none ragged-dot-none.2": 0.1}}}}
+    monkeypatch.setattr(span_reduce, "of_view", lambda view: reduced)
+    view = {"trace": {"programs": {"jit_decode_step": {
+                "runs": 5, "device_s": 0.1, "ops": {}}}},
+            "stats": {"counters": {"steps": 10, "rows": 320,
+                                   "context_tokens": 2_752_000}},
+            "config": REAL_CFG, "model": REF,
+            "bench_dir": harness.BENCH_DIR, "device_kind": "TPU v5 lite"}
+    metric = lambda name: harness.load_json(  # noqa: E731
+        harness.BENCH_DIR, "metrics", name + ".closed.json")["params"]
+    got = kernel_roofline.read(view, **metric("paged_window_roofline"))
+    floor = REF.paged_decode_bytes(real(), "bfloat16", 32, 275_200) / 819e9
+    assert got == pytest.approx(100 * floor / 0.004, rel=1e-9) and got < 100
+    assert scope_share.read(view, **metric("window_attn_device_share")) \
+        == pytest.approx(17.0)
+    assert scope_share.read(view, **metric("global_attn_device_share")) \
+        == pytest.approx(10.0)
+    # the one that moves a first token's wait: the chunk programs' kernel
+    assert scope_share.read(view, **metric("chunk_attn_device_share")) \
+        == pytest.approx(20.0)
+    reduced["programs"].pop("jit_prefill_chunks")
+    assert scope_share.read(view, **metric("chunk_attn_device_share")) \
+        is None
+    # a program without the scopes (the parent commit): nothing to read
+    for key in [k for k in reduced["programs"]["jit_decode_step"]["ops"]
+                if "attn_" in k]:
+        reduced["programs"]["jit_decode_step"]["ops"].pop(key)
+    assert kernel_roofline.read(view, **metric("paged_window_roofline")) \
+        is None
+    assert scope_share.read(view, **metric("window_attn_device_share")) \
+        is None
+
+
+def test_the_span_attribute_readers_on_a_real_trace(tmp_path):
+    """A tiny two-kind engine served for a few steps under the profiler:
+    the `serve.step` spans carry the cache's account, the `serve.moe_census`
+    spans the drained router counters, and the two metric files read the
+    held share and the rows per expert out of them."""
+    from deepspeed_tpu import ServingConfig
+    from deepspeed_tpu.serving import ServeLoop
+    from benchmark import systems
+    from benchmark.readers import span_attr_ratio
+    cfg = harness.load_json(DATA, "configs", "smallthinker-tiny.json")
+    engine, _ = systems.build_serving(cfg, 3, REF)
+    from deepspeed_tpu.inference.v2.latent_ops import COUNT_DRAIN_STEPS
+    loop = ServeLoop(engine, ServingConfig())
+    rng = np.random.RandomState(0)
+    for n in (70, 30):
+        loop.submit(rng.randint(0, 512, n).astype(np.int32),
+                    max_new_tokens=2 * COUNT_DRAIN_STEPS + 3)
+    trace_dir = tmp_path / ".cache" / "bench_trace"
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(trace_dir), profiler_options=options):
+        while loop.has_work:
+            loop.step()
+    view = {"trace": {"programs": {}}, "config": cfg,
+            "bench_dir": str(tmp_path / "benchmark")}
+    metric = lambda name: harness.load_json(  # noqa: E731
+        harness.BENCH_DIR, "metrics", name + ".json")["params"]
+    held = span_attr_ratio.read(view, **metric("kv_held_share.closed"))
+    per_expert = span_attr_ratio.read(view, **metric("rows_per_expert.closed"))
+    tel = loop.telemetry.counters
+    assert tel["kv_blocks_full_cache"] > tel["kv_blocks_held"] > 0
+    assert held == pytest.approx(
+        100 * tel["kv_blocks_held"] / tel["kv_blocks_full_cache"], rel=1e-6)
+    # the 70-token row holds 9-13 blocks on 2 layers and 3-4 on 6, of 8 x
+    assert 30 < held < 75
+    drained = loop.telemetry.steps // COUNT_DRAIN_STEPS * COUNT_DRAIN_STEPS
+    assert drained and per_expert > 0
+    assert per_expert == pytest.approx(
+        tel["moe_local_rows"] / (8 * tel["moe_router_calls"]), rel=1e-6)
+    assert tel["moe_picks"] == tel["moe_local_rows"]
+    # every step's span carries the table's live entries of both kinds
+    steps = span_attr_ratio.attributes(
+        str(next(trace_dir.rglob("*.xplane.pb"))), "serve.step")
+    assert steps and all(
+        float(a["kv_live_blocks"]) <= float(a["kv_table_blocks"])
+        for a in steps if float(a.get("decode_rows", 0)))
+    # a program without the attributes (the parent commit): nothing to read
+    assert span_attr_ratio.read(view, span="serve.step", num="kv_no_such",
+                                den="kv_blocks_full_cache") is None
+
+
+def test_the_cell_and_its_metrics_are_entered_as_the_issue_names_them():
+    bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    cell = next(c for c in bench["workloads"] if c["name"] == REAL_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "smallthinker-21b-a3b", "decode_closed_long", 1)
+    assert bench["workloads"][-1] is cell and len(cell["why"]) <= 200
+    traffic = harness.load_json(harness.BENCH_DIR, "traffic",
+                                "decode_closed_long.json")
+    assert traffic["kind"] == "closed_loop" and traffic["clients"] == 32
+    assert traffic["prompt_len"] == [4096, 12288]
+    assert traffic["output_len"] == [512, 1024]
+    assert traffic["check_requests"] == 4 and traffic["size_pool"] == 256
+    assert traffic["settle_s"] == 12
+    # every prompt is one chunk slot, every row past the window when it
+    # starts to decode, the longest request the engine admits (12,288 +
+    # 1,024) inside the lease
+    assert traffic["prompt_len"][0] >= REAL_CFG["sliding_window_size"]
+    assert traffic["prompt_len"][1] <= 12_288
+    assert 12_288 + 1_024 \
+        <= 209 * 64 == REAL_CFG["program"]["overrides"]["max_seq_len"]
+    lists = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]
+             if REAL_CELL in m.get("workloads", [])}
+    assert set(lists) == {
+        "ttft_p50_ms", "ttft_p95_ms.closed", "queue_wait_p95_ms.closed",
+        "prefill_device_ms_per_ktok.closed", "moe_device_share.closed",
+        "kv_held_share.closed", "window_attn_device_share.closed",
+        "global_attn_device_share.closed", "paged_window_roofline.closed",
+        "rows_per_expert.closed", "chunk_attn_device_share.closed"}
+    assert all(m["moves"] == "ttft_p50_ms" for m in lists.values()
+               if "moves" in m)
+    new = bench["per_layer"][-6:]
+    assert [m["name"] for m in new] == [
+        "kv_held_share.closed", "window_attn_device_share.closed",
+        "global_attn_device_share.closed", "paged_window_roofline.closed",
+        "rows_per_expert.closed", "chunk_attn_device_share.closed"]
+    assert all(m["workloads"] == [REAL_CELL] for m in new)

@@ -118,6 +118,57 @@ def test_paged_decode(chip, B, NH, NKV, D, nb, bs, MB, L):
     assert mem.temp_size_in_bytes < 16 << 20
 
 
+# the smallthinker cell's decode: 32 rows, 28/4 heads of 128, a 209-entry
+# table; the window kind (2145 blocks of 6 layers, the walk starting at the
+# window's first block) and the global kind (6689 blocks of 2 layers, no
+# window); then a uniform window at the small serving geometry
+@pytest.mark.parametrize("B,NH,NKV,D,nb,MB,L,window", [
+    pytest.param(32, 28, 4, 128, 2145, 209, 6, 4096, id="cell-window-kind"),
+    pytest.param(32, 28, 4, 128, 6689, 209, 2, None, id="cell-global-kind"),
+    pytest.param(8, 32, 4, 64, 256, 32, None, 1024, id="uniform-window"),
+])
+def test_paged_decode_with_a_window(chip, B, NH, NKV, D, nb, MB, L, window):
+    """The window is static: one kernel, the walk's first entries one more
+    scalar-prefetch operand, no copy of the arena."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    arena = chip((nb, 64, NKV, D) if L is None else (L, nb, 64, NKV, D))
+    args = (chip((B, NH, D)), arena, arena, chip((B, MB), jnp.int32),
+            chip((B,), jnp.int32)) + ((chip((), jnp.int32),) if L else ())
+
+    def attend(q, ak, av, tables, lens, *layer):
+        return pa.paged_decode_attention(
+            q, ak, av, tables, lens, layer_idx=layer[0] if layer else None,
+            window=window)
+
+    assert kernels(attend, *args) == 1
+    with jax.default_matmul_precision("default"):
+        mem = jax.jit(attend).lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20
+
+
+# (rows, window): the smallthinker cell's prompt chunks, 12,288 queries of
+# 28/4 heads of 128 over a row's keys by position (a 209-block table and
+# the chunk's own: 25,664 positions in 512-key tiles); one chunk slot and
+# the four a step can hold
+@pytest.mark.parametrize("R,window", [(1, 4096), (1, None), (4, 4096)])
+def test_chunk_attention(chip, R, window):
+    from deepspeed_tpu.ops import chunk_attention as ca
+    C, T = 12288, 209 * 64 + 12288
+    T = -(-T // ca.key_tile(T)) * ca.key_tile(T)
+
+    def attend(q, k, v, pos0, n_valid):
+        return ca.chunk_attention(q, k, v, pos0, n_valid, window=window)
+
+    args = (chip((R, C, 28, 128)), chip((R, T, 4, 128)),
+            chip((R, T, 4, 128)), chip((R,), jnp.int32),
+            chip((R,), jnp.int32))
+    assert kernels(attend, *args) == 1
+    # a query tile is 128 queries x 7 heads; with the window its key steps
+    # are a constant few however long the row
+    assert ca._query_tile(C, 7) == 128 and T // 512 == 51
+    assert (4096 + 128 - 2) // 512 + 2 == 10
+
+
 # (C, NH, NKV, nb, bs, MB).  The serving chunk, then the padded tiles: C=4
 # is a sub-8 verify span (pads to the 8-row query tile), C=20 an odd chunk
 @pytest.mark.parametrize("C,NH,NKV,nb,bs,MB", [
