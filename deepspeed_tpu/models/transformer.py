@@ -1135,12 +1135,23 @@ def _layer(cfg: TransformerConfig, x, lp, positions, window=None,
         # under every other policy) — the remat backward then recomputes only
         # norm/rope, not the q/k/v matmuls
         from ..runtime.activation_checkpointing import proj_checkpoint_name
-        q = proj_checkpoint_name(dense(h, lp["wq"], lp.get("bq"))).reshape(
-            B, S, NH, D)
-        k = proj_checkpoint_name(dense(h, lp["wk"], lp.get("bk"))).reshape(
-            B, S, NKV, D)
-        v = proj_checkpoint_name(dense(h, lp["wv"], lp.get("bv"))).reshape(
-            B, S, NKV, D)
+        def heads(name, n):
+            y = proj_checkpoint_name(dense(h, lp["w" + name],
+                                           lp.get("b" + name)))
+            if D % 128:
+                # a head narrower than a tile's 128 lanes: XLA folds the
+                # head-major transpose the attention kernel asks for into
+                # the matmul, as a convolution over `n` positions of D
+                # output features, and at D = 64 that is half the MXU's
+                # width (200-224 us a projection at OPT-1.3B where the
+                # output projection, the same FLOPs, takes 102; PERF.md
+                # sections 5-6, PR 34).  Behind the barrier the matmul
+                # stays [S, H] x [H, n * D] (94-103 us) and the transpose
+                # is a copy of 14-27 us; at D = 128 the fold costs nothing
+                # and stays
+                y = jax.lax.optimization_barrier(y)
+            return y.reshape(B, S, n, D)
+        q, k, v = heads("q", NH), heads("k", NKV), heads("v", NKV)
         if cfg.pos_emb == "rope":
             q = _rope(q, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
             k = _rope(k, positions, cfg.rope_theta, cfg.rope_pct, cfg.rope_scaling)
@@ -1422,9 +1433,41 @@ def _head_hidden(params, x, dt):
     return x
 
 
+@jax.custom_vjp
+def _grads_into(lp: PyTree, sink: PyTree, i):
+    """(`lp`, `sink`) unchanged.  Backward, `lp` takes no cotangent: it is
+    added to layer `i` of the cotangent of `sink` (a tree of `[L, ...]`
+    stacks shaped like the stacked `lp`), in place.  `sink` rides the layer
+    scan as a carry, so its cotangent is a carry of the reverse scan, and
+    the cotangent the caller hands it where it leaves the scan (a gradient
+    accumulator) is what each layer's gradient is added to where the
+    backward pass produces it: no second stacked tree, no pass to add it."""
+    return lp, sink
+
+
+def _grads_into_fwd(lp, sink, i):
+    return (lp, sink), i
+
+
+def _grads_into_bwd(i, cts):
+    g, acc = cts
+    at = jax.lax.dynamic_index_in_dim
+    return None, jax.tree.map(
+        lambda a, g: jax.lax.dynamic_update_index_in_dim(
+            a, at(a, i, 0, keepdims=False) + g.astype(a.dtype), i, 0),
+        acc, g), None
+
+
+_grads_into.defvjp(_grads_into_fwd, _grads_into_bwd)
+
+
 def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
-             return_hidden=False):
-    """Logits for [B,S] token ids (final hidden states when return_hidden)."""
+             return_hidden=False, grad_sink=None):
+    """Logits for [B,S] token ids (final hidden states when return_hidden).
+    With `grad_sink` (a tree like `params["layers"]`, any dtype, its values
+    unread) the layers' weights take no gradient: it goes to the sink's
+    cotangent (`_grads_into`), and the sink as it left the layer scan is
+    returned as a third value."""
     B, S = input_ids.shape
     dt = cfg.dtype
     if positions is None:
@@ -1449,12 +1492,22 @@ def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
     # sharding) next to the weights
     extras = _layer_extras(cfg)
     has_ex = bool(extras)
-    stack = (params["layers"], extras) if has_ex else params["layers"]
+    layers = params["layers"]
+    if grad_sink is not None:
+        if cfg.pp_axis is not None:
+            raise NotImplementedError(
+                "grad_sink under pipeline parallelism: the stages' scans "
+                "run inside pipeline_layers")
+        layers = jax.lax.stop_gradient(layers)
+    stack = (layers, extras) if has_ex else layers
 
     def stage(layer_params, x, pos):
         def body(carry, item):
-            x, aux = carry
+            x, aux, *sink = carry       # sink: (the stacks, this layer's index)
             lp, ex = item if has_ex else (item, {})
+            if sink:
+                lp, into = _grads_into(lp, *sink)
+                sink = (into, sink[1] + 1)
             # ZeRO++ qwZ per-layer fetch: when the quantized path left the
             # stacked leaves sharded, gather THIS layer's slice only
             # (runtime/zero/layer_gather.py) — stage-3 residency with
@@ -1463,11 +1516,13 @@ def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
             lp = apply_layer_gathers(lp)
             x, l_aux = layer_fn(x, lp, pos, ex.get("window"),
                                 ex.get("dense"))
-            return (x, aux + l_aux), None
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), layer_params,
+            return (x, aux + l_aux, *sink), None
+        sink = () if grad_sink is None else (grad_sink,
+                                             jnp.zeros((), jnp.int32))
+        (x, aux, *sink), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), *sink), layer_params,
             unroll=cfg.scan_unroll)
-        return x, aux
+        return (x, aux, *sink[:1])
 
     if cfg.pp_axis is not None:
         from ..runtime.pipeline.spmd import pipeline_layers
@@ -1475,26 +1530,28 @@ def _forward(cfg: TransformerConfig, params: PyTree, input_ids, positions=None,
             stage, stack, x, positions, axis_name=cfg.pp_axis,
             num_microbatches=cfg.pp_microbatches,
             schedule=cfg.pp_schedule)
+        sunk = []
     else:
-        x, moe_aux = stage(stack, x, positions)
+        x, moe_aux, *sunk = stage(stack, x, positions)
     with jax.named_scope("lm_head"):
         if cfg.final_norm:
             x = _norm(x, params["final_norm_scale"],
                       params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
         if return_hidden:
-            return x, moe_aux
+            return (x, moe_aux, *sunk)
         x = _head_hidden(params, x, dt)
         head = _lm_head(params)
         logits = jnp.einsum("bsh,hv->bsv", x, head.astype(dt),
                             preferred_element_type=jnp.float32)
         if "lm_head_bias" in params:
             logits = logits + params["lm_head_bias"]
-    return logits, moe_aux
+    return (logits, moe_aux, *sunk)
 
 
-def _lm_loss(cfg: TransformerConfig, params, batch, rng=None):
+def _lm_loss(cfg: TransformerConfig, params, batch, rng=None, grad_sink=None):
     """Next-token cross-entropy.  batch: {"input_ids": [B,S]} (labels default
-    to shifted inputs) or explicit {"input_ids", "labels", "mask"?}."""
+    to shifted inputs) or explicit {"input_ids", "labels", "mask"?}.
+    `grad_sink`: see `_forward`; returned third, after (loss, aux)."""
     ids = batch["input_ids"]
     labels = batch.get("labels")
     mask = batch.get("mask")
@@ -1521,14 +1578,17 @@ def _lm_loss(cfg: TransformerConfig, params, batch, rng=None):
         # ALST fused logits+loss: the [B,S,V] tensor is never materialized
         # (reference: TiledFusedLogitsLoss ulysses_sp.py:898)
         from ..sequence.tiled import tiled_fused_logits_loss
-        hidden, moe_aux = _forward(cfg, params, inputs, return_hidden=True)
+        hidden, moe_aux, *sink = _forward(cfg, params, inputs,
+                                          return_hidden=True,
+                                          grad_sink=grad_sink)
         with jax.named_scope("lm_head"):    # head and loss fused, by tile
             loss = tiled_fused_logits_loss(
                 hidden, _lm_head(params), labels,
                 shards=cfg.tiled_loss_shards, mask=mask,
                 bias=params.get("lm_head_bias"))
     else:
-        logits, moe_aux = _forward(cfg, params, inputs)
+        logits, moe_aux, *sink = _forward(cfg, params, inputs,
+                                          grad_sink=grad_sink)
         with jax.named_scope("loss"):
             logits = logits.astype(jnp.float32)
             logp = jax.nn.log_softmax(logits, axis=-1)
@@ -1544,7 +1604,7 @@ def _lm_loss(cfg: TransformerConfig, params, batch, rng=None):
     if cfg.moe_experts > 1:
         aux["moe_aux"] = moe_aux
         loss = loss + cfg.moe_aux_weight * moe_aux
-    return loss, aux
+    return (loss, aux, *sink)
 
 
 # ----------------------------------------------------------------------
@@ -1733,6 +1793,14 @@ class Transformer:
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
 
+    @property
+    def grad_sink(self) -> Optional[str]:
+        """The key of the stacked subtree of the parameters whose gradient
+        `loss_fn(..., grad_sink=)` adds into a sink, inside the backward
+        layer scan (`_forward`); the engine's accumulation loop asks.
+        None under pipeline parallelism: the stages run their own scans."""
+        return None if self.cfg.pp_axis is not None else "layers"
+
     def init_params(self, key) -> PyTree:
         return _init_params(key, self.cfg)
 
@@ -1753,9 +1821,9 @@ class Transformer:
                 f"is served through inference.v2 (build_engine -> "
                 f"ServeLoop) only")
 
-    def loss_fn(self, params, batch, rng=None):
+    def loss_fn(self, params, batch, rng=None, grad_sink=None):
         self.refuse_serving_only("Transformer.loss_fn (training, initialize())")
-        return _lm_loss(self.cfg, params, batch, rng)
+        return _lm_loss(self.cfg, params, batch, rng, grad_sink)
 
     def init_cache(self, batch: int, max_len: int):
         self.refuse_serving_only("Transformer.init_cache (the dense K/V cache)")

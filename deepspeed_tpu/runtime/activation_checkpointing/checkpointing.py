@@ -163,14 +163,18 @@ def remat_policy(name: Optional[str] = None,
         # kernel just to regenerate it, which is why the round-2 save_attn
         # gained nothing): ~2 bytes/token/layer/width + 4B/token/head
         "save_attn": cp.save_only_these_names(_ATTN_NAME, _LSE_NAME),
+        # (what is left to recompute: the q/k/v/output projections and
+        # the MLP up-projection, 8H^2 of a layer's 12H^2 forward matmul
+        # parameters; the down-projection's output feeds no gradient and is
+        # recomputed under no policy: tests/test_activation_checkpointing.py
+        # counts them in the step's HLO)
         # save_attn + the q/k/v/attn-out projection outputs: the layer
-        # backward recomputes only norms/rope/gelu and the attn-out + mlp-up
-        # matmuls (~10H^2 of 24H^2) instead of the whole forward
+        # backward recomputes only norms/rope/gelu and the mlp-up matmul
+        # (4H^2 of 12H^2)
         "save_attn_proj": cp.save_only_these_names(
             _ATTN_NAME, _LSE_NAME, _PROJ_NAME),
-        # + the MLP up-projection output: backward matmul recompute drops
-        # to the attn-out projection alone (~2H^2 of 24H^2) for an extra
-        # 2*ffn_size bytes/token/layer of saved residuals
+        # + the MLP up-projection output: no weight matmul is recomputed,
+        # for an extra 2*ffn_size bytes/token/layer of saved residuals
         "save_attn_proj_up": cp.save_only_these_names(
             _ATTN_NAME, _LSE_NAME, _PROJ_NAME, _MLP_UP_NAME),
         "offload": cp.save_and_offload_only_these_names(

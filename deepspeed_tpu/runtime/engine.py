@@ -501,6 +501,38 @@ class TrainEngine:
                 f"data_types.grad_accum_dtype {cfg.grad_accum_dtype!r} "
                 f"not supported (fp32 | bf16 | fp16)")
 
+        # a model whose loss adds its layer stack's gradient into a sink
+        # (models/transformer.py `_grads_into`) accumulates where the
+        # backward scan produces it: the accumulator is the only stacked
+        # tree alive, and the pass that added a micro-batch's whole tree to
+        # it is gone.  The paths that rework a micro-batch's gradient tree
+        # before it is added keep the tree
+        sink_key = getattr(loss_fn, "grad_sink", None)
+        if quantized_path or overlap_micro or comp_spec is not None:
+            sink_key = None
+
+        def add(acc, grads):
+            return jax.tree.map(lambda a, g: a + g.astype(gad), acc, grads)
+
+        def micro_accumulate(acc, params, micro, rng, loss_scale, comp_masks,
+                             step):
+            """One micro-batch: (loss, aux, `acc` + its gradient), every
+            leaf added once, as `acc + g`."""
+            if sink_key is None:
+                loss, aux, grads = micro_grads(params, micro, rng, loss_scale,
+                                               comp_masks, step)
+                return loss, aux, add(acc, grads)
+
+            def scaled_loss(p, sink):
+                loss, aux, sink = loss_fn(p, micro, rng, grad_sink=sink)
+                return (loss * loss_scale.astype(loss.dtype), sink), (loss, aux)
+            (scaled, _), vjp, (loss, aux) = jax.vjp(
+                scaled_loss, params, acc[sink_key], has_aux=True)
+            grads, sunk = vjp((jnp.ones_like(scaled), acc[sink_key]))
+            return loss, aux, {
+                k: sunk if k == sink_key else add(a, grads[k])
+                for k, a in acc.items()}
+
         def train_step(state: TrainState, batch: PyTree, rng,
                        comp_masks) -> Tuple[TrainState, Dict]:
             params = state.params
@@ -517,9 +549,9 @@ class TrainEngine:
                 def body(carry, micro):
                     acc, aux_acc, loss_sum, i = carry
                     k = jax.random.fold_in(rng, i)
-                    loss, aux, grads = micro_grads(params, micro, k, state.loss_scale,
-                                                   comp_masks, state.step)
-                    acc = jax.tree.map(lambda a, g: a + g.astype(gad), acc, grads)
+                    loss, aux, acc = micro_accumulate(
+                        acc, params, micro, k, state.loss_scale, comp_masks,
+                        state.step)
                     aux_acc = jax.tree.map(
                         lambda a, v: a + v.astype(jnp.float32), aux_acc, aux)
                     return (acc, aux_acc, loss_sum + loss.astype(jnp.float32),
@@ -555,8 +587,7 @@ class TrainEngine:
                     def body_overlap(carry, micro):
                         acc, raw_prev, aux_acc, loss_sum, i = carry
                         finished = micro_grads.finish(raw_prev)
-                        acc = jax.tree.map(
-                            lambda a, g: a + g.astype(gad), acc, finished)
+                        acc = add(acc, finished)
                         k = jax.random.fold_in(rng, i)
                         loss, aux, raw = micro_grads.raw(
                             params, micro, k, state.loss_scale, comp_masks,
@@ -572,9 +603,7 @@ class TrainEngine:
                             body_overlap,
                             (accum0, raw0, aux0, loss0,
                              jnp.ones((), jnp.int32)), rest)
-                    grads = jax.tree.map(
-                        lambda a, g: a + g.astype(gad), acc,
-                        micro_grads.finish(raw_last))
+                    grads = add(acc, micro_grads.finish(raw_last))
                     micro_losses = jnp.concatenate([loss0[None], rest_losses])
                     aux = jax.tree.map(lambda a: a / gas, aux_sum)
                     loss = loss_sum / gas
@@ -1104,14 +1133,19 @@ def initialize(
             "deepspeed_tpu.initialize (training)")
         if loss_fn is None:
             loss_fn = model.loss_fn
-            if getattr(model, "supports_layer_gather", False):
+            markers = {k: getattr(model, k) for k in
+                       ("supports_layer_gather", "grad_sink")
+                       if getattr(model, k, None)}
+            if markers:
                 # bound methods refuse attributes — wrap to carry the
-                # marker the quantized per-layer gather path checks
+                # markers the quantized per-layer gather path and the
+                # accumulation loop check
                 base_loss = loss_fn
 
-                def loss_fn(p, b, rng=None, _f=base_loss):
-                    return _f(p, b, rng)
-                loss_fn.supports_layer_gather = True
+                def loss_fn(p, b, rng=None, _f=base_loss, **kw):
+                    return _f(p, b, rng, **kw)
+                for k, v in markers.items():
+                    setattr(loss_fn, k, v)
         params = params if params is not None else model.init_params
         tp_rules = tp_rules or getattr(model, "tp_rules", None)
     if loss_fn is None or params is None:

@@ -255,3 +255,49 @@ def test_save_attn_skips_flash_forward_recompute(monkeypatch):
     for a, b in zip(grads["nothing_saveable"], grads["save_attn"]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+# policy -> the weight matmuls of a layer its backward pass computes again:
+# (under `attention`, the up-projection under `mlp`).  What a policy saves
+# it does not recompute; the down-projection's output feeds no gradient
+# and is recomputed under none of them
+@pytest.mark.parametrize("policy,attention,up", [
+    ("nothing_saveable", 4, 1),     # q, k, v, output; up-projection
+    ("save_attn", 4, 1),            # the attention kernel's output is saved
+    ("save_attn_proj", 0, 1),       # + the four projections' outputs
+    ("save_attn_proj_up", 0, 0),    # + the up-projection's
+])
+def test_recomputed_weight_matmuls_are_the_policys_own(policy, attention, up):
+    """The optimised HLO of a tiny OPT's whole train step (accumulation
+    over two micro-batches, the accumulator riding the backward layer
+    scan): the weight matmuls whose metadata lies under
+    `rematted_computation`, counted per layer call by scope and width."""
+    import re
+
+    import deepspeed_tpu as dstpu
+    from deepspeed_tpu.models import Transformer, get_model_config
+    from deepspeed_tpu.parallel.mesh import make_mesh
+    cfg = get_model_config("opt", "tiny", dtype=jnp.float32, remat=True)
+    eng = dstpu.initialize(
+        model=Transformer(cfg), topology=make_mesh(devices=jax.devices()[:1]),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 2,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 1}, "steps_per_print": 0,
+                "activation_checkpointing": {"policy": policy}})
+    batch = eng._shard_batch({"input_ids": np.zeros((4, 32), np.int32)})
+    hlo = eng._train_step.lower(eng.state, batch, jax.random.PRNGKey(0),
+                                {}).compile().as_text()
+    rows = 2 * 32
+    found = {"attention": 0, "up": 0, "down": 0}
+    for shape, scope in re.findall(
+            r"= (\S+?)\{[^ ]* dot\(.*op_name=\"[^\"]*rematted_computation/"
+            r"(attention|mlp)/bsh,hd->bsd/dot_general\"", hlo):
+        if scope == "attention":
+            assert shape == f"f32[{rows},{cfg.hidden_size}]", shape
+            found["attention"] += 1
+        else:
+            found["up" if shape == f"f32[{rows},{cfg.ffn_dim}]"
+                  else "down"] += 1
+    assert found == {"attention": attention, "up": up, "down": 0}
+    assert ".remat" not in hlo      # and XLA cloned nothing on its own

@@ -3,6 +3,8 @@
 Reference analogs: tests/unit/runtime/zero/test_zero.py (stage semantics),
 tests/unit/runtime/half_precision (loss scaling), simple_model.py fixtures.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -616,3 +618,79 @@ def test_train_step_compiles_once(fsdp, devices8, caplog, monkeypatch):
                 if "Finished XLA compilation of jit(train_step)"
                 in r.getMessage()]
     assert len(compiles) == 1, [r.getMessage()[:80] for r in compiles]
+
+
+def _tiny_opt():
+    from deepspeed_tpu.models import Transformer, get_model_config
+    model = Transformer(get_model_config("opt", "tiny", dtype=jnp.float32,
+                                         remat=True))
+    return model, model.init_params(jax.random.PRNGKey(0))
+
+
+@functools.lru_cache(maxsize=None)
+def _one_step(gas: int, given: str, clip=1.0, micro=2, seq=32):
+    """One `train_batch` of a tiny OPT, the engine given the `Transformer`
+    or its loss as a plain function: (engine, batch, what the step
+    returned, the gradients the optimizer got, the updated parameters)."""
+    from deepspeed_tpu.parallel.mesh import make_mesh
+    model, params = _tiny_opt()
+    kw = ({"model": model} if given == "model" else
+          {"loss_fn": lambda p, b, rng=None: model.loss_fn(p, b, rng)})
+    eng = dstpu.initialize(
+        params=params, topology=make_mesh(devices=jax.devices()[:1]),
+        config={"train_micro_batch_size_per_gpu": micro,
+                "gradient_accumulation_steps": gas,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": 1},
+                "gradient_clipping": clip, "steps_per_print": 0,
+                "activation_checkpointing": {"policy": "save_attn"}}, **kw)
+    eng.store_gradients = True
+    ids = np.random.RandomState(gas).randint(
+        0, model.cfg.vocab_size, (gas * micro, seq)).astype(np.int32)
+    got = eng.train_batch({"input_ids": ids})
+    return eng, ids.reshape(gas, micro, seq), jax.device_get(
+        ((got["loss"], got["micro_losses"], got["grad_norm"]),
+         eng._last_grads, eng.state.params))
+
+
+@pytest.mark.parametrize("given", ["model", "loss_fn"])
+@pytest.mark.parametrize("gas", [1, 2, 8])
+def test_accumulation_is_the_reference_sum_bit_for_bit(gas, given):
+    """Losses, gradient norm and the gradients the optimizer got against
+    an accumulation written here: every micro-batch's gradient tree added
+    whole, in order, as `acc + g`.  Given a `Transformer` the engine adds
+    each layer's gradient into the accumulator inside the backward layer
+    scan (`grad_sink`); given the same loss as a plain `loss_fn` it adds
+    the tree itself.  Both are the same sum, bit for bit, and the updated
+    parameters of the two are the same bits too (against an optimizer call
+    compiled outside the step they are one ulp apart: fused otherwise)."""
+    from deepspeed_tpu.utils import tree as tu
+    model, params = _tiny_opt()
+    eng, micros, (scalars, grads, updated) = _one_step(gas, given)
+    assert getattr(eng.loss_fn, "grad_sink", None) == (
+        "layers" if given == "model" else None)
+    clip = eng.config.gradient_clipping
+
+    @jax.jit
+    def reference(params, micros):
+        def body(carry, ids):
+            acc, total = carry
+            loss, g = jax.value_and_grad(
+                lambda p: model.loss_fn(p, {"input_ids": ids})[0])(params)
+            return (jax.tree.map(lambda a, g: a + g, acc, g),
+                    total + loss), loss
+        (acc, total), losses = jax.lax.scan(
+            body, (jax.tree.map(jnp.zeros_like, params), jnp.zeros(())),
+            micros)
+        norm = tu.global_norm(acc) * (1.0 / gas)
+        scale = (1.0 / gas) * jnp.minimum(1.0, clip / (norm + 1e-6))
+        return ((total / gas, losses, norm),
+                jax.tree.map(lambda g: g * scale, acc))
+
+    same = lambda a, b: jax.tree.map(  # noqa: E731
+        np.testing.assert_array_equal, a, jax.device_get(b))
+    want = reference(params, micros)
+    same(scalars, want[0])
+    same(grads, want[1])
+    other = "loss_fn" if given == "model" else "model"
+    same(updated, _one_step(gas, other)[2][2])

@@ -274,3 +274,96 @@ def test_fused_adam8_compiles(chip):
                    chip((shape[0], 1), f32), chip(shape, jnp.int8),
                    chip((shape[0], 1), f32), chip(shape, f32),
                    scalar, scalar, scalar, scalar) == 1
+
+
+# what the v5e compiler reported for the cell's step at PR 34, the
+# accumulator riding the backward layer scan (PERF.md section 5); before,
+# 16.407 GB: at the compiler's limit, where XLA's own rematerialisation
+# clones instructions (`*.remat`) to fit
+TRAIN_STEP_PEAK_BYTES = 14.201e9
+
+
+def test_train_step_of_the_opt_cell_fits_without_clones(topo, chip,
+                                                        monkeypatch):
+    """`opt-1.3b.train_1chip`'s own `jit_train_step` (OPT-1.3B whole,
+    `save_attn`, micro-batch 1 x 8, int8f moments, bf16 accumulation),
+    compiled for the described chip from abstract state: no instruction
+    named `*.remat`, XLA's sign that the program did not fit its memory
+    limit otherwise (at PR 33 the MLP up-projection ran a third time in
+    every layer of every micro-batch for it), and a peak within 3% of the
+    figure above.  The guard that keeps the step from drifting back to the
+    limit; the compile takes ~15 s."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models import Transformer, get_model_config
+    from deepspeed_tpu.parallel.mesh import make_mesh
+    from deepspeed_tpu.runtime import activation_checkpointing, engine
+    from deepspeed_tpu.runtime.zero.sharding import (opt_state_specs,
+                                                     param_specs)
+    from deepspeed_tpu.utils import device
+
+    def abstract_state(self, params):
+        """`TrainEngine._init_state` in shapes: a described chip holds no
+        array."""
+        mesh = self.topology.mesh
+        o_specs = opt_state_specs(self.rules, params)
+
+        def like(tree, shardings, dtype=None):
+            return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, dtype or x.dtype, sharding=s), tree, shardings)
+        master = like(params, self._named(o_specs), jnp.float32)
+        scalar = lambda dt: jax.ShapeDtypeStruct(  # noqa: E731
+            (), dt, sharding=NamedSharding(mesh, P()))
+        return engine.TrainState(
+            step=scalar(jnp.int32),
+            params=like(params, self._named(param_specs(self.rules, params)),
+                        self.compute_dtype),
+            master=master,
+            opt_state=like(jax.eval_shape(self.optimizer.init, master),
+                           self._opt_tree_shardings(params, o_specs)),
+            loss_scale=scalar(jnp.float32), good_steps=scalar(jnp.int32),
+            skipped_steps=scalar(jnp.int32))
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    monkeypatch.setattr(engine.TrainEngine, "_init_state", abstract_state)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "opt-1.3b.json")) as f:
+        prog = json.load(f)["program"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "train_1chip.json")) as f:
+        traffic = json.load(f)
+    model = Transformer(get_model_config(
+        prog["arch"], prog["size"], dtype=BF16, **prog["overrides"]))
+    try:
+        eng = ds.initialize(
+            model=model, config=dict(prog["training"]),
+            params=jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+            topology=make_mesh(devices=[topo.devices[0]]))
+        gas = eng.config.gradient_accumulation_steps
+        batch = {"input_ids": chip(
+            (gas, traffic["rows"] // gas, traffic["seq_len"] + 1), jnp.int32)}
+        with jax.default_matmul_precision("default"):
+            compiled = eng._train_step.lower(
+                eng.state, batch, chip((2,), jnp.uint32), {}).compile()
+    finally:
+        activation_checkpointing.reset()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 3      # the flash kernels
+    assert ".remat" not in hlo
+    # the q/k/v projections, forward and recomputed, are whole [S, H] x
+    # [H, H] matmuls: none writes 32 heads of 64 features (`_layer`), which
+    # runs at half the MXU's width.  The one left is the output
+    # projection's input gradient
+    split = re.findall(
+        r"= f32\[2048,32,64\]\S* convolution\(.*op_name=\"(\S+)\"", hlo)
+    assert len(split) <= 1 and all(
+        "transpose(jvp())" in at and "rematted" not in at
+        for at in split), split
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak < 1.03 * TRAIN_STEP_PEAK_BYTES, peak
