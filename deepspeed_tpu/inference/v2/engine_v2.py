@@ -1178,8 +1178,8 @@ class InferenceEngineV2:
         return (self.cfg.moe_experts > 1 and self._tpp is None
                 and not self._latent and not self._kinds)
 
-    # the latent block's router counters (latent_ops.COUNT_NAMES), a
-    # rider of its arena that every program accumulates
+    # the latent stacks' router counters (latent_ops.count_names), a
+    # rider of their arena that every program accumulates
     @property
     def supports_moe_counts(self) -> bool:
         return "moe_counts" in self.arena
@@ -1188,13 +1188,13 @@ class InferenceEngineV2:
         """Fetch-and-reset the router counters: ONE explicit d2h of a few
         int32 per drain (the serve loop's interval), ledgered like every
         other fetch."""
-        from .latent_ops import COUNT_NAMES
+        from .latent_ops import count_names
         counts = self.arena["moe_counts"]
         with span("engine.fetch", program="moe_counts", bytes=counts.nbytes):
             out = jax.device_get(counts)  # dstpu: noqa[DST001] intended: the periodic counter drain (a few int32 per interval), explicit so the transfer guard admits it
         self.profile["d2h_fetches"] += 1
         self.arena["moe_counts"] = jnp.zeros_like(counts)
-        return dict(zip(COUNT_NAMES, out.tolist()))
+        return dict(zip(count_names(self.cfg), out.tolist()))
 
     def enable_expert_paging(self, slots_per_layer: int,
                              spill: str = "none"):

@@ -1,15 +1,29 @@
-"""The latent-attention (MLA) shortcut-MoE double block over a paged latent
-arena: what `ragged_ops`' four layer bodies run for a `TransformerConfig`
-with `kv_lora_rank > 0` (LongCat-Flash).
+"""Latent-attention (MLA) MoE stacks over a paged latent arena: what
+`ragged_ops`' four layer bodies run for a `TransformerConfig` with
+`kv_lora_rank > 0`, in its two forms (`cfg.latent_form`).
 
-A layer is two attention + dense-FFN sub-blocks with the MoE branching off
-the first sub-block's post-attention norm and joining after the second FFN:
+"shortcut" (LongCat-Flash): a layer is two attention + dense-FFN sub-blocks
+with the MoE branching off the first sub-block's post-attention norm and
+joining after the second FFN:
 
     a0 = x + MLA0(rms(x));  h0 = rms(a0);  m = MoE(h0);  y0 = a0 + FFN0(h0)
     a1 = y0 + MLA1(rms(y0));  y1 = a1 + FFN1(rms(a1));  out = y1 + m
 
-The block is written once (`_forward`): the four bodies differ only in the
-rows they hand it and in the attention `form`:
+"single" (DeepSeek-V3): one attention a layer; the first
+`cfg.latent_dense_layers` layers follow it with a dense FFN, the others
+with the routed experts beside a shared expert:
+
+    a = x + MLA(rms(x));  h = rms(a)
+    out = a + FFN(h)                          a leading dense layer
+    out = a + (Shared(h) + MoE(h))            an expert layer
+
+The layer body is data (`_plans`): per sub-block whether a dense FFN follows
+its attention, whether the MoE (and a shared expert) branches off there; a
+layer's MoE part joins the residual at the layer's end.  `_forward` runs
+one scan per plan over that plan's own stacked leaves (the leading dense
+layers, then the expert layers), each with Python-static structure: no
+layer computes a branch it throws away.  The bodies differ only in the rows
+they hand it and in the attention `form`:
 
 - "fresh"  (`prefill_full`): whole prompts from position 0; K/V are
   decompressed from the tokens' own latents and go through the flash path
@@ -17,58 +31,74 @@ rows they hand it and in the attention `form`:
   next multiple of 128, the score scale folded into q);
 - "cached" (`prefill_chunks`): chunks against the arena, and "decode"
   (`_decode_core`): one token a row.  Both in the ABSORBED form through
-  the paged kernel `ops/mla_paged.py` (tiles of 8 queries x all heads, or
+  the paged kernel `ops/mla_paged.py` (tiles of queries x all heads, or
   one query's heads, against the row's arena blocks by block table).  On
   the CPU only: the same absorbed mathematics as a dense gather
   (`mla_paged_reference`).
 
+Under `cfg.rope_scaling` ("yarn") every rotation (fresh, cached, decode; q's
+rope part and the shared rope key) uses the blended inverse frequencies and
+the score scale carries the squared attention factor
+(`_yarn_score_factor`); without it the stack computes what it computed
+before the option existed.
+
 Chunk slots are padded (`[NC, C]` rows for at most the step's token budget
 of real tokens), so everything that works token by token (norms,
-projections, the dense FFNs, the router and the experts) runs over the real
-tokens only: a program of more than `ROW_TILE` rows moves the real ones to
-the front and takes them `ROW_TILE` at a time, for as many passes as they
-need (`_rows`).  Attention sees them back in their rows.
+projections, the dense FFNs, the router, the shared expert and the experts)
+runs over the real tokens only: a program of more than `ROW_TILE` rows
+moves the real ones to the front and takes them `ROW_TILE` at a time, for
+as many passes as they need (`_rows`).  Attention sees them back in their
+rows.
 
-Weights: `params["layers"]` holds, stacked over layers for the scan,
-`sub` (a list of the two sub-blocks' leaves), `moe_gate` and
-`moe_router_bias`; `params["experts"]` holds this chip's experts
-`[L, local, ...]` OUTSIDE the scan: the grouped matmuls take the whole
-stack with group sizes that are zero outside the layer at hand (a
+Weights: `params["layers"]` holds, stacked over the (expert) layers for the
+scan, `sub` (a list of the sub-blocks' leaves), `moe_gate`,
+`moe_router_bias` and, in the single form, `shared`; `params["dense_layers"]`
+the leading dense layers' `sub`; `params["experts"]` holds this chip's
+experts `[layers, local, ...]` OUTSIDE the scan: the grouped matmuls take
+the whole stack with group sizes that are zero outside the layer at hand (a
 per-layer slice handed to a custom call is first copied, 1.2 GB a layer
-at the cell's size: measured 29 of a 51 ms decode step).
+at LongCat's cell's size: measured 29 of a 51 ms decode step).
 
-The arena is ONE array `[2L, blocks, block_size, W]`: row `[c | rope(kr) |
-unused]` per token and attention (attention `2*layer + sub`), no V, nothing
-per head.  Block tables, the allocator and admission do not know: a block
-is still `block_size` tokens.  W is `kv_lora_rank + rope` rounded up to
-whole 128-lane tiles (576 -> 640), stated in the shape: the TPU tiles a
-576-wide minor dimension to 640 lanes anyway (as it would two arrays of 512
-and 64), and handed the unpadded shape XLA copies the whole arena into the
-tiled one before every kernel call (compiled for the v5e: 2.29 GB of
-temporaries per call at the cell's size, none with 640).
+The arena is ONE array `[A, blocks, block_size, W]`: row `[c | rope(kr) |
+unused]` per token and attention (A = `cfg.latent_attentions`: attention
+`2*layer + sub` of the double block, the layer's own number in the single
+form), no V, nothing per head.  Block tables, the allocator and admission
+do not know: a block is still `block_size` tokens.  W is `kv_lora_rank +
+rope` rounded up to whole 128-lane tiles (576 -> 640), stated in the shape:
+the TPU tiles a 576-wide minor dimension to 640 lanes anyway (as it would
+two arrays of 512 and 64), and handed the unpadded shape XLA copies the
+whole arena into the tiled one before every kernel call (compiled for the
+v5e: 2.29 GB of temporaries per call at LongCat's cell's size, none with
+640).
 
-The MoE holds a SHARE of the routed experts (`cfg.moe_expert_first`,
+The router is a description (`Router`, `router_of(cfg)`) read by one
+function (`_route`): softmax or sigmoid scores, a selection bias, the
+choice limited to the best few of equal expert groups, weights renormalised
+or not and scaled, identity outputs; the families' routers are values of
+it.  The MoE holds a SHARE of the routed experts (`cfg.moe_expert_first`,
 `cfg.local_experts`): it routes over every router output, runs grouped
 matmuls over the assignments to its own experts only, adds the identity
 experts' part for every token, and leaves the absent experts' part out
 (another chip's work; nothing stands in for it).  Rider `moe_counts`
-([len(COUNT_NAMES)] int32) accumulates what the router did, for
+([len(count_names(cfg))] int32) accumulates what the router did, for
 `InferenceEngineV2.drain_moe_counts`.
 """
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ...models.transformer import TransformerConfig
+from ...models.transformer import TransformerConfig, _scale_rope_freqs
 from .ragged_ops import (_dense, _embed, _gate_fused, _lm_logits,
                          _plain_mlp, greedy_tokens)
 
-__all__ = ["COUNT_NAMES", "COUNT_DRAIN_STEPS", "ROW_TILE",
-           "init_latent_arena", "prefill_full", "prefill_chunks",
-           "decode_core", "local_rows_cap", "refuse_lora"]
+__all__ = ["COUNT_NAMES", "GROUP_COUNT_NAMES", "COUNT_DRAIN_STEPS", "ROW_TILE",
+           "Router", "router_of", "count_names", "init_latent_arena",
+           "prefill_full", "prefill_chunks", "decode_core", "local_rows_cap",
+           "refuse_lora"]
 
 # rows a token-wise pass takes at once (see `_rows`)
 ROW_TILE = 1024
@@ -79,16 +109,43 @@ ROW_TILE = 1024
 # busiest local expert's rows, summed; router calls (layers x programs)
 COUNT_NAMES = ("picks", "zero_picks", "local_rows", "busiest_rows",
                "router_calls")
-# serve steps between two drains of it (`ServeLoop`: one 20-byte fetch)
+# and, after them, where the router has groups: valid tokens scored (summed
+# over layers); those whose kept groups include a group this chip holds
+# experts of
+GROUP_COUNT_NAMES = ("router_tokens", "group_hit_tokens")
+# serve steps between two drains of it (`ServeLoop`: one small fetch)
 COUNT_DRAIN_STEPS = 16
+
+
+class Router(NamedTuple):
+    """What a router does with its logits `[T, experts + identity]`."""
+    scores: str              # "softmax" | "sigmoid" of the logits
+    bias: bool               # a bias buffer enters the selection only
+    groups: int              # the experts in this many equal groups (0: none)
+    groups_kept: int         # ... of which the best few may be picked from
+    renormalise: bool        # the picks' weights sum to 1 before the scale
+    scale: float
+    identity: int            # outputs past the experts that return their input
+
+
+def router_of(cfg: TransformerConfig) -> Router:
+    return Router(cfg.moe_router_scores, cfg.moe_router_bias,
+                  cfg.moe_router_groups, cfg.moe_router_groups_kept,
+                  cfg.moe_norm_topk_prob, cfg.moe_routed_scaling,
+                  cfg.moe_zero_experts)
+
+
+def count_names(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The names of `moe_counts`' entries: its length follows the router."""
+    return COUNT_NAMES + (GROUP_COUNT_NAMES if cfg.moe_router_groups else ())
 
 
 def init_latent_arena(cfg: TransformerConfig, num_blocks: int,
                       block_size: int):
     width = -(-cfg.latent_width // 128) * 128
-    return {"c": jnp.zeros((2 * cfg.num_layers, num_blocks, block_size,
+    return {"c": jnp.zeros((cfg.latent_attentions, num_blocks, block_size,
                             width), cfg.dtype),
-            "moe_counts": jnp.zeros((len(COUNT_NAMES),), jnp.int32)}
+            "moe_counts": jnp.zeros((len(count_names(cfg)),), jnp.int32)}
 
 
 def refuse_lora(lora) -> None:
@@ -104,15 +161,32 @@ def _rms(x, scale, eps: float, mult: float = 1.0):
     return (out * (scale.astype(jnp.float32) * mult)).astype(x.dtype)
 
 
-def _rope_pairs(x, positions, theta: float):
+def _yarn_score_factor(cfg: TransformerConfig) -> float:
+    """What `cfg.rope_scaling` puts on the attention scores.  YaRN here is
+    the latent models' form: blended inverse frequencies
+    (`_scale_rope_freqs`), cos and sin times the attention factor the
+    configuration states, and m^2 on the scores, m = 0.1 mscale_all_dim
+    ln(factor) + 1.  1.0 without a scaling."""
+    if cfg.rope_scaling is None:
+        return 1.0
+    m = 0.1 * cfg.mla_yarn_mscale_all_dim * math.log(cfg.rope_scaling[1]) + 1
+    return m * m
+
+
+def _rope_pairs(x, positions, theta: float, scaling=None):
     """Rotate the pairs (2i, 2i+1) of x [T, ..., D] by positions [T] *
-    theta^(-2i/D) (the interleaved convention)."""
+    theta^(-2i/D) (the interleaved convention); under `scaling`
+    (`cfg.rope_scaling`) by the blended frequencies."""
     half = x.shape[-1] // 2
     freqs = jnp.exp(-math.log(theta)
                     * jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is not None:
+        freqs = _scale_rope_freqs(freqs, scaling, theta)
     ang = positions.astype(jnp.float32)[:, None] * freqs
     ang = ang.reshape((ang.shape[0],) + (1,) * (x.ndim - 2) + (half,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling[2] != 1.0:
+        cos, sin = cos * scaling[2], sin * scaling[2]
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -123,8 +197,9 @@ def _rope_pairs(x, positions, theta: float):
 # attention
 # ----------------------------------------------------------------------
 def _use_latent_kernel(cfg: TransformerConfig, bs: int, queries: int) -> bool:
-    from ...ops.mla_paged import QUERIES_PER_STEP as tq
+    from ...ops.mla_paged import queries_per_step
     from ...utils.device import on_tpu
+    tq = queries_per_step(cfg.num_heads)
     return _gate_fused(
         cfg, on_tpu() and bs % 8 == 0 and queries % min(queries, tq) == 0,
         reason=f"attn_impl='pallas' requested but the paged latent "
@@ -147,7 +222,9 @@ def _attend_fresh(cfg, q, c, kr, w_kvb):
     pad = lambda t: jnp.pad(  # noqa: E731
         t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
     # the attention path scales by 1/sqrt(its head width): fold the rest in
-    q = (q.astype(jnp.float32) * math.sqrt(width / dqk)).astype(q.dtype)
+    q = (q.astype(jnp.float32)
+         * (math.sqrt(width / dqk) * _yarn_score_factor(cfg))
+         ).astype(q.dtype)
     out = causal_attention(pad(q), pad(k), pad(kv[..., dn:]),
                            impl=cfg.attn_impl)
     return out[..., :dv]
@@ -168,7 +245,7 @@ def _attend_absorbed(cfg, q, arena_c, index, block_tables, pos0, n_valid,
           if _use_latent_kernel(cfg, arena_c.shape[2], S)
           else mla_paged.mla_paged_reference)
     u = fn(q_abs, q[..., dn:], arena_c, block_tables, pos0, n_valid, index,
-           sm_scale=1.0 / math.sqrt(q.shape[-1]))
+           sm_scale=_yarn_score_factor(cfg) / math.sqrt(q.shape[-1]))
     return jnp.einsum("rsnc,cnd->rsnd", u, w[..., dn:],
                       preferred_element_type=jnp.float32).astype(u.dtype)
 
@@ -186,30 +263,53 @@ def local_rows_cap(assignments: int, local: int, outputs: int) -> int:
     return min(assignments, max(16, -(-int(even) // 16) * 16))
 
 
+def _route(r: Router, logits, bias, k: int):
+    """The router's one reading of its description.  logits [T, experts +
+    r.identity] float32 -> (picks [T, k] int32, their weights [T, k]
+    float32, kept [T, r.groups] bool: the groups a token may pick from, or
+    None without groups)."""
+    score = (jax.nn.sigmoid(logits) if r.scores == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+    choose = score
+    if r.bias:                         # the bias picks, it does not weigh
+        choose = score + bias.astype(jnp.float32)
+    kept = None
+    if r.groups:
+        with jax.named_scope("router_groups"):
+            T, outputs = logits.shape
+            per = (outputs - r.identity) // r.groups
+            # a group counts by the sum of its 2 best biased scores
+            best2, _ = jax.lax.top_k(choose.reshape(T, r.groups, per), 2)
+            _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), r.groups_kept)
+            kept = jnp.any(
+                keep[:, :, None] == jnp.arange(r.groups)[None, None], axis=1)
+            choose = jnp.where(jnp.repeat(kept, per, axis=1), choose,
+                               -jnp.inf)
+    _, topi = jax.lax.top_k(choose, k)                        # [T, k]
+    weight = jnp.take_along_axis(score, topi, axis=1)
+    if r.renormalise:
+        weight = weight / jnp.maximum(
+            jnp.sum(weight, axis=1, keepdims=True), 1e-9)
+    return topi, weight * r.scale, kept
+
+
 def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
          router_in=None):
     """h [T, H] -> (MoE(h) [T, H] over the experts held here and the
-    identity experts, counts [len(COUNT_NAMES)] int32).  `experts`: the
-    whole `[L * local, ...]` stacks; `li`: the layer at hand; `router_in`
-    [T, H]: what the router scores where that is not `h` (a router on the
-    layer's input).  The experts' gate is ReLU for `reglu`, else SiLU."""
+    identity experts, counts [len(count_names(cfg))] int32).  `experts`:
+    the whole `[layers * local, ...]` stacks; `li`: the layer at hand
+    among them; `router_in` [T, H]: what the router scores where that is
+    not `h` (a router on the layer's input).  The experts' gate is ReLU
+    for `reglu`, else SiLU."""
     T, H = h.shape
     dt, k = h.dtype, cfg.moe_top_k
     E, first, El = cfg.moe_experts, cfg.moe_expert_first, cfg.local_experts
     gate_act = jax.nn.relu if cfg.activation == "reglu" else jax.nn.silu
+    r = router_of(cfg)
     with jax.named_scope("router"):
         logits = (h if router_in is None else router_in).astype(
             jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
-        score = jax.nn.softmax(logits, axis=-1)               # [T, E + Z]
-        choose = score
-        if cfg.moe_router_bias:        # the bias picks, it does not weigh
-            choose = score + lp["moe_router_bias"].astype(jnp.float32)
-        _, topi = jax.lax.top_k(choose, k)                    # [T, k]
-        weight = jnp.take_along_axis(score, topi, axis=1)
-        if cfg.moe_norm_topk_prob:
-            weight = weight / jnp.maximum(
-                jnp.sum(weight, axis=1, keepdims=True), 1e-9)
-        weight = weight * cfg.moe_routed_scaling
+        topi, weight, kept = _route(r, logits, lp.get("moe_router_bias"), k)
     if cfg.moe_zero_experts:
         with jax.named_scope("zero_experts"):
             is_zero = topi >= E
@@ -256,15 +356,48 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
         routed = jax.lax.fori_loop(0, (n_local + cap - 1) // cap, piece,
                                    jnp.zeros((T, H), jnp.float32))
     picked = picked.reshape(T, k)
-    counts = jnp.stack([
-        jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
-        jnp.max(sizes), jnp.ones((), jnp.int32)]).astype(jnp.int32)
+    counts = [jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
+              jnp.max(sizes), jnp.ones((), jnp.int32)]
+    if r.groups:
+        per = E // r.groups          # the groups this share has experts of
+        mine = kept[:, first // per:(first + El - 1) // per + 1]
+        counts += [jnp.sum(tok_valid),
+                   jnp.sum(tok_valid & jnp.any(mine, axis=1))]
+    counts = jnp.stack(counts).astype(jnp.int32)
     return (routed + zero_part).astype(dt), counts
 
 
 # ----------------------------------------------------------------------
-# the double layer, once
+# the layer, once: its body is data
 # ----------------------------------------------------------------------
+class SubBlock(NamedTuple):
+    """One attention of a layer and what follows its output projection."""
+    dense_ffn: bool      # a dense FFN on the post-attention norm
+    moe: bool            # the routed experts branch off that norm; their
+    #                      part joins the residual at the layer's end
+    shared: bool         # ... beside a shared expert on every token
+    reads: tuple         # what the token-wise stage after the attention
+    #                      takes, in this order: the residual `y`, an earlier
+    #                      sub-block's experts' part `m`, the attention's
+    #                      output `o`, `pos` where the next sub-block's
+    #                      projections follow, `real` where it routes
+
+
+def _plans(cfg: TransformerConfig):
+    """The stack as ((params key, first layer, layers, sub-blocks), ...):
+    one layer scan each, in order."""
+    L, Ld = cfg.num_layers, cfg.latent_dense_layers
+    if cfg.latent_form == "shortcut":
+        return (("layers", 0, L, (
+            SubBlock(True, True, False, ("y", "o", "pos", "real")),
+            SubBlock(True, False, False, ("y", "m", "o")))),)
+    dense = SubBlock(True, False, False, ("y", "o"))
+    moe = SubBlock(False, True, bool(cfg.moe_shared_expert_ffn),
+                   ("y", "o", "real"))
+    return ((("dense_layers", 0, Ld, (dense,)),) if Ld else ()) + (
+        ("layers", Ld, L - Ld, (moe,)),)
+
+
 def _rows(fn, n, ins, extra, row_tile: int = 0):
     """Token-wise work over the first `n` rows of `ins` ([T, ...] arrays,
     real rows in front), `row_tile` (`ROW_TILE`) rows at a time for as many
@@ -302,12 +435,13 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
     R, S = tokens.shape
     H, T, NH, dt = cfg.hidden_size, R * S, cfg.num_heads, cfg.dtype
     dn, dr, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    L, El = cfg.num_layers, cfg.local_experts
+    El = cfg.local_experts
     nb, bs, Wa = arena["c"].shape[1:]
     MB = block_tables.shape[1]
     s_q = math.sqrt(H / cfg.q_lora_rank) if cfg.mla_scale_q_lora else 1.0
     s_kv = math.sqrt(H / rank) if cfg.mla_scale_kv_lora else 1.0
-    experts = {n: w.reshape((L * El,) + w.shape[2:]).astype(dt)
+    yarn = cfg.rope_scaling
+    experts = {n: w.reshape((w.shape[0] * El,) + w.shape[2:]).astype(dt)
                for n, w in params["experts"].items()}
 
     blk = jnp.take_along_axis(block_tables,
@@ -339,11 +473,11 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
                       cfg.norm_eps, s_q)
             q = _dense(cq, sp["wq_b"]).reshape(-1, NH, dn + dr)
             q = jnp.concatenate(
-                [q[..., :dn], _rope_pairs(q[..., dn:], pos, cfg.rope_theta)],
-                -1)
+                [q[..., :dn],
+                 _rope_pairs(q[..., dn:], pos, cfg.rope_theta, yarn)], -1)
             ckv = _dense(t, sp["wkv_a"])
             c = _rms(ckv[:, :rank], sp["kv_a_norm_scale"], cfg.norm_eps, s_kv)
-            kr = _rope_pairs(ckv[:, rank:], pos, cfg.rope_theta)
+            kr = _rope_pairs(ckv[:, rank:], pos, cfg.rope_theta, yarn)
             row = jnp.pad(jnp.concatenate([c, kr], -1).astype(dt),
                           ((0, 0), (0, Wa - rank - dr)))
         return q.reshape(-1, NH * (dn + dr)), row
@@ -374,40 +508,75 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
         with jax.named_scope("mla_proj"):
             return _dense(o, sp["wo"])
 
-    def layer(carry, xs):
-        x, arena_c, counts = carry
-        lp, li = xs
-        sp0, sp1 = lp["sub"]
+    def layer_of(subs, first: int):
+        """The scan body of layers made of `subs` (`SubBlock`s).  A layer
+        is token-wise stages with an attention between two of them; stage
+        k closes sub-block k - 1 (output projection, norm, its experts and
+        dense FFN) and opens sub-block k (its projections); the last adds
+        the experts' part, which rode along since its sub-block."""
+        A, last = len(subs), len(subs) - 1
 
-        def before(x, pos):
-            return project(sp0, _rms(x, sp0["attn_norm_scale"],
-                                     cfg.norm_eps), pos), none
+        def arena_index(li, k: int):
+            """Attention k of this stack's layer li.  (No `+ 0`, no `* 1`:
+            the double block's programs stay the ones they were,
+            instruction for instruction.)"""
+            at = li + first if first else li
+            at = A * at if A > 1 else at
+            return at + k if k else at
 
-        def between(x, o, pos, real):
-            a0 = x + out_proj(sp0, o)
-            h0 = _rms(a0, sp0["mlp_norm_scale"], cfg.norm_eps)
-            m, c = _moe(cfg, lp, experts, li, h0, real)
-            y0 = a0 + ffn(sp0, h0)
-            q, row = project(sp1, _rms(y0, sp1["attn_norm_scale"],
-                                       cfg.norm_eps), pos)
-            return (y0, m, q, row), c
+        def layer(carry, xs):
+            x, arena_c, counts = carry
+            lp, li = xs                   # li: the layer's place in ITS stack
 
-        def after(y0, m, o):
-            a1 = y0 + out_proj(sp1, o)
-            y1 = a1 + ffn(sp1, _rms(a1, sp1["mlp_norm_scale"], cfg.norm_eps))
-            return (y1 + m,), none
+            def before(x, pos):
+                sp = lp["sub"][0]
+                return project(sp, _rms(x, sp["attn_norm_scale"],
+                                        cfg.norm_eps), pos), none
 
-        (q, row), _ = _rows(before, n, (x, pos), none)
-        o, arena_c = attend(sp0, 2 * li, q, row, arena_c)
-        (y0, m, q, row), counts = _rows(between, n, (x, o, pos, real),
-                                        counts)
-        o, arena_c = attend(sp1, 2 * li + 1, q, row, arena_c)
-        (x,), _ = _rows(after, n, (y0, m, o), none)
-        return (x, arena_c, counts), None
+            def closing(k: int):
+                """Stage k + 1, on the operands `subs[k].reads`."""
+                sub, sp = subs[k], lp["sub"][k]
 
-    (x, arena_c, counts), _ = jax.lax.scan(
-        layer, (x, arena["c"], arena["moe_counts"]),
-        (params["layers"], jnp.arange(L)))
+                def stage(*ins):
+                    v = dict(zip(sub.reads, ins))
+                    a = v["y"] + out_proj(sp, v["o"])
+                    h = _rms(a, sp["mlp_norm_scale"], cfg.norm_eps)
+                    m, c = v.get("m"), none
+                    if sub.moe:
+                        m, c = _moe(cfg, lp, experts, li, h, v["real"])
+                        if sub.shared:
+                            with jax.named_scope("shared_expert"):
+                                m = _plain_mlp(cfg, lp["shared"], h) + m
+                    y = a + ffn(sp, h) if sub.dense_ffn else a
+                    if k == last:
+                        return (y if m is None else y + m,), c
+                    nxt = lp["sub"][k + 1]
+                    q, row = project(nxt, _rms(y, nxt["attn_norm_scale"],
+                                               cfg.norm_eps), v["pos"])
+                    return (y,) + (() if m is None else (m,)) + (q, row), c
+                return stage
+
+            (q, row), _ = _rows(before, n, (x, pos), none)
+            held = {"y": x, "pos": pos, "real": real}
+            for k, sub in enumerate(subs):
+                held["o"], arena_c = attend(lp["sub"][k], arena_index(li, k),
+                                            q, row, arena_c)
+                outs, e = _rows(closing(k), n,
+                                tuple(held[name] for name in sub.reads),
+                                counts if sub.moe else none)
+                if sub.moe:
+                    counts = e
+                if k < last:
+                    *rest, q, row = outs
+                    held.update(zip(("y", "m"), rest))
+            return (outs[0], arena_c, counts), None
+        return layer
+
+    carry = (x, arena["c"], arena["moe_counts"])
+    for key, first, count, subs in _plans(cfg):
+        carry, _ = jax.lax.scan(layer_of(subs, first), carry,
+                                (params[key], jnp.arange(count)))
+    x, arena_c, counts = carry
     return in_rows(x), {**arena, "c": arena_c, "moe_counts": counts}
 
 

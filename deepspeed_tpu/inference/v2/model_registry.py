@@ -37,6 +37,7 @@ ARCH_REGISTRY = {
     "bloom": "bloom",
     "gptneox": "gptneox",
     "longcat_flash": "longcat_flash",
+    "deepseek_v3": "deepseek_v3",
     "smallthinker": "smallthinker",
 }
 
@@ -102,9 +103,10 @@ def check_serving_moe(model_config, serving_config) -> None:
     if getattr(model_config, "latent", False):
         raise ValueError(
             "serving.moe (expert paging) pages whole experts of a model "
-            "that holds them all; the latent-attention MoE block holds a "
-            "fixed share of its experts (moe_expert_first/count) and "
-            "routes the rest to other chips — drop serving.moe")
+            "that holds them all; a latent-attention MoE stack (either "
+            "form) holds a fixed share of its experts "
+            "(moe_expert_first/count) and routes the rest to other chips "
+            "— drop serving.moe")
     if getattr(model_config, "static_kinds", False):
         raise ValueError(
             "serving.moe (expert paging) swaps experts in the slot stacks "

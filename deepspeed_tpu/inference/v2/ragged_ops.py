@@ -606,7 +606,7 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
     flash-capable too — otherwise causal_attention would SILENTLY serve
     the jnp reference here while the chunked path raises, violating the
     no-silent-fallback contract (_gate_fused); such configs stay chunked
-    (and get that loud error).  The latent block pads its own head
+    (and get that loud error).  A latent stack pads its own head
     widths for the flash path (latent_ops._attend_fresh).  A static-kind
     stack has one prefill program: a fresh prompt is a chunk at position 0
     of `ops/chunk_attention.py`, which has the window the flash kernel

@@ -18,6 +18,7 @@ from .transformer import (
     bloom_config,
     gptneox_config,
     longcat_flash_config,
+    deepseek_v3_config,
     smallthinker_config,
 )
 
@@ -37,6 +38,7 @@ MODEL_FAMILIES = {
     "bloom": bloom_config,
     "gptneox": gptneox_config,
     "longcat_flash": longcat_flash_config,
+    "deepseek_v3": deepseek_v3_config,
     "smallthinker": smallthinker_config,
 }
 
@@ -58,5 +60,5 @@ __all__ = [
     "qwen2_config", "qwen2_moe_config", "phi_config", "phi3_config",
     "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
-    "smallthinker_config",
+    "deepseek_v3_config", "smallthinker_config",
 ]

@@ -46,6 +46,7 @@ __all__ = [
     "smallthinker_config",
     "phi_config", "phi3_config", "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
+    "deepseek_v3_config",
 ]
 
 
@@ -161,10 +162,11 @@ class TransformerConfig:
     # [latent | rotary key] per token and attention, nothing per head
     # (inference/v2/latent_ops.py).  mla_scale_*: multiply q by
     # sqrt(hidden/q_lora_rank) and the latent by sqrt(hidden/kv_lora_rank).
-    # A latent layer rotates the pairs (2i, 2i+1) and is the
-    # shortcut-connected double block: TWO attention + dense-FFN
-    # sub-blocks; the MoE reads the first sub-block's post-attention norm
-    # and its output joins the residual after the second FFN
+    # A latent layer rotates the pairs (2i, 2i+1).  Under `rope_scaling`
+    # ("yarn", factor, attention_factor, beta_fast, beta_slow, original
+    # length) it rotates by the blended frequencies, scales cos/sin by
+    # attention_factor and its scores by m^2, m = 0.1 mla_yarn_mscale_all_dim
+    # ln(factor) + 1 (the softmax temperature of the long context)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -172,13 +174,34 @@ class TransformerConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    mla_yarn_mscale_all_dim: float = 0.0
+    # the latent stack's form.  "shortcut": the shortcut-connected double
+    # block: TWO attention + dense-FFN sub-blocks a layer; the MoE reads
+    # the first sub-block's post-attention norm and its output joins the
+    # residual after the second FFN.  "single": ONE attention a layer, then
+    # a dense FFN on the first `latent_dense_layers` layers (a static
+    # prefix of the stack with its own stacked weights, not
+    # `moe_dense_layers`' both-branch select) and, on the others, the
+    # routed experts beside a shared expert of `moe_shared_expert_ffn`
+    # (plain, on every token, no gate of its own)
+    latent_form: str = "shortcut"               # shortcut | single
+    latent_dense_layers: int = 0
     moe_expert_ffn: int = 0         # routed experts' width (intermediate_size
                                     # is the dense FFNs')
-    # router outputs past moe_experts that return their input ("identity"
-    # zero-compute experts); selection by score + a bias buffer, weights
-    # from the unbiased score times moe_routed_scaling
+    # the latent stack's router, as data (inference/v2/latent_ops.py
+    # `router_of`): scores softmax or sigmoid of the logits; router outputs
+    # past moe_experts that return their input ("identity" zero-compute
+    # experts); selection by score + a bias buffer; the choice limited to
+    # the experts of the `moe_router_groups_kept` best of
+    # `moe_router_groups` equal groups, a group scored by the sum of its 2
+    # largest biased scores (0: no groups); weights from the unbiased
+    # score (renormalised over the picks under moe_norm_topk_prob) times
+    # moe_routed_scaling
+    moe_router_scores: str = "softmax"          # softmax | sigmoid
     moe_zero_experts: int = 0
     moe_router_bias: bool = False
+    moe_router_groups: int = 0
+    moe_router_groups_kept: int = 0
     moe_routed_scaling: float = 1.0
     # the share of the routed experts THIS program holds: experts
     # [first, first + count) (count 0: all).  The router still scores
@@ -277,12 +300,52 @@ class TransformerConfig:
                     and self.activation == "swiglu"
                     and self.sliding_window is None
                     and self.sliding_window_layers is None
-                    and not self.qkv_bias and self.tie_embeddings is False):
+                    and self.moe_dense_layers is None
+                    and not self.qkv_bias and self.tie_embeddings is False
+                    and (self.rope_scaling is None
+                         or self.rope_scaling[0] == "yarn")):
                 raise ValueError(
-                    "latent attention is served inside the shortcut-connected "
-                    "MoE double block only (moe_experts > 1, "
-                    "moe_expert_ffn, rope, rmsnorm, swiglu, no window, no "
-                    "qkv bias, untied head)")
+                    "latent attention is served in two forms of MoE stack, "
+                    "the shortcut-connected double block and the "
+                    "single-attention layer with leading dense layers "
+                    "(latent_form), both with moe_experts > 1, "
+                    "moe_expert_ffn, rope (plain or yarn), rmsnorm, swiglu, "
+                    "no window, no qkv bias, no moe_dense_layers, untied "
+                    "head")
+            single = self.latent_form == "single"
+            if self.latent_form not in ("shortcut", "single") or not (
+                    0 <= self.latent_dense_layers
+                    < (self.num_layers if single else 1)):
+                raise ValueError(
+                    f"latent_form {self.latent_form!r} with "
+                    f"{self.latent_dense_layers} leading dense of "
+                    f"{self.num_layers} layers: the form is 'shortcut' "
+                    f"(every layer a double block, no dense prefix) or "
+                    f"'single' (a dense prefix shorter than the stack)")
+            if self.moe_shared_expert_ffn and not single:
+                raise ValueError(
+                    "the shortcut-connected double block has no shared "
+                    "expert (its dense FFNs run on every token); "
+                    "moe_shared_expert_ffn belongs to latent_form='single'")
+            if self.moe_router_scores not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"moe_router_scores must be 'softmax' or 'sigmoid', "
+                    f"got {self.moe_router_scores!r}")
+            if self.moe_router_groups and not (
+                    self.moe_experts % self.moe_router_groups == 0
+                    and 0 < self.moe_router_groups_kept
+                    <= self.moe_router_groups
+                    and self.moe_experts // self.moe_router_groups >= 2
+                    and not self.moe_zero_experts
+                    and self.moe_top_k <= self.moe_router_groups_kept
+                    * (self.moe_experts // self.moe_router_groups)):
+                raise ValueError(
+                    f"moe_router_groups={self.moe_router_groups} (kept "
+                    f"{self.moe_router_groups_kept}) must divide the "
+                    f"{self.moe_experts} routed experts into groups of at "
+                    f"least 2, keep between 1 and all of them, leave "
+                    f"top_k={self.moe_top_k} experts to pick among the "
+                    f"kept, and stand without identity experts")
             if not (0 <= self.moe_expert_first
                     and self.moe_expert_first + self.local_experts
                     <= self.moe_experts):
@@ -291,11 +354,15 @@ class TransformerConfig:
                     f"{self.moe_expert_first + self.local_experts}) are not "
                     f"among the {self.moe_experts} routed experts")
         elif (self.moe_zero_experts or self.moe_router_bias
-              or self.moe_expert_count or self.moe_expert_first):
+              or self.moe_expert_count or self.moe_expert_first
+              or self.moe_router_groups or self.latent_dense_layers
+              or self.moe_router_scores != "softmax"):
             raise ValueError(
-                "moe_zero_experts, moe_router_bias and the expert share "
-                "(moe_expert_first/count) exist only in the "
-                "latent-attention double block (kv_lora_rank > 0)")
+                "moe_zero_experts, moe_router_bias, moe_router_scores, "
+                "moe_router_groups, latent_dense_layers and the expert "
+                "share (moe_expert_first/count) exist only in the "
+                "latent-attention double block and the single-attention "
+                "latent layer (kv_lora_rank > 0)")
         if self.rope_layers is not None:
             wins = set(self.sliding_window_layers or ())
             if not (len(self.rope_layers) == self.num_layers
@@ -338,6 +405,11 @@ class TransformerConfig:
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def latent_attentions(self) -> int:
+        """Attentions of a latent stack (rows of its arena a token)."""
+        return self.num_layers * (2 if self.latent_form == "shortcut" else 1)
 
     @property
     def latent_width(self) -> int:
@@ -617,6 +689,42 @@ def longcat_flash_config(size: str = "chat", **kw) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def deepseek_v3_config(size: str = "v3", **kw) -> TransformerConfig:
+    """DeepSeek-V3 (deepseek-ai/DeepSeek-V3 config.json): latent attention
+    in single-attention layers, the first 3 with a dense FFN, the others
+    with 256 routed experts (8 a token, a sigmoid router limited to 4 of 8
+    expert groups) beside a shared expert; YaRN over 4096 positions.
+    Serving only (inference/v2/latent_ops.py); the multi-token-prediction
+    module is not part of the served stack."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=4, num_heads=4,
+                     max_seq_len=512, vocab_size=512, intermediate_size=128,
+                     q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, moe_experts=16,
+                     moe_top_k=4, moe_expert_ffn=32,
+                     moe_shared_expert_ffn=32, latent_dense_layers=1,
+                     moe_router_groups=4, moe_router_groups_kept=2,
+                     rope_scaling=("yarn", 8.0, 1.0, 32.0, 1.0, 64)),
+        "v3": dict(hidden_size=7168, num_layers=61, num_heads=128,
+                   max_seq_len=163840, vocab_size=129280,
+                   intermediate_size=18432, q_lora_rank=1536,
+                   kv_lora_rank=512, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128, moe_experts=256,
+                   moe_top_k=8, moe_expert_ffn=2048,
+                   moe_shared_expert_ffn=2048, latent_dense_layers=3,
+                   moe_router_groups=8, moe_router_groups_kept=4,
+                   rope_scaling=("yarn", 40.0, 1.0, 32.0, 1.0, 4096)),
+    }
+    base = dict(pos_emb="rope", norm="rmsnorm", activation="swiglu",
+                tie_embeddings=False, rope_theta=1e4, norm_eps=1e-6,
+                latent_form="single", moe_router_scores="sigmoid",
+                moe_router_bias=True, moe_routed_scaling=2.5,
+                moe_norm_topk_prob=True, mla_yarn_mscale_all_dim=1.0)
+    base.update(presets[size])
+    base.update(kw)
+    return TransformerConfig(**base)
+
+
 def smallthinker_config(size: str = "21b-a3b", **kw) -> TransformerConfig:
     """SmallThinker (PowerInfer/SmallThinker-21BA3B-Instruct config.json):
     window layers with rope and global layers with no position encoding
@@ -682,9 +790,13 @@ def _init_kinds_params(key, cfg: TransformerConfig) -> PyTree:
 
 
 def _init_latent_params(key, cfg: TransformerConfig) -> PyTree:
-    """Random weights in the latent double block's layout (the leaves
-    `inference/v2/latent_ops.py` reads): per layer the two sub-blocks'
-    leaves (`sub`), the router and its bias; this chip's experts apart."""
+    """Random weights in a latent stack's layout (the leaves
+    `inference/v2/latent_ops.py` reads).  The double block: per layer the
+    two sub-blocks' leaves (`sub`), the router and its bias; this chip's
+    experts apart.  The single-attention form: `dense_layers` (the leading
+    layers: `sub` of one attention + dense FFN) and `layers` (the expert
+    layers: `sub` of one attention, the router and its bias, the shared
+    expert), each stacked over its own layers; the experts apart."""
     H, L, NH, F = (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
                    cfg.ffn_dim)
     rq, rkv, Fe = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.moe_expert_ffn
@@ -697,27 +809,39 @@ def _init_latent_params(key, cfg: TransformerConfig) -> PyTree:
     def rnd(shape, std=0.02):
         return jax.random.normal(next(keys), shape, jnp.float32) * std
 
-    def sub_block():
-        ones = lambda n: jnp.ones((L, n), jnp.float32)  # noqa: E731
+    def ffn(n, width):
+        return {"w_gate": rnd((n, H, width)), "w_up": rnd((n, H, width)),
+                "w_down": rnd((n, width, H), out_std)}
+
+    def sub_block(n, dense_ffn=True):
+        ones = lambda w: jnp.ones((n, w), jnp.float32)  # noqa: E731
         return {"attn_norm_scale": ones(H), "mlp_norm_scale": ones(H),
                 "q_a_norm_scale": ones(rq), "kv_a_norm_scale": ones(rkv),
-                "wq_a": rnd((L, H, rq)), "wq_b": rnd((L, rq, NH * dqk)),
-                "wkv_a": rnd((L, H, cfg.latent_width)),
-                "wkv_b": rnd((L, rkv, NH * dkv)),
-                "wo": rnd((L, NH * cfg.v_head_dim, H), out_std),
-                "w_gate": rnd((L, H, F)), "w_up": rnd((L, H, F)),
-                "w_down": rnd((L, F, H), out_std)}
+                "wq_a": rnd((n, H, rq)), "wq_b": rnd((n, rq, NH * dqk)),
+                "wkv_a": rnd((n, H, cfg.latent_width)),
+                "wkv_b": rnd((n, rkv, NH * dkv)),
+                "wo": rnd((n, NH * cfg.v_head_dim, H), out_std),
+                **(ffn(n, F) if dense_ffn else {})}
 
-    return {
-        "tok_embed": rnd((cfg.vocab_size, H)),
-        "lm_head": rnd((H, cfg.vocab_size)),
-        "final_norm_scale": jnp.ones((H,), jnp.float32),
-        "layers": {"sub": [sub_block(), sub_block()],
-                   "moe_gate": rnd((L, H, E)),
-                   "moe_router_bias": jnp.zeros((L, E), jnp.float32)},
-        "experts": {"w_gate_proj": rnd((L, El, H, Fe)),
-                    "w_up": rnd((L, El, H, Fe)),
-                    "w_down": rnd((L, El, Fe, H), out_std)}}
+    Ld = cfg.latent_dense_layers
+    Le = L - Ld
+    top = {"tok_embed": rnd((cfg.vocab_size, H)),
+           "lm_head": rnd((H, cfg.vocab_size)),
+           "final_norm_scale": jnp.ones((H,), jnp.float32)}
+    if cfg.latent_form == "shortcut":
+        layers = {"sub": [sub_block(L), sub_block(L)]}
+    else:
+        if Ld:
+            top["dense_layers"] = {"sub": [sub_block(Ld)]}
+        layers = {"sub": [sub_block(Le, dense_ffn=False)]}
+    layers["moe_gate"] = rnd((Le, H, E))
+    layers["moe_router_bias"] = jnp.zeros((Le, E), jnp.float32)
+    if cfg.moe_shared_expert_ffn:
+        layers["shared"] = ffn(Le, cfg.moe_shared_expert_ffn)
+    return {**top, "layers": layers,
+            "experts": {"w_gate_proj": rnd((Le, El, H, Fe)),
+                        "w_up": rnd((Le, El, H, Fe)),
+                        "w_down": rnd((Le, El, Fe, H), out_std)}}
 
 
 def _init_params(key, cfg: TransformerConfig) -> PyTree:
@@ -1808,9 +1932,15 @@ class Transformer:
         """The serving-only blocks: refused with what is missing."""
         if self.cfg.latent:
             raise NotImplementedError(
-                f"{what} has no latent-attention (MLA) double block: this "
-                f"configuration is served through inference.v2 "
-                f"(build_engine -> ServeLoop) only")
+                f"{what} has no latent-attention (MLA) layer in either "
+                f"form (the shortcut-connected double block, the "
+                f"single-attention layer over a static dense prefix): "
+                f"`_layer` has no latent projections, no absorbed or "
+                f"decompressed latent attention and no backward pass for "
+                f"them, and the training MoE (moe/sharded.py) no sigmoid "
+                f"or group-limited router, identity experts or expert "
+                f"share; this configuration is served through "
+                f"inference.v2 (build_engine -> ServeLoop) only")
         if self.cfg.static_kinds:
             raise NotImplementedError(
                 f"{what} has no static-kind stack: `_layer` has no "

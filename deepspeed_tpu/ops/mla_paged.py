@@ -17,11 +17,12 @@ queries are real (decode: Q = 1; a prefill chunk: Q = its width).
 
 As in `ops/paged_attention.py` the block table rides the grid as a
 scalar-prefetch operand and the arena's BlockSpec index maps read it.
-What the shape forces (64 heads against ONE 576-wide key, thousands of
-tokens of context):
+What the shape forces (64 or 128 heads against ONE 576-wide key, thousands
+of tokens of context):
 
 - a grid step takes a tile of queries with all their heads as the rows of
-  one matmul (decode: 64 rows; a chunk: 8 queries x 64 heads), against
+  one matmul (decode: one query's 64 or 128 rows; a chunk: as many queries
+  as give 512 rows, 8 x 64 heads or 4 x 128: `queries_per_step`), against
   several arena blocks at once, each its own in_spec, joined in VMEM into
   one tile of keys: a (row, block) grid of 96 x 33 steps with 64 x 64
   products costs ten times what the bytes take to read;
@@ -42,14 +43,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["mla_paged_attention", "mla_paged_reference"]
+__all__ = ["mla_paged_attention", "mla_paged_reference",
+           "queries_per_step"]
 
 NEG_INF = -1e30
 # a grid step's tile: arena blocks joined into one tile of keys (a table
 # of MB blocks takes ceil(MB / 8) steps of equal size: 33 blocks, 5 steps
 # of 7), and queries whose heads are one matmul's rows
 BLOCKS_PER_STEP = 8
-QUERIES_PER_STEP = 8
+ROWS_PER_STEP = 512
+
+
+def queries_per_step(heads: int) -> int:
+    """Queries of a chunk a grid step takes: with all their heads they are
+    the rows of its matmuls and of its float32 scores and accumulator in
+    VMEM, `ROWS_PER_STEP` at most (8 at up to 64 heads, 4 at 128), never
+    more than 8."""
+    return max(1, min(8, ROWS_PER_STEP // heads))
 
 
 def mla_paged_reference(q_abs, q_rope, arena, block_tables, pos0, n_valid,
@@ -143,7 +153,7 @@ def mla_paged_attention(q_abs, q_rope, arena, block_tables, pos0, n_valid,
     MB = block_tables.shape[1]
     steps = -(-MB // BLOCKS_PER_STEP)
     per_step = -(-MB // steps)
-    tq = min(QUERIES_PER_STEP, Q)
+    tq = min(queries_per_step(NH), Q)
     if Q % tq:
         raise ValueError(f"{Q} queries a row are not whole tiles of {tq}")
     tables = jnp.clip(block_tables, 0, nb - 1).astype(jnp.int32)

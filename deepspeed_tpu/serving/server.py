@@ -470,7 +470,7 @@ class ServeLoop:
                      or engine.cfg.moe_experts)  # 0 = full residency
             self._expert_pool = engine.enable_expert_paging(
                 slots, spill=self._moe.spill)
-        # the latent MoE block's router counters (an engine with
+        # the latent MoE stacks' router counters (an engine with
         # supports_moe_counts): drained every COUNT_DRAIN_STEPS steps
         # into the telemetry counters and a `serve.moe_census` span;
         # 0 = an engine without them, never asked
@@ -1473,7 +1473,7 @@ class ServeLoop:
                 % self._moe.census_interval_steps == 0):
             self._expert_pool.ingest_census(self.engine.drain_moe_census())
             self._expert_pool.rebalance(self._moe.max_promotes_per_step)
-        # the latent MoE block's router counters: drained on the same
+        # the latent MoE stacks' router counters: drained on the same
         # kind of interval, into the counters and a span a trace carries
         if (self._moe_counts_every and (self.telemetry.steps + 1)
                 % self._moe_counts_every == 0):

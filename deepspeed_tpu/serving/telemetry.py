@@ -81,13 +81,17 @@ class ServingTelemetry:
             # plus the rows of a step dropped whole (`drop_pending`)
             "steps_run_ahead": 0, "steps_collected_at_once": 0,
             "rows_overrun": 0,
-            # latent MoE block (inference/v2/latent_ops.COUNT_NAMES),
+            # latent MoE stacks (inference/v2/latent_ops.count_names),
             # drained from the device every COUNT_DRAIN_STEPS serve steps:
             # top-k picks, those on identity experts, those on the
             # experts held here, the busiest local expert's rows
-            # (summed over router calls), router calls
+            # (summed over router calls), router calls; under a router
+            # with expert groups also the valid tokens it scored (summed
+            # over layers) and those whose kept groups include a group
+            # this chip holds experts of
             "moe_picks": 0, "moe_zero_picks": 0, "moe_local_rows": 0,
             "moe_busiest_rows": 0, "moe_router_calls": 0,
+            "moe_router_tokens": 0, "moe_group_hit_tokens": 0,
             # two-kind cache (a window + global stack): summed over decode
             # steps, in block x layer units, what the step's rows held of
             # both kinds, what one kind over all layers would have held

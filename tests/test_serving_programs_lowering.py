@@ -1,0 +1,83 @@
+"""The serving programs of the families the benchmark already runs lower to
+the StableHLO they lowered to at PR 34 (commit 9e7457a), hash for hash: a
+change made for one family's layer (PR 38: a second form of the latent
+layer, a router read from a description, YaRN in the latent path, counters
+whose number follows the configuration) must leave the others' programs as
+they were.
+
+`EXPECTED` was printed by `python tests/test_serving_programs_lowering.py`
+run against the parent's tree.  A PR that changes one of these programs on
+purpose prints them again and says so.
+"""
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.serving
+
+B, MB, NB, BS = 4, 8, 16, 16
+
+EXPECTED = {
+    "longcat_flash": {"decode_step": "6f403fbb2d098583",
+                      "decode_tokens": "1daebb256a455fd3",
+                      "prefill_chunks": "897f0dc0a6cac1da",
+                      "prefill_chunks_tiled": "50647f324902610f",
+                      "prefill_full": "475125cb672fb834"},
+    "qwen2": {"decode_step": "5387aafd2fa5f207",
+              "decode_tokens": "8a4f4792919bdae8",
+              "prefill_chunks": "1fe435c08a0e8fe1",
+              "prefill_full": "93898cc383c0a6ac"},
+    "smallthinker": {"decode_step": "562dbcde58cc8193",
+                     "decode_tokens": "36711b4b8566b70d",
+                     "prefill_chunks": "8e8b11e44668712d"},
+}
+
+
+def program_hashes(family: str) -> dict:
+    from deepspeed_tpu.inference.v2 import ragged_ops
+    from deepspeed_tpu.models import Transformer, get_model_config
+    cfg = get_model_config(family, "tiny", dtype=jnp.float32)
+    params = jax.eval_shape(Transformer(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    arena = jax.eval_shape(
+        lambda: ragged_ops.init_arena(cfg, NB, BS, max_seqs=B))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    flags = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    # a static-kind stack's tables are [rows, 2, MB]
+    tables = i32(B, 2, MB) if cfg.static_kinds else i32(B, MB)
+    calls = {
+        "decode_step": (ragged_ops.decode_step,
+                        (i32(B), i32(B), tables, flags), {}),
+        "decode_tokens": (ragged_ops.decode_tokens,
+                          (i32(B), i32(B), tables, flags,
+                           jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+                          dict(n_steps=4)),
+        "prefill_chunks": (ragged_ops.prefill_chunks,
+                           (i32(B, 32), i32(B), i32(B), tables, flags), {}),
+    }
+    if cfg.latent:         # more rows than a token-wise pass takes (`_rows`)
+        calls["prefill_chunks_tiled"] = (
+            ragged_ops.prefill_chunks,
+            (i32(B, 512), i32(B), i32(B), tables, flags), {})
+    if ragged_ops.prefill_full_supported(cfg):
+        calls["prefill_full"] = (ragged_ops.prefill_full,
+                                 (i32(B, 128), i32(B), tables, flags), {})
+    # (the tests' own matmul precision, `tests/conftest.py`: it is part of
+    # the text)
+    with jax.default_matmul_precision("highest"):
+        return {name: hashlib.sha256(
+            fn.lower(cfg, params, arena, *args, **kw).as_text().encode()
+        ).hexdigest()[:16] for name, (fn, args, kw) in calls.items()}
+
+
+@pytest.mark.parametrize("family", ["longcat_flash", "qwen2", "smallthinker"])
+def test_a_family_the_benchmark_runs_lowers_to_the_parents_programs(family):
+    assert program_hashes(family) == EXPECTED[family]
+
+
+if __name__ == "__main__":
+    import pprint
+    pprint.pprint({f: program_hashes(f)
+                   for f in ("longcat_flash", "qwen2", "smallthinker")})

@@ -212,6 +212,11 @@ def test_merged_arena_decode(chip):
     pytest.param(96, 1, 64, 512, 64, 8, 3488, 64, 33, id="longcat-decode"),
     pytest.param(4, 1024, 64, 512, 64, 8, 3488, 64, 33, id="longcat-chunks"),
     pytest.param(4, 1, 2, 128, 64, 2, 16, 16, 1, id="one-block"),
+    # the DeepSeek-V3 cell's: 128 heads, 41 blocks in 6 steps of 7, one
+    # attention a layer; chunk slots in tiles of 4 queries x 128 heads
+    pytest.param(64, 1, 128, 512, 64, 5, 2890, 64, 41, id="deepseek-decode"),
+    pytest.param(2, 1024, 128, 512, 64, 5, 2890, 64, 41,
+                 id="deepseek-chunks"),
 ])
 def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
     """The latent attention kernel at the cell's widths: one Mosaic
@@ -367,3 +372,78 @@ def test_train_step_of_the_opt_cell_fits_without_clones(topo, chip,
         for at in split), split
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert peak < 1.03 * TRAIN_STEP_PEAK_BYTES, peak
+
+
+# `deepseek-v3.decode_closed_chat`'s programs: what the v5e compiler reports
+# for the weights (12.37 GB), the arena (1.18 GB) and each program's
+# temporaries; the chip has 16.9 GB to give
+SERVE_PEAK_BYTES = {"decode_step": 13.70e9, "prefill_full": 13.85e9,
+                    "prefill_chunks": 13.91e9}
+
+
+def test_serving_programs_of_the_deepseek_cell_fit_and_discard_nothing(
+        topo, chip, monkeypatch):
+    """The cell's own `decode_step`, `prefill_full[1, 1024]` and
+    `prefill_chunks[1]` (`benchmark/configs/deepseek-v3.json`: 1 dense + 4
+    expert layers at the published widths, 16 of 256 experts, 64 rows of
+    41 blocks), compiled for the described chip from abstract weights:
+    each fits with the arena donated, runs the latent kernel, and the
+    decode program holds ONE dense FFN of 18432 (the leading layer's,
+    outside the expert layers' loop) and one shared expert in the loop's
+    body: no layer computes a branch it throws away.  ~10 s a program."""
+    import re
+
+    from benchmark import harness
+    from deepspeed_tpu.inference.v2 import ragged_ops
+    from deepspeed_tpu.inference.v2.model_registry import arch_config
+    from deepspeed_tpu.utils import device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    file = harness.load_json(harness.BENCH_DIR, "configs", "deepseek-v3.json")
+    ref = harness.load_module(harness.BENCH_DIR, "references", "deepseek_v3")
+    prog, sizes = file["program"], ref.sizes(file)
+    cfg = arch_config(prog["arch"], prog["size"], dtype=BF16,
+                      **prog["overrides"])
+    eng = prog["engine"]
+    B, MB = eng["max_seqs"], eng["max_blocks_per_seq"]
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(lambda: ref._make_params(
+        jnp.uint32(0), s=sizes, dtype=BF16)))
+    arena = on(jax.eval_shape(lambda: ragged_ops.init_arena(
+        cfg, eng["num_blocks"], 64, max_seqs=B)))
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert held == ref.weight_bytes(sizes, "bfloat16")
+    assert arena["c"].shape == (5, 2890, 64, 640)
+    i32 = lambda *shape: chip(shape, jnp.int32)  # noqa: E731
+    flags = lambda n: chip((n,), jnp.bool_)  # noqa: E731
+    S = eng["prefill_chunk_size"]
+    calls = {
+        "decode_step": (ragged_ops.decode_step,
+                        (i32(B), i32(B), i32(B, MB), flags(B))),
+        "prefill_full": (ragged_ops.prefill_full,
+                         (i32(1, S), i32(1), i32(1, MB), flags(1))),
+        "prefill_chunks": (ragged_ops.prefill_chunks,
+                           (i32(1, S), i32(1), i32(1), i32(1, MB), flags(1))),
+    }
+    for name, (fn, args) in calls.items():
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(cfg, params, arena, *args).compile()
+        mem, hlo = compiled.memory_analysis(), compiled.as_text()
+        assert mem.peak_memory_in_bytes < 1.02 * SERVE_PEAK_BYTES[name], name
+        assert mem.alias_size_in_bytes >= arena["c"].size * 2    # donated
+        assert mem.temp_size_in_bytes < 0.5e9, name
+        assert "tpu_custom_call" in hlo, name
+        if name != "decode_step":
+            continue
+        matmuls = [l for l in hlo.splitlines()
+                   if re.search(r" (dot|convolution)\(", l)]
+        under = lambda scope: [l for l in matmuls  # noqa: E731
+                               if f"/{scope}/" in l]
+        # gate and up of ONE 18432-wide FFN, then its down-projection
+        assert len([l for l in under("dense_ffn") if "18432]" in l]) == 2
+        assert len(under("dense_ffn")) == 3
+        # the shared expert once in the expert layers' loop body
+        assert len(under("shared_expert")) == 3
+        assert all("while/body" in l for l in under("shared_expert"))
+        assert not any("/while/body/while" in l for l in under("dense_ffn"))
