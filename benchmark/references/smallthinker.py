@@ -474,6 +474,23 @@ def paged_decode_flops(s: Sizes, rows: float, context_tokens: float) -> float:
     return keys_read(s, rows, context_tokens) * 2 * 2 * s.heads * s.head_dim
 
 
+def prefill_flops(s: Sizes, prompt_tokens: int) -> float:
+    """Multiply-adds, twice, that the forward pass of ONE fresh prompt
+    needs before its first token: every token through a layer's attention
+    projections and router and through its `top_k` experts; every (query,
+    key) pair the mask lets through (key <= query, on a window layer also
+    key > query - window), every query head's score and weighted sum over
+    the head's width; the head on the last position only."""
+    n = prompt_tokens
+    matmuls = _count([leaf for leaf in layer_leaves(s) if len(leaf[1]) == 2]) \
+        + s.top_k * _count(expert_leaves(s))
+    w = min(n, s.window)
+    pairs = s.global_layers * n * (n + 1) / 2 \
+        + (s.layers - s.global_layers) * (w * (w + 1) / 2 + (n - w) * w)
+    return 2.0 * (s.layers * n * matmuls
+                  + pairs * 2 * s.heads * s.head_dim + s.hidden * s.vocab)
+
+
 def decode_step_bytes(s: Sizes, dtype: str, rows: float,
                       context_tokens: float) -> float:
     """HBM bytes one decode step must move: attention's weights, the norms,

@@ -257,7 +257,7 @@ def test_the_new_metrics_are_entries_over_existing_readers():
     for name in ("shared_expert_device_share.closed",
                  "router_device_share.closed",
                  "local_group_hit_share.closed"):
-        assert names[name]["workloads"] == [REAL]
+        assert REAL in names[name]["workloads"]
         assert names[name]["moves"] == "ttft_p50_ms"
         spec = harness.load_json(harness.BENCH_DIR, "metrics", name + ".json")
         assert os.path.isfile(os.path.join(
@@ -272,33 +272,19 @@ def test_the_new_metrics_are_entries_over_existing_readers():
             "prefill_device_ms_per_ktok.closed"} <= set(lists)
 
 
-def test_the_cell_before_this_one_holds_all_but_its_place(monkeypatch):
-    """`test_smallthinker.py` asserts that its cell and metrics are LAST in
-    the lists this PR appends to (`tests/conftest.py` expects that test to
-    fail).  All else it asserts is held here: its own body, on
-    `BENCHMARK.json` with both lists cut after that cell's own entries."""
+def test_the_cell_before_this_one_is_found_by_name():
+    """`test_smallthinker.py` looked its cell and metrics up at the END of
+    the lists this cell's entries were appended to, and this test ran its
+    body on the lists cut there.  Since PR 40 it looks them up by name, so
+    its body holds on the lists whole, with this cell's entries after its
+    own (`tests/conftest.py` still marks it `xfail`, not strictly: a
+    benchmark PR may not edit that file, so it passes unexpectedly)."""
     import test_smallthinker as theirs
-
-    def cut_after(entries, own):
-        return entries[:max(i for i, e in enumerate(entries) if own(e)) + 1]
-
-    whole = harness.load_json
-
-    def load(*parts):
-        loaded = whole(*parts)
-        if parts[-1] == "BENCHMARK.json":
-            loaded["workloads"] = cut_after(
-                loaded["workloads"], lambda c: c["name"] == theirs.REAL_CELL)
-            loaded["per_layer"] = cut_after(
-                loaded["per_layer"],
-                lambda m: m.get("workloads") == [theirs.REAL_CELL])
-        return loaded
-    cut = load(harness.ROOT, "BENCHMARK.json")
-    # (what the cut hides is what came after, this cell's entries in it)
-    assert REAL not in [c["name"] for c in cut["workloads"]]
-    assert "router_device_share.closed" not in [
-        m["name"] for m in cut["per_layer"]]
-    monkeypatch.setattr(harness, "load_json", load)
+    cells = [c["name"] for c in BENCH["workloads"]]
+    assert cells.index(theirs.REAL_CELL) < cells.index(REAL)
+    metrics = [m["name"] for m in BENCH["per_layer"]]
+    assert metrics.index("chunk_attn_device_share.closed") \
+        < metrics.index("router_device_share.closed")
     theirs.test_the_cell_and_its_metrics_are_entered_as_the_issue_names_them()
 
 
