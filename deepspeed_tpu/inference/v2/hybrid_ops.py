@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ...models.transformer import TransformerConfig, _rope
-from .latent_ops import COUNT_NAMES, _moe, _rms, _rows
+from .latent_ops import _moe, _rms, _rows, count_names
 from .ragged_ops import (_dense, _embed, _gate_fused, _kernel_capable,
                          _lm_logits, greedy_tokens)
 
@@ -100,7 +100,7 @@ def init_kinds_arena(cfg: TransformerConfig, num_blocks: int,
     zeros = lambda n, nb: jnp.zeros((n, nb) + shape, cfg.dtype)  # noqa: E731
     return {"gk": zeros(Lg, nb_g), "gv": zeros(Lg, nb_g),
             "wk": zeros(Lw, nb_w), "wv": zeros(Lw, nb_w),
-            "moe_counts": jnp.zeros((len(COUNT_NAMES),), jnp.int32)}
+            "moe_counts": jnp.zeros((len(count_names(cfg)),), jnp.int32)}
 
 
 def _use_kernels(cfg: TransformerConfig, bs: int) -> bool:

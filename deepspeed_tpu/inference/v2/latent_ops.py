@@ -55,9 +55,12 @@ scan, `sub` (a list of the sub-blocks' leaves), `moe_gate`,
 `moe_router_bias` and, in the single form, `shared`; `params["dense_layers"]`
 the leading dense layers' `sub`; `params["experts"]` holds this chip's
 experts `[layers, local, ...]` OUTSIDE the scan: the grouped matmuls take
-the whole stack with group sizes that are zero outside the layer at hand (a
-per-layer slice handed to a custom call is first copied, 1.2 GB a layer
-at LongCat's cell's size: measured 29 of a 51 ms decode step).
+the whole stack (a per-layer slice handed to a custom call is first
+copied, 1.2 GB a layer at LongCat's cell's size: measured 29 of a 51 ms
+decode step).  On the chip they are `ops/grouped_matmul.py` over the live
+(expert of the whole stack, row tile) items of the layer at hand, gate and
+up in one pass; elsewhere three `lax.ragged_dot` calls with group sizes that
+are zero outside the layer (`_use_expert_kernel`: the platform's choice).
 
 The arena is ONE array `[A, blocks, block_size, W]`: row `[c | rope(kr) |
 unused]` per token and attention (A = `cfg.latent_attentions`: attention
@@ -95,7 +98,8 @@ from ...models.transformer import TransformerConfig, _scale_rope_freqs
 from .ragged_ops import (_dense, _embed, _gate_fused, _lm_logits,
                          _plain_mlp, greedy_tokens)
 
-__all__ = ["COUNT_NAMES", "GROUP_COUNT_NAMES", "COUNT_DRAIN_STEPS", "ROW_TILE",
+__all__ = ["COUNT_NAMES", "GROUP_COUNT_NAMES", "KERNEL_COUNT_NAMES",
+           "COUNT_DRAIN_STEPS", "ROW_TILE",
            "Router", "router_of", "count_names", "init_latent_arena",
            "prefill_full", "prefill_chunks", "decode_core", "local_rows_cap",
            "refuse_lora"]
@@ -113,6 +117,13 @@ COUNT_NAMES = ("picks", "zero_picks", "local_rows", "busiest_rows",
 # over layers); those whose kept groups include a group this chip holds
 # experts of
 GROUP_COUNT_NAMES = ("router_tokens", "group_hit_tokens")
+# and, last, where the experts' grouped matmuls are the kernel
+# (`_use_expert_kernel`: the chip), how it engaged, summed over layers and
+# passes: the expert-weight fetches its grid made, in units of one expert's
+# whole weight (`ops.grouped_matmul.weight_fetches`), and the experts a pass
+# reached.  Their ratio is 1 where every reached expert's weights were read
+# once a matmul
+KERNEL_COUNT_NAMES = ("expert_weight_fetches", "experts_reached")
 # serve steps between two drains of it (`ServeLoop`: one small fetch)
 COUNT_DRAIN_STEPS = 16
 
@@ -137,7 +148,16 @@ def router_of(cfg: TransformerConfig) -> Router:
 
 def count_names(cfg: TransformerConfig) -> Tuple[str, ...]:
     """The names of `moe_counts`' entries: its length follows the router."""
-    return COUNT_NAMES + (GROUP_COUNT_NAMES if cfg.moe_router_groups else ())
+    return (COUNT_NAMES + (GROUP_COUNT_NAMES if cfg.moe_router_groups else ())
+            + (KERNEL_COUNT_NAMES if _use_expert_kernel() else ()))
+
+
+def _use_expert_kernel() -> bool:
+    """The experts' grouped matmuls are `ops/grouped_matmul.py` on the chip
+    and three `lax.ragged_dot` calls elsewhere (the kernel's reference):
+    the platform's choice, as `_use_latent_kernel`'s."""
+    from ...utils.device import on_tpu
+    return on_tpu()
 
 
 def init_latent_arena(cfg: TransformerConfig, num_blocks: int,
@@ -301,6 +321,9 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
     among them; `router_in` [T, H]: what the router scores where that is
     not `h` (a router on the layer's input).  The experts' gate is ReLU
     for `reglu`, else SiLU."""
+    # (imported here, as the latent kernel is: Pallas loads while the device
+    # is still seeding weights, not before the process has dispatched a thing)
+    from ...ops import grouped_matmul
     T, H = h.shape
     dt, k = h.dtype, cfg.moe_top_k
     E, first, El = cfg.moe_experts, cfg.moe_expert_first, cfg.local_experts
@@ -328,33 +351,52 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
         cap = local_rows_cap(T * k, El, E + cfg.moe_zero_experts)
         ends = jnp.cumsum(sizes)
         order = jnp.pad(order, (0, cap))     # a window never slides back
+        kernel = _use_expert_kernel()
+        tile = grouped_matmul.row_tile(cap)
         every = jnp.zeros((experts["w_up"].shape[0],), jnp.int32)
 
-        def piece(i, acc):
+        def piece(i, carry):
             """Rows [i * cap, (i + 1) * cap) of the sorted assignments."""
+            acc, engaged = carry      # the kernel's two counts, where it runs
             lo = i * cap
             sel = jax.lax.dynamic_slice(order, (lo,), (cap,))
             part = (jnp.clip(ends, lo, lo + cap)
                     - jnp.clip(ends - sizes, lo, lo + cap))   # per expert
-            # groups of the whole stack: empty outside this layer
-            groups = jax.lax.dynamic_update_slice(every, part, (li * El,))
+            # `ragged_dot`'s groups of the whole stack: empty outside this
+            # layer
+            groups = None if kernel else jax.lax.dynamic_update_slice(
+                every, part, (li * El,))
             tok = sel // k
             xs = jnp.take(h, tok, axis=0)
-            g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
-                                   preferred_element_type=jnp.float32)
-            u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
-                                   preferred_element_type=jnp.float32)
-            act = (gate_act(g) * u).astype(dt)
-            down = jax.lax.ragged_dot(act, experts["w_down"], groups,
-                                      preferred_element_type=jnp.float32)
+            if kernel:
+                # the live (expert of the whole stack, row tile) items
+                items = grouped_matmul.list_items(part, cap, tile, li * El)
+                act = grouped_matmul.grouped_matmul(
+                    xs, (experts["w_gate_proj"], experts["w_up"]), items,
+                    tile=tile, gate_act=gate_act, out_dtype=dt)
+                down = grouped_matmul.grouped_matmul(
+                    act, (experts["w_down"],), items, tile=tile)
+                engaged = engaged + jnp.stack([
+                    grouped_matmul.weight_fetches(items),
+                    jnp.sum(part > 0).astype(jnp.int32)])
+            else:
+                g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
+                                       preferred_element_type=jnp.float32)
+                u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
+                                       preferred_element_type=jnp.float32)
+                act = (gate_act(g) * u).astype(dt)
+                down = jax.lax.ragged_dot(act, experts["w_down"], groups,
+                                          preferred_element_type=jnp.float32)
             # rows past the last group belong to no expert held here
             mine = (lo + jnp.arange(cap) < n_local)[:, None]
             return acc.at[tok].add(
-                jnp.where(mine, down * wf[sel][:, None], 0.0))
+                jnp.where(mine, down * wf[sel][:, None], 0.0)), engaged
 
         # one piece unless routing piles more than `cap` rows on this share
-        routed = jax.lax.fori_loop(0, (n_local + cap - 1) // cap, piece,
-                                   jnp.zeros((T, H), jnp.float32))
+        routed, engaged = jax.lax.fori_loop(
+            0, (n_local + cap - 1) // cap, piece,
+            (jnp.zeros((T, H), jnp.float32),
+             jnp.zeros((2,), jnp.int32) if kernel else ()))
     picked = picked.reshape(T, k)
     counts = [jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
               jnp.max(sizes), jnp.ones((), jnp.int32)]
@@ -363,6 +405,7 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
         mine = kept[:, first // per:(first + El - 1) // per + 1]
         counts += [jnp.sum(tok_valid),
                    jnp.sum(tok_valid & jnp.any(mine, axis=1))]
+    counts += list(engaged)
     counts = jnp.stack(counts).astype(jnp.int32)
     return (routed + zero_part).astype(dt), counts
 

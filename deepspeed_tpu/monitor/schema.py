@@ -49,6 +49,7 @@ SERVING_TAGS = frozenset(
         "moe_picks", "moe_zero_picks", "moe_local_rows",
         "moe_busiest_rows", "moe_router_calls",
         "moe_router_tokens", "moe_group_hit_tokens",
+        "moe_expert_weight_fetches", "moe_experts_reached",
         # two-kind cache (a window + global stack): block x layer units
         # held and what one kind would hold, window-kind blocks handed
         # back, admissions refused by the kind that was short

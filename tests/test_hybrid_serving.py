@@ -16,6 +16,7 @@ that reach 4.6, the limit is 1e-4, and the three broken references below
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu as ds
@@ -30,6 +31,7 @@ from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.serving import RequestState, ServeLoop
 from deepspeed_tpu.serving.scheduler import AdmissionError
 
+from test_grouped_matmul import moe_through_the_kernel
 from test_serving import FakeClock
 
 pytestmark = pytest.mark.serving
@@ -191,6 +193,34 @@ def test_padded_chunk_slots_cost_passes_only_for_their_real_tokens(
     # decode steps, 8 layers, top-2
     assert counts["picks"] == counts["local_rows"] == (n + new) * 8 * 2
     assert counts["zero_picks"] == 0
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("drawn", [0.0, 4.0],
+                         ids=["as_routed", "one_expert_takes_most"])
+def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
+                                                            drawn):
+    """`_moe` with the grouped-matmul kernel (the chip's path, interpreted)
+    on a later layer of this stack: ReLU gates, the router on the layer's
+    input, every expert held here (no pass but the first); as it routes,
+    and with the router drawn to one expert, whose rows then span tiles."""
+    from deepspeed_tpu.inference.v2 import latent_ops
+    eng = engine()
+    cfg, li, T = eng.cfg, 5, 96
+    lp = jax.tree.map(lambda a: a[li], eng.params["layers"])
+    lp = dict(lp, moe_gate=lp["moe_gate"].at[:, 2].add(drawn))
+    experts = {n: w.reshape((-1,) + w.shape[2:])
+               for n, w in eng.params["experts"].items()}
+    h, x = jax.random.normal(jax.random.PRNGKey(1), (2, T, S.hidden))
+    counts, passes = moe_through_the_kernel(
+        monkeypatch, cfg, lp, experts, li, h, jnp.arange(T) < 90, TOL,
+        router_in=jnp.abs(x) if drawn else x)
+    assert passes == 1 and counts["zero_picks"] == 0
+    assert counts["local_rows"] == counts["picks"] == 90 * cfg.moe_top_k
+    assert latent_ops.local_rows_cap(T * cfg.moe_top_k, cfg.local_experts,
+                                     cfg.moe_experts) == T * cfg.moe_top_k
+    if drawn:
+        assert counts["busiest_rows"] > 80
 
 
 def greedy_chain_ok(req):

@@ -27,6 +27,7 @@ from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
                                         build_engine, latent_ops, ragged_ops)
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import mla_paged
+from test_grouped_matmul import arena_copy, moe_through_the_kernel
 
 pytestmark = pytest.mark.serving
 
@@ -195,6 +196,29 @@ def test_assignments_past_the_buffer_run_it_again():
     assert counts["local_rows"] <= 60 * 4
 
 
+@pytest.mark.kernels
+@pytest.mark.parametrize("piled", [0.0, 1.0],
+                         ids=["one_pass", "past_the_buffer"])
+def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
+                                                            piled):
+    """`_moe` with the grouped-matmul kernel (the chip's path, interpreted)
+    on the layer of the test above: as the router routes, and with its
+    picks piled on the experts held here so that the compact buffer (176
+    rows: a whole tile and a partial one) runs a second time."""
+    cfg = get_model_config("longcat_flash", "tiny", dtype=F32,
+                           moe_expert_count=8)
+    lp = REF.layer_params(REF.seed_key(REF.seed_arg(SEED)), np.uint32(0), S,
+                          F32)
+    lp["moe_router_bias"] = lp["moe_router_bias"].at[:8].add(piled)
+    h = jax.random.normal(jax.random.PRNGKey(0), (64, S.hidden))
+    experts = {n: jnp.concatenate([jnp.ones_like(w), w])
+               for n, w in lp["experts"].items()}
+    counts, passes = moe_through_the_kernel(
+        monkeypatch, cfg, lp, experts, 1, h, jnp.arange(64) < 60, TOL)
+    assert passes == (2 if piled else 1)
+    assert (counts["local_rows"] > 176) == bool(piled)
+
+
 def _latents(rng, dtype=F32, MB=5):
     """A small arena and tables of MB blocks with garbage past the live
     ones."""
@@ -289,7 +313,8 @@ def test_decode_and_chunks_through_the_kernel_match_the_gather(
     first = int(np.asarray(eng.put([1], [p])[1]).argmax())
     table = eng.state.block_table(eng.state.seqs[1])
     tables = jnp.asarray(np.stack([table] + [np.zeros(32, np.int32)] * 3))
-    arena = lambda: jax.tree.map(jnp.copy, eng.arena)  # noqa: E731
+    # (the counters' number follows the platform's gate, flipped below)
+    arena = functools.partial(arena_copy, eng)
     decode = (jnp.asarray([first, 0, 0, 0]), jnp.asarray([40, 0, 0, 0]),
               tables, jnp.asarray([True, False, False, False]))
     chunk = (jnp.asarray(np.stack([prompt(32, seed=s) for s in range(4)])),
@@ -307,6 +332,32 @@ def test_decode_and_chunks_through_the_kernel_match_the_gather(
                                         *chunk)
     assert np.abs(np.asarray(fused - dense))[0].max() < TOL
     assert np.abs(np.asarray(fused_c[0] - dense_c[0]))[0].max() < TOL
+
+
+@pytest.mark.kernels
+def test_an_engine_on_the_chips_path_serves_the_reference_and_counts(
+        interpret, monkeypatch):
+    """An engine built where the platform says "tpu" (both kernels, the
+    latent attention's and the experts', interpreted): chunked prefill and
+    decode give the reference's logits, the counters' rider carries the
+    kernel's two entries through `drain_moe_counts`, and their ratio is 1
+    (this grid reads a reached expert's weights once a matmul)."""
+    import deepspeed_tpu.utils.device as device_mod
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
+    # (its own cfg: a jitted program is cached by its static cfg)
+    eng = engine(engine_kw=dict(full_prompt_prefill=False), max_seq_len=488)
+    assert eng.arena["moe_counts"].shape == (7,)
+    n = 40
+    got, toks = serve(eng, prompt(n, seed=6), steps=2)
+    assert np.abs(got - ref_logits(toks)[n - 1:]).max() < TOL
+    counts = eng.drain_moe_counts()
+    assert tuple(counts) == latent_ops.COUNT_NAMES \
+        + latent_ops.KERNEL_COUNT_NAMES
+    assert counts["picks"] == (n + 2) * 2 * 4 and counts["local_rows"] > 0
+    # at most every held expert of both layers, each program
+    assert 0 < counts["experts_reached"] <= counts["router_calls"] * 8
+    assert counts["expert_weight_fetches"] == counts["experts_reached"]
+    assert not any(eng.drain_moe_counts().values())
 
 
 def test_padded_chunk_slots_cost_passes_only_for_their_real_tokens(

@@ -30,6 +30,7 @@ from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
                                         build_engine, latent_ops, ragged_ops)
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import mla_paged
+from test_grouped_matmul import arena_copy, moe_through_the_kernel
 
 pytestmark = pytest.mark.serving
 
@@ -281,6 +282,31 @@ def test_a_token_none_of_whose_groups_is_local_costs_no_row():
     assert not np.asarray(got).any()
 
 
+@pytest.mark.kernels
+@pytest.mark.parametrize("drawn", [0.0, 5.0],
+                         ids=["as_routed", "every_pick_local"])
+def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
+                                                            drawn):
+    """`_moe` with the grouped-matmul kernel (the chip's path, interpreted)
+    on the layer of the test above, a later layer of a two-layer stack:
+    under the grouped sigmoid router as it routes, and with every pick
+    drawn to the four experts held here (nine counters then)."""
+    cfg = get_model_config("deepseek_v3", "tiny", dtype=F32,
+                           moe_expert_count=4)
+    lp = REF.layer_params(REF.seed_key(REF.seed_arg(SEED)), np.uint32(1), S,
+                          F32, False)
+    lp = dict(lp, moe_router_bias=lp["moe_router_bias"].at[:4].add(drawn))
+    h = jax.random.normal(jax.random.PRNGKey(0), (24, S.hidden))
+    experts = {n: jnp.concatenate([jnp.ones_like(w), w])
+               for n, w in lp["experts"].items()}
+    counts, passes = moe_through_the_kernel(
+        monkeypatch, cfg, lp, experts, 1, h, jnp.arange(24) < 20, TOL)
+    assert passes == 1 and len(counts) == 9
+    if drawn:
+        assert counts["local_rows"] == 20 * 4
+        assert counts["experts_reached"] == 4
+
+
 def test_the_shares_add_up_to_the_uncut_layer():
     """16 routed experts over 4 shares of 4 (a group each): each share's
     program gives attention, the shared expert and ITS experts' part; with
@@ -374,7 +400,8 @@ def test_decode_and_chunks_through_the_kernel_match_the_gather(
     first = int(np.asarray(eng.query(1)).argmax())
     table = eng.state.block_table(eng.state.seqs[1])
     tables = jnp.asarray(np.stack([table] + [np.zeros(32, np.int32)] * 3))
-    arena = lambda: jax.tree.map(jnp.copy, eng.arena)  # noqa: E731
+    # (the counters' number follows the platform's gate, flipped below)
+    arena = functools.partial(arena_copy, eng)
     on = jnp.asarray([True, False, False, False])
     decode = (jnp.asarray([first, 0, 0, 0]), jnp.asarray([90, 0, 0, 0]),
               tables, on)

@@ -238,6 +238,45 @@ def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
     assert mem.temp_size_in_bytes < 2 ** 20
 
 
+# (rows of the compact buffer, hidden, expert width, local experts, expert
+# layers, ReLU gate): the experts' share of the three expert cells, decode
+# (`local_rows_cap` of 64 x 8, 96 x 12, 32 x 6 picks) and prefill (1024 slots;
+# a 4096-row pass of smallthinker's chunk program), and a buffer whose last
+# row tile is partial
+@pytest.mark.parametrize("rows,H,F,El,L,relu", [
+    pytest.param(128, 7168, 2048, 16, 4, False, id="deepseek-decode"),
+    pytest.param(2048, 7168, 2048, 16, 4, False, id="deepseek-prefill"),
+    pytest.param(96, 6144, 2048, 16, 3, False, id="longcat-decode"),
+    pytest.param(1024, 6144, 2048, 16, 3, False, id="longcat-prefill"),
+    pytest.param(192, 2560, 768, 64, 8, True, id="smallthinker-decode"),
+    pytest.param(24576, 2560, 768, 64, 8, True, id="smallthinker-chunk"),
+    pytest.param(176, 2560, 768, 64, 8, True, id="partial-last-tile"),
+])
+def test_grouped_matmul(chip, rows, H, F, El, L, relu):
+    """The experts' two passes of `latent_ops._moe` (gate and up fused,
+    then down) over the whole weight stack at the cells' widths: two Mosaic
+    kernels within the VMEM they ask for, and no copy of the stack (a
+    per-layer slice handed to a custom call would be one)."""
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    tile = gm.row_tile(rows)
+    gate = jax.nn.relu if relu else jax.nn.silu
+
+    def experts(x, wg, wu, wd, sizes, li):
+        items = gm.list_items(sizes, rows, tile, li * El)
+        act = gm.grouped_matmul(x, (wg, wu), items, tile=tile,
+                                gate_act=gate, out_dtype=x.dtype)
+        return gm.grouped_matmul(act, (wd,), items, tile=tile)
+
+    args = (chip((rows, H)), chip((L * El, H, F)), chip((L * El, H, F)),
+            chip((L * El, F, H)), chip((El,), jnp.int32),
+            chip((), jnp.int32))
+    assert kernels(experts, *args) == 2
+    with jax.default_matmul_precision("default"):
+        mem = jax.jit(experts).lower(*args).compile().memory_analysis()
+    # the activations between the two passes at most
+    assert mem.temp_size_in_bytes <= rows * F * 2 + 2 ** 20
+
+
 @pytest.mark.parametrize("M,K,N", [(256, 2048, 5632), (8, 2048, 2048)])
 def test_tile_matmul(chip, M, K, N):
     """The fused-TP ring's per-hop GEMM (ops.tp_matmul; `tile_matmul`
