@@ -10,6 +10,9 @@ Public API parity (reference: deepspeed/__init__.py):
 """
 from __future__ import annotations
 
+import time as _time
+_IMPORT_T0 = _time.perf_counter_ns()    # the `host.import` span begins here
+
 import argparse
 
 __version__ = "0.1.0"
@@ -31,6 +34,13 @@ from .runtime import activation_checkpointing as checkpointing
 from . import moe
 
 dist = comm  # reference idiom: `import deepspeed.comm as dist`
+
+# what importing the package cost (jax included, where this is what first
+# imports it), on the host-clock log of utils/spans.py
+from .utils.spans import span
+with span("host.import") as _imported:
+    _imported.begun(_IMPORT_T0)
+del span
 
 
 def init_inference(*args, **kwargs):

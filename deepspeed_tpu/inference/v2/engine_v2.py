@@ -223,6 +223,17 @@ class InferenceEngineV2:
     def __init__(self, model, params=None,
                  config: Optional[RaggedInferenceEngineConfig] = None,
                  topology=None):
+        # set-up's `engine.build` span: weights cast and placed, the arena
+        # made, the first small programs run
+        with span("engine.build") as built:
+            self._build(model, params, config, topology)
+            built.set_metadata(
+                params_bytes=sum(x.nbytes
+                                 for x in jax.tree.leaves(self.params)),
+                arena_bytes=sum(x.nbytes
+                                for x in jax.tree.leaves(self.arena)))
+
+    def _build(self, model, params, config, topology) -> None:
         self.cfg = model.cfg if hasattr(model, "cfg") else model
         self.config = config or RaggedInferenceEngineConfig()
         if params is None:

@@ -73,6 +73,9 @@ SERVING_TAGS = frozenset(
         "queue_depth", "batch_occupancy", "prefill_tokens_step",
         "decode_tokens_step", "prefill_tokens_saved",
         "prefix_cached_blocks",
+        # the host-clock log (utils/spans.py): seconds of garbage
+        # collection inside serve steps; steps whose host time was a pause
+        "gc_seconds", "long_steps",
         # host KV spill tier (serving/kv_tier.py): occupancy gauge +
         # demotion/promotion block and byte counters
         "host_cached_blocks", "kv_demoted_blocks",

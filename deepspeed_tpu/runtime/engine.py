@@ -1127,6 +1127,16 @@ def initialize(
     `model` may be a deepspeed_tpu.models.Model (bundles init/loss/tp rules);
     otherwise pass `loss_fn` + `params` explicitly.
     """
+    # set-up's `train.build` span: config, state initialised and sharded,
+    # the step built (it compiles at the first `train_batch`)
+    with span("train.build"):
+        return _initialize(loss_fn, params, config, topology, tp_rules,
+                           eval_fn, model, mpu, optimizer, lr_scheduler,
+                           training_data)
+
+
+def _initialize(loss_fn, params, config, topology, tp_rules, eval_fn, model,
+                mpu, optimizer, lr_scheduler, training_data) -> TrainEngine:
     if model is not None:
         # a block that is served only says here what training would take
         getattr(model, "refuse_serving_only", lambda what: None)(

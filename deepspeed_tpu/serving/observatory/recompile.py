@@ -9,11 +9,11 @@ the fresh trace constants (PR 4).  The recorder makes it direct:
 
 - **Compile-event hook.**  jax publishes per-compile durations through
   `jax.monitoring` (`/jax/core/compile/backend_compile_duration` fires
-  once per backend compile — probed, not assumed).
-  Listener registration is process-global and permanent (jax has no
-  unregister), so ONE module-level dispatcher is installed lazily and
-  fans out to the live recorders in a WeakSet — recorders can come and
-  go without leaking listeners.
+  once per backend compile — probed, not assumed).  The package has ONE
+  listener on that stream, `utils.device.CompileCounter`'s (it keeps the
+  process's log by phase and function name); an armed recorder
+  subscribes to it and is held weakly, so recorders come and go without
+  leaking listeners.
 - **Timestamped + bounded.**  Each event lands in a `MetricRing` row
   {t, event, duration_s} on the recorder's clock (the serve FakeClock
   in tests — deterministic), evicted-and-counted past `capacity`.
@@ -29,17 +29,15 @@ the fresh trace constants (PR 4).  The recorder makes it direct:
   compiled something".
 
 The recorder observes only while armed (`start()`/`stop()` or the
-context manager) — a stopped recorder costs one WeakSet membership
-test per compile, and serving with no recorder constructed costs
-nothing at all.
+context manager): a stopped recorder is not subscribed and costs
+nothing.
 """
 from __future__ import annotations
 
-import threading
 import time
-import weakref
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
+from ...utils.device import CompileCounter
 from .metrics import MetricRing
 
 __all__ = ["RecompileFlightRecorder", "COMPILE_EVENTS",
@@ -49,30 +47,6 @@ __all__ = ["RecompileFlightRecorder", "COMPILE_EVENTS",
 #: happened" (trace/lowering events are excluded
 #: on purpose — re-tracing a cached program is not a recompile)
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
-
-# process-global dispatcher state: jax.monitoring listeners cannot be
-# unregistered individually, so exactly one is ever installed and it
-# fans out to whatever recorders are alive + armed right now
-_active: "weakref.WeakSet[RecompileFlightRecorder]" = weakref.WeakSet()
-_install_lock = threading.Lock()
-_installed = False
-
-
-def _dispatch(event: str, duration_s: float, **kwargs: Any) -> None:
-    if event not in COMPILE_EVENTS:
-        return
-    for rec in list(_active):
-        rec._on_compile(event, duration_s)
-
-
-def _ensure_listener() -> None:
-    global _installed
-    with _install_lock:
-        if _installed:
-            return
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(_dispatch)
-        _installed = True
 
 
 def program_cache_census(engine=None) -> Dict[str, int]:
@@ -123,18 +97,18 @@ class RecompileFlightRecorder:
         self.total_compile_s = 0.0
         self._armed = False
         self._baseline: Dict[str, int] = {}
-        _ensure_listener()
 
     # -- arming -----------------------------------------------------------
     def start(self) -> "RecompileFlightRecorder":
+        if not self._armed:
+            CompileCounter.subscribe(self._on_compile)
         self._armed = True
-        _active.add(self)
         self._baseline = program_cache_census(self.engine)
         return self
 
     def stop(self) -> None:
         self._armed = False
-        _active.discard(self)
+        CompileCounter.unsubscribe(self._on_compile)
 
     def __enter__(self) -> "RecompileFlightRecorder":
         return self.start()
@@ -148,7 +122,7 @@ class RecompileFlightRecorder:
 
     # -- the hook ---------------------------------------------------------
     def _on_compile(self, event: str, duration_s: float) -> None:
-        if not self._armed:
+        if not self._armed or event not in COMPILE_EVENTS:
             return
         self.total_events += 1
         self.total_compile_s += float(duration_s)

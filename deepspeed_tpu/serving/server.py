@@ -1012,7 +1012,11 @@ class ServeLoop:
             self.clock if self._timeline is not None else None)
         with span("serve.step", step=self.telemetry.steps + 1) as whole, \
                 phases:
-            return self._step_phases(phases, whole)
+            out = self._step_phases(phases, whole)
+        # the step as the host-clock log has it (utils/spans.py): its
+        # length, its wait for the device, its garbage collections
+        self.telemetry.record_host_step(whole.record)
+        return out
 
     def _step_phases(self, phases: _StepPhases, whole) -> List[Request]:
         now = self.clock()

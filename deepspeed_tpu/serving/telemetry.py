@@ -12,10 +12,12 @@ sink API (`write_events([(tag, value, step)])`).
 """
 from __future__ import annotations
 
+import gc
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..utils import spans
 from .request import Request, RequestState
 
 __all__ = ["ServingTelemetry", "FleetTelemetry"]
@@ -124,6 +126,16 @@ class ServingTelemetry:
             # were at their cap (capacity was NOT the blocker)
             "quota_deferred": 0,
         }
+        # the host-clock log of utils/spans.py (`record_host_step`):
+        # seconds this loop's serve steps spent in garbage collections,
+        # and steps whose host time (duration minus the wait inside
+        # `engine.fetch`) reached `spans.LONG_SPAN_NS`: a pause.  Measured
+        # times, so apart from `counters`, which repeat run for run
+        self.gc_seconds = 0.0
+        self.long_steps = 0
+        self.longest_step_s = self.longest_host_step_s = 0.0
+        # the process's collections by generation when this loop began
+        self._gc_collections0 = [g["collections"] for g in gc.get_stats()]
         # REQUEST-dispatch shares: one count per request per verify
         # dispatch it rode (a 16-row dispatch adds 16), with the tokens
         # that request gained.  spec_tokens_per_dispatch is therefore
@@ -227,6 +239,39 @@ class ServingTelemetry:
         row = self.tenants.setdefault(
             tenant, {k: 0 for k in self.TENANT_KEYS})
         row[key] += n
+
+    def record_host_step(self, rec: spans.StepRecord) -> None:
+        """What a closed `serve.step` span left on the host-clock log."""
+        host_ns = rec.duration - rec.wait
+        self.gc_seconds += rec.gc * 1e-9
+        if host_ns >= spans.LONG_SPAN_NS:
+            self.long_steps += 1
+        self.longest_step_s = max(self.longest_step_s, rec.duration * 1e-9)
+        self.longest_host_step_s = max(self.longest_host_step_s,
+                                       host_ns * 1e-9)
+
+    def host_summary(self) -> Dict[str, Any]:
+        """`summary()["host"]`: this loop's steps on the host-clock log,
+        beside two things that are the process's: collections by
+        generation since this loop began, and the longest record of each
+        of the five span names with the longest records in the long-span
+        ring (set-up's builds and compiling dispatches, long fetches,
+        collections: docs/OBSERVABILITY.md says how to read a pause)."""
+        longest: Dict[str, spans.LongRecord] = {}
+        for rec in list(spans.long_spans()):
+            if (rec.name not in longest
+                    or rec.duration > longest[rec.name].duration):
+                longest[rec.name] = rec
+        top = sorted(longest.values(), key=lambda r: -r.duration)[:5]
+        return {
+            "gc_s": self.gc_seconds, "long_steps": self.long_steps,
+            "gc_collections": [g["collections"] - g0 for g, g0 in zip(
+                gc.get_stats(), self._gc_collections0)],
+            "longest_step_s": self.longest_step_s,
+            "longest_host_step_s": self.longest_host_step_s,
+            "long_spans": [{"name": r.name, "parent": r.parent,
+                            "step": r.step, "seconds": r.duration * 1e-9,
+                            **r.attrs} for r in top]}
 
     def record_finish(self, req: Request) -> None:
         if req.state is RequestState.DONE:
@@ -409,6 +454,7 @@ class ServingTelemetry:
         )
         if elapsed_s is not None and elapsed_s > 0:
             out["goodput_tok_s"] = sum(self.tokens_out) / elapsed_s
+        out["host"] = self.host_summary()
         if self.timeline is not None:
             out["step_phases"] = self.timeline.aggregates()
         # multi-tenant view: only present when tenancy produced rows /
@@ -435,6 +481,8 @@ class ServingTelemetry:
             ("serving/prefill_tokens_step", self.prefill_tokens_step),
             ("serving/decode_tokens_step", self.decode_tokens_step),
             ("serving/prefill_tokens_saved", self.prefill_tokens_saved),
+            ("serving/gc_seconds", self.gc_seconds),
+            ("serving/long_steps", self.long_steps),
         ]
         if self.prefix_cached_blocks is not None:
             gauges.append(("serving/prefix_cached_blocks",
@@ -510,6 +558,8 @@ class ServingTelemetry:
         for key, v in self.counters.items():
             emit(f"{prefix}_{key}_total", v, "counter")
         emit(f"{prefix}_steps_total", self.steps, "counter")
+        emit(f"{prefix}_gc_seconds_total", self.gc_seconds, "counter")
+        emit(f"{prefix}_long_steps_total", self.long_steps, "counter")
         emit(f"{prefix}_queue_depth", self.queue_depth)
         emit(f"{prefix}_batch_occupancy", self.batch_occupancy)
         emit(f"{prefix}_prefill_tokens_step", self.prefill_tokens_step)

@@ -6,15 +6,22 @@ from `jax.devices()` propagate: a process that cannot reach its device
 must fail there, not run a slower path that looks plausible.
 
 Also here, because they are process-wide the same way: where the
-persistent compile cache lives, and the count of what JAX compiled.
+persistent compile cache lives, and the log of what JAX traced, lowered
+and compiled (the package's one `jax.monitoring` listener).
 """
 from __future__ import annotations
 
+import collections
 import os
+import time
+import weakref
+from typing import Deque, List, NamedTuple, Optional
 
 import jax
+import jax.monitoring
 
-__all__ = ["platform", "on_tpu", "place_compile_cache", "CompileCounter"]
+__all__ = ["platform", "on_tpu", "place_compile_cache", "CompileCounter",
+           "CompileEvent", "COMPILE_PHASES"]
 
 
 def platform() -> str:
@@ -48,25 +55,101 @@ def place_compile_cache(min_compile_secs: float = 0.0) -> str:
     return path
 
 
+# -- what JAX compiled: the package's one `jax.monitoring` listener ------------
+# the three phases jax reports with the name of the function they worked
+# on, and the persistent cache's own read
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir_module",
+    # wraps the look in the persistent cache: on a hit it is the
+    # retrieval, on a miss the compile (and the write)
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_request",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+# bounded like the rings of utils/spans.py; a traced program reports every
+# jitted function traced inside it, thousands of events a serving set-up
+COMPILE_RING = 65_536
+
+
+class CompileEvent(NamedTuple):
+    phase: str                 # a value of COMPILE_PHASES or CACHE_EVENTS
+    fun_name: Optional[str]    # as jax names the function; the cache: None
+    start: int                 # `time.perf_counter_ns`, as a span's `t0`
+    seconds: float             # 0.0 for a cache event
+
+
+_events: Deque[CompileEvent] = collections.deque(maxlen=COMPILE_RING)
+_totals = {"requests": 0, "hits": 0, "compile_s": 0.0}
+_subscribers: List[weakref.WeakMethod] = []
+
+
+def _on_event(name: str, **kw) -> None:
+    phase = CACHE_EVENTS.get(name)
+    if phase is None:
+        return
+    if phase == "cache_request":
+        _totals["requests"] += 1
+    elif phase == "cache_hit":
+        _totals["hits"] += 1
+    _events.append(CompileEvent(phase, None, time.perf_counter_ns(), 0.0))
+
+
+def _on_duration(name: str, seconds: float, **kw) -> None:
+    phase = COMPILE_PHASES.get(name)
+    if phase is None:
+        return
+    if phase == "backend_compile":
+        _totals["compile_s"] += seconds
+    _events.append(CompileEvent(
+        phase, kw.get("fun_name"),
+        time.perf_counter_ns() - int(seconds * 1e9), float(seconds)))
+    for ref in list(_subscribers):
+        callback = ref()
+        if callback is None:
+            _subscribers.remove(ref)
+        else:
+            callback(name, seconds)
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
 class CompileCounter:
-    """Counts what JAX compiled and what its persistent cache served."""
+    """What JAX compiled and what its persistent cache served since this
+    counter was made.  The listener is the module's, installed once and
+    listening from the import on: `events()` is the whole process's log,
+    by phase and function name."""
 
     def __init__(self):
-        import jax.monitoring as mon
-        self.requests = self.hits = 0
-        self.compile_s = 0.0
-        mon.register_event_listener(self._event)
-        mon.register_event_duration_secs_listener(self._duration)
+        self._base = dict(_totals)
 
-    def _event(self, name, **kw):
-        if name == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
+    requests = property(lambda self: _totals["requests"]
+                        - self._base["requests"])
+    hits = property(lambda self: _totals["hits"] - self._base["hits"])
+    compile_s = property(lambda self: _totals["compile_s"]
+                         - self._base["compile_s"])
 
-    def _duration(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
+    @staticmethod
+    def events() -> Deque[CompileEvent]:
+        """The process's compile events, oldest first (the ring itself)."""
+        return _events
+
+    @staticmethod
+    def subscribe(callback) -> None:
+        """`callback(jax event name, seconds)` (a bound method, held
+        weakly) at every duration event of COMPILE_PHASES from now on."""
+        _subscribers.append(weakref.WeakMethod(callback))
+
+    @staticmethod
+    def unsubscribe(callback) -> None:
+        _subscribers[:] = [ref for ref in _subscribers
+                           if ref() not in (None, callback)]
 
     def snapshot(self) -> dict:
         return {"compiled": self.requests - self.hits,

@@ -621,7 +621,13 @@ def test_span_catalogue_holds_the_source_to_one_primitive():
             assert f"def {gone}" not in text, (path, gone)
     assert direct == []
     assert found == set(spans.SPAN_NAMES)
+    assert {"host.import", "host.gc", "engine.build", "train.build"} <= found
     assert not (root / "utils" / "nvtx.py").exists()
+    # one `jax.monitoring` listener in the package: `CompileCounter`'s
+    listeners = [str(path.relative_to(root)) for path in root.rglob("*.py")
+                 if re.search(r"register_event\w*listener\(",
+                              path.read_text(encoding="utf-8"))]
+    assert listeners == ["utils/device.py"]
 
 
 @pytest.mark.parametrize("burst", [1, 4], ids=["per_step", "burst"])
@@ -714,6 +720,219 @@ def test_train_spans_land_in_a_real_profiler_trace(tmp_path):
     for name in ("train.shard_batch", "train.dispatch"):
         inner = [e for e in evs if e[2] == name]
         assert len(inner) == 2 and all(_inside(e, steps) for e in inner)
+
+
+# -- the host-clock log: spans outside a profiler session -------------------
+class BlockedFetchEngine(FakeEngine):
+    """A fake engine whose step `block_at` waits for its tokens inside an
+    `engine.fetch` span, as the real one does."""
+
+    def __init__(self, block_at, seconds=0.1, **kw):
+        super().__init__(**kw)
+        self.block_at, self.seconds, self.steps = block_at, seconds, 0
+
+    def step(self, decode=True):
+        from deepspeed_tpu.utils.spans import span
+        import time
+        self.steps += 1
+        with span("engine.fetch", program="fake", bytes=4) as fetch:
+            if self.steps == self.block_at:
+                time.sleep(self.seconds)        # the one planted sleep
+            fetch.set_metadata(rows=len(self.state.seqs))
+        return super().step(decode=decode)
+
+
+def test_every_step_leaves_a_record_whose_wait_and_gc_add_up():
+    """No profiler session: each `serve.step` still leaves one record; a
+    fetch that blocks is the step's `wait`, a forced collection its `gc`
+    (and its host time), and the operator's summary says both."""
+    import gc
+    from deepspeed_tpu.utils import spans
+    spans.steps().clear()
+    spans.long_spans().clear()
+    loop = ServeLoop(BlockedFetchEngine(block_at=3, max_seqs=2, budget=8),
+                     ServingConfig(), clock=FakeClock())
+    loop.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=6)
+    took = []
+    record_step = loop.telemetry.record_step
+
+    def collecting(*args, **kw):
+        if loop.telemetry.steps == 4:           # inside the fifth step
+            t0 = spans.perf_counter_ns()
+            gc.collect()
+            took.append(spans.perf_counter_ns() - t0)
+        return record_step(*args, **kw)
+    loop.telemetry.record_step = collecting
+    while loop.has_work:
+        loop.step()
+    recs = list(spans.steps())
+    assert [r.name for r in recs] == ["serve.step"] * loop.telemetry.steps
+    assert [r.step for r in recs] == list(range(1, len(recs) + 1))
+    blocked, collected = recs[2], recs[4]
+    assert blocked.wait >= 100e6 and blocked.duration >= blocked.wait
+    assert blocked.duration - blocked.wait < blocked.wait   # host's share
+    assert len(took) == 1
+    assert 0.8 * took[0] <= collected.gc <= took[0] <= collected.duration
+    assert collected.wait < collected.gc
+    assert all(0 <= r.wait <= r.duration for r in recs)
+    # the blocked fetch is a long span under the phase that made it (which
+    # is as long, and so recorded too, after it: it closed later)
+    long = [r for r in spans.long_spans() if r.step == 3]
+    assert [(r.name, r.parent) for r in long] == [
+        ("engine.fetch", "serve.engine"), ("serve.engine", "serve.step")]
+    assert long[0].attrs == {"program": "fake", "bytes": 4, "rows": 1}
+    assert long[0].duration == pytest.approx(blocked.wait, rel=0.05)
+    # the operator's side
+    host = loop.telemetry.summary()["host"]
+    assert 0.1 <= host["longest_step_s"] == pytest.approx(
+        max(r.duration for r in recs) * 1e-9)
+    assert host["longest_host_step_s"] == pytest.approx(
+        (collected.duration - collected.wait) * 1e-9)
+    assert host["gc_s"] == pytest.approx(sum(r.gc for r in recs) * 1e-9)
+    assert host["gc_s"] >= 0.8e-9 * took[0]
+    assert host["gc_collections"][2] >= 1
+    long_steps = host["long_steps"]
+    assert long_steps == loop.telemetry.long_steps == (
+        1 if collected.duration - collected.wait >= 50e6 else 0)
+    # measured times stay out of `counters`, which repeat run for run
+    assert not {"gc_seconds", "long_steps"} & set(loop.telemetry.counters)
+    by_name = {r["name"]: r for r in host["long_spans"]}
+    assert len(by_name) == len(host["long_spans"]) <= 5
+    assert by_name["engine.fetch"]["seconds"] >= 0.1
+    assert by_name["engine.fetch"]["parent"] == "serve.engine"
+    assert by_name["engine.fetch"]["program"] == "fake"
+    text = loop.telemetry.prometheus_text()
+    assert f"dstpu_serving_gc_seconds_total {host['gc_s']:g}" in text
+    assert f"dstpu_serving_long_steps_total {long_steps:g}" in text
+    assert schema.unregistered(["serving/gc_seconds",
+                                "serving/long_steps"]) == []
+
+
+def test_other_spans_are_recorded_from_50_ms_and_not_below():
+    from deepspeed_tpu.utils import spans
+    spans.long_spans().clear()
+    now = spans.perf_counter_ns
+    with spans.span("engine.plan", rows=3) as plan:
+        plan.begun(now() - spans.LONG_SPAN_NS + 10_000_000)   # 40 ms
+    assert list(spans.long_spans()) == []
+    with spans.span("serve.step", step=41):
+        with spans.span("serve.engine"):
+            with spans.span("engine.dispatch", program="p") as late:
+                late.begun(now() - spans.LONG_SPAN_NS)
+                late.set_metadata(tokens=7)
+    (rec,) = spans.long_spans()
+    assert (rec.name, rec.parent, rec.step) \
+        == ("engine.dispatch", "serve.engine", 41)
+    assert rec.attrs == {"program": "p", "tokens": 7}
+    assert rec.duration >= spans.LONG_SPAN_NS
+    with spans.span("engine.build") as built:      # under no span at all
+        built.begun(now() - 2 * spans.LONG_SPAN_NS)
+    assert (spans.long_spans()[-1].parent, spans.long_spans()[-1].step) \
+        == (None, None)
+
+
+def test_the_rings_are_bounded_and_drop_the_oldest():
+    from deepspeed_tpu.utils import spans
+    spans.steps().clear()
+    spans.long_spans().clear()
+    for i in range(spans.STEP_RING + 5):
+        with spans.span("train.step", step=i):
+            pass
+    assert len(spans.steps()) == spans.STEP_RING == 16_384
+    assert (spans.steps()[0].step, spans.steps()[-1].step) \
+        == (5, spans.STEP_RING + 4)
+    for i in range(spans.LONG_RING + 3):
+        with spans.span("engine.plan", rows=i) as old:
+            old.begun(spans.perf_counter_ns() - spans.LONG_SPAN_NS)
+    assert len(spans.long_spans()) == spans.LONG_RING == 4_096
+    assert spans.long_spans()[0].attrs == {"rows": 3}
+    spans.steps().clear()
+    spans.long_spans().clear()
+
+
+def test_a_step_counts_the_collections_of_other_threads_too():
+    """A collection stops every thread, so the open step is charged it
+    whichever thread collected; `wait` is the step's own thread's."""
+    import gc
+    import threading
+    from deepspeed_tpu.utils import spans
+
+    def elsewhere():
+        with spans.span("engine.fetch", program="other", bytes=1) as f:
+            f.begun(spans.perf_counter_ns() - spans.LONG_SPAN_NS)
+        gc.collect()
+    with spans.span("serve.step", step=1) as step:
+        worker = threading.Thread(target=elsewhere)
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+    assert step.record.gc > 0 and step.record.wait == 0
+
+
+def test_host_gc_lands_in_the_xplane_and_in_the_open_steps_gc(tmp_path):
+    """With a session open a forced collection is a `host.gc` event with
+    its generation and what it collected, inside the step that was open;
+    the step's record carries the same collection's time."""
+    import gc
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    from deepspeed_tpu.utils import spans
+    with _traced(tmp_path):
+        with spans.span("serve.step", step=9) as step:
+            step.set_metadata(decode_rows=5)
+            gc.collect()
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    events = [(e.name.split("#", 1)[0], int(e.start_ns),
+               int(e.start_ns) + int(e.duration_ns), dict(e.stats))
+              for plane in ProfileData.from_file(files[-1]).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith(("host.gc", "serve.step"))]
+    (outer,) = [e for e in events if e[0] == "serve.step"]
+    # attributes and `set_metadata` still reach the profiler's trace
+    assert outer[3]["step"] == 9 and outer[3]["decode_rows"] == 5
+    full = [e for e in events if e[0] == "host.gc"
+            and e[3]["generation"] == 2]
+    assert len(full) == 1 and "collected" in full[0][3]
+    assert outer[1] <= full[0][1] and full[0][2] <= outer[2]
+    assert step.record.gc >= 0.5 * (full[0][2] - full[0][1])
+    assert step.record.gc <= step.record.duration
+    assert step.attrs == {"step": 9, "decode_rows": 5}
+
+
+def test_compile_counter_keeps_the_phases_apart_by_function():
+    """The package's one listener: trace, lowering and backend compile of
+    a function by its name, each with its start on the spans' clock."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.utils import spans
+    from deepspeed_tpu.utils.device import (COMPILE_PHASES, CompileCounter)
+
+    def a_named_program(x):
+        return x * 5 - 2
+    counter = CompileCounter()
+    t0 = spans.perf_counter_ns()
+    jax.jit(a_named_program)(jnp.ones(3))
+    t1 = spans.perf_counter_ns()
+    # by time, not by index: the ring may be full, and then it does not grow
+    mine = [e for e in list(CompileCounter.events())
+            if e.start >= t0 and e.fun_name
+            and "a_named_program" in e.fun_name]
+    assert {e.phase for e in mine} == {"jaxpr_trace", "jaxpr_to_mlir_module",
+                                       "backend_compile"}
+    assert set(COMPILE_PHASES.values()) == {
+        "jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile",
+        "cache_retrieval"}
+    assert all(t0 <= e.start <= t1 and e.seconds > 0 for e in mine)
+    assert all(e.start + e.seconds * 1e9 <= t1 + 1e6 for e in mine)
+    backend = [e for e in mine if e.phase == "backend_compile"]
+    assert counter.compile_s >= sum(e.seconds for e in backend) > 0
+    # a second counter starts from zero; the first keeps its total
+    assert CompileCounter().compile_s == 0.0 < counter.compile_s
+    jax.jit(a_named_program)(jnp.ones(3))       # jit's own cache: no event
+    assert [e for e in list(CompileCounter.events())
+            if e.start > t1 and e.phase == "backend_compile"] == []
 
 
 def test_submit_due_starts_the_request_clock_when_the_caller_says():
