@@ -61,6 +61,10 @@ decode step).  On the chip they are `ops/grouped_matmul.py` over the live
 (expert of the whole stack, row tile) items of the layer at hand, gate and
 up in one pass; elsewhere three `lax.ragged_dot` calls with group sizes that
 are zero outside the layer (`_use_expert_kernel`: the platform's choice).
+Their float32 outputs come back to their tokens by one gather a pick and a
+sum over the picks in a fixed order (scope `experts/combine`) where the
+compact buffer holds every assignment (`cap == T * k`: a fact of the
+program's shape), and by the pieces' scatter-add where it holds a share.
 
 The arena is ONE array `[A, blocks, block_size, W]`: row `[c | rope(kr) |
 unused]` per token and attention (A = `cfg.latent_attentions`: attention
@@ -350,18 +354,21 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
         n_local = jnp.sum(sizes)
         cap = local_rows_cap(T * k, El, E + cfg.moe_zero_experts)
         ends = jnp.cumsum(sizes)
-        order = jnp.pad(order, (0, cap))     # a window never slides back
+        # the buffer holds every assignment (all the router's experts are
+        # held here, or the program is tiny): one piece, no overflow
+        whole = cap == T * k
+        if not whole:
+            order = jnp.pad(order, (0, cap))  # a window never slides back
         kernel = _use_expert_kernel()
         tile = grouped_matmul.row_tile(cap)
         every = jnp.zeros((experts["w_up"].shape[0],), jnp.int32)
 
-        def piece(i, carry):
-            """Rows [i * cap, (i + 1) * cap) of the sorted assignments."""
-            acc, engaged = carry      # the kernel's two counts, where it runs
-            lo = i * cap
-            sel = jax.lax.dynamic_slice(order, (lo,), (cap,))
-            part = (jnp.clip(ends, lo, lo + cap)
-                    - jnp.clip(ends - sizes, lo, lo + cap))   # per expert
+        def outputs(sel, part):
+            """The experts' outputs for the `cap` sorted assignments `sel`,
+            `part` of them each expert's: (their tokens [cap], the down
+            projections' products [cap, H] float32, the kernel's two
+            counts where it runs).  Rows past the last group belong to no
+            expert held here and hold anything."""
             # `ragged_dot`'s groups of the whole stack: empty outside this
             # layer
             groups = None if kernel else jax.lax.dynamic_update_slice(
@@ -376,27 +383,56 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
                     tile=tile, gate_act=gate_act, out_dtype=dt)
                 down = grouped_matmul.grouped_matmul(
                     act, (experts["w_down"],), items, tile=tile)
-                engaged = engaged + jnp.stack([
+                return tok, down, jnp.stack([
                     grouped_matmul.weight_fetches(items),
                     jnp.sum(part > 0).astype(jnp.int32)])
-            else:
-                g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
-                                       preferred_element_type=jnp.float32)
-                u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
-                                       preferred_element_type=jnp.float32)
-                act = (gate_act(g) * u).astype(dt)
-                down = jax.lax.ragged_dot(act, experts["w_down"], groups,
-                                          preferred_element_type=jnp.float32)
-            # rows past the last group belong to no expert held here
-            mine = (lo + jnp.arange(cap) < n_local)[:, None]
-            return acc.at[tok].add(
-                jnp.where(mine, down * wf[sel][:, None], 0.0)), engaged
+            g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
+                                   preferred_element_type=jnp.float32)
+            u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
+                                   preferred_element_type=jnp.float32)
+            act = (gate_act(g) * u).astype(dt)
+            return tok, jax.lax.ragged_dot(
+                act, experts["w_down"], groups,
+                preferred_element_type=jnp.float32), ()
 
-        # one piece unless routing piles more than `cap` rows on this share
-        routed, engaged = jax.lax.fori_loop(
-            0, (n_local + cap - 1) // cap, piece,
-            (jnp.zeros((T, H), jnp.float32),
-             jnp.zeros((2,), jnp.int32) if kernel else ()))
+        if whole:
+            # every (token, pick) has its row in the buffer, so the k
+            # outputs of a token are gathered, not scatter-added
+            _, down, engaged = outputs(order, sizes)
+            with jax.named_scope("combine"):
+                # the row of the sorted buffer that holds pick j of token
+                # t: `order`'s inverse, a permutation (never out of bounds)
+                pos = jnp.argsort(order).reshape(T, k)
+                mine = local.reshape(T, k)
+                routed = jnp.zeros((T, H), jnp.float32)
+                for j in range(k):
+                    rows = down.at[pos[:, j]].get(mode="promise_in_bounds")
+                    # `where`, not a product with 0: a row no expert held
+                    # here wrote may hold anything
+                    routed = routed + jnp.where(
+                        mine[:, j, None], weight[:, j, None] * rows, 0.0)
+        else:
+            def piece(i, carry):
+                """Rows [i * cap, (i + 1) * cap) of the sorted
+                assignments."""
+                acc, engaged = carry  # the kernel's two counts, where it runs
+                lo = i * cap
+                sel = jax.lax.dynamic_slice(order, (lo,), (cap,))
+                part = (jnp.clip(ends, lo, lo + cap)
+                        - jnp.clip(ends - sizes, lo, lo + cap))  # per expert
+                tok, down, reached = outputs(sel, part)
+                engaged = engaged + reached      # () + () off the chip
+                # rows past the last group belong to no expert held here
+                mine = (lo + jnp.arange(cap) < n_local)[:, None]
+                return acc.at[tok].add(
+                    jnp.where(mine, down * wf[sel][:, None], 0.0)), engaged
+
+            # one piece unless routing piles more than `cap` rows on this
+            # share
+            routed, engaged = jax.lax.fori_loop(
+                0, (n_local + cap - 1) // cap, piece,
+                (jnp.zeros((T, H), jnp.float32),
+                 jnp.zeros((2,), jnp.int32) if kernel else ()))
     picked = picked.reshape(T, k)
     counts = [jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
               jnp.max(sizes), jnp.ones((), jnp.int32)]

@@ -413,6 +413,32 @@ def test_train_step_of_the_opt_cell_fits_without_clones(topo, chip,
     assert peak < 1.03 * TRAIN_STEP_PEAK_BYTES, peak
 
 
+def abstract_cell(chip, monkeypatch, config: str, reference: str):
+    """A serving cell's programs' operands for the described chip, from
+    `benchmark/configs/<config>.json` and its reference module's abstract
+    weights: (cfg, params, arena, the file's engine options, the reference,
+    its sizes), with the platform's gates flipped to the chip's."""
+    from benchmark import harness
+    from deepspeed_tpu.inference.v2 import ragged_ops
+    from deepspeed_tpu.inference.v2.model_registry import arch_config
+    from deepspeed_tpu.utils import device
+
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
+    file = harness.load_json(harness.BENCH_DIR, "configs", config + ".json")
+    ref = harness.load_module(harness.BENCH_DIR, "references", reference)
+    prog, sizes = file["program"], ref.sizes(file)
+    cfg = arch_config(prog["arch"], prog["size"], dtype=BF16,
+                      **prog["overrides"])
+    eng = prog["engine"]
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: chip(a.shape, a.dtype), tree)
+    params = on(jax.eval_shape(lambda: ref._make_params(
+        jnp.uint32(0), s=sizes, dtype=BF16)))
+    arena = on(jax.eval_shape(lambda: ragged_ops.init_arena(
+        cfg, eng["num_blocks"], 64, max_seqs=eng["max_seqs"])))
+    return cfg, params, arena, eng, ref, sizes
+
+
 # `deepseek-v3.decode_closed_chat`'s programs: what the v5e compiler reports
 # for the weights (12.37 GB), the arena (1.18 GB) and each program's
 # temporaries; the chip has 16.9 GB to give
@@ -432,25 +458,11 @@ def test_serving_programs_of_the_deepseek_cell_fit_and_discard_nothing(
     body: no layer computes a branch it throws away.  ~10 s a program."""
     import re
 
-    from benchmark import harness
     from deepspeed_tpu.inference.v2 import ragged_ops
-    from deepspeed_tpu.inference.v2.model_registry import arch_config
-    from deepspeed_tpu.utils import device
 
-    monkeypatch.setattr(device, "platform", lambda: "tpu")
-    file = harness.load_json(harness.BENCH_DIR, "configs", "deepseek-v3.json")
-    ref = harness.load_module(harness.BENCH_DIR, "references", "deepseek_v3")
-    prog, sizes = file["program"], ref.sizes(file)
-    cfg = arch_config(prog["arch"], prog["size"], dtype=BF16,
-                      **prog["overrides"])
-    eng = prog["engine"]
+    cfg, params, arena, eng, ref, sizes = abstract_cell(
+        chip, monkeypatch, "deepseek-v3", "deepseek_v3")
     B, MB = eng["max_seqs"], eng["max_blocks_per_seq"]
-    on = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: chip(a.shape, a.dtype), tree)
-    params = on(jax.eval_shape(lambda: ref._make_params(
-        jnp.uint32(0), s=sizes, dtype=BF16)))
-    arena = on(jax.eval_shape(lambda: ragged_ops.init_arena(
-        cfg, eng["num_blocks"], 64, max_seqs=B)))
     held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
     assert held == ref.weight_bytes(sizes, "bfloat16")
     assert arena["c"].shape == (5, 2890, 64, 640)
@@ -486,3 +498,35 @@ def test_serving_programs_of_the_deepseek_cell_fit_and_discard_nothing(
         assert len(under("shared_expert")) == 3
         assert all("while/body" in l for l in under("shared_expert"))
         assert not any("/while/body/while" in l for l in under("dense_ffn"))
+
+
+def test_the_chunk_program_of_the_smallthinker_cell_gathers_its_experts(
+        topo, chip, monkeypatch):
+    """`smallthinker-21b-a3b.decode_closed_long`'s `prefill_chunks[1,
+    12288]` (`benchmark/configs/smallthinker-21b-a3b.json`: 8 layers at the
+    published widths, all 64 experts held, so a 4096-row pass's compact
+    buffer holds its 24,576 assignments), compiled for the described chip
+    from abstract weights: the experts' outputs come back to their tokens
+    through `experts/combine` (a gather a pick, no scatter-add of rows, no
+    loop over pieces around the kernels), and the program fits as it did.
+    ~25 s."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import ragged_ops
+
+    cfg, params, arena, eng, _, _ = abstract_cell(
+        chip, monkeypatch, "smallthinker-21b-a3b", "smallthinker")
+    MB, S = eng["max_blocks_per_seq"], eng["prefill_chunk_size"]
+    i32 = lambda *shape: chip(shape, jnp.int32)  # noqa: E731
+    with jax.default_matmul_precision("default"):
+        compiled = ragged_ops.prefill_chunks.lower(
+            cfg, params, arena, i32(1, S), i32(1), i32(1), i32(1, 2, MB),
+            chip((1,), jnp.bool_)).compile()
+    mem, hlo = compiled.memory_analysis(), compiled.as_text()
+    assert mem.peak_memory_in_bytes < 1.02 * 12.45e9
+    rows = r"f32\[4096,2560\]"
+    gathers = re.findall(rows + r"\{1,0[^\n]* fusion\([^\n]*"
+                         r"experts/combine/gather", hlo)
+    assert len(gathers) >= cfg.moe_top_k
+    assert not re.search(rows + r"[^\n]*experts/[^\n]*scatter-add", hlo)
+    assert "experts/while/body" not in hlo
