@@ -104,6 +104,9 @@ class LogitsRows(Mapping):
         # a two-kind cache's account of the decode rows, by counter name
         # (`InferenceEngineV2._count_kinds`); one kind: nothing
         self.kv_kinds: Dict[str, int] = {}
+        # a decode step's account of per-sequence recurrent state
+        # (`InferenceEngineV2._count_state`); a model without: nothing
+        self.state_account: Dict[str, int] = {}
         # what `engine.collect` returns: the rows it left out because
         # their sequence had been flushed (or replaced under its uid)
         self.overrun = 0
@@ -293,6 +296,17 @@ class InferenceEngineV2:
                 "tp_collectives='fused') cannot serve the latent (MLA) "
                 "block: its cache has no head dimension to shard and its "
                 "kernel and expert share are not wrapped for a mesh")
+        # a state-space parallel block: per-sequence recurrent state in
+        # slots beside the paged K/V
+        self._ssm = bool(getattr(self.cfg, "ssm", False))
+        if self._ssm and (self.tp > 1
+                          or self.config.tp_collectives != "xla"):
+            raise NotImplementedError(
+                "tensor parallelism (tensor_parallel_size > 1, "
+                "tp_collectives='fused') cannot serve the state-space "
+                "parallel block: the per-sequence recurrent state and the "
+                "mixer's heads are not split over a mesh, and its scan and "
+                "update kernels are not wrapped for one")
         # a static-kind stack (window + global layers): two kinds of cache
         self._kinds = bool(getattr(self.cfg, "static_kinds", False))
         if self._kinds and (self.tp > 1
@@ -344,7 +358,18 @@ class InferenceEngineV2:
             window = (self.cfg.window, self.arena["wk"].shape[1])
         self.state = DSStateManager(
             nb, self.config.block_size, self.config.max_blocks_per_seq,
-            self.config.max_seqs, window=window)
+            self.config.max_seqs, window=window,
+            # recurrent state: a slot a decode row, so a live sequence
+            # always has one and `free_slots` counts both
+            state_slots=self.config.max_seqs if self._ssm else 0)
+        if self._ssm:
+            # what `_count_state` multiplies: the bytes a row's slot moves
+            # both ways a decode step, and of K/V a token holds
+            from .ssm_ops import state_bytes_per_slot
+            self._state_bytes_row = 2 * state_bytes_per_slot(self.cfg)
+            self._kv_bytes_token = (
+                2 * self.cfg.num_layers * self.cfg.kv_heads
+                * self.cfg.head_dim * jnp.dtype(self.cfg.dtype).itemsize)
         # per-sequence token ceiling: arena lease AND model context — learned
         # position embeddings clip silently past max_seq_len, so enforce it
         # here with a loud error instead
@@ -493,8 +518,17 @@ class InferenceEngineV2:
 
     def _refuse_latent(self, what: str) -> None:
         """Mechanisms written for ONE kind of per-head K/V block pair
-        refuse the latent (MLA) arena and the two-kind cache where they
-        are switched on."""
+        refuse the latent (MLA) arena, the two-kind cache and
+        per-sequence recurrent state where they are switched on."""
+        if self._ssm:
+            raise NotImplementedError(
+                f"{what}: not wired for per-sequence recurrent state. A "
+                f"sequence of this model is its K/V blocks AND a slot of "
+                f"state-space state and convolution tail that every token "
+                f"rewrites: a block holds no snapshot of the state at its "
+                f"edge (so a cached or migrated prefix could not be "
+                f"continued), and the programs take no LoRA, draft-span, "
+                f"burst or multi-step operands")
         if self._latent:
             raise NotImplementedError(
                 f"{what}: not wired for the latent (MLA) arena, which "
@@ -514,7 +548,13 @@ class InferenceEngineV2:
     # the serving layer probes this before enabling an adapter pool
     @property
     def supports_lora(self) -> bool:
-        return not (self._latent or self._kinds)
+        return not (self._latent or self._kinds or self._ssm)
+
+    # per-sequence recurrent state beside the blocks: the serving layer
+    # refuses what assumes "a sequence's state is its blocks"
+    @property
+    def recurrent_state(self) -> bool:
+        return self._ssm
 
     def attach_lora(self, lora) -> None:
         """Attach (None = detach) the stacked multi-LoRA factors the
@@ -962,6 +1002,7 @@ class InferenceEngineV2:
                     flens = np.zeros(NS, np.int32)
                     ftables = np.zeros((NS,) + self.state.table_shape, np.int32)
                     factive = np.zeros(NS, bool)
+                    fslots = np.zeros(NS, np.int32)
                     for i, d in enumerate(fresh):
                         n = len(d.prompt)
                         self.state.ensure_capacity(d, n)
@@ -969,13 +1010,15 @@ class InferenceEngineV2:
                         flens[i] = n
                         ftables[i] = self.state.block_table(d)
                         factive[i] = True
+                        fslots[i] = d.state_slot
                 plan.set_metadata(rows=len(fresh))
             if fresh:
                 with span("engine.dispatch", program="prefill_full"):
                     logits, toks, self.arena = prefill_full(
                         self.cfg, self.params, self.arena,
                         self._host_in(ftokens), self._host_in(flens),
-                        self._host_in(ftables), self._host_in(factive))
+                        self._host_in(ftables), self._host_in(factive),
+                        **self._slots_kw(fslots))
                 for d in fresh:
                     d.seen_tokens = len(d.prompt)
                 pending.prefill.append(_Program(
@@ -1009,10 +1052,14 @@ class InferenceEngineV2:
             tlens = np.zeros(cap_alloc, np.int32)
             tables = np.zeros((cap_alloc,) + self.state.table_shape, np.int32)
             active = np.zeros(cap_alloc, bool)
+            cslots = np.zeros(cap_alloc, np.int32)
+            # (recurrent state: a chunk starts from what the chunk before
+            # it left in the slot, so a program holds one chunk a sequence)
+            chunked = set()
             while budget > 0 and len(planned) < cap:
                 d = next((s for s in self.state.seqs.values()
-                          if pseen[s.uid] < len(s.prompt) and not s.done),
-                         None)
+                          if pseen[s.uid] < len(s.prompt) and not s.done
+                          and s.uid not in chunked), None)
                 if d is None:
                     break
                 start = pseen[d.uid]
@@ -1033,6 +1080,9 @@ class InferenceEngineV2:
                 tlens[i] = len(d.prompt)
                 tables[i] = self.state.block_table(d)
                 active[i] = True
+                cslots[i] = d.state_slot
+                if self._ssm:
+                    chunked.add(d.uid)
                 planned.append((d, start, n))
                 pseen[d.uid] = start + n
                 budget -= n
@@ -1049,7 +1099,8 @@ class InferenceEngineV2:
                     self.params, self.arena, self._host_in(tokens[:NC]),
                     self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
                     self._host_in(tables[:NC]), self._host_in(active[:NC]),
-                    self._host_in(tlens[:NC]), **lkw)
+                    self._host_in(tlens[:NC]), **lkw,
+                    **self._slots_kw(cslots[:NC]))
             for d, start, n in planned:
                 d.seen_tokens = start + n
                 if self._kinds:
@@ -1081,7 +1132,9 @@ class InferenceEngineV2:
                 lens = np.zeros(B, np.int32)
                 tables = np.zeros((B,) + self.state.table_shape, np.int32)
                 active = np.zeros(B, bool)
+                dslots = np.zeros(B, np.int32)
                 for i, d in enumerate(batch):
+                    dslots[i] = d.state_slot
                     if id(d) in fed:
                         source[i] = fed[id(d)]
                     else:
@@ -1104,7 +1157,7 @@ class InferenceEngineV2:
                         ahead.decode.toks if fed else self._no_tokens,
                         self._host_in(source)),
                     self._host_in(lens), self._host_in(tables),
-                    self._host_in(active), **lkw)
+                    self._host_in(active), **lkw, **self._slots_kw(dslots))
             pending.kv_live_blocks = sum(
                 d.seen_tokens // self.config.block_size + 1 for d in batch)
             pending.kv_table_blocks = tables.size
@@ -1116,7 +1169,35 @@ class InferenceEngineV2:
                                       list(zip(batch, range(len(batch)))))
             pending.decode_rows = len(batch)
             pending.fed_rows = sum(id(d) in fed for d in batch)
+        if self._ssm:
+            # on every step, so that every `serve.step` span has the four
+            # attributes (a reader sums them over the spans there are)
+            self._count_state(
+                pending, len(batch),
+                int(lens.sum()) + len(batch) if batch else 0)
         return pending
+
+    def _slots_kw(self, slots) -> dict:
+        """The rows' recurrent-state slots as a program's `slots=`; a
+        model without such state hands its programs the operands it
+        always did."""
+        return {"slots": self._host_in(slots)} if self._ssm else {}
+
+    def _count_state(self, pending: LogitsRows, rows: int,
+                     tokens: int) -> None:
+        """A step's account of per-sequence recurrent state, from its
+        decode `rows` (none: the byte counts are 0) and the `tokens` they
+        attend to: the slots there are and those live sequences hold; the
+        bytes of state the step must read and write back (every row's
+        slot, both ways) and of cache altogether (those and the rows' keys
+        and values)."""
+        state = rows * self._state_bytes_row
+        pending.state_account = dict(
+            state_slots=self.state.state_slots,
+            state_slots_live=(self.state.state_slots
+                              - self.state.free_state_slots),
+            state_bytes_step=state,
+            cache_bytes_step=state + tokens * self._kv_bytes_token)
 
     def _count_kinds(self, pending: LogitsRows, batch) -> None:
         """A decode step's account of the two-kind cache, in block x layer
@@ -1146,7 +1227,7 @@ class InferenceEngineV2:
     # (decode_burst_step drafts= runs the compiled verify program)
     @property
     def supports_draft_verify(self) -> bool:
-        return not (self._latent or self._kinds)
+        return not (self._latent or self._kinds or self._ssm)
     # per-request counter-based sampling streams (serving/streaming.
     # seeded_sample — the streaming layer's replayable stochastic
     # decode): the compiled burst and multi-step programs run the SAME
@@ -1168,14 +1249,14 @@ class InferenceEngineV2:
     # termination, and ONE packed device->host fetch (decode_multi_step)
     @property
     def supports_multi_step(self) -> bool:
-        return self._tpp is None
+        return self._tpp is None and not self._ssm
 
     # grammar-constrained decoding (serving/structured): fsm= operands
     # on decode_multi_step and the draft-verify path — the fused-TP
     # program set carries neither
     @property
     def supports_structured(self) -> bool:
-        return self._tpp is None
+        return self._tpp is None and not self._ssm
 
     # expert-paged MoE decode (serving/experts.ExpertPool): the slot
     # stacks/maps ride params["layers"] through every layer scan, which
@@ -1329,6 +1410,9 @@ class InferenceEngineV2:
         RNG.  Unflagged rows are untouched; greedy rows never consume a
         stream.  Requires a stochastic mode ("sample" rides the per-row
         program so the seed flags get a row axis)."""
+        if self._ssm:
+            self._refuse_latent("burst decode (decode_burst_step, "
+                                "generate) and its draft-verify path")
         if seeds and drafts is not None:
             raise RuntimeError(
                 "draft-and-verify cannot serve seeded sampling streams: "
@@ -1532,6 +1616,8 @@ class InferenceEngineV2:
         Returns {uid: [n_e] int32} — exactly the tokens the row
         emitted, EOS included, nothing past termination; the last
         emitted token stays pending so groups chain like bursts."""
+        if self._ssm:
+            self._refuse_latent("multi-step decode groups")
         if k < 1:
             raise ValueError(f"decode_multi_step needs k >= 1, got {k}")
         if not self.supports_multi_step:

@@ -39,6 +39,7 @@ ARCH_REGISTRY = {
     "longcat_flash": "longcat_flash",
     "deepseek_v3": "deepseek_v3",
     "smallthinker": "smallthinker",
+    "falcon_h1": "falcon_h1",
 }
 
 

@@ -45,6 +45,9 @@ class SequenceDescriptor:
     # a two-kind cache's window-kind lease: table entry -> block, for the
     # entries the sequence still holds (`blocks` is the global kind's)
     window_blocks: Dict[int, int] = field(default_factory=dict)
+    # the slot of per-sequence recurrent state the sequence holds from
+    # `create` to `flush` (-1: the model keeps none)
+    state_slot: int = -1
 
     @property
     def in_prefill(self) -> bool:
@@ -71,10 +74,18 @@ class DSStateManager:
     beyond the rows' steady shares.  `ensure_capacity` leases both kinds,
     hands a window-kind block back once it lies wholly behind the window,
     and a row's table is `[2, max_blocks_per_seq]` with -1 at the window
-    kind's dead entries."""
+    kind's dead entries.
+
+    `state_slots` > 0 adds a THIRD kind of state: slots of fixed-size
+    per-sequence recurrent state (a state-space mixer's), one a live
+    sequence, leased at `create` and handed back at `flush` through a free
+    list of their own (`free_state_slots`).  A slot is not cleared when it
+    changes hands: a fresh prompt's scan starts from zeros and overwrites
+    it (`ssm_ops`)."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int, max_seqs: int, window=None):
+                 max_blocks_per_seq: int, max_seqs: int, window=None,
+                 state_slots: int = 0):
         self.allocator = BlockedAllocator(num_blocks)
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -87,6 +98,8 @@ class DSStateManager:
         # window-kind blocks handed back because they fell behind the
         # window (flushes not counted)
         self.window_released = 0
+        self.state_slots = state_slots
+        self._free_state_slots = list(range(state_slots - 1, -1, -1))
 
     # -- lifecycle -------------------------------------------------------
     def create(self, uid: int, prompt_tokens,
@@ -103,6 +116,10 @@ class DSStateManager:
         if len(self.seqs) >= self.max_seqs:
             raise RuntimeError(
                 f"too many concurrent sequences (max_seqs={self.max_seqs})")
+        if self.state_slots and not self._free_state_slots:
+            raise RuntimeError(
+                f"no free recurrent-state slot (all {self.state_slots} "
+                f"held by live sequences)")
         d = SequenceDescriptor(uid=uid,
                                prompt=np.asarray(prompt_tokens, np.int32))  # dstpu: noqa[DST001] prompt tokens arrive as host arrays per the engine contract
         if prefix is not None:
@@ -124,6 +141,8 @@ class DSStateManager:
             d.blocks = list(blocks)
             d.seen_tokens = covered
             d.prefix_covered = covered
+        if self.state_slots:
+            d.state_slot = self._free_state_slots.pop()
         self.seqs[uid] = d
         return d
 
@@ -139,6 +158,9 @@ class DSStateManager:
         if d.window_blocks:
             self.window_allocator.free(d.window_blocks.values())
             d.window_blocks.clear()
+        if d.state_slot >= 0:
+            self._free_state_slots.append(d.state_slot)
+            d.state_slot = -1
 
     def ensure_capacity(self, d: SequenceDescriptor, upto_tokens: int,
                         first_query: int = None) -> None:
@@ -230,6 +252,12 @@ class DSStateManager:
         return KindCounts((self.allocator.free_blocks,
                            self.window_allocator.free_blocks))
 
+    @property
+    def free_state_slots(self) -> int:
+        """Recurrent-state slots no live sequence holds (0 where the model
+        keeps no such state: ask `state_slots` first)."""
+        return len(self._free_state_slots)
+
     # -- block conservation audit ----------------------------------------
     def audit(self, cache_blocks=()) -> Dict[str, int]:
         """Verify block conservation: free + live + shared-refcounted
@@ -278,7 +306,23 @@ class DSStateManager:
         }
         if self.window:
             out.update(self._audit_window())
+        if self.state_slots:
+            out.update(self._audit_state_slots())
         return out
+
+    def _audit_state_slots(self) -> Dict[str, int]:
+        """The slots' conservation: every slot is free or held by exactly
+        one live sequence, and every live sequence holds one."""
+        held = [d.state_slot for d in self.seqs.values()]
+        free = self._free_state_slots
+        if (sorted(held + free) != list(range(self.state_slots))
+                or len(held) != len(self.seqs)):
+            raise RuntimeError(
+                f"recurrent-state slot conservation violated: "
+                f"{len(self.seqs)} live sequences hold {sorted(held)}, free "
+                f"{sorted(free)}, of {self.state_slots} slots")
+        return {"state_slots_free": len(free), "state_slots_live": len(held),
+                "state_slots_total": self.state_slots}
 
     def _audit_window(self) -> Dict[str, int]:
         """The window kind's conservation: every allocated block is held by

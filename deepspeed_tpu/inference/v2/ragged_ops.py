@@ -71,7 +71,17 @@ def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
     layers apart, each kind with its own blocks:
     `hybrid_ops.init_kinds_arena` (keys "gk"/"gv", "wk"/"wv"), which takes
     `num_blocks` as the byte budget and sizes the window kind by
-    `max_seqs`."""
+    `max_seqs`.  A state-space parallel block (`cfg.ssm`) keeps one slot
+    of recurrent state a sequence beside its paged K/V:
+    `ssm_ops.init_ssm_arena` (keys "ssm", "conv"; `max_seqs` slots)."""
+    if cfg.ssm:
+        if (topology is not None and topology.tp_size > 1) or moe_census:
+            raise ValueError(
+                "the recurrent-state arena is not sharded over tp (the "
+                "mixer's heads are not split over a mesh) and has no "
+                "expert-paging census rider")
+        from .ssm_ops import init_ssm_arena
+        return init_ssm_arena(cfg, num_blocks, block_size, max_seqs)
     if cfg.static_kinds:
         if (topology is not None and topology.tp_size > 1) or moe_census:
             raise ValueError(
@@ -380,7 +390,8 @@ def _lm_logits(cfg: TransformerConfig, params, x):
          static_argnames=("n_tp", "mesh"))
 def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
                    n_valids, block_tables, active, total_lens=None,
-                   n_tp: int = 1, mesh=None, adapter_ids=None, lora=None):
+                   n_tp: int = 1, mesh=None, adapter_ids=None, lora=None,
+                   slots=None):
     """Advance up to NC prompt chunks in ONE compiled program (the ragged
     composition of Dynamic SplitFuse: reference ragged/ragged_wrapper.py +
     kernels/ragged_ops/atom_builder/ build one batch from many sequences'
@@ -401,8 +412,16 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     so a later chunk attends keys a former chunk just wrote, while QKV
     projections, MLP and logits batch over all NC*C tokens (better MXU
     shapes than NC separate calls, and NC fewer host dispatches).
+    `slots` [NC]: each chunk's state slot, for a state-space parallel
+    block alone (`ssm_ops`: the scan starts from the slot where the
+    chunk continues a prompt and ends by writing it).
     Returns (logits [NC, V] — last valid token each, their argmax
     [NC] int32 (`greedy_tokens`), arena)."""
+    if cfg.ssm:
+        from . import ssm_ops
+        ssm_ops.refuse_lora(lora)
+        return ssm_ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
+                                      n_valids, block_tables, active, slots)
     if cfg.latent or cfg.static_kinds:
         from . import hybrid_ops, latent_ops
         latent_ops.refuse_lora(lora)
@@ -610,8 +629,10 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
     widths for the flash path (latent_ops._attend_fresh).  A static-kind
     stack has one prefill program: a fresh prompt is a chunk at position 0
     of `ops/chunk_attention.py`, which has the window the flash kernel
-    lacks and holds no whole sequence of keys in VMEM."""
-    if cfg.latent:
+    lacks and holds no whole sequence of keys in VMEM.  A state-space
+    parallel block's attention is plain causal attention at any head width
+    the flash path takes or pads (`ssm_ops.prefill_full`)."""
+    if cfg.latent or cfg.ssm:
         return True
     if cfg.static_kinds:
         return False
@@ -625,7 +646,7 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
-                 block_tables, active):
+                 block_tables, active, slots=None):
     """Prefill FRESH full prompts with dense causal flash attention.
 
     The chunked path (`prefill_chunks`) serializes a per-chunk blocked
@@ -653,6 +674,10 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
     `blk -> nb` for invalid slots) discards padded K/V writes, and the
     logits slice reads only each prompt's LAST VALID token.
     """
+    if cfg.ssm:
+        from . import ssm_ops
+        return ssm_ops.prefill_full(cfg, params, arena, tokens, lens,
+                                    block_tables, active, slots)
     if cfg.latent:
         from . import latent_ops
         return latent_ops.prefill_full(cfg, params, arena, tokens, lens,
@@ -733,7 +758,7 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
          static_argnames=("n_tp", "mesh"))
 def decode_step(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                 block_tables, active, n_tp: int = 1, mesh=None,
-                adapter_ids=None, lora=None):
+                adapter_ids=None, lora=None, slots=None):
     """One generated token for up to B sequences.
 
     tokens: [B] int32 (this step's input token per sequence);
@@ -742,12 +767,13 @@ def decode_step(cfg: TransformerConfig, params, arena, tokens, seq_lens,
     parallel degree (only gates the fused kernel — sharding itself flows
     from the operands' NamedShardings); adapter_ids [B] + `lora` stacked
     factors: the per-row gather-LoRA epilogue (see `prefill_chunks`),
-    `lora=None` = the exact single-tenant program.  Returns
+    `lora=None` = the exact single-tenant program; `slots` [B]: the rows'
+    state slots, for a state-space parallel block alone.  Returns
     (logits [B, V], their argmax [B] int32 (`greedy_tokens`), arena).
     """
     logits, arena = _decode_core(cfg, params, arena, tokens, seq_lens,
                                  block_tables, active, n_tp, mesh,
-                                 adapter_ids, lora)
+                                 adapter_ids, lora, slots)
     return logits, greedy_tokens(logits), arena
 
 
@@ -1339,6 +1365,12 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
             "speculative verify spans are not wired into the latent (MLA) "
             "block or the static-kind stack (the engine reports "
             "supports_draft_verify = False)")
+    if cfg.ssm:
+        raise NotImplementedError(
+            "speculative verify spans are not wired into the state-space "
+            "parallel block: a rejected draft would have to roll the "
+            "recurrent state back, and a slot holds one state (the engine "
+            "reports supports_draft_verify = False)")
     B, S = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1520,7 +1552,18 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
 
 def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                  block_tables, active, n_tp: int = 1, mesh=None,
-                 adapter_ids=None, lora=None):
+                 adapter_ids=None, lora=None, slots=None):
+    if cfg.ssm:
+        from . import ssm_ops
+        ssm_ops.refuse_lora(lora)
+        if slots is None:
+            raise NotImplementedError(
+                "this decode program hands the state-space parallel block "
+                "no row -> slot vector: burst, multi-step and draft-verify "
+                "decode are not wired for per-sequence recurrent state "
+                "(decode_step is)")
+        return ssm_ops.decode_core(cfg, params, arena, tokens, seq_lens,
+                                   block_tables, active, slots)
     if cfg.latent or cfg.static_kinds:
         from . import hybrid_ops, latent_ops
         latent_ops.refuse_lora(lora)

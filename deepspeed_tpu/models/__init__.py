@@ -20,6 +20,7 @@ from .transformer import (
     longcat_flash_config,
     deepseek_v3_config,
     smallthinker_config,
+    falcon_h1_config,
 )
 
 from .hf_loader import load_hf_model, hf_to_config, convert_state_dict
@@ -40,6 +41,7 @@ MODEL_FAMILIES = {
     "longcat_flash": longcat_flash_config,
     "deepseek_v3": deepseek_v3_config,
     "smallthinker": smallthinker_config,
+    "falcon_h1": falcon_h1_config,
 }
 
 
@@ -60,5 +62,5 @@ __all__ = [
     "qwen2_config", "qwen2_moe_config", "phi_config", "phi3_config",
     "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
-    "deepseek_v3_config", "smallthinker_config",
+    "deepseek_v3_config", "smallthinker_config", "falcon_h1_config",
 ]

@@ -44,6 +44,7 @@ __all__ = [
     "TransformerConfig", "Transformer", "gpt2_config", "llama_config",
     "mistral_config", "mixtral_config", "qwen2_config", "qwen2_moe_config",
     "smallthinker_config",
+    "falcon_h1_config",
     "phi_config", "phi3_config", "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
     "deepseek_v3_config",
@@ -208,6 +209,33 @@ class TransformerConfig:
     # every expert; assignments to absent ones are another chip's work
     moe_expert_first: int = 0
     moe_expert_count: int = 0
+    # a state-space (Mamba-2) mixer BESIDE attention in every layer, on when
+    # ssm_state > 0 (Falcon-H1): both branches read the input norm and add
+    # to the residual, then a gated MLP.  ssm_heads heads of ssm_head_dim
+    # channels, a state of ssm_state values a channel, B and C shared by
+    # the heads of a group, a causal depthwise convolution over the last
+    # ssm_conv positions of [x | B | C], prompts scanned in chunks of
+    # ssm_chunk positions.  The state is per SEQUENCE and of fixed size: it
+    # lives in slots beside the paged K/V (inference/v2/ssm_ops.py).
+    # The multipliers are the published muP scalars, applied where the
+    # published modelling code applies them: ssm_multipliers on the column
+    # ranges [z | x | B | C | dt] of the mixer's in-projection,
+    # mlp_multipliers on (the gate's pre-activation, the FFN's output)
+    ssm_state: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
 
     def __post_init__(self):
         # static feature-compat checks: fail at config time, not with silently
@@ -384,10 +412,36 @@ class TransformerConfig:
                     "activation, moe_experts > 1 with moe_expert_ffn, no "
                     "qkv bias, untied head, no dense or shared-expert "
                     "layers, no rope scaling")
-        elif self.attn_head_dim or self.activation == "reglu":
+        elif (self.attn_head_dim and not self.ssm) \
+                or self.activation == "reglu":
             raise ValueError(
-                "attn_head_dim and the 'reglu' experts exist only in the "
-                "static-kind stack (rope_layers)")
+                "attn_head_dim exists only in the static-kind stack "
+                "(rope_layers) and the state-space parallel block "
+                "(ssm_state), the 'reglu' experts only in the former")
+        if self.ssm:
+            if not (self.ssm_heads and self.ssm_head_dim
+                    and self.ssm_groups >= 1
+                    and self.ssm_heads % self.ssm_groups == 0
+                    and self.ssm_conv >= 2 and self.ssm_chunk >= 1
+                    and len(self.ssm_multipliers) == 5
+                    and len(self.mlp_multipliers) == 2
+                    and self.pos_emb == "rope" and self.norm == "rmsnorm"
+                    and self.activation == "swiglu"
+                    and self.moe_experts == 1 and not self.latent
+                    and self.rope_layers is None
+                    and self.sliding_window is None
+                    and self.sliding_window_layers is None
+                    and not self.qkv_bias and self.tie_embeddings is False
+                    and self.rope_scaling is None and self.rope_pct == 1.0
+                    and not self.post_norm and not self.embed_proj_dim):
+                raise ValueError(
+                    "the state-space parallel block (ssm_state > 0) is "
+                    "served in one form: ssm_heads heads of ssm_head_dim "
+                    "in ssm_groups equal groups, a convolution of at least "
+                    "2 positions, five ssm_multipliers and two "
+                    "mlp_multipliers, beside full causal attention with "
+                    "plain rope, rmsnorm, a dense swiglu FFN, no biases on "
+                    "the projections, an untied head")
         if self.embed_proj_dim and self.tiled_loss_shards > 1:
             raise ValueError(
                 "tiled_loss_shards with embed_proj_dim is not supported: "
@@ -419,6 +473,22 @@ class TransformerConfig:
     @property
     def local_experts(self) -> int:
         return self.moe_expert_count or self.moe_experts
+
+    @property
+    def ssm(self) -> bool:
+        """A state-space mixer beside attention in every layer: served
+        over per-sequence state slots beside the paged K/V."""
+        return self.ssm_state > 0
+
+    @property
+    def ssm_width(self) -> int:
+        """The mixer's channels (heads x their width)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """What the convolution runs over: [x | B | C]."""
+        return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def static_kinds(self) -> bool:
@@ -758,9 +828,89 @@ def smallthinker_config(size: str = "21b-a3b", **kw) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def falcon_h1_config(size: str = "34b", **kw) -> TransformerConfig:
+    """Falcon-H1 (tiiuae/Falcon-H1-34B-Instruct config.json): a Mamba-2
+    mixer and grouped-query attention side by side in every layer, both on
+    the input norm, then a gated MLP; muP multipliers on the embedding,
+    the projections and the head.  Serving only
+    (inference/v2/ssm_ops.py)."""
+    presets = {
+        # the published ratios at a small size: 2 groups of heads, a
+        # convolution over 4 positions, a scan chunk shorter than a prompt
+        "tiny": dict(hidden_size=64, num_layers=2, num_heads=4,
+                     num_kv_heads=2, attn_head_dim=16, intermediate_size=128,
+                     max_seq_len=512, vocab_size=512, ssm_state=16,
+                     ssm_heads=4, ssm_head_dim=16, ssm_groups=2, ssm_chunk=8),
+        "34b": dict(hidden_size=5120, num_layers=72, num_heads=20,
+                    num_kv_heads=4, attn_head_dim=128,
+                    intermediate_size=21504, max_seq_len=262144,
+                    vocab_size=261120, ssm_state=256, ssm_heads=32,
+                    ssm_head_dim=128, ssm_groups=2, ssm_chunk=128),
+    }
+    base = dict(pos_emb="rope", norm="rmsnorm", activation="swiglu",
+                tie_embeddings=False, rope_theta=1e11, norm_eps=1e-5,
+                ssm_conv=4,
+                embedding_multiplier=5.656854249492381,
+                lm_head_multiplier=0.0078125,
+                attention_in_multiplier=1.0,
+                attention_out_multiplier=0.0375,
+                key_multiplier=0.011048543456039804,
+                ssm_in_multiplier=0.25,
+                ssm_out_multiplier=0.08838834764831845,
+                mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+                ssm_multipliers=(0.3535533905932738, 0.25,
+                                 0.1767766952966369, 0.5,
+                                 0.3535533905932738))
+    base.update(presets[size])
+    base.update(kw)
+    for name in ("mlp_multipliers", "ssm_multipliers"):
+        base[name] = tuple(float(m) for m in base[name])
+    return TransformerConfig(**base)
+
+
 # ----------------------------------------------------------------------
 # init
 # ----------------------------------------------------------------------
+def _init_ssm_params(key, cfg: TransformerConfig) -> PyTree:
+    """Random weights in the state-space parallel block's layout (the
+    leaves `inference/v2/ssm_ops.py` reads): attention, the mixer, the
+    gated MLP and the two norms per layer.  `dt_bias` and `A_log` as
+    Mamba-2 initialises them: a step log-uniform in [1e-3, 1e-1] through
+    the inverse of softplus, a decay rate uniform in [1, 16]."""
+    H, L, NH, NKV, D = (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
+                        cfg.kv_heads, cfg.head_dim)
+    F, Wm, Wc, NHm = (cfg.ffn_dim, cfg.ssm_width, cfg.ssm_conv_width,
+                      cfg.ssm_heads)
+    out_std = 0.02 / math.sqrt(2 * L)
+    keys = iter(jax.random.split(key, 20))
+
+    def rnd(shape, std=0.02):
+        return jax.random.normal(next(keys), shape, jnp.float32) * std
+
+    step = jnp.exp(jax.random.uniform(
+        next(keys), (L, NHm), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "tok_embed": rnd((cfg.vocab_size, H)),
+        "lm_head": rnd((H, cfg.vocab_size)),
+        "final_norm_scale": jnp.ones((H,), jnp.float32),
+        "layers": {
+            "attn_norm_scale": jnp.ones((L, H), jnp.float32),
+            "mlp_norm_scale": jnp.ones((L, H), jnp.float32),
+            "wq": rnd((L, H, NH * D)), "wk": rnd((L, H, NKV * D)),
+            "wv": rnd((L, H, NKV * D)), "wo": rnd((L, NH * D, H), out_std),
+            "ssm_in": rnd((L, H, Wm + Wc + NHm)),
+            "ssm_conv_w": rnd((L, cfg.ssm_conv, Wc), 0.2),
+            "ssm_conv_b": jnp.zeros((L, Wc), jnp.float32),
+            "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "ssm_a_log": jnp.log(jax.random.uniform(
+                next(keys), (L, NHm), jnp.float32, 1.0, 16.0)),
+            "ssm_d": jnp.ones((L, NHm), jnp.float32),
+            "ssm_norm_scale": jnp.ones((L, Wm), jnp.float32),
+            "ssm_out": rnd((L, Wm, H), out_std),
+            "w_gate": rnd((L, H, F)), "w_up": rnd((L, H, F)),
+            "w_down": rnd((L, F, H), out_std)}}
+
+
 def _init_kinds_params(key, cfg: TransformerConfig) -> PyTree:
     """Random weights in the static-kind stack's layout (the leaves
     `inference/v2/hybrid_ops.py` reads): attention, the two norms and the
@@ -849,6 +999,8 @@ def _init_params(key, cfg: TransformerConfig) -> PyTree:
         return _init_latent_params(key, cfg)
     if cfg.static_kinds:
         return _init_kinds_params(key, cfg)
+    if cfg.ssm:
+        return _init_ssm_params(key, cfg)
     H, L = cfg.hidden_size, cfg.num_layers
     D, NH, NKV = cfg.head_dim, cfg.num_heads, cfg.kv_heads
     F, V = cfg.ffn_dim, cfg.vocab_size
@@ -1950,6 +2102,15 @@ class Transformer:
                 f"layers' attention a backward pass; this configuration "
                 f"is served through inference.v2 (build_engine -> "
                 f"ServeLoop) only")
+
+        if self.cfg.ssm:
+            raise NotImplementedError(
+                f"{what} has no state-space mixer: `_layer` has no "
+                f"convolution, no selective scan (and nothing gives "
+                f"`ops/ssm.py`'s chunked scan a backward pass), no gated "
+                f"group norm and no muP multipliers; this configuration is "
+                f"served through inference.v2 (build_engine -> ServeLoop) "
+                f"only")
 
     def loss_fn(self, params, batch, rng=None, grad_sink=None):
         self.refuse_serving_only("Transformer.loss_fn (training, initialize())")

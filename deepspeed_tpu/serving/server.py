@@ -118,6 +118,36 @@ class _StepPhases:
         return False
 
 
+def _refuse_recurrent_state(config: ServingConfig) -> None:
+    """What assumes "a sequence's state is its K/V blocks" cannot serve an
+    engine that keeps per-sequence recurrent state beside them (a
+    state-space mixer: `engine.recurrent_state`).  The prefix cache and
+    its host tier, page export and import, LoRA and tensor parallelism
+    are refused by the engine where they are switched on."""
+    on = lambda c: c is not None and getattr(c, "enabled", True)  # noqa: E731
+    asked = {
+        "speculative decoding (draft and verify)":
+            config.speculative is not None
+            and config.speculative.mode != "off",
+        "burst decode (decode_burst > 1)": config.decode_burst > 1,
+        "multi-step decode groups (multi_step > 1)": config.multi_step > 1,
+        "preemption by KV swap or recompute": on(config.preemption),
+        "grammar-constrained decoding (it rides the multi-step and "
+        "verify programs)": on(config.structured),
+        "expert paging": on(config.moe),
+    }
+    for what, wanted in asked.items():
+        if wanted:
+            raise NotImplementedError(
+                f"{what}: not wired for per-sequence recurrent state. A "
+                f"sequence of this model holds a slot of state-space "
+                f"state that every decoded token rewrites in place: the "
+                f"burst, multi-step and verify programs take no row -> "
+                f"slot vector, a rejected draft or a preempted row would "
+                f"need the state rolled back or saved, and a slot holds "
+                f"one state")
+
+
 class ServeLoop:
     """Synchronous serving core over an `InferenceEngineV2`-shaped engine.
 
@@ -189,6 +219,8 @@ class ServeLoop:
                     f"engine runs {eng_coll!r}: build the engine from "
                     f"this config (model_registry.apply_serving_tp) or "
                     f"make them agree")
+        if getattr(engine, "recurrent_state", False):
+            _refuse_recurrent_state(self.config)
         # burst serving needs the extended engine contract: decode_burst_
         # step(uids, n_steps, mode, temperature, top_k, max_tokens) and
         # the decode= kwarg on put()/step().  Loud here, not a silent
@@ -1350,7 +1382,10 @@ class ServeLoop:
             decode_rows=getattr(out, "decode_rows", 0),
             fed_on_device_rows=getattr(out, "fed_rows", 0),
             kv_live_blocks=getattr(out, "kv_live_blocks", 0),
-            kv_table_blocks=getattr(out, "kv_table_blocks", 0), **kinds)
+            kv_table_blocks=getattr(out, "kv_table_blocks", 0), **kinds,
+            # per-sequence recurrent state's account of the step's decode
+            # rows (`InferenceEngineV2._count_state`); else nothing
+            **getattr(out, "state_account", {}))
         for name, n in kinds.items():
             self.telemetry.count(name, n)
         self.telemetry.count("admitted", len(admitted))

@@ -973,3 +973,28 @@ def test_transfer_guard_negative_control():
                            host_to_device="disallow"):
         with pytest.raises(Exception, match="[Tt]ransfer"):
             _ = x + np.float32(1.0)              # implicit scalar h2d
+
+
+def test_audit_blocks_counts_leased_and_free_state_slots():
+    """A model with per-sequence recurrent state: a slot a decode row
+    (`max_seqs`) and a scratch one; `audit_blocks` counts them beside the
+    blocks, `flush` hands one back (tests/test_ssm_serving.py has the
+    programs, and the manager's own refusal when slots run out)."""
+    eng = build_engine("falcon_h1", "tiny", dtype=jnp.float32,
+                       engine_config=RaggedInferenceEngineConfig(
+                           num_blocks=24, block_size=8, max_blocks_per_seq=6,
+                           max_seqs=2))
+    assert eng.arena["ssm"].shape[1] == 3           # two slots and scratch
+    assert eng.free_slots == 2
+    eng.put([1, 2], [np.arange(9, dtype=np.int32)] * 2)
+    audit = eng.audit_blocks()
+    assert (audit["state_slots_live"], audit["state_slots_free"],
+            audit["state_slots_total"]) == (2, 0, 2)
+    assert eng.free_slots == 0 and audit["live"] == 4
+    with pytest.raises(RuntimeError, match="too many concurrent sequences"):
+        eng.put([3], [np.arange(9, dtype=np.int32)])
+    assert 3 not in eng.state.seqs
+    eng.flush(1)
+    audit = eng.audit_blocks()
+    assert (audit["state_slots_live"], audit["state_slots_free"]) == (1, 1)
+    assert eng.free_slots == 1

@@ -28,7 +28,7 @@ class TestFamilies:
         params = model.init_params(jax.random.PRNGKey(0))
         ids = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0,
                                  cfg.vocab_size)
-        if cfg.latent or cfg.static_kinds:   # served through inference.v2 only
+        if cfg.latent or cfg.static_kinds or cfg.ssm:   # inference.v2 only
             with pytest.raises(NotImplementedError, match="loss_fn"):
                 model.loss_fn(params, {"input_ids": ids})
             return
@@ -45,7 +45,7 @@ class TestFamilies:
         """Prefill-via-cache logits == full forward logits (the decode path
         shares weights but not code with the train path)."""
         cfg = _tiny(family)
-        if cfg.latent or cfg.static_kinds:   # a paged arena of its own only
+        if cfg.latent or cfg.static_kinds or cfg.ssm:   # its own arena only
             with pytest.raises(NotImplementedError, match="init_cache"):
                 Transformer(cfg).init_cache(batch=1, max_len=32)
             return

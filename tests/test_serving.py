@@ -1090,3 +1090,43 @@ def test_serve_loop_transfer_guard_disallow_real_engine():
         {"transfer_guard": "log"}).transfer_guard == "log"
     with pytest.raises(ConfigError, match="transfer_guard"):
         ServingConfig(transfer_guard="everything").validate()
+
+
+# ----------------------------------------------------------------------
+# admission over per-sequence recurrent state (a state-space mixer's slots
+# beside the K/V blocks): tests/test_ssm_serving.py has the rest
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("short, engine_kw, prompts, first_free_slots", [
+    ("slots", dict(max_seqs=2), (10, 10, 10), 0),
+    ("blocks", dict(num_blocks=5), (20, 20), 3),
+])
+def test_admission_waits_for_what_is_short_slots_or_blocks(
+        short, engine_kw, prompts, first_free_slots):
+    """Two decode rows, so two state slots, and ample blocks: the third
+    request waits for a slot though blocks are free.  Four slots and blocks
+    for one request's lifetime: the second waits for blocks though slots
+    are free.  Either way it is served once the first has finished, and
+    nothing leaks."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.config.config import ServingConfig
+    from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
+                                            build_engine)
+    eng = build_engine(
+        "falcon_h1", "tiny", dtype=jnp.float32,
+        engine_config=RaggedInferenceEngineConfig(**dict(dict(
+            num_blocks=48, block_size=8, max_blocks_per_seq=12, max_seqs=4,
+            prefill_chunk_size=32, max_prefill_tokens_per_step=32),
+            **engine_kw)))
+    loop = ServeLoop(eng, ServingConfig(audit_blocks=True))
+    reqs = [loop.submit(np.arange(n, dtype=np.int32) + i, max_new_tokens=8)
+            for i, n in enumerate(prompts)]
+    loop.step()
+    assert [r.state is RequestState.QUEUED for r in reqs] \
+        == [False] * (len(reqs) - 1) + [True]
+    assert eng.free_slots == first_free_slots
+    assert (eng.free_blocks > 40) == (short == "slots")
+    loop.run_until_idle()
+    assert all(r.state is RequestState.DONE for r in reqs)
+    audit = eng.audit_blocks()
+    assert audit["state_slots_free"] == audit["state_slots_total"]
+    assert audit["free"] == audit["total"]

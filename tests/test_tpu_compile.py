@@ -530,3 +530,63 @@ def test_the_chunk_program_of_the_smallthinker_cell_gathers_its_experts(
     assert len(gathers) >= cfg.moe_top_k
     assert not re.search(rows + r"[^\n]*experts/[^\n]*scatter-add", hlo)
     assert "experts/while/body" not in hlo
+
+
+# `falcon-h1-34b.decode_closed_short`'s programs: what the v5e compiler
+# reports for the weights (10.51 GB), the arena (K/V 1.31 GB, state 2.44 GB,
+# tails 0.02 GB) and each program's temporaries
+FALCON_PEAK_BYTES = {"decode_step": 14.42e9, "prefill_full": 14.33e9,
+                     "prefill_full_2": 14.33e9, "prefill_chunks": 14.33e9,
+                     "prefill_chunks_4": 14.42e9}
+
+
+def test_serving_programs_of_the_falcon_h1_cell_fit_and_update_in_place(
+        topo, chip, monkeypatch):
+    """The cell's own `decode_step`, `prefill_full[1, 512]`,
+    `prefill_full[2, 256]`, `prefill_chunks[1]` and `prefill_chunks[4]`
+    (`benchmark/configs/falcon-h1-34b.json`: 6 layers at the published
+    widths, 96 rows and state slots), compiled for the described chip from
+    abstract weights: each fits with the arena donated and next to no
+    temporaries (the recurrent state among it: no second copy of 2.4 GB,
+    which XLA's own gather and scatter of two rows' states make, nor the
+    convolution tails' 18 MB padded to 763), the decode program runs the
+    in-place `ssm_update` and the paged kernel, the prefill programs the
+    chunked `ssd_scan`.  ~6 s a program."""
+    from deepspeed_tpu.inference.v2 import ragged_ops
+
+    cfg, params, arena, eng, ref, sizes = abstract_cell(
+        chip, monkeypatch, "falcon-h1-34b", "falcon_h1")
+    B, MB = eng["max_seqs"], eng["max_blocks_per_seq"]
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert held == ref.weight_bytes(sizes, "bfloat16")
+    assert arena["ssm"].shape == (6, 97, 32, 256, 128)
+    assert arena["conv"].shape == (6, 97, 3 * 5120)
+    donated = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(arena))
+    i32 = lambda *shape: chip(shape, jnp.int32)  # noqa: E731
+    flags = lambda n: chip((n,), jnp.bool_)  # noqa: E731
+    S = eng["prefill_chunk_size"]
+    calls = {
+        "decode_step": (ragged_ops.decode_step, "ssm_update",
+                        (i32(B), i32(B), i32(B, MB), flags(B)), i32(B)),
+        "prefill_full": (ragged_ops.prefill_full, "ssd_scan",
+                         (i32(1, S), i32(1), i32(1, MB), flags(1)), i32(1)),
+        "prefill_full_2": (ragged_ops.prefill_full, "ssd_scan",
+                           (i32(2, S // 2), i32(2), i32(2, MB), flags(2)),
+                           i32(2)),
+        "prefill_chunks": (ragged_ops.prefill_chunks, "ssd_scan",
+                           (i32(1, S), i32(1), i32(1), i32(1, MB), flags(1)),
+                           i32(1)),
+        "prefill_chunks_4": (ragged_ops.prefill_chunks, "ssd_scan",
+                             (i32(4, S), i32(4), i32(4), i32(4, MB),
+                              flags(4)), i32(4)),
+    }
+    for name, (fn, kernel, args, slots) in calls.items():
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(cfg, params, arena, *args,
+                                slots=slots).compile()
+        mem, hlo = compiled.memory_analysis(), compiled.as_text()
+        assert mem.peak_memory_in_bytes < 1.02 * FALCON_PEAK_BYTES[name], name
+        assert mem.alias_size_in_bytes >= donated, name          # in place
+        assert mem.temp_size_in_bytes < 0.2e9, name
+        assert hlo.count("tpu_custom_call") >= 2, name
+        assert kernel in hlo, name
