@@ -1088,7 +1088,7 @@ class InferenceEngineV2:
                 budget -= n
             plan.set_metadata(rows=len(planned))
         if planned:
-            with span("engine.dispatch", program="prefill_chunks"):
+            with span("engine.dispatch", program="prefill_chunks") as sent:
                 NC = 1
                 while NC < len(planned):
                     NC *= 2
@@ -1101,6 +1101,17 @@ class InferenceEngineV2:
                     self._host_in(tables[:NC]), self._host_in(active[:NC]),
                     self._host_in(tlens[:NC]), **lkw,
                     **self._slots_kw(cslots[:NC]))
+                if self._kinds:
+                    # how often the chunk attention kernel's mask-free
+                    # body runs: its live key steps, and those an edge
+                    # crosses
+                    from .hybrid_ops import chunk_attn_steps
+                    live, masked = chunk_attn_steps(
+                        self.cfg, pos0s[:NC], nvalids[:NC], C,
+                        self.config.max_blocks_per_seq,
+                        self.config.block_size)
+                    sent.set_metadata(attn_steps_live=live,
+                                      attn_steps_masked=masked)
             for d, start, n in planned:
                 d.seen_tokens = start + n
                 if self._kinds:

@@ -54,7 +54,8 @@ from .ragged_ops import (_dense, _embed, _gate_fused, _kernel_capable,
                          _lm_logits, greedy_tokens)
 
 __all__ = ["ROW_TILE", "kind_layers", "kind_pools", "window_blocks",
-           "init_kinds_arena", "prefill_chunks", "decode_core"]
+           "init_kinds_arena", "chunk_attn_steps", "prefill_chunks",
+           "decode_core"]
 
 # rows a token-wise pass takes at once: every pass reads the weights of
 # every expert that has a row, so a pass is as large as its float32
@@ -101,6 +102,31 @@ def init_kinds_arena(cfg: TransformerConfig, num_blocks: int,
     return {"gk": zeros(Lg, nb_g), "gv": zeros(Lg, nb_g),
             "wk": zeros(Lw, nb_w), "wv": zeros(Lw, nb_w),
             "moe_counts": jnp.zeros((len(count_names(cfg)),), jnp.int32)}
+
+
+def _chunk_keys(MB: int, bs: int, S: int) -> Tuple[int, int]:
+    """(length, key tile) of the buffer a chunk program lays a row's keys
+    out in by position: its table's blocks, then room for a chunk that
+    starts in the last of them, in whole key tiles."""
+    from ...ops.chunk_attention import key_tile
+    bk = key_tile(MB * bs + S)
+    return -(-(MB * bs + S) // bk) * bk, bk
+
+
+def chunk_attn_steps(cfg: TransformerConfig, pos0, n_valid, S: int, MB: int,
+                     bs: int) -> Tuple[int, int]:
+    """(live, masked) key steps of `ops/chunk_attention.py` in one chunk
+    program of `S`-token slots at `pos0` with `n_valid` real tokens each,
+    summed over the layers of both kinds, a kv head (numpy on the host,
+    by the kernel's own rule): the steps that compute, and of those the
+    ones an edge crosses, which pay for the mask."""
+    from ...ops.chunk_attention import count_steps
+    G, bk = cfg.num_heads // cfg.kv_heads, _chunk_keys(MB, bs, S)[1]
+    live = masked = 0
+    for layers, window in zip(kind_layers(cfg), (None, cfg.window)):
+        l, m = count_steps(pos0, n_valid, S, G, bk, window)
+        live, masked = live + layers * l, masked + layers * m
+    return live, masked
 
 
 def _use_kernels(cfg: TransformerConfig, bs: int) -> bool:
@@ -158,9 +184,7 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
     # the chunk programs lay a row's keys out by position: its table's
     # blocks, then room for a chunk that starts in the last of them
     if not decode:
-        from ...ops.chunk_attention import key_tile
-        room = MB * bs + S
-        room = -(-room // key_tile(room)) * key_tile(room)
+        room, _ = _chunk_keys(MB, bs, S)
 
     def attend(kind: int, index, window, q, k, v, ak, av):
         """Attention proper of a layer of kind `kind` (0 global, 1 window)

@@ -23,6 +23,24 @@ computes nothing.  With a window the key steps are a constant few
 (`(W + bq) / bk + 1`) however long the row; without one they are the
 buffer's tiles, of which a causal tile uses those up to its diagonal.
 A query tile past `n_valid` costs its steps' fixed overhead only.
+
+A live step is one of two kinds (`step_kind`, the one rule the kernel and
+the host's `count_steps` share).  INTERIOR: every key of the step is
+visible to every query of the tile, padded ones included: the step's last
+key is at or before the tile's FIRST query and, with a window, its first
+key is after the tile's LAST query less the window.  Of a tile's steps
+only the one on its diagonal and the one or two on the window's lower edge
+are not.  EDGE: every other live step.  The edge body masks the scores;
+the interior body has no iota, compare or select.  A select whose
+condition is all true is the identity, so both bodies give the same bits.
+
+The running max and sum of the online softmax are kept LANE-REPLICATED
+(`[rows, 128]` scratch, every lane of a row the same number) and used
+that way: `[rows, 1]` columns cut out of them had to be broadcast back
+over the lanes at every use (four cross-lane permutes a row group a step,
+each a round trip through the XLU in the middle of the step's dependency
+chain: they, not the mask, were two thirds of a live step on a v5e).  The
+same scalar arithmetic on every lane: the bits do not move.
 """
 from __future__ import annotations
 
@@ -32,6 +50,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -75,16 +94,68 @@ def chunk_attention_reference(q, k, v, pos0, n_valid,
     return jnp.where(real[:, :, None, None], out, 0.0).astype(q.dtype)
 
 
-def _span(meta_ref, r, t, bq: int, bk: int, window):
+def _tiles(pos0, n_valid, t, bq: int, bk: int, window, xp=jnp):
     """(first key tile, last key tile, whether any query is real) of query
-    tile `t` of row `r`."""
-    pos0, n_valid = meta_ref[r, 0], meta_ref[r, 1]
+    tile `t` of a row whose chunk stands at `pos0` with `n_valid` real
+    queries."""
     lo = pos0 + t * bq
-    hi = pos0 + jnp.minimum((t + 1) * bq, n_valid) - 1
+    hi = pos0 + xp.minimum((t + 1) * bq, n_valid) - 1
     real = t * bq < n_valid
-    first = 0 if window is None else jnp.maximum(lo - window + 1, 0) // bk
-    last = jnp.where(real, jnp.maximum(hi, 0) // bk, first)
+    first = 0 if window is None else xp.maximum(lo - window + 1, 0) // bk
+    last = xp.where(real, xp.maximum(hi, 0) // bk, first)
     return first, last, real
+
+
+def step_kind(pos0, n_valid, t, j, *, bq: int, bk: int, window, xp=jnp):
+    """(live, interior) of key step `j` of query tile `t` of a row: the one
+    rule the kernel's two bodies and the host's count (`count_steps`)
+    follow.  Integers in (traced scalars with `xp=jnp`, numpy arrays that
+    broadcast with `xp=np`), booleans out.
+
+    live: the tile has a real query and the step's key tile lies between
+    the tile's first and last (`_tiles`).  interior: live, and every key of
+    the step is visible to EVERY query of the tile, padded ones too: the
+    step's last key is at or before the tile's first query, and with a
+    window its first key is after the tile's last query less the window."""
+    first, last, real = _tiles(pos0, n_valid, t, bq, bk, window, xp)
+    live = real & (first + j <= last)
+    key_lo = (first + j) * bk
+    interior = key_lo + bk - 1 <= pos0 + t * bq
+    if window is not None:
+        interior &= key_lo > pos0 + (t + 1) * bq - 1 - window
+    return live, live & interior
+
+
+def count_steps(pos0, n_valid, C: int, groups: int, bk: int, window):
+    """(live, masked) key steps of one kv head of `chunk_attention` over
+    chunk slots at `pos0` with `n_valid` real queries of `C` (numpy, on
+    the host): the steps that compute, and of those the ones an edge
+    crosses, which pay for the mask.  Their ratio says how often the
+    mask-free body runs."""
+    bq = _query_tile(C, groups)
+    pos0 = np.asarray(pos0, np.int64).reshape(-1, 1, 1)  # dstpu: noqa[DST001] the planner's host array of chunk starts
+    n_valid = np.asarray(n_valid, np.int64).reshape(-1, 1, 1)  # dstpu: noqa[DST001] the planner's host array of chunk lengths
+    t = np.arange(C // bq).reshape(1, -1, 1)
+    # (a tile's steps end at its diagonal: no row needs more than these)
+    last = int(pos0.max(initial=0)) + C  # dstpu: noqa[DST001] numpy on the host
+    j = np.arange(last // bk + 1).reshape(1, 1, -1)
+    live, interior = step_kind(pos0, n_valid, t, j, bq=bq, bk=bk,
+                               window=window, xp=np)
+    live, interior = live.sum(), interior.sum()
+    return int(live), int(live - interior)  # dstpu: noqa[DST001] numpy on the host
+
+
+def _lanes(x, n: int):
+    """A lane-replicated `[rows, 128]` array at `n` lanes: whole vregs
+    again, no broadcast of a column."""
+    reps = -(-n // 128)
+    x = pltpu.repeat(x, reps, axis=1) if reps > 1 else x
+    return x[:, :n] if n % 128 else x
+
+
+def _span(meta_ref, r, t, bq: int, bk: int, window):
+    """`_tiles` of query tile `t` of row `r`."""
+    return _tiles(meta_ref[r, 0], meta_ref[r, 1], t, bq, bk, window)
 
 
 def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
@@ -92,7 +163,9 @@ def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
     # q_ref/o_ref [1, 1, G, bq, D]; k_ref/v_ref [1, 1, bk, D]; scratch m/l
     # [G * bq, 128], acc [G * bq, D] float32
     r, t, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    first, last, real = _span(meta_ref, r, t, bq, bk, window)
+    first, _, _ = _span(meta_ref, r, t, bq, bk, window)
+    live, interior = step_kind(meta_ref[r, 0], meta_ref[r, 1], t, j, bq=bq,
+                               bk=bk, window=window)
     D = q_ref.shape[-1]
     rows = groups * bq
 
@@ -102,39 +175,48 @@ def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    @pl.when(jnp.logical_and(real, first + j <= last))
-    def _compute():
+    def step(masked: bool):
         q = q_ref[0, 0].reshape(rows, D)
         k, v = k_ref[0, 0], v_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        # row g * bq + i is query i of the tile, whatever its head
-        q_pos = (meta_ref[r, 0] + t * bq
-                 + jax.lax.broadcasted_iota(jnp.int32, (groups, bq, bk), 1)
-                 ).reshape(rows, bk)
-        key_pos = (first + j) * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, bk), 1)
-        seen = key_pos <= q_pos
-        if window is not None:
-            seen &= key_pos > q_pos - window
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_s[:, :1]
+        if masked:
+            # row g * bq + i is query i of the tile, whatever its head
+            q_pos = (meta_ref[r, 0] + t * bq
+                     + jax.lax.broadcasted_iota(jnp.int32, (groups, bq, bk), 1)
+                     ).reshape(rows, bk)
+            key_pos = (first + j) * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, bk), 1)
+            seen = key_pos <= q_pos
+            if window is not None:
+                seen &= key_pos > q_pos - window
+            s = jnp.where(seen, s, NEG_INF)
+        # the running max and sum live lane-replicated ([rows, 128]): whole
+        # vregs at every use, no lane broadcast of a [rows, 1] column
+        m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a row with no key seen yet keeps m at NEG_INF: exp(0) must not count
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - _lanes(m_new, bk))
+        if masked:
+            # a row with no key seen yet keeps m at NEG_INF: exp(0) must
+            # not count
+            p = jnp.where(seen, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_s[...] = jnp.broadcast_to(
-            alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_s.shape)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * _lanes(alpha, D) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        m_s[...] = m_new
+
+    # a select whose condition is all true is the identity: the interior
+    # body leaves the mask's iotas, compares and selects out and gives the
+    # bits the masked one would
+    pl.when(interior)(functools.partial(step, False))
+    pl.when(live & ~interior)(functools.partial(step, True))
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = jnp.maximum(l_s[:, :1], 1e-30)       # a padded query: zeros
-        o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype).reshape(
-            groups, bq, D)
+        l = jnp.maximum(l_s[...], 1e-30)        # a padded query: zeros
+        o_ref[0, 0] = (acc_s[...] / _lanes(l, D)).astype(
+            o_ref.dtype).reshape(groups, bq, D)
 
 
 def _query_tile(C: int, groups: int) -> int:

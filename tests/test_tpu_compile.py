@@ -151,10 +151,20 @@ def test_paged_decode_with_a_window(chip, B, NH, NKV, D, nb, MB, L, window):
 # the chunk's own: 25,664 positions in 512-key tiles); one chunk slot and
 # the four a step can hold
 @pytest.mark.parametrize("R,window", [(1, 4096), (1, None), (4, 4096)])
-def test_chunk_attention(chip, R, window):
+def test_chunk_attention(chip, R, window, monkeypatch):
+    """One Mosaic kernel named `chunk_attention` (the benchmark's readers
+    match the name), its two bodies over one set of scratch: it compiles
+    inside the 5 MiB of scoped VMEM it needed with one body (4.4-4.9 MiB:
+    the blocks, the three scratch arrays, the compiler's spills), and the
+    program's temporaries are the wrapper's transposed keys and values
+    and nothing of the kernel's."""
+    import functools
+    from jax.experimental.pallas import tpu as pltpu
     from deepspeed_tpu.ops import chunk_attention as ca
     C, T = 12288, 209 * 64 + 12288
     T = -(-T // ca.key_tile(T)) * ca.key_tile(T)
+    monkeypatch.setattr(ca.pltpu, "CompilerParams", functools.partial(
+        pltpu.CompilerParams, vmem_limit_bytes=5 << 20))
 
     def attend(q, k, v, pos0, n_valid):
         return ca.chunk_attention(q, k, v, pos0, n_valid, window=window)
@@ -162,11 +172,38 @@ def test_chunk_attention(chip, R, window):
     args = (chip((R, C, 28, 128)), chip((R, T, 4, 128)),
             chip((R, T, 4, 128)), chip((R,), jnp.int32),
             chip((R,), jnp.int32))
-    assert kernels(attend, *args) == 1
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(attend).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('op_name="jit(attend)/chunk_attention/') >= 1
+    # (one row's keys and values fit VMEM whole and XLA keeps them there)
+    kv = 2 * R * T * 4 * 128 * 2 if R > 1 else 0
+    assert compiled.memory_analysis().temp_size_in_bytes < kv + 2 ** 20
     # a query tile is 128 queries x 7 heads; with the window its key steps
     # are a constant few however long the row
     assert ca._query_tile(C, 7) == 128 and T // 512 == 51
     assert (4096 + 128 - 2) // 512 + 2 == 10
+
+
+# (R, C, NH, NKV, D, T, window): off the cell's shapes, where the running
+# max and sum (128 lanes a row) meet scores and outputs that are not whole
+# vregs wide: heads of 64, a buffer of one 200-key tile, a key tile of 328
+# under a window, heads of 256
+@pytest.mark.parametrize("R,C,NH,NKV,D,T,window", [
+    (4, 64, 4, 2, 64, 256, 20), (2, 256, 8, 2, 64, 1536, 600),
+    (1, 128, 8, 2, 128, 200, None), (1, 128, 8, 2, 128, 328, 100),
+    (1, 512, 16, 16, 256, 1024, None)])
+def test_chunk_attention_off_the_cells_shapes(chip, R, C, NH, NKV, D, T,
+                                              window):
+    from deepspeed_tpu.ops import chunk_attention as ca
+
+    def attend(q, k, v, pos0, n_valid):
+        return ca.chunk_attention(q, k, v, pos0, n_valid, window=window)
+
+    assert kernels(attend, chip((R, C, NH, D)), chip((R, T, NKV, D)),
+                   chip((R, T, NKV, D)), chip((R,), jnp.int32),
+                   chip((R,), jnp.int32)) == 1
 
 
 # (C, NH, NKV, nb, bs, MB).  The serving chunk, then the padded tiles: C=4

@@ -126,6 +126,150 @@ def test_chunk_attention_matches_the_dense_mask(R, C, NH, NKV, D, T, window):
         assert got.shape == q.shape and got.dtype == q.dtype
 
 
+def _steps_by_hand(pos0, n_valid, C, T, bq, bk, window):
+    """(live, interior) `[tiles, steps]` of one row from the dense mask and
+    nothing else: step `j` of a tile takes the key tile `j` past the one
+    that holds the first key its first query sees; it is live where some
+    REAL query of the tile sees some key of it, interior where EVERY query
+    of the tile, padded ones too, sees every key of it."""
+    q_pos = pos0 + np.arange(C)[:, None]
+    key = np.arange(T + 2 * bk)[None]          # (past the buffer: unseen)
+    seen = key <= q_pos
+    if window is not None:
+        seen &= key > q_pos - window
+    tiles, steps = C // bq, T // bk
+    live, interior = (np.zeros((tiles, steps), bool) for _ in range(2))
+    for t in range(tiles):
+        if t * bq >= n_valid:
+            continue
+        first = int(np.argmax(seen[t * bq])) // bk
+        for j in range(steps):
+            tile = seen[t * bq:(t + 1) * bq, (first + j) * bk:][:, :bk]
+            live[t, j] = tile[:max(min(n_valid - t * bq, bq), 0)].any()
+            interior[t, j] = live[t, j] and tile.all() \
+                and tile.shape[1] == bk
+    return live, interior
+
+
+# query tiles of 8 against key tiles of 16 in a buffer of six: a fresh
+# prompt, chunks whose first query stands ON a key tile's edge (16, 64)
+# and off it (37, 61), padding from mid-tile (13, 21), one real query, an
+# empty chunk, a chunk at the buffer's end; windows below the query tile,
+# equal to it, ON the key tile (16, 32), across tiles, past the buffer
+_POSITIONS = [(0, 32), (0, 13), (16, 32), (37, 21), (64, 32), (61, 1),
+              (64, 24), (5, 0)]
+_WINDOWS = [None, 1, 4, 8, 16, 24, 32, 40, 10 ** 6]
+
+
+@pytest.mark.parametrize("pos0,n_valid", _POSITIONS)
+@pytest.mark.parametrize("window", _WINDOWS)
+def test_a_step_is_interior_exactly_where_no_edge_crosses_it(
+        pos0, n_valid, window):
+    C, T, bq, bk = 32, 96, 8, 16
+    t, j = np.arange(C // bq)[:, None], np.arange(T // bk)[None]
+    live, interior = ca.step_kind(pos0, n_valid, t, j, bq=bq, bk=bk,
+                                  window=window, xp=np)
+    want_live, want_interior = _steps_by_hand(pos0, n_valid, C, T, bq, bk,
+                                              window)
+    np.testing.assert_array_equal(live, want_live)
+    np.testing.assert_array_equal(interior, want_interior)
+    # jax (the kernel's side) gives what numpy (the host's) gives
+    got = ca.step_kind(pos0, n_valid, jnp.asarray(t), jnp.asarray(j), bq=bq,
+                       bk=bk, window=window)
+    np.testing.assert_array_equal(np.asarray(got[0]), live)
+    np.testing.assert_array_equal(np.asarray(got[1]), interior)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Query tiles of 8 rows a head and key tiles of 16: many tiles at
+    sizes interpret mode runs in no time."""
+    monkeypatch.setattr(ca, "ROWS_PER_STEP", 16)
+    monkeypatch.setattr(ca, "KEY_TILE", 16)
+
+
+def _masked_everywhere(monkeypatch):
+    """Every live step through the edge body: the kernel before it had
+    two."""
+    rule = ca.step_kind
+
+    def no_interior(*a, **kw):
+        live, interior = rule(*a, **kw)
+        return live, interior & False
+    monkeypatch.setattr(ca, "step_kind", no_interior)
+
+
+@pytest.mark.parametrize("window", _WINDOWS)
+def test_interior_steps_give_the_bits_of_the_masked_body(
+        small_tiles, monkeypatch, window):
+    """The same inputs through both bodies and through the masked one
+    alone: equal bit for bit, padded queries' rows too, and at the real
+    queries the dense mask's numbers; the host's count is the count of
+    each body's steps by the dense mask."""
+    R, C, NH, NKV, D, T = len(_POSITIONS), 32, 4, 2, 32, 96
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(R, C, NH, D), jnp.float32)
+    k = jnp.asarray(rng.randn(R, T, NKV, D), jnp.float32)
+    v = jnp.asarray(rng.randn(R, T, NKV, D), jnp.float32)
+    pos0, n_valid = (jnp.asarray(a, jnp.int32) for a in zip(*_POSITIONS))
+    assert (ca._query_tile(C, NH // NKV), ca.key_tile(T)) == (8, 16)
+    got = ca.chunk_attention(q, k, v, pos0, n_valid, window=window,
+                             interpret=True)
+    want = ca.chunk_attention_reference(q, k, v, pos0, n_valid,
+                                        window=window)
+    real = np.arange(C)[None] < np.asarray(n_valid)[:, None]
+    assert np.abs(np.asarray(got) - np.asarray(want))[real].max() < 2e-5
+    by_hand = [_steps_by_hand(p, n, C, T, 8, 16, window)
+               for p, n in _POSITIONS]
+    live = sum(int(l.sum()) for l, _ in by_hand)
+    interior = sum(int(i.sum()) for _, i in by_hand)
+    assert ca.count_steps(*zip(*_POSITIONS), C, NH // NKV, 16, window) \
+        == (live, live - interior)
+    assert live > interior and (interior > 0) == (window is None
+                                                  or window >= 24)
+    _masked_everywhere(monkeypatch)
+    masked = ca.chunk_attention(q, k, v, pos0, n_valid, window=window,
+                                interpret=True)
+    # (XLA:CPU compiles the two bodies' 16-wide dots differently, an ulp
+    # apart: the bits are compared at the kernel's own tiles, below)
+    assert np.abs(np.asarray(got) - np.asarray(masked)).max() < 1e-6
+
+
+@pytest.mark.parametrize("window", [None, 1100, 100, 4096])
+def test_interior_steps_at_the_kernels_own_tiles(monkeypatch, window):
+    """Key tiles of 512 and query tiles of 128 x 8 heads, a chunk deep in
+    a buffer of five key tiles beside a fresh one cut mid-tile: bit-equal
+    to the masked body alone."""
+    R, C, NH, NKV, D, T = 2, 256, 16, 2, 64, 2560
+    rng = np.random.RandomState(6)
+    q = jnp.asarray(rng.randn(R, C, NH, D), jnp.float32)
+    k = jnp.asarray(rng.randn(R, T, NKV, D), jnp.float32)
+    v = jnp.asarray(rng.randn(R, T, NKV, D), jnp.float32)
+    rows = [(2048 + 37, 256), (0, 141)]
+    pos0, n_valid = (jnp.asarray(a, jnp.int32) for a in zip(*rows))
+    assert (ca._query_tile(C, NH // NKV), ca.key_tile(T)) == (128, 512)
+    got = ca.chunk_attention(q, k, v, pos0, n_valid, window=window,
+                             interpret=True)
+    want = ca.chunk_attention_reference(q, k, v, pos0, n_valid,
+                                        window=window)
+    real = np.arange(C)[None] < np.asarray(n_valid)[:, None]
+    assert np.abs(np.asarray(got) - np.asarray(want))[real].max() < 2e-5
+    by_hand = [_steps_by_hand(p, n, C, T, 128, 512, window) for p, n in rows]
+    live = sum(int(l.sum()) for l, _ in by_hand)
+    interior = sum(int(i.sum()) for _, i in by_hand)
+    assert ca.count_steps(pos0, n_valid, C, NH // NKV, 512, window) \
+        == (live, live - interior)
+    # the deep chunk's two tiles see four whole key tiles and cross the
+    # diagonal in the fifth; a window of 1100 leaves each of them one whole
+    # tile between its edges, a window of 100 none
+    assert (live, interior) == {None: (12, 8), 4096: (12, 8), 1100: (9, 2),
+                                100: (5, 0)}[window]
+    _masked_everywhere(monkeypatch)
+    masked = ca.chunk_attention(q, k, v, pos0, n_valid, window=window,
+                                interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(masked))
+
+
 def test_chunk_attention_reference_is_the_plain_softmax():
     """The yardstick itself, against numpy at one query."""
     rng = np.random.RandomState(1)
@@ -203,6 +347,48 @@ def test_the_stack_through_both_kernels_matches_the_dense_path(
     assert np.abs(np.asarray(fused - dense))[0].max() < 2e-5
     assert np.abs(np.asarray(fused_c[0] - dense_c[0]))[0].max() < 2e-5
     assert np.asarray(dense)[0].std() > 0.1
+
+
+def test_a_chunk_dispatch_says_how_often_the_mask_runs():
+    """The `engine.dispatch` span of every chunk program of a two-kind
+    stack carries the kernel's live key steps and those an edge crosses,
+    summed over the slots and the layers of both kinds: the dense mask's
+    count, on the CPU as on the chip."""
+    from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
+                                            build_engine, hybrid_ops)
+    from deepspeed_tpu.utils import spans
+    eng = build_engine(
+        "smallthinker", "tiny", dtype=jnp.float32, attn_head_dim=64,
+        sliding_window=20, engine_config=RaggedInferenceEngineConfig(
+            num_blocks=48, block_size=8, max_blocks_per_seq=24, max_seqs=4,
+            prefill_chunk_size=64, max_prefill_tokens_per_step=64))
+    seen, orig = [], spans._Span.set_metadata
+
+    def recording(self, **attrs):
+        if self.name == "engine.dispatch" and "attn_steps_live" in attrs:
+            seen.append((self.attrs["program"], attrs))
+        return orig(self, **attrs)
+    spans._Span.set_metadata = recording
+    try:
+        out = eng.put([1], [np.arange(90, dtype=np.int32)])
+        while 1 not in out:
+            out.update(eng.step())
+    finally:
+        spans._Span.set_metadata = orig
+    # two programs: 64 tokens at position 0, then 26 at 64; keys by
+    # position in a buffer of 24 blocks and a chunk, one key tile
+    G, T = eng.cfg.num_heads // eng.cfg.kv_heads, 24 * 8 + 64
+    bq, layers = ca._query_tile(64, G), hybrid_ops.kind_layers(eng.cfg)
+    assert ca.key_tile(T) == T and min(layers) > 0
+    want = []
+    for pos0, n in ((0, 64), (64, 26)):
+        live = masked = 0
+        for count, window in zip(layers, (None, 20)):
+            l, i = _steps_by_hand(pos0, n, 64, T, bq, T, window)
+            live, masked = live + count * l.sum(), masked + count * (l ^ i).sum()
+        want.append(("prefill_chunks", dict(attn_steps_live=live,
+                                            attn_steps_masked=masked)))
+    assert seen == want and want[0][1]["attn_steps_live"] > 0
 
 
 def test_a_uniform_window_through_the_kernels_matches_the_dense_path(
