@@ -296,17 +296,18 @@ class InferenceEngineV2:
                 "tp_collectives='fused') cannot serve the latent (MLA) "
                 "block: its cache has no head dimension to shard and its "
                 "kernel and expert share are not wrapped for a mesh")
-        # a state-space parallel block: per-sequence recurrent state in
-        # slots beside the paged K/V
+        # state-space mixers: per-sequence recurrent state in slots beside
+        # the paged K/V
         self._ssm = bool(getattr(self.cfg, "ssm", False))
         if self._ssm and (self.tp > 1
                           or self.config.tp_collectives != "xla"):
             raise NotImplementedError(
                 "tensor parallelism (tensor_parallel_size > 1, "
-                "tp_collectives='fused') cannot serve the state-space "
-                "parallel block: the per-sequence recurrent state and the "
-                "mixer's heads are not split over a mesh, and its scan and "
-                "update kernels are not wrapped for one")
+                "tp_collectives='fused') cannot serve per-sequence "
+                "recurrent state: the state slots and the mixer's heads "
+                "are not split over a mesh, its scan and update kernels "
+                "are not wrapped for one, and an expert share behind a "
+                "mixer has no exchange")
         # a static-kind stack (window + global layers): two kinds of cache
         self._kinds = bool(getattr(self.cfg, "static_kinds", False))
         if self._kinds and (self.tp > 1
@@ -364,11 +365,12 @@ class InferenceEngineV2:
             state_slots=self.config.max_seqs if self._ssm else 0)
         if self._ssm:
             # what `_count_state` multiplies: the bytes a row's slot moves
-            # both ways a decode step, and of K/V a token holds
+            # both ways a decode step (over the layers with a mixer), and
+            # of K/V a token holds (over the layers with attention)
             from .ssm_ops import state_bytes_per_slot
             self._state_bytes_row = 2 * state_bytes_per_slot(self.cfg)
             self._kv_bytes_token = (
-                2 * self.cfg.num_layers * self.cfg.kv_heads
+                2 * self.cfg.ssm_attn_layers * self.cfg.kv_heads
                 * self.cfg.head_dim * jnp.dtype(self.cfg.dtype).itemsize)
         # per-sequence token ceiling: arena lease AND model context — learned
         # position embeddings clip silently past max_seq_len, so enforce it
@@ -523,10 +525,11 @@ class InferenceEngineV2:
         if self._ssm:
             raise NotImplementedError(
                 f"{what}: not wired for per-sequence recurrent state. A "
-                f"sequence of this model is its K/V blocks AND a slot of "
-                f"state-space state and convolution tail that every token "
-                f"rewrites: a block holds no snapshot of the state at its "
-                f"edge (so a cached or migrated prefix could not be "
+                f"sequence here is its K/V blocks (of the layers with "
+                f"attention) AND a slot of state-space state and "
+                f"convolution tail (of the layers with a mixer) that every "
+                f"token rewrites: a block holds no snapshot of the state "
+                f"at its edge (so a cached or migrated prefix could not be "
                 f"continued), and the programs take no LoRA, draft-span, "
                 f"burst or multi-step operands")
         if self._latent:
@@ -737,6 +740,10 @@ class InferenceEngineV2:
         out = self.state.audit(cache_blocks=cache_blocks)
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.audit_host())
+        if self._ssm:
+            # what a slot and a block stand for: the arenas' rows by kind
+            out.update(state_layers=self.arena["ssm"].shape[0],
+                       kv_layers=self.arena["k"].shape[0])
         return out
 
     def _host_in(self, x):
@@ -1201,9 +1208,13 @@ class InferenceEngineV2:
         attend to: the slots there are and those live sequences hold; the
         bytes of state the step must read and write back (every row's
         slot, both ways) and of cache altogether (those and the rows' keys
-        and values)."""
+        and values); the layers, and those whose kind holds a state and
+        those whose kind holds keys, which size both."""
         state = rows * self._state_bytes_row
         pending.state_account = dict(
+            layers=self.cfg.num_layers,
+            state_layers=self.cfg.ssm_state_layers,
+            kv_layers=self.cfg.ssm_attn_layers,
             state_slots=self.state.state_slots,
             state_slots_live=(self.state.state_slots
                               - self.state.free_state_slots),
@@ -1276,13 +1287,14 @@ class InferenceEngineV2:
     @property
     def supports_moe(self) -> bool:
         # (a latent model holds a SHARE of its experts: nothing to page;
-        # the static-kind stack's experts lie outside `params["layers"]`,
-        # where the slot stacks would ride)
+        # the static-kind stack's and the state-space family's experts lie
+        # outside `params["layers"]`, where the slot stacks would ride)
         return (self.cfg.moe_experts > 1 and self._tpp is None
-                and not self._latent and not self._kinds)
+                and not self._latent and not self._kinds and not self._ssm)
 
-    # the latent stacks' router counters (latent_ops.count_names), a
-    # rider of their arena that every program accumulates
+    # the router counters of `latent_ops._moe`'s callers
+    # (latent_ops.count_names), a rider of their arena that every program
+    # accumulates
     @property
     def supports_moe_counts(self) -> bool:
         return "moe_counts" in self.arena

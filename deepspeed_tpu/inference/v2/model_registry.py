@@ -40,6 +40,7 @@ ARCH_REGISTRY = {
     "deepseek_v3": "deepseek_v3",
     "smallthinker": "smallthinker",
     "falcon_h1": "falcon_h1",
+    "granite_moe_hybrid": "granite_moe_hybrid",
 }
 
 
@@ -108,6 +109,13 @@ def check_serving_moe(model_config, serving_config) -> None:
             "form) holds a fixed share of its experts "
             "(moe_expert_first/count) and routes the rest to other chips "
             "— drop serving.moe")
+    if getattr(model_config, "ssm", False) and E > 1:
+        raise ValueError(
+            "serving.moe (expert paging) pages whole experts of a model "
+            "that holds them all in `params['layers']`; the experts behind "
+            "a state-space mixer lie apart, outside the layer scans, as a "
+            "fixed share (moe_expert_first/count) whose rest is another "
+            "chip's work — drop serving.moe")
     if getattr(model_config, "static_kinds", False):
         raise ValueError(
             "serving.moe (expert paging) swaps experts in the slot stacks "

@@ -71,9 +71,10 @@ def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
     layers apart, each kind with its own blocks:
     `hybrid_ops.init_kinds_arena` (keys "gk"/"gv", "wk"/"wv"), which takes
     `num_blocks` as the byte budget and sizes the window kind by
-    `max_seqs`.  A state-space parallel block (`cfg.ssm`) keeps one slot
-    of recurrent state a sequence beside its paged K/V:
-    `ssm_ops.init_ssm_arena` (keys "ssm", "conv"; `max_seqs` slots)."""
+    `max_seqs`.  The state-space family (`cfg.ssm`) keeps one slot of
+    recurrent state a sequence, over its layers with a mixer, beside the
+    paged K/V of its layers with attention: `ssm_ops.init_ssm_arena` (keys
+    "ssm", "conv"; `max_seqs` slots; "moe_counts" with experts)."""
     if cfg.ssm:
         if (topology is not None and topology.tp_size > 1) or moe_census:
             raise ValueError(

@@ -1,10 +1,15 @@
-"""The state-space parallel block (a Mamba-2 mixer BESIDE grouped-query
-attention in every layer, then a gated MLP; muP multipliers) over paged
-K/V AND per-sequence recurrent state: what `ragged_ops`' programs run for
-a `TransformerConfig` with `ssm_state` (Falcon-H1).
+"""The state-space family (Mamba-2 mixers and grouped-query attention in
+layers of statically known KINDS, then a gated MLP or routed experts;
+scalar multipliers) over paged K/V AND per-sequence recurrent state: what
+`ragged_ops`' programs run for a `TransformerConfig` with `ssm_state`.
 
-A layer, input x [T, H], `n = rms(x, g_in)` read by both branches:
+`cfg.ssm_period` is one period of layer kinds, which is the model's and
+data here: "ssm" (the mixer alone), "attn" (attention alone) or "both" (the
+two side by side on one input norm).  Every layer "both" is the state-space
+parallel block (Falcon-H1); nine "ssm" to one "attn" is Granite-4.0-H.  A
+layer, input x [T, H], `n = rms(x, g_in)`, r = `residual_multiplier`:
 
+    mixer (kinds "ssm", "both"):
     p = (n W_in) * (ssm_in_multiplier * mup)       [z | xBC | dt], mup =
         ssm_multipliers over the columns [z | x | B | C | dt]
     xBC = silu(conv(xBC) + bias)   causal, depthwise, over the last
@@ -13,30 +18,50 @@ A layer, input x [T, H], `n = rms(x, g_in)` read by both branches:
     h_t = exp(dt_t A) h_{t-1} + dt_t B_t (outer) x_t;  y_t = C_t h_t + D x_t
     y = rms_grouped(y * silu(z), g_norm)           gate first, then RMS over
         each of the G groups of channels
-    m = ssm_out_multiplier * (y W_out)
+    m = r ssm_out_multiplier * (y W_out)
+    attention (kinds "attn", "both"):
     q, k, v = n W_q, key_multiplier * (n W_k), n W_v  (n times
-        attention_in_multiplier); rope over the whole head, pairs
-        (i, i + D/2); causal softmax(q k / sqrt(D)) v
-    a = attention_out_multiplier * (o W_o)
-    x1 = x + m + a
-    out = x1 + mlp_multipliers[1] * ((W_up h) * silu(mlp_multipliers[0] *
-        (W_gate h))) W_down,   h = rms(x1, g_ff)
+        attention_in_multiplier); under pos_emb "rope" rotated over the
+        whole head, pairs (i, i + D/2), under "none" as projected; causal
+        softmax(q k s) v, s = attention_multiplier or 1 / sqrt(D)
+    a = r attention_out_multiplier * (o W_o)
+    x1 = x + m + a                                 (what the kind has)
+    FFN, h = rms(x1, g_ff):
+    out = x1 + r mlp_multipliers[1] * ((W_up h) * silu(mlp_multipliers[0] *
+        (W_gate h))) W_down                         moe_experts == 1
+    out = x1 + r (sum_picks w_e E_e(h) + Shared(h))  moe_experts > 1:
+        `latent_ops._moe` over the experts held here, the shared expert
+        (`moe_shared_expert_ffn`) on every token
 
 with `embedding_multiplier` on the embedding and `lm_head_multiplier` on
 the logits.  A multiplier sits on a matmul's float32 result, before the
-one rounding to the stored type.
+one rounding to the stored type; the score scale rides q's projection so
+(the kernels scale by 1 / sqrt(D) and q is rounded once).
 
-The arena holds attention's paged `k`/`v` `[L, blocks, bs, NKV, D]` as the
-dense family's, and beside them one SLOT a live sequence: `ssm` `[L, slots
-+ 1, NHm, N, P]` float32 (the state transposed, channels on the lanes:
-`ops/ssm.py`) and `conv` `[L, slots + 1, (ssm_conv - 1) * x|B|C]` (the
-convolution's tail: the inputs of the last positions, in the stored type,
-which is what they were computed in; one flat row a slot, because with a
-minor pair `[3, 5120]` XLA carries the buffer through the chunk program's
-layer loop in a layout that pads the 3 to 128 lanes: 763 MB for 18).  The last slot is scratch: a padded
-row of the decode kernel reads and writes it.  **The state is float32**: the
+Consecutive layers of one kind are one `lax.scan` (`layer_runs`), the
+periods are scanned outside them; a scan of one step is its body; a layer
+indexes the stacks by its place among its kind.  The
+leaves of `params["layers"]` are stacked by what has them: the mixer's
+(`ssm_*`) over the layers with a mixer, attention's (`wq`, `wk`, `wv`,
+`wo`) over those with attention, the norms and the FFN's over all; the
+routed experts lie apart (`params["experts"]` `[L, local, ...]`), outside
+the scans, as in `latent_ops`.
+
+The arena is sized BY KIND: attention's paged `k`/`v` `[La, blocks, bs,
+NKV, D]` over the La layers with attention, and one SLOT a live sequence
+over the Lm layers with a mixer: `ssm` `[Lm, slots + 1, NHm / pack, N,
+pack * P]` float32 (the state transposed, channels on the lanes, heads
+narrower than 128 lanes side by side: `ops/ssm.py`) and `conv` `[Lm, slots
++ 1, (ssm_conv - 1) * x|B|C]` (the convolution's tail: the inputs of the
+last positions, in the stored type, which is what they were computed in;
+one flat row a slot, because with a minor pair `[3, 5120]` XLA carries the
+buffer through the chunk program's layer loop in a layout that pads the 3
+to 128 lanes: 763 MB for 18).  A layer reads and writes its kind's row
+and nothing of the other kind.  The last slot is scratch: a padded row of
+the decode kernel reads and writes it.  **The state is float32**: the
 recurrence rounds what it stores once a token for as many steps as a
 request has, so a narrower store is a change of precision, not a layout.
+With experts the router's counters ride along (`moe_counts`).
 
 Every program takes the rows' slots beside their block tables (`slots`
 [rows]; a decode batch is not in slot order).  A prompt's scan STARTS from
@@ -50,39 +75,75 @@ in place (`ops/ssm.ssm_update`).
 
 Scopes: `ssm` (in-projection, `ssm/conv`, `ssm/scan` or `ssm/update`,
 gated norm, out-projection), `attn` (projections, rope, `kv_write`, the
-attention proper, `W_o`), `dense_ffn`, `lm_head`.
+attention proper, `W_o`), `dense_ffn` or `router`, `experts`,
+`experts/combine`, `shared_expert`; `lm_head`.
 """
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ...models.transformer import TransformerConfig, _rope
-from .latent_ops import _rms
-from .ragged_ops import (_embed, _kv_write, _lm_logits, _use_paged_kernel,
-                         _use_paged_prefill, greedy_tokens)
+from .latent_ops import _moe, _rms, _rows, count_names
+from .ragged_ops import (_embed, _kv_write, _lm_logits, _plain_mlp,
+                         _use_paged_kernel, _use_paged_prefill, greedy_tokens)
 
-__all__ = ["init_ssm_arena", "state_bytes_per_slot", "prefill_full",
-           "prefill_chunks", "decode_core", "refuse_lora"]
+__all__ = ["ROW_TILE", "layer_runs", "init_ssm_arena",
+           "state_bytes_per_slot", "prefill_full", "prefill_chunks",
+           "decode_core", "refuse_lora"]
+
+# rows the experts take at once where a program has more slots than that
+# (chunk slots are padded: the real rows go in front, `latent_ops._rows`)
+ROW_TILE = 512
+ATTN_LEAVES = ("wq", "wk", "wv", "wo")
+
+
+class Run(NamedTuple):
+    """Consecutive layers of one kind within a period."""
+    kind: str            # "ssm" | "attn" | "both"
+    first: int           # the first one's place in the period
+    count: int
+    state_row: int       # ... among the period's layers with a mixer
+    attn_row: int        # ... among the period's layers with attention
+
+
+def layer_runs(cfg: TransformerConfig) -> Tuple[Run, ...]:
+    period, runs = cfg.ssm_period, []
+    for j, kind in enumerate(period):
+        if runs and runs[-1].kind == kind:
+            runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+        else:
+            runs.append(Run(kind, j, 1,
+                            sum(k != "attn" for k in period[:j]),
+                            sum(k != "ssm" for k in period[:j])))
+    return tuple(runs)
 
 
 def init_ssm_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
                    max_seqs: int):
-    """Paged K/V beside `max_seqs` state slots and one scratch slot."""
-    L = cfg.num_layers
-    kv = (L, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "ssm": jnp.zeros((L, max_seqs + 1, cfg.ssm_heads, cfg.ssm_state,
-                              cfg.ssm_head_dim), jnp.float32),
-            "conv": jnp.zeros((L, max_seqs + 1, (cfg.ssm_conv - 1)
-                               * cfg.ssm_conv_width), cfg.dtype)}
+    """Paged K/V over the layers with attention beside `max_seqs` state
+    slots and one scratch slot over the layers with a mixer."""
+    from ...ops.ssm import state_shape
+    Lm, La = cfg.ssm_state_layers, cfg.ssm_attn_layers
+    kv = (La, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    arena = {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+             "ssm": jnp.zeros((Lm, max_seqs + 1) + state_shape(
+                 cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state,
+                 cfg.ssm_head_dim), jnp.float32),
+             "conv": jnp.zeros((Lm, max_seqs + 1, (cfg.ssm_conv - 1)
+                                * cfg.ssm_conv_width), cfg.dtype)}
+    if cfg.moe_experts > 1:
+        arena["moe_counts"] = jnp.zeros((len(count_names(cfg)),), jnp.int32)
+    return arena
 
 
 def state_bytes_per_slot(cfg: TransformerConfig) -> int:
-    """Recurrent state a sequence holds over all layers (state + tail)."""
-    return cfg.num_layers * (
+    """Recurrent state a sequence holds over the layers with a mixer
+    (state + tail)."""
+    return cfg.ssm_state_layers * (
         cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim * 4
         + (cfg.ssm_conv - 1) * cfg.ssm_conv_width
         * jnp.dtype(cfg.dtype).itemsize)
@@ -91,9 +152,9 @@ def state_bytes_per_slot(cfg: TransformerConfig) -> int:
 def refuse_lora(lora) -> None:
     if lora is not None:
         raise NotImplementedError(
-            "LoRA adapters are not wired for the state-space parallel "
-            "block: the gather epilogue sits on the dense block's output "
-            "projection, and this block's programs take no adapter operands")
+            "LoRA adapters are not wired for the state-space family: the "
+            "gather epilogue sits on the dense block's output projection, "
+            "and these programs take no adapter operands")
 
 
 def _scaled(h, w, mult):
@@ -116,10 +177,14 @@ def _in_multipliers(cfg: TransformerConfig):
 
 def _use_ssm_kernels(cfg: TransformerConfig) -> bool:
     """The Pallas scan and update where the chip is (their transposes
-    want whole 128-lane tiles); `attn_impl='jnp'` keeps the dense forms."""
+    want whole 128-lane tiles: a stored row of heads, the state's N, the
+    chunk); `attn_impl='jnp'` keeps the dense forms."""
+    from ...ops.ssm import state_shape
     from ...utils.device import on_tpu
+    row = state_shape(cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state,
+                      cfg.ssm_head_dim)[-1]
     return (on_tpu() and cfg.attn_impl != "jnp"
-            and cfg.ssm_head_dim % 128 == 0 and cfg.ssm_state % 128 == 0
+            and row % 128 == 0 and cfg.ssm_state % 128 == 0
             and cfg.ssm_chunk % 128 == 0)
 
 
@@ -167,7 +232,8 @@ def _gated_out(cfg, lp, y, z):
     y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
     y = (y.reshape(T, -1) * lp["ssm_norm_scale"].astype(jnp.float32)
          ).astype(z.dtype)
-    return _scaled(y, lp["ssm_out"], cfg.ssm_out_multiplier)
+    return _scaled(y, lp["ssm_out"],
+                   cfg.ssm_out_multiplier * cfg.residual_multiplier)
 
 
 def _split_in(cfg, lp, n):
@@ -195,14 +261,20 @@ def _step_size(lp, dt):
 
 
 def _qkv(cfg, lp, n, positions):
-    """Projected and rotated q [R, S, NH, D], k, v [R, S, NKV, D] of n
-    [R, S, H]."""
+    """Projected q [R, S, NH, D], k, v [R, S, NKV, D] of n [R, S, H],
+    rotated where the model rotates.  The kernels scale the scores by 1 /
+    sqrt(D): a model that states another scale has the ratio on q's
+    projection."""
     R, S, H = n.shape
     NH, NKV, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     n2, a_in = n.reshape(R * S, H), cfg.attention_in_multiplier
-    q = _scaled(n2, lp["wq"], a_in).reshape(R, S, NH, D)
+    a_q = a_in * (cfg.attention_multiplier * math.sqrt(D)
+                  if cfg.attention_multiplier else 1.0)
+    q = _scaled(n2, lp["wq"], a_q).reshape(R, S, NH, D)
     k = _scaled(n2, lp["wk"], a_in * cfg.key_multiplier).reshape(R, S, NKV, D)
     v = _scaled(n2, lp["wv"], a_in).reshape(R, S, NKV, D)
+    if cfg.pos_emb == "none":
+        return q, k, v
     return (_rope(q, positions, cfg.rope_theta),
             _rope(k, positions, cfg.rope_theta), v)
 
@@ -214,13 +286,130 @@ def _mlp(cfg, lp, x1):
         up = _scaled(h, lp["w_up"], 1.0)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)).astype(h.dtype)
-        return _scaled(act, lp["w_down"], cfg.mlp_multipliers[1])
+        return _scaled(act, lp["w_down"],
+                       cfg.mlp_multipliers[1] * cfg.residual_multiplier)
+
+
+def _experts_ffn(cfg, lp, experts, li, x1, real):
+    """x1 + r (the held experts' part + the shared expert) on [T, H] rows
+    (`real`: the rows that are tokens), and the router's counts."""
+    h = _rms(x1, lp["mlp_norm_scale"], cfg.norm_eps)
+    m, counts = _moe(cfg, lp, experts, li, h, real)
+    m = m.astype(jnp.float32)
+    if cfg.moe_shared_expert_ffn:
+        with jax.named_scope("shared_expert"):
+            m = m + _plain_mlp(cfg, lp["shared"], h).astype(jnp.float32)
+    return x1 + (m * cfg.residual_multiplier).astype(x1.dtype), counts
 
 
 def _logits(cfg, params, x):
     logits = _lm_logits(cfg, params, x)
     with jax.named_scope("lm_head"):
         return logits * cfg.lm_head_multiplier
+
+
+def _stacked_over(name: str) -> str:
+    """Which layers a leaf of `params["layers"]` is stacked over: those
+    with a mixer ("ssm"), those with attention ("attn"), or all."""
+    if name.startswith("ssm_"):
+        return "ssm"
+    return "attn" if name in ATTN_LEAVES else "all"
+
+
+def _steps(body, carry, xs, n: int):
+    """`lax.scan` of `body` over `xs`' leading `n`; one step is the body
+    itself on the one slice."""
+    if n == 1:
+        return body(carry, jax.tree.map(lambda a: a[0], xs))[0]
+    return jax.lax.scan(body, carry, xs)[0]
+
+
+def _stack(cfg: TransformerConfig, params, arena, x, real, mixer, attend):
+    """The layers over x [T, H]: per period its runs of one kind, each a
+    scan over that run's layers (`_steps`).  `mixer(lp, row, n, ssm, conv)
+    -> (m, ssm, conv)` and `attend(lp, row, n, ak, av) -> (a, ak, av)` are
+    the program's two branches on the normed input, `row` the layer's place
+    among its kind's arena rows; `real` [T]: the rows that are tokens.
+    Returns (x, arena)."""
+    period, L = cfg.ssm_period, cfg.num_layers
+    P, dt = len(period), cfg.dtype
+    per_state = sum(k != "attn" for k in period)
+    per_attn = sum(k != "ssm" for k in period)
+    T = x.shape[0]
+    experts = None
+    if cfg.moe_experts > 1:
+        experts = {n: w.reshape((L * cfg.local_experts,) + w.shape[2:])
+                   .astype(dt) for n, w in params["experts"].items()}
+    # more slots than the experts take at once: the real rows go in front
+    compact = experts is not None and T > ROW_TILE and T % ROW_TILE == 0
+    if compact:
+        order = jnp.argsort(~real, stable=True)
+        back, n_real = jnp.argsort(order), jnp.sum(real)
+
+    def ffn(lp, li, x1, counts):
+        if experts is None:
+            return x1 + _mlp(cfg, lp, x1), counts
+
+        def one(x1, real):
+            out, c = _experts_ffn(cfg, lp, experts, li, x1, real)
+            return (out,), c
+
+        if not compact:
+            (out,), c = one(x1, real)
+            return out, counts + c
+        (out,), counts = _rows(one, n_real, (x1[order], real[order]),
+                               counts, ROW_TILE)
+        return out[back], counts
+
+    leaves = params["layers"]
+
+    def layers_of(kind: str):
+        mine = [n for n in leaves if kind == "both"
+                or _stacked_over(n) in ("all", kind)]
+
+        def layer(carry, rows):
+            x, ak, av, ssm, conv, counts = carry                    # [T, H]
+            li, srow, arow = rows
+            # (a layer takes its leaves out of the whole stacks by its own
+            # place among each: a run's slice of a stack handed to the scan
+            # as `xs` is a copy, 1.2 GB of them in the decode program of
+            # nine mixers)
+            row = {"all": li, "ssm": srow, "attn": arow}
+            lp = {n: jax.tree.map(lambda a: a[row[_stacked_over(n)]],
+                                  leaves[n]) for n in mine}
+            n = _rms(x, lp["attn_norm_scale"], cfg.norm_eps)
+            x1 = x
+            if kind != "attn":
+                with jax.named_scope("ssm"):
+                    m, ssm, conv = mixer(lp, srow, n, ssm, conv)
+                x1 = x1 + m
+            if kind != "ssm":
+                with jax.named_scope("attn"):
+                    a, ak, av = attend(lp, arow, n, ak, av)
+                x1 = x1 + a
+            out, counts = ffn(lp, li, x1, counts)
+            return (out, ak, av, ssm, conv, counts), None
+        return layer
+
+    runs = layer_runs(cfg)
+
+    def one_period(carry, pi):
+        for run in runs:
+            nth = jnp.arange(run.count)
+            carry = _steps(layers_of(run.kind), carry, (
+                pi * P + run.first + nth,
+                pi * per_state + run.state_row + nth,
+                pi * per_attn + run.attn_row + nth), run.count)
+        return carry, None
+
+    x, ak, av, ssm, conv, counts = _steps(
+        one_period, (x, arena["k"], arena["v"], arena["ssm"], arena["conv"],
+                     arena.get("moe_counts", ())),
+        jnp.arange(L // P), L // P)
+    out = {**arena, "k": ak, "v": av, "ssm": ssm, "conv": conv}
+    if experts is not None:
+        out["moe_counts"] = counts
+    return x, out
 
 
 def _attend_chunks(cfg, q, ak, av, li, block_tables, positions, pos0s,
@@ -259,12 +448,12 @@ def _attend_chunks(cfg, q, ak, av, li, block_tables, positions, pos0s,
     return o
 
 
-def _prefill(cfg: TransformerConfig, params, arena, tokens, pos0s, n_valids,
-             block_tables, active, slots, fresh: bool):
+def _prefill_rows(cfg: TransformerConfig, params, arena, tokens, pos0s,
+                  n_valids, block_tables, active, slots, fresh: bool):
     """Rows of prompt positions [pos0, pos0 + n_valid): tokens [R, S];
     `fresh`: every row starts at position 0 (a static promise: causal
     flash attention over the row itself, zero initial state).  Returns
-    (logits [R, V] at each row's last position, their argmax, arena)."""
+    (the last layer's output [R, S, H], arena)."""
     from ...ops import ssm as kernels
     R, S = tokens.shape
     H, dt_ = cfg.hidden_size, cfg.dtype
@@ -273,6 +462,7 @@ def _prefill(cfg: TransformerConfig, params, arena, tokens, pos0s, n_valids,
     nb, bs = arena["k"].shape[1], arena["k"].shape[2]
     MB = block_tables.shape[1]
     n_slots = arena["ssm"].shape[1]
+    pack = arena["ssm"].shape[-1] // cfg.ssm_head_dim
     pos0s = jnp.where(active, pos0s, 0)
     n_valids = jnp.where(active, n_valids, 0)
     positions = pos0s[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
@@ -291,84 +481,90 @@ def _prefill(cfg: TransformerConfig, params, arena, tokens, pos0s, n_valids,
     carried = (pos0s > 0)[:, None, None]
     fused = _use_ssm_kernels(cfg)
 
-    def layer(carry, xs):
-        x, ak, av, ssm, conv = carry                              # [R*S, H]
-        lp, li = xs
-        n = _rms(x, lp["attn_norm_scale"], cfg.norm_eps)
-        with jax.named_scope("ssm"):
-            z, xbc, dtr = _split_in(cfg, lp, n)
-            with jax.named_scope("conv"):
-                tail = None if fresh else jnp.where(
-                    carried, conv[li, slot_r].reshape(R, K - 1, -1), 0)
-                xbc, ext = _conv(cfg, lp, xbc.reshape(R, S, -1), tail)
-                conv = conv.at[li, slot_w].set(
-                    _next_tail(ext, tail, n_valids, K - 1).reshape(R, -1),
-                    mode="drop")
-            xs_, b, c = _split_conv(cfg, xbc)
-            a_neg = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
-            step = jnp.where(valid[..., None],
-                             _step_size(lp, dtr).reshape(R, S, -1), 0.0)
-            with jax.named_scope("scan"):
-                if fused:
-                    y, ssm = kernels.ssd_scan(
-                        xs_, step, a_neg, b, c, ssm, li, slot_k,
-                        jnp.zeros_like(slots) if fresh else pos0s > 0,
-                        cfg.ssm_chunk)
-                else:
-                    h0 = jnp.zeros((R,) + ssm.shape[2:], jnp.float32) \
-                        if fresh else jnp.where(carried[..., None],
-                                                ssm[li, slot_r], 0.0)
-                    y, h = kernels.ssd_scan_reference(
-                        xs_, step, a_neg, b, c, h0, cfg.ssm_chunk)
-                    ssm = ssm.at[li, slot_w].set(h, mode="drop")
-            y = y + lp["ssm_d"].astype(jnp.float32)[:, None] \
-                * xs_.astype(jnp.float32)
-            m = _gated_out(cfg, lp, y.reshape(R * S, -1), z)
-        with jax.named_scope("attn"):
-            q, k, v = _qkv(cfg, lp, n.reshape(R, S, H), positions)
-            ak, av = _kv_write(ak, av, li, blk, off, k, v, False)
-            if fresh:
-                from ...ops.attention import causal_attention
-                o = causal_attention(q, k, v, impl=cfg.attn_impl
-                                     ).reshape(R * S, NH * D)
+    def mixer(lp, li, n, ssm, conv):
+        z, xbc, dtr = _split_in(cfg, lp, n)
+        with jax.named_scope("conv"):
+            tail = None if fresh else jnp.where(
+                carried, conv[li, slot_r].reshape(R, K - 1, -1), 0)
+            xbc, ext = _conv(cfg, lp, xbc.reshape(R, S, -1), tail)
+            conv = conv.at[li, slot_w].set(
+                _next_tail(ext, tail, n_valids, K - 1).reshape(R, -1),
+                mode="drop")
+        xs_, b, c = _split_conv(cfg, xbc)
+        a_neg = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
+        step = jnp.where(valid[..., None],
+                         _step_size(lp, dtr).reshape(R, S, -1), 0.0)
+        with jax.named_scope("scan"):
+            if fused:
+                y, ssm = kernels.ssd_scan(
+                    xs_, step, a_neg, b, c, ssm, li, slot_k,
+                    jnp.zeros_like(slots) if fresh else pos0s > 0,
+                    cfg.ssm_chunk)
             else:
-                o = _attend_chunks(cfg, q, ak, av, li, block_tables,
-                                   positions, pos0s, n_valids
-                                   ).reshape(R * S, NH * D)
-            a = _scaled(o, lp["wo"], cfg.attention_out_multiplier)
-        x1 = x + m + a
-        return (x1 + _mlp(cfg, lp, x1), ak, av, ssm, conv), None
+                h0 = jnp.zeros((R, cfg.ssm_heads, cfg.ssm_state,
+                                cfg.ssm_head_dim), jnp.float32) \
+                    if fresh else jnp.where(
+                        carried[..., None],
+                        kernels.unpack_state(ssm[li, slot_r], pack), 0.0)
+                y, h = kernels.ssd_scan_reference(
+                    xs_, step, a_neg, b, c, h0, cfg.ssm_chunk)
+                ssm = ssm.at[li, slot_w].set(kernels.pack_state(h, pack),
+                                             mode="drop")
+        y = y + lp["ssm_d"].astype(jnp.float32)[:, None] \
+            * xs_.astype(jnp.float32)
+        return _gated_out(cfg, lp, y.reshape(R * S, -1), z), ssm, conv
 
-    (x, ak, av, ssm, conv), _ = jax.lax.scan(
-        layer, (x, arena["k"], arena["v"], arena["ssm"], arena["conv"]),
-        (params["layers"], jnp.arange(cfg.num_layers)))
-    last = jnp.clip(n_valids - 1, 0, S - 1)
-    logits = _logits(cfg, params, x.reshape(R, S, H)[jnp.arange(R), last])
-    return logits, greedy_tokens(logits), {
-        **arena, "k": ak, "v": av, "ssm": ssm, "conv": conv}
+    def attend(lp, li, n, ak, av):
+        q, k, v = _qkv(cfg, lp, n.reshape(R, S, H), positions)
+        ak, av = _kv_write(ak, av, li, blk, off, k, v, False)
+        if fresh:
+            from ...ops.attention import causal_attention
+            o = causal_attention(q, k, v, impl=cfg.attn_impl
+                                 ).reshape(R * S, NH * D)
+        else:
+            o = _attend_chunks(cfg, q, ak, av, li, block_tables,
+                               positions, pos0s, n_valids
+                               ).reshape(R * S, NH * D)
+        return _scaled(o, lp["wo"], cfg.attention_out_multiplier
+                       * cfg.residual_multiplier), ak, av
+
+    x, arena = _stack(cfg, params, arena, x, valid.reshape(R * S), mixer,
+                      attend)
+    return x.reshape(R, S, H), arena
+
+
+def _prefill(cfg: TransformerConfig, params, arena, tokens, pos0s, n_valids,
+             block_tables, active, slots, fresh: bool):
+    """`_prefill_rows`, then (logits [R, V] at each row's last position,
+    their argmax, arena)."""
+    x, arena = _prefill_rows(cfg, params, arena, tokens, pos0s, n_valids,
+                             block_tables, active, slots, fresh)
+    last = jnp.clip(jnp.where(active, n_valids, 0) - 1, 0, x.shape[1] - 1)
+    logits = _logits(cfg, params, x[jnp.arange(x.shape[0]), last])
+    return logits, greedy_tokens(logits), arena
 
 
 def prefill_full(cfg, params, arena, tokens, lens, block_tables, active,
                  slots):
-    """`ragged_ops.prefill_full` for the state-space parallel block: fresh
-    whole prompts, dense causal flash attention, the scan from zeros."""
+    """`ragged_ops.prefill_full` for the state-space family: fresh whole
+    prompts, dense causal flash attention, the scan from zeros."""
     return _prefill(cfg, params, arena, tokens, jnp.zeros_like(lens), lens,
                     block_tables, active, slots, fresh=True)
 
 
 def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
                    block_tables, active, slots):
-    """`ragged_ops.prefill_chunks` for the state-space parallel block: a
-    chunk slot a SEQUENCE (the engine plans no two chunks of one sequence
-    into a program), each from its slot's state where it continues."""
+    """`ragged_ops.prefill_chunks` for the state-space family: a chunk
+    slot a SEQUENCE (the engine plans no two chunks of one sequence into a
+    program), each from its slot's state where it continues."""
     return _prefill(cfg, params, arena, tokens, pos0s, n_valids,
                     block_tables, active, slots, fresh=False)
 
 
 def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active,
                 slots):
-    """`ragged_ops._decode_core` for the state-space parallel block:
-    (logits [B, V], arena), the active rows' slots updated in place."""
+    """`ragged_ops._decode_core` for the state-space family: (logits [B,
+    V], arena), the active rows' slots updated in place."""
     from ...ops import ssm as kernels
     B = tokens.shape[0]
     NH, D = cfg.num_heads, cfg.head_dim
@@ -388,53 +584,46 @@ def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active,
     # the kernel's rows all name a slot that exists: the scratch one
     slot_k = jnp.where(active, slot_r, n_slots - 1)
 
-    def layer(carry, xs):
-        x, ak, av, ssm, conv = carry                                 # [B, H]
-        lp, li = xs
-        n = _rms(x, lp["attn_norm_scale"], cfg.norm_eps)
-        with jax.named_scope("ssm"):
-            z, xbc_in, dtr = _split_in(cfg, lp, n)
-            with jax.named_scope("conv"):
-                tail = conv[li, slot_r]                    # [B, (K - 1) W]
-                Wc = xbc_in.shape[-1]
-                xbc, _ = _conv(cfg, lp, xbc_in[:, None],
-                               tail.reshape(B, -1, Wc))
-                conv = conv.at[li, slot_w].set(jnp.concatenate(
-                    [tail[:, Wc:], xbc_in], axis=1), mode="drop")
-            xs_, b, c = _split_conv(cfg, xbc[:, 0])
-            step = _step_size(lp, dtr)                               # [B, NHm]
-            xf = xs_.astype(jnp.float32)
-            decay = jnp.exp(step * -jnp.exp(
-                lp["ssm_a_log"].astype(jnp.float32)))
-            with jax.named_scope("update"):
-                args = (xf * step[..., None],
-                        jnp.broadcast_to(decay[..., None], xf.shape),
-                        b.astype(jnp.float32), c.astype(jnp.float32))
-                if fused_ssm:
-                    y, ssm = kernels.ssm_update(ssm, li, slot_k, *args)
-                else:
-                    y, ssm = kernels.ssm_update_reference(ssm, li, slot_w,
-                                                          *args)
-            y = y + lp["ssm_d"].astype(jnp.float32)[:, None] * xf
-            m = _gated_out(cfg, lp, y.reshape(B, -1), z)
-        with jax.named_scope("attn"):
-            q, k, v = _qkv(cfg, lp, n[:, None], seq_lens[:, None])
-            ak, av = _kv_write(ak, av, li, blk, off, k[:, 0], v[:, 0], False)
-            if fused_attn:
-                from ...ops.paged_attention import paged_decode_attention
-                o = paged_decode_attention(q[:, 0], ak, av, block_tables,
-                                           lens, layer_idx=li)
+    def mixer(lp, li, n, ssm, conv):
+        z, xbc_in, dtr = _split_in(cfg, lp, n)
+        with jax.named_scope("conv"):
+            tail = conv[li, slot_r]                        # [B, (K - 1) W]
+            Wc = xbc_in.shape[-1]
+            xbc, _ = _conv(cfg, lp, xbc_in[:, None],
+                           tail.reshape(B, -1, Wc))
+            conv = conv.at[li, slot_w].set(jnp.concatenate(
+                [tail[:, Wc:], xbc_in], axis=1), mode="drop")
+        xs_, b, c = _split_conv(cfg, xbc[:, 0])
+        step = _step_size(lp, dtr)                               # [B, NHm]
+        xf = xs_.astype(jnp.float32)
+        decay = jnp.exp(step * -jnp.exp(
+            lp["ssm_a_log"].astype(jnp.float32)))
+        with jax.named_scope("update"):
+            args = (xf * step[..., None],
+                    jnp.broadcast_to(decay[..., None], xf.shape),
+                    b.astype(jnp.float32), c.astype(jnp.float32))
+            if fused_ssm:
+                y, ssm = kernels.ssm_update(ssm, li, slot_k, *args)
             else:
-                from ...ops.paged_attention import paged_decode_reference
-                o = paged_decode_reference(q[:, 0], ak[li], av[li],
-                                           block_tables, lens)
-            a = _scaled(o.reshape(B, NH * D), lp["wo"],
-                        cfg.attention_out_multiplier)
-        x1 = x + m + a
-        return (x1 + _mlp(cfg, lp, x1), ak, av, ssm, conv), None
+                y, ssm = kernels.ssm_update_reference(ssm, li, slot_w,
+                                                      *args)
+        y = y + lp["ssm_d"].astype(jnp.float32)[:, None] * xf
+        return _gated_out(cfg, lp, y.reshape(B, -1), z), ssm, conv
 
-    (x, ak, av, ssm, conv), _ = jax.lax.scan(
-        layer, (x, arena["k"], arena["v"], arena["ssm"], arena["conv"]),
-        (params["layers"], jnp.arange(cfg.num_layers)))
-    return _logits(cfg, params, x), {
-        **arena, "k": ak, "v": av, "ssm": ssm, "conv": conv}
+    def attend(lp, li, n, ak, av):
+        q, k, v = _qkv(cfg, lp, n[:, None], seq_lens[:, None])
+        ak, av = _kv_write(ak, av, li, blk, off, k[:, 0], v[:, 0], False)
+        if fused_attn:
+            from ...ops.paged_attention import paged_decode_attention
+            o = paged_decode_attention(q[:, 0], ak, av, block_tables,
+                                       lens, layer_idx=li)
+        else:
+            from ...ops.paged_attention import paged_decode_reference
+            o = paged_decode_reference(q[:, 0], ak[li], av[li],
+                                       block_tables, lens)
+        return _scaled(o.reshape(B, NH * D), lp["wo"],
+                       cfg.attention_out_multiplier
+                       * cfg.residual_multiplier), ak, av
+
+    x, arena = _stack(cfg, params, arena, x, active, mixer, attend)
+    return _logits(cfg, params, x), arena

@@ -21,6 +21,7 @@ from .transformer import (
     deepseek_v3_config,
     smallthinker_config,
     falcon_h1_config,
+    granite_moe_hybrid_config,
 )
 
 from .hf_loader import load_hf_model, hf_to_config, convert_state_dict
@@ -42,6 +43,7 @@ MODEL_FAMILIES = {
     "deepseek_v3": deepseek_v3_config,
     "smallthinker": smallthinker_config,
     "falcon_h1": falcon_h1_config,
+    "granite_moe_hybrid": granite_moe_hybrid_config,
 }
 
 
@@ -63,4 +65,5 @@ __all__ = [
     "falcon_config", "opt_config",
     "bloom_config", "gptneox_config", "longcat_flash_config",
     "deepseek_v3_config", "smallthinker_config", "falcon_h1_config",
+    "granite_moe_hybrid_config",
 ]

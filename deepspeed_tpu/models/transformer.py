@@ -209,24 +209,41 @@ class TransformerConfig:
     # every expert; assignments to absent ones are another chip's work
     moe_expert_first: int = 0
     moe_expert_count: int = 0
-    # a state-space (Mamba-2) mixer BESIDE attention in every layer, on when
-    # ssm_state > 0 (Falcon-H1): both branches read the input norm and add
-    # to the residual, then a gated MLP.  ssm_heads heads of ssm_head_dim
-    # channels, a state of ssm_state values a channel, B and C shared by
-    # the heads of a group, a causal depthwise convolution over the last
-    # ssm_conv positions of [x | B | C], prompts scanned in chunks of
-    # ssm_chunk positions.  The state is per SEQUENCE and of fixed size: it
-    # lives in slots beside the paged K/V (inference/v2/ssm_ops.py).
-    # The multipliers are the published muP scalars, applied where the
+    # a state-space (Mamba-2) mixer, on when ssm_state > 0: served over
+    # per-sequence state slots beside the paged K/V
+    # (inference/v2/ssm_ops.py).  `ssm_layout` is ONE PERIOD of layer
+    # kinds, repeated over the stack: "ssm" (the mixer alone), "attn"
+    # (grouped-query attention alone) or "both" (the two side by side on
+    # one input norm, both added to the residual); None: every layer
+    # "both" (Falcon-H1); nine "ssm" to one "attn" is Granite-4.0-H.  A
+    # layer holds only its kind's state: a slot's rows count the layers
+    # with a mixer, a block's the layers with attention.  After the mixer
+    # branch comes the FFN: the gated MLP, or (moe_experts > 1) the routed
+    # experts of moe_expert_ffn held here (moe_expert_first/count) beside
+    # a shared expert of moe_shared_expert_ffn.  ssm_heads heads of
+    # ssm_head_dim channels, a state of ssm_state values a channel, B and
+    # C shared by the heads of a group, a causal depthwise convolution
+    # over the last ssm_conv positions of [x | B | C], prompts scanned in
+    # chunks of ssm_chunk positions.  pos_emb "rope" rotates q and k,
+    # "none" leaves them as projected.  The state is per SEQUENCE and of
+    # fixed size.
+    # The multipliers are the published scalars, applied where the
     # published modelling code applies them: ssm_multipliers on the column
     # ranges [z | x | B | C | dt] of the mixer's in-projection,
-    # mlp_multipliers on (the gate's pre-activation, the FFN's output)
+    # mlp_multipliers on (the gate's pre-activation, the FFN's output),
+    # residual_multiplier on every branch as it joins the residual stream,
+    # attention_multiplier on the scores in 1/sqrt(head_dim)'s place (0:
+    # that), lm_head_multiplier on the logits (a published
+    # `logits_scaling` is its inverse)
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    ssm_layout: Optional[Tuple[str, ...]] = None
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
     embedding_multiplier: float = 1.0
     lm_head_multiplier: float = 1.0
     attention_in_multiplier: float = 1.0
@@ -382,15 +399,17 @@ class TransformerConfig:
                     f"{self.moe_expert_first + self.local_experts}) are not "
                     f"among the {self.moe_experts} routed experts")
         elif (self.moe_zero_experts or self.moe_router_bias
-              or self.moe_expert_count or self.moe_expert_first
               or self.moe_router_groups or self.latent_dense_layers
-              or self.moe_router_scores != "softmax"):
+              or self.moe_router_scores != "softmax"
+              or ((self.moe_expert_count or self.moe_expert_first)
+                  and not self.ssm)):
             raise ValueError(
                 "moe_zero_experts, moe_router_bias, moe_router_scores, "
-                "moe_router_groups, latent_dense_layers and the expert "
-                "share (moe_expert_first/count) exist only in the "
-                "latent-attention double block and the single-attention "
-                "latent layer (kv_lora_rank > 0)")
+                "moe_router_groups and latent_dense_layers exist only in "
+                "the latent-attention double block and the single-attention "
+                "latent layer (kv_lora_rank > 0), the expert share "
+                "(moe_expert_first/count) there and behind a state-space "
+                "mixer (ssm_state > 0)")
         if self.rope_layers is not None:
             wins = set(self.sliding_window_layers or ())
             if not (len(self.rope_layers) == self.num_layers
@@ -419,29 +438,53 @@ class TransformerConfig:
                 "(rope_layers) and the state-space parallel block "
                 "(ssm_state), the 'reglu' experts only in the former")
         if self.ssm:
+            period = self.ssm_period
+            experts = self.moe_experts > 1
             if not (self.ssm_heads and self.ssm_head_dim
                     and self.ssm_groups >= 1
                     and self.ssm_heads % self.ssm_groups == 0
                     and self.ssm_conv >= 2 and self.ssm_chunk >= 1
                     and len(self.ssm_multipliers) == 5
                     and len(self.mlp_multipliers) == 2
-                    and self.pos_emb == "rope" and self.norm == "rmsnorm"
+                    and period and self.num_layers % len(period) == 0
+                    and all(k in ("ssm", "attn", "both") for k in period)
+                    and any(k != "attn" for k in period)
+                    and self.pos_emb in ("rope", "none")
+                    and self.norm == "rmsnorm"
                     and self.activation == "swiglu"
-                    and self.moe_experts == 1 and not self.latent
+                    and not self.latent
                     and self.rope_layers is None
                     and self.sliding_window is None
                     and self.sliding_window_layers is None
-                    and not self.qkv_bias and self.tie_embeddings is False
+                    and self.moe_dense_layers is None
+                    and not self.qkv_bias
                     and self.rope_scaling is None and self.rope_pct == 1.0
-                    and not self.post_norm and not self.embed_proj_dim):
+                    and not self.post_norm and not self.embed_proj_dim
+                    and (bool(self.moe_expert_ffn) if experts else not (
+                        self.moe_expert_ffn or self.moe_expert_count
+                        or self.moe_expert_first))
+                    and 0 <= self.moe_expert_first
+                    and self.moe_expert_first + self.local_experts
+                    <= self.moe_experts):
                 raise ValueError(
-                    "the state-space parallel block (ssm_state > 0) is "
-                    "served in one form: ssm_heads heads of ssm_head_dim "
-                    "in ssm_groups equal groups, a convolution of at least "
-                    "2 positions, five ssm_multipliers and two "
-                    "mlp_multipliers, beside full causal attention with "
-                    "plain rope, rmsnorm, a dense swiglu FFN, no biases on "
-                    "the projections, an untied head")
+                    "the state-space family (ssm_state > 0: a mixer alone, "
+                    "attention alone, or the state-space parallel block of "
+                    "both) is served in one form: ssm_heads heads of "
+                    "ssm_head_dim in ssm_groups equal groups, a convolution "
+                    "of at least 2 positions, five ssm_multipliers and two "
+                    "mlp_multipliers; ssm_layout one period of 'ssm' | "
+                    "'attn' | 'both' that divides num_layers and has a "
+                    "mixer; full causal attention with plain rope or no "
+                    "position encoding (pos_emb 'none'), rmsnorm, no "
+                    "biases on the projections; then a dense swiglu FFN, "
+                    "or moe_experts > 1 of moe_expert_ffn with the share "
+                    "[moe_expert_first, + moe_expert_count) among them and "
+                    "an optional plain shared expert")
+        elif (self.ssm_layout is not None or self.residual_multiplier != 1.0
+              or self.attention_multiplier):
+            raise ValueError(
+                "ssm_layout, residual_multiplier and attention_multiplier "
+                "exist only in the state-space family (ssm_state > 0)")
         if self.embed_proj_dim and self.tiled_loss_shards > 1:
             raise ValueError(
                 "tiled_loss_shards with embed_proj_dim is not supported: "
@@ -476,9 +519,29 @@ class TransformerConfig:
 
     @property
     def ssm(self) -> bool:
-        """A state-space mixer beside attention in every layer: served
-        over per-sequence state slots beside the paged K/V."""
+        """State-space mixers in the stack (in every layer, or in the
+        layers `ssm_layout` gives them): served over per-sequence state
+        slots beside the paged K/V."""
         return self.ssm_state > 0
+
+    @property
+    def ssm_period(self) -> Tuple[str, ...]:
+        """One period of layer kinds: "ssm" | "attn" | "both"."""
+        return tuple(self.ssm_layout) if self.ssm_layout else ("both",)
+
+    @property
+    def ssm_state_layers(self) -> int:
+        """Layers with a mixer: the rows of a state slot."""
+        period = self.ssm_period
+        return self.num_layers // len(period) \
+            * sum(k != "attn" for k in period)
+
+    @property
+    def ssm_attn_layers(self) -> int:
+        """Layers with attention: the rows of a K/V block."""
+        period = self.ssm_period
+        return self.num_layers // len(period) \
+            * sum(k != "ssm" for k in period)
 
     @property
     def ssm_width(self) -> int:
@@ -868,19 +931,69 @@ def falcon_h1_config(size: str = "34b", **kw) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def granite_moe_hybrid_config(size: str = "h-small",
+                              **kw) -> TransformerConfig:
+    """Granite-4.0-H (ibm-granite/granite-4.0-h-small config.json,
+    `model_type` `granitemoehybrid`): layers of ONE kind each, nine
+    Mamba-2 mixers to one grouped-query attention layer without a position
+    encoding (period `m m m m m a m m m m`), every layer followed by 72
+    routed experts (10 a token, softmax over the picks) beside a shared
+    expert; scalar multipliers on the embedding, every residual branch,
+    the attention scores and the logits; a tied head.  Serving only
+    (inference/v2/ssm_ops.py).  `num_layers` cuts whole periods."""
+    period = ("ssm",) * 5 + ("attn",) + ("ssm",) * 4
+    presets = {
+        # the published ratios at a small size: a period of four kinds with
+        # one attention layer, 64-wide mixer heads (two a 128-lane row) in
+        # one group, a scan chunk shorter than a prompt, 3 of 8 experts a
+        # token
+        "tiny": dict(hidden_size=128, num_layers=4, num_heads=4,
+                     num_kv_heads=2, attn_head_dim=32, max_seq_len=512,
+                     vocab_size=512, ssm_state=16, ssm_heads=4,
+                     ssm_head_dim=64, ssm_groups=1, ssm_chunk=8,
+                     moe_experts=8, moe_top_k=3, moe_expert_ffn=32,
+                     moe_shared_expert_ffn=64,
+                     attention_multiplier=0.03125,   # 1 / head_dim, as 1/128
+                     ssm_layout=("ssm", "ssm", "attn", "ssm")),
+        "h-small": dict(hidden_size=4096, num_layers=40, num_heads=32,
+                        num_kv_heads=8, attn_head_dim=128,
+                        max_seq_len=131072, vocab_size=100352,
+                        ssm_state=128, ssm_heads=128, ssm_head_dim=64,
+                        ssm_groups=1, ssm_chunk=256, moe_experts=72,
+                        moe_top_k=10, moe_expert_ffn=768,
+                        moe_shared_expert_ffn=1536, ssm_layout=period),
+    }
+    base = dict(pos_emb="none", norm="rmsnorm", activation="swiglu",
+                tie_embeddings=True, norm_eps=1e-5, ssm_conv=4,
+                moe_norm_topk_prob=True,
+                embedding_multiplier=12.0, residual_multiplier=0.22,
+                attention_multiplier=0.0078125,
+                lm_head_multiplier=1.0 / 16)       # logits_scaling 16
+    base.update(presets[size])
+    base.update(kw)
+    # (no dense FFN anywhere: the experts' width is the model's FFN width)
+    base.setdefault("intermediate_size", base["moe_expert_ffn"])
+    return TransformerConfig(**base)
+
+
 # ----------------------------------------------------------------------
 # init
 # ----------------------------------------------------------------------
 def _init_ssm_params(key, cfg: TransformerConfig) -> PyTree:
-    """Random weights in the state-space parallel block's layout (the
-    leaves `inference/v2/ssm_ops.py` reads): attention, the mixer, the
-    gated MLP and the two norms per layer.  `dt_bias` and `A_log` as
-    Mamba-2 initialises them: a step log-uniform in [1e-3, 1e-1] through
-    the inverse of softplus, a decay rate uniform in [1, 16]."""
+    """Random weights in the state-space family's layout (the leaves
+    `inference/v2/ssm_ops.py` reads): per layer the two norms and the FFN
+    (the gated MLP, or the router and the shared expert: the routed experts
+    this chip holds lie apart, outside the layer scan); the mixer's leaves
+    stacked over the layers that have one, attention's over those that
+    have it (`cfg.ssm_layout`; every layer has both where it is None).
+    `dt_bias` and `A_log` as Mamba-2 initialises them: a step log-uniform
+    in [1e-3, 1e-1] through the inverse of softplus, a decay rate uniform
+    in [1, 16]."""
     H, L, NH, NKV, D = (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
                         cfg.kv_heads, cfg.head_dim)
     F, Wm, Wc, NHm = (cfg.ffn_dim, cfg.ssm_width, cfg.ssm_conv_width,
                       cfg.ssm_heads)
+    Lm, La = cfg.ssm_state_layers, cfg.ssm_attn_layers
     out_std = 0.02 / math.sqrt(2 * L)
     keys = iter(jax.random.split(key, 20))
 
@@ -888,27 +1001,41 @@ def _init_ssm_params(key, cfg: TransformerConfig) -> PyTree:
         return jax.random.normal(next(keys), shape, jnp.float32) * std
 
     step = jnp.exp(jax.random.uniform(
-        next(keys), (L, NHm), jnp.float32, math.log(1e-3), math.log(1e-1)))
-    return {
+        next(keys), (Lm, NHm), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    params = {
         "tok_embed": rnd((cfg.vocab_size, H)),
         "lm_head": rnd((H, cfg.vocab_size)),
         "final_norm_scale": jnp.ones((H,), jnp.float32),
         "layers": {
             "attn_norm_scale": jnp.ones((L, H), jnp.float32),
             "mlp_norm_scale": jnp.ones((L, H), jnp.float32),
-            "wq": rnd((L, H, NH * D)), "wk": rnd((L, H, NKV * D)),
-            "wv": rnd((L, H, NKV * D)), "wo": rnd((L, NH * D, H), out_std),
-            "ssm_in": rnd((L, H, Wm + Wc + NHm)),
-            "ssm_conv_w": rnd((L, cfg.ssm_conv, Wc), 0.2),
-            "ssm_conv_b": jnp.zeros((L, Wc), jnp.float32),
+            "wq": rnd((La, H, NH * D)), "wk": rnd((La, H, NKV * D)),
+            "wv": rnd((La, H, NKV * D)), "wo": rnd((La, NH * D, H), out_std),
+            "ssm_in": rnd((Lm, H, Wm + Wc + NHm)),
+            "ssm_conv_w": rnd((Lm, cfg.ssm_conv, Wc), 0.2),
+            "ssm_conv_b": jnp.zeros((Lm, Wc), jnp.float32),
             "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
             "ssm_a_log": jnp.log(jax.random.uniform(
-                next(keys), (L, NHm), jnp.float32, 1.0, 16.0)),
-            "ssm_d": jnp.ones((L, NHm), jnp.float32),
-            "ssm_norm_scale": jnp.ones((L, Wm), jnp.float32),
-            "ssm_out": rnd((L, Wm, H), out_std),
-            "w_gate": rnd((L, H, F)), "w_up": rnd((L, H, F)),
-            "w_down": rnd((L, F, H), out_std)}}
+                next(keys), (Lm, NHm), jnp.float32, 1.0, 16.0)),
+            "ssm_d": jnp.ones((Lm, NHm), jnp.float32),
+            "ssm_norm_scale": jnp.ones((Lm, Wm), jnp.float32),
+            "ssm_out": rnd((Lm, Wm, H), out_std)}}
+    if cfg.tie_embeddings:
+        del params["lm_head"]
+    ffn = lambda n, width: {  # noqa: E731
+        "w_gate": rnd((n, H, width)), "w_up": rnd((n, H, width)),
+        "w_down": rnd((n, width, H), out_std)}
+    if cfg.moe_experts == 1:
+        params["layers"].update(ffn(L, F))
+        return params
+    El, Fe = cfg.local_experts, cfg.moe_expert_ffn
+    params["layers"]["moe_gate"] = rnd((L, H, cfg.moe_experts))
+    if cfg.moe_shared_expert_ffn:
+        params["layers"]["shared"] = ffn(L, cfg.moe_shared_expert_ffn)
+    params["experts"] = {"w_gate_proj": rnd((L, El, H, Fe)),
+                         "w_up": rnd((L, El, H, Fe)),
+                         "w_down": rnd((L, El, Fe, H), out_std)}
+    return params
 
 
 def _init_kinds_params(key, cfg: TransformerConfig) -> PyTree:
@@ -2108,9 +2235,12 @@ class Transformer:
                 f"{what} has no state-space mixer: `_layer` has no "
                 f"convolution, no selective scan (and nothing gives "
                 f"`ops/ssm.py`'s chunked scan a backward pass), no gated "
-                f"group norm and no muP multipliers; this configuration is "
-                f"served through inference.v2 (build_engine -> ServeLoop) "
-                f"only")
+                f"norm, no per-layer kinds (a mixer alone, attention "
+                f"alone, both) and no scalar multipliers, and the training "
+                f"MoE (moe/sharded.py) no experts behind a mixer, no "
+                f"expert share and no plain shared expert; this "
+                f"configuration is served through inference.v2 "
+                f"(build_engine -> ServeLoop) only")
 
     def loss_fn(self, params, batch, rng=None, grad_sink=None):
         self.refuse_serving_only("Transformer.loss_fn (training, initialize())")

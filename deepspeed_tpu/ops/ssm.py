@@ -8,7 +8,11 @@ per head (P channels, a state of N values a channel; `A` < 0 a head, `dt`
 > 0 a head and token; B and C shared by the heads of a group).  `D * x`, the
 gate and the norm are the caller's.  The state is kept TRANSPOSED, `[N,
 P]` with the channels on the lanes, so that `y` (a sum over N) is a sum
-over sublanes and comes out as a lane-dense row.
+over sublanes and comes out as a lane-dense row.  Heads narrower than the
+128 lanes lie SIDE BY SIDE in a row (`lane_heads`: two of 64 channels), so
+that the stored state `[heads / pack, N, pack * P]` pads no lane; heads of
+a row are of one group.  Callers hand x, dt and y a head at a time and the
+state packed (`state_shape`, `pack_state`, `unpack_state`).
 
 - `ssd_scan`: a prompt (or a chunk of one) in chunks of `chunk` positions,
   as matmuls (the state-space-dual form).  With `cs` the running sum of
@@ -20,11 +24,13 @@ over sublanes and comes out as a lane-dense row.
   `dt` 0 (padding) decays nothing and adds nothing, so it leaves the state
   as it was.  Operands in the stored type, float32 accumulation and state.
 - `ssm_update`: one token a row, IN PLACE on the arena's state
-  `[L, slots, heads, N, P]` (`input_output_aliases`): a grid step reads the
-  `[hb, N, P]` tile of the row's slot, updates it and writes it back, so
-  the step moves the state once each way and nothing else of that size
-  (gather + update + scatter through XLA is three times the traffic).
-  Rows name their slots by a vector (a decode batch is not in slot order).
+  `[L, slots, heads / pack, N, pack * P]` (`input_output_aliases`): a grid
+  step reads the `[hb, N, pack * P]` tile of the row's slot, updates it and
+  writes it back, so the step moves the state once each way and nothing
+  else of that size (gather + update + scatter through XLA is three times
+  the traffic).  Rows name their slots by a vector (a decode batch is not
+  in slot order).  The update is elementwise over a row's lanes, so packed
+  heads are one wider head to it.
 
 Each has a dense `jax.numpy` form: what the CPU runs and what the tests
 hold the kernels to.
@@ -37,9 +43,11 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["ssd_scan", "ssd_scan_reference", "ssm_update",
-           "ssm_update_reference", "heads_per_step"]
+           "ssm_update_reference", "heads_per_step", "lane_heads",
+           "state_shape", "pack_state", "unpack_state"]
 
-# heads a grid step takes (all of one group: they share B and C)
+LANES = 128
+# rows of heads a grid step takes (all of one group: they share B and C)
 HEADS_PER_STEP = 8
 
 
@@ -48,6 +56,40 @@ def heads_per_step(heads: int, groups: int) -> int:
     while (heads // groups) % hb:
         hb -= 1
     return hb
+
+
+def lane_heads(heads: int, groups: int, head_dim: int) -> int:
+    """Heads that share a 128-lane row of the stored state: as many of
+    `head_dim` channels as fill it, all of one group."""
+    pack = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 \
+        else 1
+    while (heads // groups) % pack:
+        pack //= 2
+    return pack
+
+
+def state_shape(heads: int, groups: int, n: int, head_dim: int):
+    """What a slot stores of one layer: [heads / pack, N, pack * P]."""
+    pack = lane_heads(heads, groups, head_dim)
+    return (heads // pack, n, pack * head_dim)
+
+
+def pack_state(h, pack: int):
+    """[..., NH, N, P] a head -> [..., NH / pack, N, pack * P] as stored."""
+    *lead, NH, N, P = h.shape
+    if pack == 1:
+        return h
+    return jnp.moveaxis(h.reshape(*lead, NH // pack, pack, N, P), -3, -2) \
+        .reshape(*lead, NH // pack, N, pack * P)
+
+
+def unpack_state(h, pack: int):
+    """`pack_state`'s inverse."""
+    *lead, rows, N, W = h.shape
+    if pack == 1:
+        return h
+    return jnp.moveaxis(h.reshape(*lead, rows, N, pack, W // pack), -2, -3) \
+        .reshape(*lead, rows * pack, N, W // pack)
 
 
 def _chunked(x, dt, b, c, chunk: int):
@@ -95,12 +137,16 @@ def ssd_scan_reference(x, dt, a, b, c, h0, chunk: int):
 
 
 def _scan_kernel(slot_ref, meta_ref, x_ref, b_ref, c_ref, s_ref, y_ref,
-                 h_ref, *, hb: int, rows: int):
-    """One (row, head tile, chunk): meta [hb, 2, Q] = (running sum of dt A,
-    dt) as rows; x [hb, Q, P]; b, c [Q, N]; the state rides `h_ref`, the
-    slot's tile (its block stays put along the chunk axis): taken from
+                 h_ref, *, hb: int, rows: int, pack: int):
+    """One (row, tile of `hb` lane rows, chunk): meta [hb * pack, 2, Q] =
+    (running sum of dt A, dt) as rows, a head each; x [hb, Q, pack * P], a
+    lane row's heads side by side; b, c [Q, N]; the state rides `h_ref`,
+    the slot's tile (its block stays put along the chunk axis): taken from
     `s_ref` at the first chunk where the row continues a prompt, zeros
-    where it starts one."""
+    where it starts one.  Heads of one lane row share x's and h's tiles
+    and differ in their decay: each runs the row's matmuls whole (a
+    64-wide operand fills the MXU's columns no better) and keeps its own
+    lanes."""
     from jax.experimental import pallas as pl
     r, j = pl.program_id(0), pl.program_id(2)
 
@@ -115,75 +161,91 @@ def _scan_kernel(slot_ref, meta_ref, x_ref, b_ref, c_ref, s_ref, y_ref,
     b_t = b.astype(jnp.float32).T                           # [N, Q]
     t_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     s_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    P = x_ref.shape[-1] // pack
     for i in range(hb):
-        cs = meta_ref[0, i, 0:1, :]                         # [1, Q]
-        dt = meta_ref[0, i, 1:2, :]
-        x = x_ref[0, i]                                     # [Q, P]
-        cs_s = jnp.broadcast_to(cs, (Q, Q))                 # [t, s] = cs[s]
-        cs_t = cs_s.T                                       # [t, s] = cs[t]
-        decay = jnp.exp(jnp.where(t_i >= s_i, cs_t - cs_s, -jnp.inf))
-        h = h_ref[0, 0, i]                                  # [N, P]
-        y = jnp.dot((gram * decay * dt).astype(x.dtype), x,
-                    preferred_element_type=jnp.float32)
-        y += jnp.exp(cs_t[:, :1]) * jnp.dot(
-            c, h.astype(c.dtype), preferred_element_type=jnp.float32)
+        x = x_ref[0, i]                                     # [Q, pack * P]
+        h = h_ref[0, 0, i]                                  # [N, pack * P]
+        carried = jnp.dot(c, h.astype(c.dtype),
+                          preferred_element_type=jnp.float32)
+        for k in range(pack):
+            cs = meta_ref[0, i * pack + k, 0:1, :]          # [1, Q]
+            dt = meta_ref[0, i * pack + k, 1:2, :]
+            cs_s = jnp.broadcast_to(cs, (Q, Q))             # [t, s] = cs[s]
+            cs_t = cs_s.T                                   # [t, s] = cs[t]
+            decay = jnp.exp(jnp.where(t_i >= s_i, cs_t - cs_s, -jnp.inf))
+            y_k = jnp.dot((gram * decay * dt).astype(x.dtype), x,
+                          preferred_element_type=jnp.float32)
+            y_k += jnp.exp(cs_t[:, :1]) * carried
+            # the sum at the chunk's end: `dt A` <= 0, so it is the least
+            # (a reduction, where a [1, 1] slice at lane Q - 1 would not
+            # lower)
+            last = jnp.min(cs, axis=1, keepdims=True)       # [1, 1]
+            w = dt * jnp.exp(last - cs)                     # [1, Q]
+            h_k = jnp.exp(last) * h + jnp.dot(
+                (b_t * w).astype(x.dtype), x,
+                preferred_element_type=jnp.float32)
+            if k == 0:
+                y, h_new = y_k, h_k
+            else:                       # head k's lanes, and the later ones'
+                mine = lambda n: jax.lax.broadcasted_iota(  # noqa: E731
+                    jnp.int32, (n, pack * P), 1) >= k * P
+                y = jnp.where(mine(Q), y_k, y)
+                h_new = jnp.where(mine(h.shape[0]), h_k, h_new)
         y_ref[0, i] = y
-        # the sum at the chunk's end: `dt A` <= 0, so it is the least (a
-        # reduction, where a [1, 1] slice at lane Q - 1 would not lower)
-        last = jnp.min(cs, axis=1, keepdims=True)           # [1, 1]
-        w = dt * jnp.exp(last - cs)                         # [1, Q]
-        h_ref[0, 0, i] = jnp.exp(last) * h + jnp.dot(
-            (b_t * w).astype(x.dtype), x, preferred_element_type=jnp.float32)
+        h_ref[0, 0, i] = h_new
 
 
 def ssd_scan(x, dt, a, b, c, state, layer, slots, carried, chunk: int,
              interpret: bool = False):
     """The chunked scan as a Pallas kernel over (row, head tile, chunk),
     reading and writing the rows' state IN PLACE on the arena (donate it):
-    `state` [L, slots, NH, N, P] float32; row r starts from `state[layer,
-    slots[r]]` where `carried[r]` and from zeros where not, and leaves its
-    final state there.  Every row's slot must exist: a row whose state is
-    to be dropped names a scratch slot.  x, dt, a, b, c as
-    `ssd_scan_reference`.  Returns (y [R, S, NH, P] float32, state).
+    `state` [L, slots, NH / pack, N, pack * P] float32 (`state_shape`); row
+    r starts from `state[layer, slots[r]]` where `carried[r]` and from
+    zeros where not, and leaves its final state there.  Every row's slot
+    must exist: a row whose state is to be dropped names a scratch slot.
+    x, dt, a, b, c as `ssd_scan_reference`.  Returns (y [R, S, NH, P]
+    float32, state).
     (Through XLA the rows' gather and scatter copy the whole arena once a
     layer as soon as a program holds two rows: 2.4 GB of temporaries.)"""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     R, S, NH, P = x.shape
     G, N = b.shape[2:]
-    hb = heads_per_step(NH, G)
+    pack = state.shape[-1] // P
+    NR, W = NH // pack, pack * P                # lane rows, their width
+    hb = heads_per_step(NR, G)
     xs, dts, bs, cs_ = _chunked(x, dt, b, c, chunk)
     nC = xs.shape[1]
     Sp = nC * chunk
     cs = jnp.cumsum(dts * a, axis=2)                        # [R, nC, Q, NH]
     meta = jnp.stack([cs, dts], axis=-1).reshape(R, Sp, NH, 2) \
         .transpose(0, 2, 3, 1)                              # [R, NH, 2, Sp]
-    xh = xs.reshape(R, Sp, NH, P).transpose(0, 2, 1, 3)     # [R, NH, Sp, P]
+    xh = xs.reshape(R, Sp, NR, W).transpose(0, 2, 1, 3)     # [R, NR, Sp, W]
     bh = bs.reshape(R, Sp, G, N).transpose(0, 2, 1, 3)      # [R, G, Sp, N]
     ch = cs_.reshape(R, Sp, G, N).transpose(0, 2, 1, 3)
     where = jnp.concatenate([jnp.asarray(layer, jnp.int32).reshape(1),
                              jnp.asarray(slots, jnp.int32),
                              jnp.asarray(carried, jnp.int32)])
-    tiles_per_group = NH // G // hb
+    tiles_per_group = NR // G // hb
     head_map = lambda r, t, j, m: (r, t, j, 0)              # noqa: E731
     group_map = lambda r, t, j, m: (r, t // tiles_per_group, j, 0)  # noqa
     state_map = lambda r, t, j, m: (m[0], m[1 + r], t, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(R, NH // hb, nC),
-        in_specs=[pl.BlockSpec((1, hb, 2, chunk),
+        grid=(R, NR // hb, nC),
+        in_specs=[pl.BlockSpec((1, hb * pack, 2, chunk),
                                lambda r, t, j, m: (r, t, 0, j)),
-                  pl.BlockSpec((1, hb, chunk, P), head_map),
+                  pl.BlockSpec((1, hb, chunk, W), head_map),
                   pl.BlockSpec((1, 1, chunk, N), group_map),
                   pl.BlockSpec((1, 1, chunk, N), group_map),
-                  pl.BlockSpec((1, 1, hb, N, P), state_map)],
-        out_specs=[pl.BlockSpec((1, hb, chunk, P), head_map),
-                   pl.BlockSpec((1, 1, hb, N, P), state_map)])
+                  pl.BlockSpec((1, 1, hb, N, W), state_map)],
+        out_specs=[pl.BlockSpec((1, hb, chunk, W), head_map),
+                   pl.BlockSpec((1, 1, hb, N, W), state_map)])
     y, state = pl.pallas_call(
-        functools.partial(_scan_kernel, hb=hb, rows=R),
+        functools.partial(_scan_kernel, hb=hb, rows=R, pack=pack),
         name="ssd_scan",
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, NH, Sp, P), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((R, NR, Sp, W), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # (the scalar-prefetch operand counts: `state` is input 5)
         input_output_aliases={5: 1},
@@ -192,20 +254,30 @@ def ssd_scan(x, dt, a, b, c, state, layer, slots, carried, chunk: int,
         # (an explicit False would override a test's interpret default)
         **({"interpret": True} if interpret else {}),
     )(where, meta, xh, bh, ch, state)
-    return y.transpose(0, 2, 1, 3)[:, :S], state
+    return y.transpose(0, 2, 1, 3).reshape(R, Sp, NH, P)[:, :S], state
+
+
+def _as_rows(state, *per_head):
+    """[B, NH, P] operands a head -> [B, NH / pack, pack * P], a lane row
+    of the stored `state` each (the update is elementwise over the lanes,
+    and a row's heads share B and C)."""
+    rows, width = state.shape[2], state.shape[4]
+    return tuple(t.reshape(t.shape[0], rows, width) for t in per_head)
 
 
 def ssm_update_reference(state, layer, slots, x_dt, decay, b, c):
-    """One token a row, dense.  state [L, slots, NH, N, P] float32; slots
-    [B] (one past the last slot: the row is dropped); x_dt = dt * x and
-    decay = exp(dt A) broadcast over the channels, [B, NH, P] float32; b, c
-    [B, G, N] float32.  Returns (y [B, NH, P] float32, state)."""
-    NH, G = x_dt.shape[1], b.shape[1]
-    rep = lambda t: jnp.repeat(t, NH // G, axis=1)          # noqa: E731
+    """One token a row, dense.  state [L, slots, NH / pack, N, pack * P]
+    float32 (`state_shape`); slots [B] (one past the last slot: the row is
+    dropped); x_dt = dt * x and decay = exp(dt A) broadcast over the
+    channels, [B, NH, P] float32; b, c [B, G, N] float32.  Returns (y [B,
+    NH, P] float32, state)."""
+    shape, G = x_dt.shape, b.shape[1]
+    x_dt, decay = _as_rows(state, x_dt, decay)
+    rep = lambda t: jnp.repeat(t, state.shape[2] // G, axis=1)  # noqa: E731
     h = state[layer, jnp.minimum(slots, state.shape[1] - 1)]
     h = h * decay[:, :, None, :] + rep(b)[..., None] * x_dt[:, :, None, :]
     y = jnp.sum(h * rep(c)[..., None], axis=2)
-    return y, state.at[layer, slots].set(h, mode="drop")
+    return y.reshape(shape), state.at[layer, slots].set(h, mode="drop")
 
 
 def _update_kernel(meta_ref, s_ref, x_ref, d_ref, b_ref, c_ref, y_ref,
@@ -231,7 +303,9 @@ def ssm_update(state, layer, slots, x_dt, decay, b, c,
     slot, whose content is then garbage."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    L, n_slots, NH, N, P = state.shape
+    shape = x_dt.shape
+    x_dt, decay = _as_rows(state, x_dt, decay)
+    L, n_slots, NH, N, P = state.shape          # NH lane rows of P lanes
     B, G = x_dt.shape[0], b.shape[1]
     hb = heads_per_step(NH, G)
     tiles_per_group = NH // G // hb
@@ -263,4 +337,4 @@ def ssm_update(state, layer, slots, x_dt, decay, b, c,
         # (an explicit False would override a test's interpret default)
         **({"interpret": True} if interpret else {}),
     )(meta, state, x_dt, decay, b[:, :, None], c[:, :, None])
-    return y, state
+    return y.reshape(shape), state

@@ -10,7 +10,9 @@ run against the parent's tree.  A PR that changes one of these programs on
 purpose prints them again and says so: PR 43 did for `longcat_flash` and
 `smallthinker` (their tiny configurations hold every expert, so
 `latent_ops._moe` gathers the experts' outputs where it used to scatter-add
-them); `qwen2`'s are PR 34's.
+them); `qwen2`'s are PR 34's; `deepseek_v3`'s were printed at PR 46 from its
+parent (73ee70b), when the state-space family learned layer kinds and
+experts through `latent_ops._moe`.
 """
 import hashlib
 
@@ -32,6 +34,11 @@ EXPECTED = {
               "decode_tokens": "8a4f4792919bdae8",
               "prefill_chunks": "1fe435c08a0e8fe1",
               "prefill_full": "93898cc383c0a6ac"},
+    "deepseek_v3": {"decode_step": "46a77386e9a7828f",
+                    "decode_tokens": "965ab8993892b659",
+                    "prefill_chunks": "c771819f6b89a8dc",
+                    "prefill_chunks_tiled": "caf9a454d4ce164c",
+                    "prefill_full": "86236bf825e860f2"},
     "smallthinker": {"decode_step": "bc5c37f8dcbb06aa",
                      "decode_tokens": "0b37c9ef7ff20e91",
                      "prefill_chunks": "63f68572ffa79331"},
@@ -75,7 +82,8 @@ def program_hashes(family: str) -> dict:
         ).hexdigest()[:16] for name, (fn, args, kw) in calls.items()}
 
 
-@pytest.mark.parametrize("family", ["longcat_flash", "qwen2", "smallthinker"])
+@pytest.mark.parametrize("family", ["longcat_flash", "qwen2", "smallthinker",
+                                    "deepseek_v3"])
 def test_a_family_the_benchmark_runs_lowers_to_the_parents_programs(family):
     assert program_hashes(family) == EXPECTED[family]
 
@@ -83,4 +91,5 @@ def test_a_family_the_benchmark_runs_lowers_to_the_parents_programs(family):
 if __name__ == "__main__":
     import pprint
     pprint.pprint({f: program_hashes(f)
-                   for f in ("longcat_flash", "qwen2", "smallthinker")})
+                   for f in ("longcat_flash", "qwen2", "smallthinker",
+                             "deepseek_v3")})

@@ -627,3 +627,122 @@ def test_serving_programs_of_the_falcon_h1_cell_fit_and_update_in_place(
         assert mem.temp_size_in_bytes < 0.2e9, name
         assert hlo.count("tpu_custom_call") >= 2, name
         assert kernel in hlo, name
+
+
+# (slots, heads, head_dim, groups, N, chunk, layers): the granite cell's
+# mixer (two 64-wide heads a 128-lane row of the stored state, one group)
+# and falcon-h1's (whole rows, two groups)
+SSM_SHAPES = [pytest.param(96, 128, 64, 1, 128, 256, 9, id="granite-4.0-h"),
+              pytest.param(96, 32, 128, 2, 256, 128, 6, id="falcon-h1")]
+
+
+@pytest.mark.parametrize("slots,NH,P,G,N,chunk,L", SSM_SHAPES)
+def test_ssm_update_in_place_at_the_cells_shapes(chip, slots, NH, P, G, N,
+                                                 chunk, L):
+    """The one-token update at 96 rows on the arena's stored layout: one
+    Mosaic kernel, the arena aliased (no copy of 3.7 or 2.4 GB)."""
+    from deepspeed_tpu.ops import ssm
+    F32 = jnp.float32
+    state = chip((L, slots + 1) + ssm.state_shape(NH, G, N, P), F32)
+    assert state.shape[-1] == 128
+    row = chip((slots, NH, P), F32)
+    bc = chip((slots, G, N), F32)
+    fn = jax.jit(lambda s, i, x, d, b, c: ssm.ssm_update(s, 1, i, x, d, b, c),
+                 donate_argnums=0)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(state, chip((slots,), jnp.int32), row, row, bc,
+                            bc).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert mem.alias_size_in_bytes >= state.size * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("slots,NH,P,G,N,chunk,L", SSM_SHAPES)
+@pytest.mark.parametrize("R,S", [(1, 512), (4, 512)])
+def test_ssd_scan_in_place_at_the_cells_shapes(chip, slots, NH, P, G, N,
+                                               chunk, L, R, S):
+    """The chunked scan of one and of four 512-token rows, the state in
+    place on the arena: one Mosaic kernel, no copy of the arena; what XLA
+    runs around it (the cumulative sum, the relayouts) stays under the
+    operands' own size."""
+    from deepspeed_tpu.ops import ssm
+    F32 = jnp.float32
+    state = chip((L, slots + 1) + ssm.state_shape(NH, G, N, P), F32)
+    x, dt = chip((R, S, NH, P)), chip((R, S, NH), F32)
+    bc = chip((R, S, G, N))
+    fn = jax.jit(lambda x, dt, a, b, c, s, i, carried: ssm.ssd_scan(
+        x, dt, a, b, c, s, 1, i, carried, chunk), donate_argnums=5)
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(x, dt, chip((NH,), F32), bc, bc, state,
+                            chip((R,), jnp.int32),
+                            chip((R,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert mem.alias_size_in_bytes >= state.size * 4
+    assert mem.temp_size_in_bytes < 12 * R * S * NH * P
+
+
+# `granite-4.0-h-small.decode_closed_support`'s programs: the weights are
+# 9.93 GB, the arenas 4.15 GB (3.66 of state)
+GRANITE_PEAK_BYTES = {"decode_step": 14.25e9, "prefill_full": 14.19e9,
+                      "prefill_full_2": 14.19e9, "prefill_chunks": 14.19e9,
+                      "prefill_chunks_4": 14.36e9}
+
+
+def test_serving_programs_of_the_granite_cell_fit_and_size_arenas_by_kind(
+        topo, chip, monkeypatch):
+    """The cell's own `decode_step`, `prefill_full[1, 512]`,
+    `prefill_full[2, 256]`, `prefill_chunks[1]` and `prefill_chunks[4]`
+    (`benchmark/configs/granite-4.0-h-small.json`: one period of 9
+    state-space layers and 1 attention layer at the published widths, 36
+    of 72 experts, 96 rows and state slots), compiled for the described
+    chip from abstract weights: the state arena has NINE rows of 3.66 GB
+    together (two heads a lane row: not 7.3) and the K/V arena ONE; each
+    program fits with the arenas donated and next to no temporaries (no
+    run's slice of the stacked weights copied: 1.2 GB of them once), runs
+    the state kernel of its kind, the paged or flash attention and two
+    grouped matmuls a layer.  ~7 s a program."""
+    from deepspeed_tpu.inference.v2 import ragged_ops
+
+    cfg, params, arena, eng, ref, sizes = abstract_cell(
+        chip, monkeypatch, "granite-4.0-h-small", "granite_moe_hybrid")
+    B, MB = eng["max_seqs"], eng["max_blocks_per_seq"]
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert held == ref.weight_bytes(sizes, "bfloat16")
+    assert arena["ssm"].shape == (9, 97, 64, 128, 128)
+    assert arena["ssm"].size * 4 == pytest.approx(3.66e9, rel=2e-3)
+    assert arena["conv"].shape == (9, 97, 3 * 8448)
+    assert arena["k"].shape == arena["v"].shape == (1, 1664, 64, 8, 128)
+    donated = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(arena))
+    i32 = lambda *shape: chip(shape, jnp.int32)  # noqa: E731
+    flags = lambda n: chip((n,), jnp.bool_)  # noqa: E731
+    S = eng["prefill_chunk_size"]
+    calls = {
+        "decode_step": (ragged_ops.decode_step, "ssm_update",
+                        (i32(B), i32(B), i32(B, MB), flags(B)), i32(B)),
+        "prefill_full": (ragged_ops.prefill_full, "ssd_scan",
+                         (i32(1, S), i32(1), i32(1, MB), flags(1)), i32(1)),
+        "prefill_full_2": (ragged_ops.prefill_full, "ssd_scan",
+                           (i32(2, S // 2), i32(2), i32(2, MB), flags(2)),
+                           i32(2)),
+        "prefill_chunks": (ragged_ops.prefill_chunks, "ssd_scan",
+                           (i32(1, S), i32(1), i32(1), i32(1, MB), flags(1)),
+                           i32(1)),
+        "prefill_chunks_4": (ragged_ops.prefill_chunks, "ssd_scan",
+                             (i32(4, S), i32(4), i32(4), i32(4, MB),
+                              flags(4)), i32(4)),
+    }
+    for name, (fn, kernel, args, slots) in calls.items():
+        with jax.default_matmul_precision("default"):
+            compiled = fn.lower(cfg, params, arena, *args,
+                                slots=slots).compile()
+        mem, hlo = compiled.memory_analysis(), compiled.as_text()
+        assert mem.peak_memory_in_bytes < 1.02 * GRANITE_PEAK_BYTES[name], \
+            name
+        assert mem.alias_size_in_bytes >= donated, name          # in place
+        assert mem.temp_size_in_bytes < 0.35e9, name
+        # two runs of state-space layers, the attention layer between: a
+        # state kernel and two grouped matmuls a run, attention's kernel
+        assert hlo.count("tpu_custom_call") >= 7, name
+        assert kernel in hlo and "grouped_matmul" in hlo, name
