@@ -65,6 +65,11 @@ Their float32 outputs come back to their tokens by one gather a pick and a
 sum over the picks in a fixed order (scope `experts/combine`) where the
 compact buffer holds every assignment (`cap == T * k`: a fact of the
 program's shape), and by the pieces' scatter-add where it holds a share.
+Where it holds them all, the kernel runs and an even routing gives an
+expert a row tile's rows or more (a prompt's pass, not a decode step:
+`grouped_matmul.aligns`, shapes again), each expert's rows start on a tile
+edge in a longer buffer, so that no (expert, tile) item multiplies another
+expert's rows, and a pick's row is found behind its expert's padding.
 
 The arena is ONE array `[A, blocks, block_size, W]`: row `[c | rope(kr) |
 unused]` per token and attention (A = `cfg.latent_attentions`: attention
@@ -124,10 +129,14 @@ GROUP_COUNT_NAMES = ("router_tokens", "group_hit_tokens")
 # and, last, where the experts' grouped matmuls are the kernel
 # (`_use_expert_kernel`: the chip), how it engaged, summed over layers and
 # passes: the expert-weight fetches its grid made, in units of one expert's
-# whole weight (`ops.grouped_matmul.weight_fetches`), and the experts a pass
-# reached.  Their ratio is 1 where every reached expert's weights were read
-# once a matmul
-KERNEL_COUNT_NAMES = ("expert_weight_fetches", "experts_reached")
+# whole weight (`ops.grouped_matmul.weight_fetches`), the experts a pass
+# reached, and the live (expert, row tile) items of its list.  Fetches over
+# reached is 1 where every reached expert's weights were read once a matmul;
+# items over reached is the grid steps an expert's weights stay for (1 at a
+# few rows an expert; `ceil(rows / tile)` where the segments lie on tile
+# edges, about one more where they lie end to end)
+KERNEL_COUNT_NAMES = ("expert_weight_fetches", "experts_reached",
+                      "expert_items")
 # serve steps between two drains of it (`ServeLoop`: one small fetch)
 COUNT_DRAIN_STEPS = 16
 
@@ -349,35 +358,50 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
         picked = jnp.repeat(tok_valid, k)
         local = (ids >= first) & (ids < first + El) & picked
         key = jnp.where(local, ids - first, El)
-        order = jnp.argsort(key, stable=True)   # local rows first, by expert
-        sizes = jnp.bincount(key, length=El + 1).astype(jnp.int32)[:El]
-        n_local = jnp.sum(sizes)
         cap = local_rows_cap(T * k, El, E + cfg.moe_zero_experts)
-        ends = jnp.cumsum(sizes)
         # the buffer holds every assignment (all the router's experts are
         # held here, or the program is tiny): one piece, no overflow
         whole = cap == T * k
-        if not whole:
-            order = jnp.pad(order, (0, cap))  # a window never slides back
         kernel = _use_expert_kernel()
         tile = grouped_matmul.row_tile(cap)
+        # each expert's rows from a row-tile edge, in a longer buffer: what
+        # the kernel's pass over a whole prompt's assignments wants, and a
+        # fact of the program's shape
+        aligned = kernel and grouped_matmul.aligns(cap, tile, El, whole)
+        # local rows first, by expert (`aligned`: with padding rows among
+        # them, which name no assignment)
+        order = grouped_matmul.sort_rows(key, El, tile, aligned)
+        sizes = jnp.bincount(key, length=El + 1).astype(jnp.int32)[:El]
+        n_local = jnp.sum(sizes)
+        ends = jnp.cumsum(sizes)
+        if not whole:
+            order = jnp.pad(order, (0, cap))  # a window never slides back
         every = jnp.zeros((experts["w_up"].shape[0],), jnp.int32)
 
         def outputs(sel, part):
-            """The experts' outputs for the `cap` sorted assignments `sel`,
-            `part` of them each expert's: (their tokens [cap], the down
-            projections' products [cap, H] float32, the kernel's two
-            counts where it runs).  Rows past the last group belong to no
-            expert held here and hold anything."""
+            """The experts' outputs for the sorted assignments `sel` (`cap`
+            of them end to end; the aligned buffer's rows where the
+            segments lie on tile edges), `part` of them each expert's:
+            (their tokens, the down projections' products [rows, H]
+            float32, the kernel's three counts where it runs).  Rows of no
+            expert's segment belong to no expert held here and hold
+            anything."""
             # `ragged_dot`'s groups of the whole stack: empty outside this
             # layer
             groups = None if kernel else jax.lax.dynamic_update_slice(
                 every, part, (li * El,))
             tok = sel // k
-            xs = jnp.take(h, tok, axis=0)
+            if aligned:
+                # a padding row reads the last token; so no index leaves `h`,
+                # and the default's fill is a select pass over every row
+                tok = jnp.minimum(tok, T - 1)
+                xs = h.at[tok].get(mode="promise_in_bounds")
+            else:
+                xs = jnp.take(h, tok, axis=0)
             if kernel:
                 # the live (expert of the whole stack, row tile) items
-                items = grouped_matmul.list_items(part, cap, tile, li * El)
+                items = grouped_matmul.list_items(part, cap, tile, li * El,
+                                                  aligned=aligned)
                 act = grouped_matmul.grouped_matmul(
                     xs, (experts["w_gate_proj"], experts["w_up"]), items,
                     tile=tile, gate_act=gate_act, out_dtype=dt)
@@ -385,7 +409,7 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
                     act, (experts["w_down"],), items, tile=tile)
                 return tok, down, jnp.stack([
                     grouped_matmul.weight_fetches(items),
-                    jnp.sum(part > 0).astype(jnp.int32)])
+                    jnp.sum(part > 0).astype(jnp.int32), items.count[0]])
             g = jax.lax.ragged_dot(xs, experts["w_gate_proj"], groups,
                                    preferred_element_type=jnp.float32)
             u = jax.lax.ragged_dot(xs, experts["w_up"], groups,
@@ -401,8 +425,12 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
             _, down, engaged = outputs(order, sizes)
             with jax.named_scope("combine"):
                 # the row of the sorted buffer that holds pick j of token
-                # t: `order`'s inverse, a permutation (never out of bounds)
-                pos = jnp.argsort(order).reshape(T, k)
+                # t: `order`'s inverse, a permutation (never out of bounds;
+                # the padding rows' places come last and are left out)
+                pos = jnp.argsort(order)
+                if aligned:
+                    pos = pos[:T * k]
+                pos = pos.reshape(T, k)
                 mine = local.reshape(T, k)
                 routed = jnp.zeros((T, H), jnp.float32)
                 for j in range(k):
@@ -415,7 +443,7 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
             def piece(i, carry):
                 """Rows [i * cap, (i + 1) * cap) of the sorted
                 assignments."""
-                acc, engaged = carry  # the kernel's two counts, where it runs
+                acc, engaged = carry   # the kernel's counts, where it runs
                 lo = i * cap
                 sel = jax.lax.dynamic_slice(order, (lo,), (cap,))
                 part = (jnp.clip(ends, lo, lo + cap)
@@ -432,7 +460,8 @@ def _moe(cfg: TransformerConfig, lp, experts, li, h, tok_valid,
             routed, engaged = jax.lax.fori_loop(
                 0, (n_local + cap - 1) // cap, piece,
                 (jnp.zeros((T, H), jnp.float32),
-                 jnp.zeros((2,), jnp.int32) if kernel else ()))
+                 jnp.zeros((len(KERNEL_COUNT_NAMES),), jnp.int32)
+                 if kernel else ()))
     picked = picked.reshape(T, k)
     counts = [jnp.sum(picked), jnp.sum(picked & is_zero), n_local,
               jnp.max(sizes), jnp.ones((), jnp.int32)]

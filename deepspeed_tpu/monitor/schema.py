@@ -50,6 +50,7 @@ SERVING_TAGS = frozenset(
         "moe_busiest_rows", "moe_router_calls",
         "moe_router_tokens", "moe_group_hit_tokens",
         "moe_expert_weight_fetches", "moe_experts_reached",
+        "moe_expert_items",
         # two-kind cache (a window + global stack): block x layer units
         # held and what one kind would hold, window-kind blocks handed
         # back, admissions refused by the kind that was short

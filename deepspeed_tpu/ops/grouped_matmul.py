@@ -12,6 +12,14 @@ row tile) ITEMS in expert-major order (`list_items`), the list rides the
 grid as scalar-prefetch operands, and the index maps of the rows, the
 weights and the output read it.
 
+An expert's segment lies where the wrapper's LAYOUT puts it: end to end,
+the experts' rows first and dead rows behind them, or each from a row-tile
+edge in a longer buffer (`aligns` says when, `sort_rows` builds either).
+End to end a tile is shared by the experts that meet in it, each an item
+that multiplies the whole tile and keeps its own rows; aligned a tile is
+one expert's, and an expert of s rows is `ceil(s / tile)` items where end
+to end it is about one more.
+
 The grid is (column block of the output, item).  Within a column block
 the items run in order, so
 
@@ -46,8 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["Items", "row_tile", "item_slots", "list_items", "weight_fetches",
-           "grouped_matmul"]
+__all__ = ["Items", "row_tile", "aligns", "item_slots", "sort_rows",
+           "list_items", "weight_fetches", "grouped_matmul"]
 
 # rows of a grid step's matmul
 ROW_TILE = 128
@@ -79,20 +87,81 @@ def row_tile(rows: int) -> int:
                 ROW_TILE)
 
 
+def aligns(rows: int, tile: int, groups: int, whole: bool) -> bool:
+    """Whether a pass over `rows` sorted assignments to `groups` experts
+    lays each expert's segment on a row-tile edge (`sort_rows`) instead of
+    end to end: where the buffer holds every assignment (`whole`: one
+    piece, gathered once) and an even routing gives each expert a tile's
+    rows or more, so the padding cannot double the buffer.  Where an expert
+    has a few rows (a decode step) either layout is an item an expert and
+    alignment would only multiply the rows gathered.
+
+    What it buys was measured, not derived (`PERF.md` §6, PR 47).  The
+    kernels alone read 2-3% faster: a segment end to end touches a tile
+    more than its rows need, but a tile of one's own moves a whole tile's
+    bytes for its live rows.  The pass around them gains more (an expert
+    layer of a 512-row prompt 12%, of a 4096-row pass 5-12%): the longer
+    buffer's row gather is made of indices the code makes
+    (`promise_in_bounds`: no fill pass), and XLA no longer copies the
+    kernel's float32 output between memory spaces ahead of the combine's
+    gathers, which it does to the 84 MB an end-to-end 512-row pass
+    writes."""
+    return whole and rows >= groups * tile
+
+
+def aligned_rows(rows: int, tile: int, groups: int) -> int:
+    """Rows of the buffer that holds `rows` rows, those of `groups` segments
+    each from a tile edge and the others behind them: a segment's padding
+    is under a tile, so `item_slots` tiles hold them whatever the sizes."""
+    return tile * item_slots(rows, tile, groups)
+
+
 def item_slots(rows: int, tile: int, groups: int) -> int:
     """The static length of a pass's item list: every row tile once, and
     once more for each expert that begins inside one."""
     return -(-rows // tile) + groups
 
 
-def list_items(sizes, rows: int, tile: int, first_group=0) -> Items:
+def sort_rows(key, groups: int, tile: int, aligned: bool = False):
+    """`key` [n] int32, the segment of each row (`groups` for a row of
+    none) -> `order` [buffer rows] int32, the row that lies at each place
+    of the buffer `list_items` describes: the segments in order, a
+    segment's rows in theirs, the rows of none behind them.  End to end
+    the buffer is the n rows and `order` a permutation.  `aligned` it is
+    `aligned_rows(n, tile, groups)` long and `order` a permutation of its
+    places: an index of n or more is a padding row (as many behind each
+    segment as fill its last tile, the rest last), which a gather clamps
+    into the rows and nobody reads back.  Either way
+    `jnp.argsort(order)[:n]` is each row's place.
+
+    The padding is SORTED in, as keys behind the rows' own under a stable
+    sort: no row is looked up one by one, which is what the chip does
+    worst (placing them by index gathers cost what the layout won)."""
+    if aligned:
+        n = key.shape[0]
+        sizes = jnp.bincount(key, length=groups + 1).astype(jnp.int32)[:groups]
+        filled = jnp.cumsum(-sizes % tile)
+        row = jnp.arange(aligned_rows(n, tile, groups) - n, dtype=jnp.int32)
+        # the segments filled before this padding row: a compare, no lookup
+        e = jnp.sum(row[:, None] >= filled[None], axis=1).astype(jnp.int32)
+        key = jnp.concatenate([key, jnp.where(e < groups, e, groups + 1)])
+    return jnp.argsort(key, stable=True)
+
+
+def list_items(sizes, rows: int, tile: int, first_group=0,
+               aligned: bool = False) -> Items:
     """`sizes` [E] int32, the rows of each of one layer's experts in buffer
     order (sum <= `rows`) -> the pass's items; `first_group`: the layer's
-    first expert among the whole stack."""
+    first expert among the whole stack.  `aligned`: the segments lie in a
+    buffer of `aligned_rows(rows, tile, E)` rows, each from a tile edge
+    (`sort_rows` builds it): a tile has one expert's rows, `lo` is 0 and
+    the live items are `sum(ceil(size / tile))`."""
     sizes = sizes.astype(jnp.int32)
     E = sizes.shape[0]
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
+    # a segment's room: its rows, or (`aligned`) whole tiles
+    room = -(-sizes // tile) * tile if aligned else sizes
+    starts = jnp.cumsum(room) - room
+    ends = starts + sizes
     first_tile = starts // tile
     spans = jnp.where(sizes > 0, (ends - 1) // tile - first_tile + 1, 0)
     item_ends = jnp.cumsum(spans)

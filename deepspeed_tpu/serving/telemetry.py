@@ -91,12 +91,17 @@ class ServingTelemetry:
             # with expert groups also the valid tokens it scored (summed
             # over layers) and those whose kept groups include a group
             # this chip holds experts of; where the grouped matmuls are
-            # the kernel, the expert-weight fetches its grid made and the
-            # experts its passes reached
+            # the kernel, the expert-weight fetches its grid made, the
+            # experts its passes reached and its lists' live (expert, row
+            # tile) items: items over reached is the grid steps a reached
+            # expert's weights stay for (1 at a few rows an expert; about
+            # one more a segment where segments lie end to end than where
+            # each starts on a tile edge)
             "moe_picks": 0, "moe_zero_picks": 0, "moe_local_rows": 0,
             "moe_busiest_rows": 0, "moe_router_calls": 0,
             "moe_router_tokens": 0, "moe_group_hit_tokens": 0,
             "moe_expert_weight_fetches": 0, "moe_experts_reached": 0,
+            "moe_expert_items": 0,
             # two-kind cache (a window + global stack): summed over decode
             # steps, in block x layer units, what the step's rows held of
             # both kinds, what one kind over all layers would have held

@@ -30,8 +30,41 @@ CASES = {
     "the_buffer_one_tile": (96, 96, [1, 2, 3, 4, 5, 6], 6),
     "small_tiles": (256, 64, [3, 130, 5, 60, 1, 1], 0),
 }
+# the same, each expert's segment from a row-tile edge in the longer buffer
+# (`aligned_rows`)
+ALIGNED = {
+    "aligned_empty_experts": (256, 128, [0, 50, 0, 100, 30, 0], 0),
+    "aligned_one_expert_takes_every_row": (256, 128, [0, 0, 256, 0, 0, 0], 0),
+    "aligned_more_than_a_tiles_rows": (384, 128, [10, 200, 0, 130, 1, 43], 6),
+    "aligned_zero_live_rows": (256, 128, [0, 0, 0, 0, 0, 0], 0),
+    "aligned_small_tiles": (256, 64, [3, 130, 5, 60, 1, 1], 0),
+    # the worst routing: every expert a row past whole tiles fills the
+    # buffer's `rows // tile + experts` tiles to the last
+    "aligned_every_expert_a_row_past_a_tile": (390, 64, [65] * 6, 0),
+    "aligned_every_expert_a_row_past_two": (774, 64, [129] * 6, 6),
+}
 MODES = {"gate_up_silu": jax.nn.silu, "gate_up_relu": jax.nn.relu,
          "down": None}
+
+
+def keys_of(sizes, rows):
+    """The end-to-end buffer's keys: a segment's number; E for a row of
+    none."""
+    sizes = np.asarray(sizes)
+    keys = np.full(rows, len(sizes))
+    keys[:sizes.sum()] = np.repeat(np.arange(len(sizes)), sizes)
+    return jnp.asarray(keys, jnp.int32)
+
+
+def case_of(name):
+    """(rows, tile, sizes, first, aligned, where each live row of the
+    end-to-end buffer lies in the buffer the kernel runs over)."""
+    aligned = name in ALIGNED
+    rows, tile, sizes, first = (ALIGNED if aligned else CASES)[name]
+    # `sort_rows`' inverse: the place of each row of the keys' order
+    at = np.argsort(np.asarray(gm.sort_rows(
+        keys_of(sizes, rows), len(sizes), tile, aligned)))[:sum(sizes)]
+    return rows, tile, sizes, first, aligned, at
 
 
 @pytest.fixture
@@ -59,23 +92,30 @@ def ragged(x, weights, sizes, first, gate_act):
 
 
 @pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", [*CASES, *ALIGNED])
 def test_the_kernel_is_ragged_dot_on_the_live_rows(interpret, case, mode):
-    rows, tile, sizes, first = CASES[case]
+    rows, tile, sizes, first, aligned, at = case_of(case)
     gate_act = MODES[mode]
     x, wg, wu = operands(rows)
     weights = (wg,) if gate_act is None else (wg, wu)
-    items = gm.list_items(jnp.asarray(sizes, jnp.int32), rows, tile, first)
-    got = gm.grouped_matmul(x, weights, items, tile=tile, gate_act=gate_act,
+    size = jnp.asarray(sizes, jnp.int32)
+    items = gm.list_items(size, rows, tile, first, aligned=aligned)
+    # the aligned buffer: the same rows behind its segments, others between
+    xk = x[np.minimum(np.asarray(gm.sort_rows(
+        keys_of(sizes, rows), len(sizes), tile, True)), rows - 1)] \
+        if aligned else x
+    got = gm.grouped_matmul(xk, weights, items, tile=tile, gate_act=gate_act,
                             cols=128)
-    assert got.shape == (rows, N) and got.dtype == jnp.float32
+    assert got.shape == (gm.aligned_rows(rows, tile, len(sizes))
+                         if aligned else rows, N)
+    assert got.dtype == jnp.float32
     n = sum(sizes)
-    want = ragged(x, weights, sizes, first, gate_act)
-    assert np.abs(np.asarray(got - want))[:n].max(initial=0.0) < 1e-3
+    want = np.asarray(ragged(x, weights, sizes, first, gate_act))[:n]
+    assert np.abs(np.asarray(got)[at] - want).max(initial=0.0) < 1e-3
     # and with the output in one column block
-    whole = gm.grouped_matmul(x, weights, items, tile=tile,
+    whole = gm.grouped_matmul(xk, weights, items, tile=tile,
                               gate_act=gate_act)
-    assert np.array_equal(np.asarray(whole)[:n], np.asarray(got)[:n])
+    assert np.array_equal(np.asarray(whole)[at], np.asarray(got)[at])
 
 
 def test_the_fused_pass_casts_once_to_the_models_dtype(interpret):
@@ -105,25 +145,46 @@ def test_one_weight_with_a_gate_or_two_without_is_refused():
         gm.grouped_matmul(x, (wg, wu), items, tile=128)
 
 
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", [*CASES, *ALIGNED])
 def test_the_items_cover_every_live_row_once_in_expert_order(case):
-    rows, tile, sizes, first = CASES[case]
-    items = gm.list_items(jnp.asarray(sizes, jnp.int32), rows, tile, first)
+    rows, tile, sizes, first, aligned, at = case_of(case)
+    size = jnp.asarray(sizes, jnp.int32)
+    items = gm.list_items(size, rows, tile, first, aligned=aligned)
     expert, t, lo, hi, count = (np.asarray(a) for a in items)
     slots = gm.item_slots(rows, tile, len(sizes))
     assert expert.shape == t.shape == lo.shape == hi.shape == (slots,)
     n = int(count[0])
     assert n <= slots
     # every live row is written once, by the expert that owns it
-    owner = np.full(rows, -1)
+    buffer = gm.aligned_rows(rows, tile, len(sizes)) if aligned else rows
+    owner = np.full(buffer, -1)
     for e, ti, a, b in zip(expert[:n], t[:n], lo[:n], hi[:n]):
         assert 0 <= a < b <= tile
         span = slice(ti * tile + a, ti * tile + b)
-        assert (owner[span] == -1).all()
+        assert span.stop <= buffer and (owner[span] == -1).all()
         owner[span] = e
     want = np.repeat(first + np.arange(len(sizes)), sizes)
-    assert np.array_equal(owner[:len(want)], want)
-    assert (owner[len(want):] == -1).all()
+    assert np.array_equal(owner[at], want)
+    assert (owner == -1).sum() == buffer - len(want)
+    if aligned:
+        # a tile is never shared: an item an expert's tile, from its first
+        # row; an empty expert costs none, one of more than a tile's rows
+        # spans whole tiles
+        assert (lo[:n] == 0).all() and len(set(t[:n])) == n
+        assert n == sum(-(-s // tile) for s in sizes) <= buffer // tile
+        live = expert[:n]
+        assert (hi[:n][:-1][live[1:] == live[:-1]] == tile).all()
+        # `sort_rows` builds that buffer: a segment's rows in order from a
+        # tile edge, then its padding (indices past the rows'), the rows of
+        # no segment behind them all
+        source = np.asarray(gm.sort_rows(keys_of(sizes, rows), len(sizes),
+                                         tile, True))
+        assert sorted(source) == list(range(buffer))
+        assert np.array_equal(source[at], np.arange(len(want)))
+        assert (source[owner == -1] >= len(want)).all()
+        first_rows = (np.cumsum(sizes) - sizes)[np.asarray(sizes) > 0]
+        assert (at[first_rows] % tile == 0).all()
+        assert (np.diff(at)[np.diff(want) == 0] == 1).all()
     # expert-major, an expert's tiles ascending: no tile is left and met again
     assert (np.diff(expert[:n]) >= 0).all() and (np.diff(t[:n]) >= 0).all()
     # dead entries repeat the last live one: no index moves, nothing is copied
@@ -140,6 +201,29 @@ def test_the_items_cover_every_live_row_once_in_expert_order(case):
 def test_the_row_tile_follows_the_buffer(rows, tile):
     assert gm.row_tile(rows) == tile
     assert gm.item_slots(rows, tile, 16) == -(-rows // tile) + 16
+
+
+@pytest.mark.parametrize("name,rows,groups,whole,tile,aligned,buffer", [
+    # granite's prefill program: 512 slots x 10 picks, 36 experts held
+    ("granite_prefill", 5120, 36, True, 128, True, 9728),
+    # smallthinker's 4096-row pass x 6 picks over 64 experts
+    ("smallthinker_pass", 24576, 64, True, 128, True, 32768),
+    # their decode programs: a few rows an expert, an item either way
+    ("granite_decode", 960, 36, True, 96, False, 960),
+    ("smallthinker_decode", 192, 64, True, 96, False, 192),
+    # a share's buffer (deepseek's prefill: 2,048 of 8,192 picks) keeps its
+    # pieces end to end, a tile's rows an expert or not
+    ("deepseek_share", 2048, 16, False, 128, False, 2048),
+    ("longcat_share", 1024, 16, False, 128, False, 1024),
+])
+def test_the_segments_are_aligned_where_the_shape_says(
+        name, rows, groups, whole, tile, aligned, buffer):
+    assert gm.row_tile(rows) == tile
+    assert gm.aligns(rows, tile, groups, whole) is aligned
+    if aligned:
+        # no more tiles than the item list has slots: the grid is as long
+        assert gm.aligned_rows(rows, tile, groups) == buffer \
+            == tile * gm.item_slots(rows, tile, groups) <= 2 * rows + tile
 
 
 @pytest.mark.parametrize("K_,N_,weights,cols", [
@@ -175,7 +259,7 @@ def moe_through_the_kernel(monkeypatch, cfg, lp, experts, li, h, valid, tol,
                            router_in=None):
     """`_moe` as the CPU runs it (three `ragged_dot` calls) and with the
     platform's gate flipped (the kernel, interpreted) on the same inputs:
-    the same output and router counts, and the kernel's two counts behind
+    the same output and router counts, and the kernel's three counts behind
     them.  Returns (the counts by name, the passes the step took)."""
     import jax.experimental.pallas as pl
 
@@ -217,6 +301,14 @@ def moe_through_the_kernel(monkeypatch, cfg, lp, experts, li, h, valid, tol,
     assert c["local_rows"] == sizes.sum()
     # this grid reads every reached expert's weights once a matmul
     assert c["experts_reached"] == reached == c["expert_weight_fetches"]
+    # and its live items are the list's, in the layout the shape asks for
+    tile = gm.row_tile(cap)
+    aligned = gm.aligns(cap, tile, cfg.local_experts,
+                        cap == h.shape[0] * cfg.moe_top_k)
+    assert c["expert_items"] == sum(int(gm.list_items(
+        jnp.asarray(np.clip(ends, lo, lo + cap)
+                    - np.clip(ends - sizes, lo, lo + cap)), cap, tile,
+        aligned=aligned).count[0]) for lo in passes) >= reached
     return c, len(passes)
 
 
@@ -278,7 +370,7 @@ def test_the_passes_are_the_kernels_two_counters(name):
     assert spec["reader"] == "span_attr_ratio"
     assert spec["params"]["span"] == "serve.moe_census"
     assert (spec["params"]["num"], spec["params"]["den"]) \
-        == latent_ops.KERNEL_COUNT_NAMES
+        == latent_ops.KERNEL_COUNT_NAMES[:2]
     entry = {m["name"]: m for m in json.load(open(os.path.join(
         harness.ROOT, "BENCHMARK.json")))["per_layer"]}[name]
     assert entry["unit"] == "passes" and entry["better"] == "lower"

@@ -340,13 +340,14 @@ def test_an_engine_on_the_chips_path_serves_the_reference_and_counts(
     """An engine built where the platform says "tpu" (both kernels, the
     latent attention's and the experts', interpreted): chunked prefill and
     decode give the reference's logits, the counters' rider carries the
-    kernel's two entries through `drain_moe_counts`, and their ratio is 1
-    (this grid reads a reached expert's weights once a matmul)."""
+    kernel's three entries through `drain_moe_counts`, fetches over reached
+    is 1 (this grid reads a reached expert's weights once a matmul) and an
+    expert of a few rows is one item."""
     import deepspeed_tpu.utils.device as device_mod
     monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
     # (its own cfg: a jitted program is cached by its static cfg)
     eng = engine(engine_kw=dict(full_prompt_prefill=False), max_seq_len=488)
-    assert eng.arena["moe_counts"].shape == (7,)
+    assert eng.arena["moe_counts"].shape == (8,)
     n = 40
     got, toks = serve(eng, prompt(n, seed=6), steps=2)
     assert np.abs(got - ref_logits(toks)[n - 1:]).max() < TOL
@@ -356,7 +357,8 @@ def test_an_engine_on_the_chips_path_serves_the_reference_and_counts(
     assert counts["picks"] == (n + 2) * 2 * 4 and counts["local_rows"] > 0
     # at most every held expert of both layers, each program
     assert 0 < counts["experts_reached"] <= counts["router_calls"] * 8
-    assert counts["expert_weight_fetches"] == counts["experts_reached"]
+    assert counts["expert_weight_fetches"] == counts["experts_reached"] \
+        <= counts["expert_items"]
     assert not any(eng.drain_moe_counts().values())
 
 

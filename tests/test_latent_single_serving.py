@@ -301,7 +301,7 @@ def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
                for n, w in lp["experts"].items()}
     counts, passes = moe_through_the_kernel(
         monkeypatch, cfg, lp, experts, 1, h, jnp.arange(24) < 20, TOL)
-    assert passes == 1 and len(counts) == 9
+    assert passes == 1 and len(counts) == 10
     if drawn:
         assert counts["local_rows"] == 20 * 4
         assert counts["experts_reached"] == 4

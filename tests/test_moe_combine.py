@@ -173,6 +173,57 @@ def test_the_gathered_path_through_the_kernel(monkeypatch):
     assert counts["local_rows"] == REAL * cfg.moe_top_k
 
 
+@pytest.fixture
+def unwritten_rows(monkeypatch):
+    """... and out of the kernel too: the rows no live item covers (the
+    padding between aligned segments, the rows past the last) come out NaN,
+    where the interpreter leaves them as it made them."""
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    real = gm.grouped_matmul
+
+    def planted(x, weights, items, *, tile, **kw):
+        out = real(x, weights, items, tile=tile, **kw)
+        live = jnp.arange(items.expert.shape[0]) < items.count[0]
+        row = jnp.arange(x.shape[0])[None]
+        first = (items.tile * tile)[:, None]
+        covered = jnp.any(live[:, None] & (row >= first + items.lo[:, None])
+                          & (row < first + items.hi[:, None]), axis=0)
+        return jnp.where(covered[:, None], out, jnp.nan)
+    monkeypatch.setattr(gm, "grouped_matmul", planted)
+
+
+@pytest.mark.kernels
+def test_the_gathered_path_through_the_kernel_on_aligned_segments(
+        monkeypatch, unwritten_rows):
+    """A row tile of 4 makes the tiny pass what a prompt's pass is at 128
+    (a tile's rows an expert or more): each expert's rows from a tile edge
+    in the longer buffer, the picks found again behind the padding."""
+    from test_grouped_matmul import moe_through_the_kernel
+
+    from deepspeed_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "ROW_TILE", 4)
+    cfg, lp, experts, h, x, valid = layer("smallthinker", {})
+    k, El = cfg.moe_top_k, cfg.local_experts
+    assert gm.row_tile(T * k) == 4 and gm.aligns(T * k, 4, El, True)
+    counts, passes = moe_through_the_kernel(
+        monkeypatch, cfg, lp, experts, 1, h, valid, TOL, router_in=x)
+    assert passes == 1 and counts["local_rows"] == REAL * k
+    topi, _, _ = latent_ops._route(latent_ops.router_of(cfg),
+                                   x @ lp["moe_gate"], None, k)
+    sizes = np.bincount(np.asarray(topi)[:REAL].reshape(-1), minlength=El)
+    assert sizes.max() > 4          # an expert of more than a tile's rows
+    assert counts["expert_items"] == sum(-(-s // 4) for s in sizes) \
+        == int(gm.list_items(jnp.asarray(sizes), T * k, 4,
+                             aligned=True).count[0])
+    # and against every expert applied to every token, the padding poisoned
+    got, _ = latent_ops._moe(cfg, lp, experts, 1, h, valid, router_in=x)
+    routed, identity = dense(cfg, lp, experts, 1, h, valid, x)
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    assert np.abs(got - (routed + identity)).max() < TOL
+    assert np.array_equal(got[REAL:], identity[REAL:])
+
+
 @pytest.mark.parametrize("drawn", [0.0, 8.0], ids=["as_routed", "overflow"])
 def test_a_share_keeps_its_pieces_and_their_scatter_add(drawn,
                                                         undefined_rows):

@@ -275,43 +275,57 @@ def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
     assert mem.temp_size_in_bytes < 2 ** 20
 
 
-# (rows of the compact buffer, hidden, expert width, local experts, expert
-# layers, ReLU gate): the experts' share of the three expert cells, decode
-# (`local_rows_cap` of 64 x 8, 96 x 12, 32 x 6 picks) and prefill (1024 slots;
-# a 4096-row pass of smallthinker's chunk program), and a buffer whose last
-# row tile is partial
-@pytest.mark.parametrize("rows,H,F,El,L,relu", [
-    pytest.param(128, 7168, 2048, 16, 4, False, id="deepseek-decode"),
-    pytest.param(2048, 7168, 2048, 16, 4, False, id="deepseek-prefill"),
-    pytest.param(96, 6144, 2048, 16, 3, False, id="longcat-decode"),
-    pytest.param(1024, 6144, 2048, 16, 3, False, id="longcat-prefill"),
-    pytest.param(192, 2560, 768, 64, 8, True, id="smallthinker-decode"),
-    pytest.param(24576, 2560, 768, 64, 8, True, id="smallthinker-chunk"),
-    pytest.param(176, 2560, 768, 64, 8, True, id="partial-last-tile"),
+# (rows of the compact buffer, whether they are every assignment, hidden,
+# expert width, local experts, expert layers, ReLU gate): the experts' share
+# of the four expert cells, decode (`local_rows_cap` of 64 x 8, 96 x 12,
+# 32 x 6, 96 x 10 picks) and prefill (1024 slots; a 4096-row pass of
+# smallthinker's chunk program; granite's 512 slots), and a buffer whose last
+# row tile is partial.  The two prompt passes that hold every assignment lay
+# each expert's rows on a tile edge (`aligns`): the buffer the kernels see is
+# `aligned_rows` long
+@pytest.mark.parametrize("rows,whole,H,F,El,L,relu", [
+    pytest.param(128, False, 7168, 2048, 16, 4, False, id="deepseek-decode"),
+    pytest.param(2048, False, 7168, 2048, 16, 4, False,
+                 id="deepseek-prefill"),
+    pytest.param(96, False, 6144, 2048, 16, 3, False, id="longcat-decode"),
+    pytest.param(1024, False, 6144, 2048, 16, 3, False,
+                 id="longcat-prefill"),
+    pytest.param(192, True, 2560, 768, 64, 8, True,
+                 id="smallthinker-decode"),
+    pytest.param(24576, True, 2560, 768, 64, 8, True,
+                 id="smallthinker-chunk"),
+    pytest.param(960, True, 4096, 768, 36, 10, False, id="granite-decode"),
+    pytest.param(5120, True, 4096, 768, 36, 10, False,
+                 id="granite-prefill"),
+    pytest.param(176, True, 2560, 768, 64, 8, True, id="partial-last-tile"),
 ])
-def test_grouped_matmul(chip, rows, H, F, El, L, relu):
+def test_grouped_matmul(chip, rows, whole, H, F, El, L, relu):
     """The experts' two passes of `latent_ops._moe` (gate and up fused,
-    then down) over the whole weight stack at the cells' widths: two Mosaic
-    kernels within the VMEM they ask for, and no copy of the stack (a
-    per-layer slice handed to a custom call would be one)."""
+    then down) over the whole weight stack at the cells' widths, in the
+    layout the shape asks for: two Mosaic kernels within the VMEM they ask
+    for, and no copy of the stack (a per-layer slice handed to a custom
+    call would be one)."""
     from deepspeed_tpu.ops import grouped_matmul as gm
     tile = gm.row_tile(rows)
+    aligned = gm.aligns(rows, tile, El, whole)
+    assert aligned == (rows in (24576, 5120))
+    buffer = gm.aligned_rows(rows, tile, El) if aligned else rows
     gate = jax.nn.relu if relu else jax.nn.silu
 
     def experts(x, wg, wu, wd, sizes, li):
-        items = gm.list_items(sizes, rows, tile, li * El)
+        items = gm.list_items(sizes, rows, tile, li * El, aligned=aligned)
         act = gm.grouped_matmul(x, (wg, wu), items, tile=tile,
                                 gate_act=gate, out_dtype=x.dtype)
         return gm.grouped_matmul(act, (wd,), items, tile=tile)
 
-    args = (chip((rows, H)), chip((L * El, H, F)), chip((L * El, H, F)),
+    args = (chip((buffer, H)), chip((L * El, H, F)), chip((L * El, H, F)),
             chip((L * El, F, H)), chip((El,), jnp.int32),
             chip((), jnp.int32))
     assert kernels(experts, *args) == 2
     with jax.default_matmul_precision("default"):
         mem = jax.jit(experts).lower(*args).compile().memory_analysis()
     # the activations between the two passes at most
-    assert mem.temp_size_in_bytes <= rows * F * 2 + 2 ** 20
+    assert mem.temp_size_in_bytes <= buffer * F * 2 + 2 ** 20
 
 
 @pytest.mark.parametrize("M,K,N", [(256, 2048, 5632), (8, 2048, 2048)])
@@ -567,6 +581,10 @@ def test_the_chunk_program_of_the_smallthinker_cell_gathers_its_experts(
     assert len(gathers) >= cfg.moe_top_k
     assert not re.search(rows + r"[^\n]*experts/[^\n]*scatter-add", hlo)
     assert "experts/while/body" not in hlo
+    # a pass's 24,576 assignments lie with each expert's rows from a tile
+    # edge: 128 x (192 + 64) rows under the kernels, never the compact 24,576
+    assert re.search(r"grouped_matmul[.0-9]* = f32\[32768,2560\]", hlo)
+    assert not re.search(r"grouped_matmul[.0-9]* = f32\[24576,", hlo)
 
 
 # `falcon-h1-34b.decode_closed_short`'s programs: what the v5e compiler
@@ -703,6 +721,8 @@ def test_serving_programs_of_the_granite_cell_fit_and_size_arenas_by_kind(
     run's slice of the stacked weights copied: 1.2 GB of them once), runs
     the state kernel of its kind, the paged or flash attention and two
     grouped matmuls a layer.  ~7 s a program."""
+    import re
+
     from deepspeed_tpu.inference.v2 import ragged_ops
 
     cfg, params, arena, eng, ref, sizes = abstract_cell(
@@ -746,3 +766,9 @@ def test_serving_programs_of_the_granite_cell_fit_and_size_arenas_by_kind(
         # state kernel and two grouped matmuls a run, attention's kernel
         assert hlo.count("tpu_custom_call") >= 7, name
         assert kernel in hlo and "grouped_matmul" in hlo, name
+        # a 512-row pass of a prompt holds each expert's rows from a tile
+        # edge, 128 x (5120 / 128 + 36) rows; a decode step's 960 lie end to
+        # end
+        rows = "960" if name == "decode_step" else "9728"
+        assert set(re.findall(r"grouped_matmul[.0-9]* = f32\[(\d+),4096\]",
+                              hlo)) == {rows}, name
