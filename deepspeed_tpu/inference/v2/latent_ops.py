@@ -97,6 +97,7 @@ experts' part for every token, and leaves the absent experts' part out
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Tuple
 
@@ -263,24 +264,43 @@ def _attend_fresh(cfg, q, c, kr, w_kvb):
     return out[..., :dv]
 
 
+def _live_tiles(cfg, arena_c, block_tables, positions, valid):
+    """The list the paged kernel's grid walks for rows of queries at
+    `positions` [R, S] (`mla_paged.live_tiles`), or None where the kernel
+    does not run: a function of the step's tables and positions, the same
+    for every attention of a program."""
+    if not _use_latent_kernel(cfg, arena_c.shape[2], positions.shape[1]):
+        return None
+    from ...ops import mla_paged
+    return mla_paged.live_tiles(
+        block_tables, positions[:, 0], jnp.sum(valid, axis=1),
+        positions.shape[1], cfg.num_heads, *arena_c.shape[1:3])
+
+
 def _attend_absorbed(cfg, q, arena_c, index, block_tables, pos0, n_valid,
-                     w_kvb):
+                     w_kvb, tiles=None):
     """Rows of queries q [R, S, NH, dn + dr] against the arena, absorbed:
     the latents are read and never decompressed.  Query i of row r stands
-    at pos0[r] + i; n_valid[r] of them are real."""
+    at pos0[r] + i; n_valid[r] of them are real.  `tiles`: the program's
+    `_live_tiles`; None where the kernel does not run."""
     R, S, NH, _ = q.shape
     dn = cfg.qk_nope_head_dim
     w = w_kvb.astype(q.dtype).reshape(cfg.kv_lora_rank, NH, -1)
     q_abs = jnp.einsum("rsnd,cnd->rsnc", q[..., :dn], w[..., :dn],
                        preferred_element_type=jnp.float32).astype(q.dtype)
     from ...ops import mla_paged
-    fn = (mla_paged.mla_paged_attention
-          if _use_latent_kernel(cfg, arena_c.shape[2], S)
-          else mla_paged.mla_paged_reference)
+    fn = (mla_paged.mla_paged_reference if tiles is None else
+          functools.partial(mla_paged.mla_paged_attention, tiles=tiles))
     u = fn(q_abs, q[..., dn:], arena_c, block_tables, pos0, n_valid, index,
            sm_scale=_yarn_score_factor(cfg) / math.sqrt(q.shape[-1]))
-    return jnp.einsum("rsnc,cnd->rsnd", u, w[..., dn:],
-                      preferred_element_type=jnp.float32).astype(u.dtype)
+    o = jnp.einsum("rsnc,cnd->rsnd", u, w[..., dn:],
+                   preferred_element_type=jnp.float32).astype(u.dtype)
+    if tiles is None:
+        return o
+    # the kernel writes no query tile without a real query: zeroed here,
+    # in this product's epilogue
+    real = jnp.arange(S)[None] < n_valid[:, None]
+    return jnp.where(real[:, :, None, None], o, 0)
 
 
 # ----------------------------------------------------------------------
@@ -570,6 +590,9 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
     in_line = lambda a: a.reshape((T,) + a.shape[2:])[order] \
         if compact else a.reshape((T,) + a.shape[2:])  # noqa: E731
     none = jnp.zeros((), jnp.int32)
+    # one list of live key tiles a program, outside the layer scan
+    tiles = None if form == "fresh" else _live_tiles(
+        cfg, arena["c"], block_tables, positions, valid)
     x = _embed(cfg, params, toks, pos)                            # [T, H]
 
     def project(sp, t, pos):
@@ -604,7 +627,7 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
             else:
                 o = _attend_absorbed(
                     cfg, q, arena_c, index, block_tables, positions[:, 0],
-                    jnp.sum(valid, axis=1), sp["wkv_b"])
+                    jnp.sum(valid, axis=1), sp["wkv_b"], tiles)
         return in_line(o.reshape(R, S, NH * cfg.v_head_dim).astype(dt)), \
             arena_c
 

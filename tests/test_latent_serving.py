@@ -269,6 +269,16 @@ def test_absorbed_attention_equals_the_decompressed_form():
     assert not np.asarray(absorbed[1]).any()         # a row with no query
 
 
+def written(got, n_valid):
+    """The kernel's output with the rows nothing wrote set to zero: those
+    of a query tile that holds no real query (the caller's to zero)."""
+    got = np.asarray(got, np.float32)
+    tq = min(mla_paged.queries_per_step(got.shape[2]), got.shape[1])
+    tile_first = np.arange(got.shape[1]) // tq * tq
+    return np.where((tile_first[None] < np.asarray(n_valid)[:, None])
+                    [:, :, None, None], got, 0.0)
+
+
 @pytest.fixture
 def interpret(monkeypatch):
     import jax.experimental.pallas as pl
@@ -295,10 +305,167 @@ def test_the_paged_kernel_matches_the_gather(interpret, dtype, tol, Q, MB):
                                          n_valid, 2, 0.3)
     got = mla_paged.mla_paged_attention(
         qa, qr, arena, tables, pos0, n_valid, jnp.asarray(2), 0.3)
-    assert got.dtype == dtype and not np.asarray(got[3], np.float32).any()
-    np.testing.assert_allclose(np.asarray(got, np.float32),
+    assert got.dtype == dtype
+    np.testing.assert_allclose(written(got, n_valid),
                                np.asarray(want, np.float32), atol=tol,
                                rtol=tol)
+
+
+@pytest.mark.kernels
+def test_rows_the_kernel_does_not_write_come_out_zero(interpret, monkeypatch):
+    """A query tile without a real query is no item of the kernel's grid,
+    so whatever lay in the output's memory stays there (here: NaN, planted
+    behind the kernel); `_attend_absorbed` zeroes those rows behind the
+    value up-projection and passes the rest on."""
+    import deepspeed_tpu.utils.device as device_mod
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
+    cfg = get_model_config("longcat_flash", "tiny", dtype=F32)
+    rng = np.random.RandomState(4)
+    arena, tables = _latents(rng, MB=12)
+    arena = jnp.pad(arena, ((0, 0), (0, 0), (0, 0), (0, 128)))[
+        ..., :-(-(S.kv_rank + S.d_rope) // 128) * 128]
+    Q, pos0, n_valid = 16, jnp.asarray([0, 17, 70, 5]), \
+        jnp.asarray([16, 3, 9, 0])
+    q = jnp.asarray(rng.randn(4, Q, S.heads, S.d_nope + S.d_rope), F32)
+    w_kvb = jnp.asarray(rng.randn(S.kv_rank, S.heads * (S.d_nope + S.d_v)),
+                        F32)
+    positions = pos0[:, None] + jnp.arange(Q)[None]
+    valid = jnp.arange(Q)[None] < n_valid[:, None]
+    tiles = latent_ops._live_tiles(cfg, arena, tables, positions, valid)
+    assert tiles is not None
+    attend = mla_paged.mla_paged_attention
+    unwritten = ~np.asarray(written(np.ones((4, Q, S.heads, 1)), n_valid),
+                            bool)
+    monkeypatch.setattr(
+        mla_paged, "mla_paged_attention", lambda *a, **kw: jnp.where(
+            unwritten, jnp.nan, attend(*a, **kw)))
+    got = latent_ops._attend_absorbed(cfg, q, arena, 1, tables, pos0,
+                                      n_valid, w_kvb, tiles)
+    want = latent_ops._attend_absorbed(cfg, q, arena, 1, tables, pos0,
+                                       n_valid, w_kvb)
+    assert unwritten.sum() == (8 + 0 + 16) * S.heads
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    assert not np.asarray(got)[~np.asarray(valid)].any()
+
+
+def _walked(rng, heads, Q, MB, pos0, n_valid, garbage=10 ** 6, nb=40):
+    """A case on the walk's edges: block 8, so a key tile is 64 keys (8
+    table entries); every table entry past a row's live blocks is
+    `garbage`.  Returns the kernel's and the gather's arguments."""
+    arena = jnp.asarray(rng.randn(3, nb, 8, 128), F32)
+    tables = rng.randint(0, nb, (len(pos0), MB))
+    for b, (p, n) in enumerate(zip(pos0, n_valid)):
+        tables[b, (p + max(n, 1) - 1) // 8 + 1:] = garbage
+    qa = jnp.asarray(rng.randn(len(pos0), Q, heads, 16), F32)
+    qr = jnp.asarray(rng.randn(len(pos0), Q, heads, 8), F32)
+    return (qa, qr, arena, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(pos0, jnp.int32), jnp.asarray(n_valid, jnp.int32))
+
+
+# (heads, queries a row, table width, pos0, n_valid, garbage)
+WALKS = {
+    # a row's last key is a block's last, a block's first, a tile's last,
+    # a tile's first, the table's last
+    "block-and-tile-edges": (4, 1, 20, [7, 8, 63, 64, 127, 128, 159],
+                             [1] * 7, 10 ** 6),
+    "a-table-of-one": (4, 1, 1, [0, 3, 7], [1, 1, 1], 10 ** 6),
+    "no-whole-tiles": (4, 1, 10, [79, 70, 64, 63, 5], [1] * 5, 10 ** 6),
+    "inactive-first": (4, 1, 10, [0, 30, 70], [0, 1, 1], 10 ** 6),
+    "inactive-last": (4, 1, 10, [30, 70, 0], [1, 1, 0], 10 ** 6),
+    "inactive-between": (4, 1, 10, [30, 0, 0, 70], [1, 0, 0, 1], 10 ** 6),
+    "all-inactive": (4, 1, 10, [30, 0, 70], [0, 0, 0], 10 ** 6),
+    "negative-garbage": (4, 1, 10, [79, 9, 64], [1, 1, 1], -7),
+    # chunk rows: query tiles of 8; a row of whole tiles, one whose last
+    # three tiles hold no real query, one with a single real query in its
+    # second tile, one with none at all, one that ends on the table's end
+    "chunk-rows": (4, 32, 10, [0, 17, 40, 5, 48], [32, 5, 9, 0, 32],
+                   10 ** 6),
+    "chunk-rows-negative-garbage": (4, 16, 19, [64, 100, 3], [16, 1, 7],
+                                    -1),
+    "64-heads-decode": (64, 1, 10, [79, 8, 0, 64], [1, 1, 0, 1], 10 ** 6),
+    "64-heads-chunk": (64, 16, 10, [0, 60], [16, 11], 10 ** 6),
+}
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_the_kernel_on_the_walks_edges(interpret, case):
+    """Interpret mode against the gather where the list of live key tiles
+    has its edges; the index rides in traced."""
+    heads, Q, MB, pos0, n_valid, garbage = WALKS[case]
+    args = _walked(np.random.RandomState(1), heads, Q, MB, pos0, n_valid,
+                   garbage)
+    want = mla_paged.mla_paged_reference(*args, 1, 0.3)
+    got = jax.jit(lambda index: mla_paged.mla_paged_attention(
+        *args, index, 0.3))(jnp.asarray(1))
+    # (a padded query beside real ones is zero; the gather zeroes all)
+    np.testing.assert_allclose(written(got, n_valid), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("Q", [1, 16])
+def test_tables_of_10_and_33_give_the_same_bits(interpret, Q):
+    """The key tile follows from the static shapes alone (8 table entries,
+    fewer only under a narrower table), so the same rows under a wider
+    table walk the same tiles: equal outputs, bit for bit."""
+    pos0, n_valid = [79 - Q + 1, 30, 0, 64 - Q], [Q, max(Q - 3, 1), 0, Q]
+    qa, qr, arena, tables, *rest = _walked(
+        np.random.RandomState(2), 4, Q, 10, pos0, n_valid)
+    wide = jnp.pad(tables, ((0, 0), (0, 23)), constant_values=-3)
+    narrow = mla_paged.mla_paged_attention(qa, qr, arena, tables, *rest,
+                                           2, 0.3)
+    assert np.array_equal(
+        written(narrow, n_valid),
+        written(mla_paged.mla_paged_attention(qa, qr, arena, wide, *rest,
+                                               2, 0.3), n_valid))
+
+
+@pytest.mark.parametrize("Q,heads", [(1, 4), (32, 4), (8, 128)])
+def test_the_list_holds_the_live_items_and_copies_the_live_blocks(Q, heads):
+    """`live_tiles`: one item a (row, query tile, key tile) that holds a
+    key some real query of the tile sees, in that order; a slot past the
+    tile's last such block holds what it held an item earlier, so the
+    pipeline copies a block exactly where a live table entry stands."""
+    bs, P, B, MB, nb = 8, mla_paged.BLOCKS_PER_STEP, 6, 20, 1000
+    tq = min(mla_paged.queries_per_step(heads), Q)
+    pos0 = np.asarray([159 - Q + 1, 0, 70, 63, 0, 17])
+    n_valid = np.asarray([Q, 1, 0, max(Q - 3, 1), 0, Q])
+    tables = np.random.RandomState(0).permutation(B * MB).reshape(B, MB)
+    count, groups, tiles, blocks, first, real = (
+        np.asarray(x) for x in mla_paged.live_tiles(
+            jnp.asarray(tables, jnp.int32), jnp.asarray(pos0, jnp.int32),
+            jnp.asarray(n_valid, jnp.int32), Q, heads, nb, bs))
+    N = B * (Q // tq) * -(-MB // P)
+    assert groups.shape == tiles.shape == (N,) and blocks.shape == (P * N,)
+    # (the places behind the live items too: a pipeline may look ahead)
+    assert 0 <= groups.min() and groups.max() < B * (Q // tq)
+    assert 0 <= tiles.min() and tiles.max() < -(-MB // P)
+    assert 0 <= blocks.min() and blocks.max() < nb
+    slots, want, copies, live_blocks = blocks.reshape(P, N), [], 0, 0
+    for b in range(B):
+        for t in range(Q // tq):
+            g = b * (Q // tq) + t
+            n_real = min(max(n_valid[b] - t * tq, 0), tq)
+            assert (first[g], real[g]) == (pos0[b] + t * tq, n_real)
+            if n_real:
+                n_blocks = (pos0[b] + t * tq + n_real - 1) // bs + 1
+                want += [(g, j, n_blocks) for j in range(-(-n_blocks // P))]
+    assert count == len(want)
+    assert list(zip(groups[:count], tiles[:count])) == [w[:2] for w in want]
+    for i, (g, j, n_blocks) in enumerate(want):
+        for s in range(P):
+            before = slots[s, i - 1] if i else 0
+            if j * P + s < n_blocks:
+                assert slots[s, i] == tables[g // (Q // tq), j * P + s]
+                copies += slots[s, i] != before
+                live_blocks += 1
+            else:
+                assert slots[s, i] == before
+    # one query tile a row: every live block is copied once; later query
+    # tiles of a row find its first blocks where the tile before left them
+    assert copies == live_blocks if Q == tq else copies < live_blocks
 
 
 @pytest.mark.kernels
@@ -332,6 +499,44 @@ def test_decode_and_chunks_through_the_kernel_match_the_gather(
                                         *chunk)
     assert np.abs(np.asarray(fused - dense))[0].max() < TOL
     assert np.abs(np.asarray(fused_c[0] - dense_c[0]))[0].max() < TOL
+
+
+def test_the_list_refuses_an_arena_it_cannot_pack():
+    """The running maximum packs (grid step, block) into one int32: more
+    grid steps times blocks than that holds is refused while tracing."""
+    one = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="do not pack"):
+        mla_paged.live_tiles(jnp.zeros((1, 8), jnp.int32), one, one + 1, 1,
+                             4, 2 ** 31, 8)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("program", ["decode", "chunks"])
+def test_a_programs_attentions_walk_one_list(interpret, monkeypatch, program):
+    """The list of live key tiles is made once a program, outside the
+    layer scan, and every attention of the scan's body is handed it."""
+    import deepspeed_tpu.utils.device as device_mod
+    monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
+    eng = engine(max_seq_len=480)                  # (a cfg of its own)
+    lists, handed = [], []
+    make, attend = mla_paged.live_tiles, mla_paged.mla_paged_attention
+    monkeypatch.setattr(mla_paged, "live_tiles", lambda *a, **kw: (
+        lists.append(make(*a, **kw)) or lists[-1]))
+    monkeypatch.setattr(mla_paged, "mla_paged_attention", lambda *a, **kw: (
+        handed.append(kw["tiles"]) or attend(*a, **kw)))
+    tables = jnp.zeros((4, 32), jnp.int32)
+    active = jnp.asarray([True, False, False, False])
+    if program == "decode":
+        fn = functools.partial(latent_ops.decode_core, eng.cfg)
+        args = (jnp.zeros(4, jnp.int32), jnp.asarray([9, 0, 0, 0]), tables,
+                active)
+    else:
+        fn = functools.partial(latent_ops.prefill_chunks, eng.cfg)
+        args = (jnp.zeros((4, 32), jnp.int32), jnp.asarray([8, 0, 0, 0]),
+                jnp.asarray([19, 0, 0, 0]), tables, active)
+    jax.make_jaxpr(fn)(eng.params, eng.arena, *args)
+    assert len(lists) == 1 and len(handed) == 2     # a double block's two
+    assert all(h is lists[0] for h in handed)
 
 
 @pytest.mark.kernels

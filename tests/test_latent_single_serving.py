@@ -31,6 +31,7 @@ from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import mla_paged
 from test_grouped_matmul import arena_copy, moe_through_the_kernel
+from test_latent_serving import written
 
 pytestmark = pytest.mark.serving
 
@@ -381,8 +382,36 @@ def test_the_paged_kernel_at_128_heads_with_the_scaled_softmax(interpret, Q):
                                          n_valid, 1, scale)
     got = mla_paged.mla_paged_attention(qa, qr, arena, tables, pos0, n_valid,
                                         jnp.asarray(1), scale)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
-                               rtol=2e-5)
+    np.testing.assert_allclose(written(got, n_valid), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("Q,pos0,n_valid", [
+    # decode: a row that ends a key tile (8 entries of 8 keys), one that
+    # opens the next, an inactive row between, one at the table's end
+    pytest.param(1, [63, 0, 64, 71], [1, 0, 1, 1], id="decode"),
+    # tiles of 4 queries x 128 heads: whole tiles, a second tile with no
+    # real query, a row with none, a second tile with one real query
+    pytest.param(8, [64, 0, 9, 56], [8, 3, 0, 5], id="chunk-rows"),
+])
+def test_the_walk_at_128_heads(interpret, Q, pos0, n_valid):
+    """The list of live (row, query tile, key tile) items at 128 heads,
+    against the gather; garbage past the live blocks."""
+    rng = np.random.RandomState(3)
+    arena = jnp.asarray(rng.randn(2, 12, 8, 128), F32)
+    tables = rng.randint(0, 12, (4, 9))
+    for b, (p, n) in enumerate(zip(pos0, n_valid)):
+        tables[b, (p + max(n, 1) - 1) // 8 + 1:] = -5 if b % 2 else 10 ** 6
+    args = (jnp.asarray(rng.randn(4, Q, 128, 16), F32),
+            jnp.asarray(rng.randn(4, Q, 128, 8), F32), arena,
+            jnp.asarray(tables, jnp.int32), jnp.asarray(pos0, jnp.int32),
+            jnp.asarray(n_valid, jnp.int32))
+    want = mla_paged.mla_paged_reference(*args, 1, S.softmax_scale)
+    got = mla_paged.mla_paged_attention(*args, jnp.asarray(1),
+                                        S.softmax_scale)
+    np.testing.assert_allclose(written(got, n_valid), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.kernels

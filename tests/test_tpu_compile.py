@@ -242,23 +242,29 @@ def test_merged_arena_decode(chip):
 
 
 # (B, Q, NH, rank, rope, attentions, nb, bs, MB): the LongCat-Flash cell's
-# decode (one query's 64 heads against one 576-wide row, 33 blocks in 5
-# steps of 7, arena minor padded to 640 lanes) and its chunk slots (tiles
-# of 8 queries); then one block a step and 2 heads
+# decode (one query's 64 heads against one 576-wide row; a row's live
+# blocks of its table's 33 in key tiles of 8, each block its own in_spec;
+# arena minor padded to 640 lanes) and its chunk slots (tiles of 8
+# queries: 512 query tiles, up to 2,560 items on the list); then one block
+# a tile and 2 heads
 @pytest.mark.parametrize("B,Q,NH,R,Dr,A,nb,bs,MB", [
     pytest.param(96, 1, 64, 512, 64, 8, 3488, 64, 33, id="longcat-decode"),
     pytest.param(4, 1024, 64, 512, 64, 8, 3488, 64, 33, id="longcat-chunks"),
     pytest.param(4, 1, 2, 128, 64, 2, 16, 16, 1, id="one-block"),
-    # the DeepSeek-V3 cell's: 128 heads, 41 blocks in 6 steps of 7, one
-    # attention a layer; chunk slots in tiles of 4 queries x 128 heads
+    # the DeepSeek-V3 cell's: 128 heads, tables of 41 blocks, one attention
+    # a layer; chunk slots in tiles of 4 queries x 128 heads (3,072 items)
     pytest.param(64, 1, 128, 512, 64, 5, 2890, 64, 41, id="deepseek-decode"),
     pytest.param(2, 1024, 128, 512, 64, 5, 2890, 64, 41,
                  id="deepseek-chunks"),
+    # a 32k table over the same rows: 6,144 items at most, 49,152 slots
+    pytest.param(96, 1, 64, 512, 64, 8, 3488, 64, 512, id="longcat-32k"),
 ])
 def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
     """The latent attention kernel at the cell's widths: one Mosaic
-    kernel, and no relayout of the arena (an arena whose minor dimension
-    is not whole 128-lane tiles is copied whole before every call)."""
+    kernel whose grid is the list of live key tiles (the list fits the
+    scalar memory), no relayout of the arena (an arena whose minor
+    dimension is not whole 128-lane tiles is copied whole before every
+    call) and next to no temporaries for the list."""
     from deepspeed_tpu.ops.mla_paged import mla_paged_attention
     W = -(-(R + Dr) // 128) * 128
 
