@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ...utils.spans import span
+from .families import family_of
 from .ragged_manager import DSStateManager, SequenceDescriptor
 from .ragged_ops import (init_arena, prefill_chunks, decode_step,
                          decode_tokens, decode_multi_step, verify_tokens,
@@ -32,7 +33,7 @@ from .ragged_ops import (init_arena, prefill_chunks, decode_step,
 __all__ = ["RaggedInferenceEngineConfig", "InferenceEngineV2", "LogitsRows"]
 
 
-# what the four block readers/writers refuse for a latent arena
+# what the four block readers/writers refuse for an arena without K/V pages
 _PAGE_IO = ("arena page export/import (KV tiering, fleet migration, "
             "disaggregated handoff)")
 
@@ -102,10 +103,10 @@ class LogitsRows(Mapping):
         # kernel's time follows
         self.kv_live_blocks = self.kv_table_blocks = 0
         # a two-kind cache's account of the decode rows, by counter name
-        # (`InferenceEngineV2._count_kinds`); one kind: nothing
+        # (`hybrid_ops.step_account`); one kind: nothing
         self.kv_kinds: Dict[str, int] = {}
         # a decode step's account of per-sequence recurrent state
-        # (`InferenceEngineV2._count_state`); a model without: nothing
+        # (`ssm_ops.step_account`); a model without: nothing
         self.state_account: Dict[str, int] = {}
         # what `engine.collect` returns: the rows it left out because
         # their sequence had been flushed (or replaced under its uid)
@@ -288,35 +289,14 @@ class InferenceEngineV2:
                 "tp_collectives='fused' requires tensor_parallel_size > 1 "
                 "(there is no collective to fuse at tp=1; the default "
                 "'xla' keeps tp=1 byte-identical)")
-        self._latent = bool(getattr(self.cfg, "latent", False))
-        if self._latent and (self.tp > 1
-                             or self.config.tp_collectives != "xla"):
-            raise ValueError(
+        # the model's family: its programs and arena, what they take, and
+        # the step accounts (`families.py`)
+        self.family = family_of(self.cfg)
+        if not self.family.shards and (
+                self.tp > 1 or self.config.tp_collectives != "xla"):
+            self.family.refuse(
                 "tensor parallelism (tensor_parallel_size > 1, "
-                "tp_collectives='fused') cannot serve the latent (MLA) "
-                "block: its cache has no head dimension to shard and its "
-                "kernel and expert share are not wrapped for a mesh")
-        # state-space mixers: per-sequence recurrent state in slots beside
-        # the paged K/V
-        self._ssm = bool(getattr(self.cfg, "ssm", False))
-        if self._ssm and (self.tp > 1
-                          or self.config.tp_collectives != "xla"):
-            raise NotImplementedError(
-                "tensor parallelism (tensor_parallel_size > 1, "
-                "tp_collectives='fused') cannot serve per-sequence "
-                "recurrent state: the state slots and the mixer's heads "
-                "are not split over a mesh, its scan and update kernels "
-                "are not wrapped for one, and an expert share behind a "
-                "mixer has no exchange")
-        # a static-kind stack (window + global layers): two kinds of cache
-        self._kinds = bool(getattr(self.cfg, "static_kinds", False))
-        if self._kinds and (self.tp > 1
-                            or self.config.tp_collectives != "xla"):
-            raise ValueError(
-                "tensor parallelism (tensor_parallel_size > 1, "
-                "tp_collectives='fused') cannot serve the static-kind "
-                "stack: its two-kind arena, its chunk attention kernel "
-                "and its experts are not wrapped for a mesh")
+                "tp_collectives='fused')", self.family.tp_error)
         if self.tp > 1:
             if self.cfg.num_heads % self.tp or self.cfg.kv_heads % self.tp:
                 raise ValueError(
@@ -350,28 +330,12 @@ class InferenceEngineV2:
                                 self.config.block_size, self.topology,
                                 merged=self.config.arena_merged,
                                 max_seqs=self.config.max_seqs)
-        # a two-kind arena has divided `num_blocks`, its byte budget, by
-        # the model's kinds and `max_seqs` (hybrid_ops.kind_pools): the
-        # ledger counts the pools it made
-        nb, window = self.config.num_blocks, None
-        if self._kinds:
-            nb = self.arena["gk"].shape[1]
-            window = (self.cfg.window, self.arena["wk"].shape[1])
+        # the ledger counts the pools of the arena the family made
+        nb, window, state_slots = self.family.pools(self.cfg, self.arena,
+                                                    self.config)
         self.state = DSStateManager(
             nb, self.config.block_size, self.config.max_blocks_per_seq,
-            self.config.max_seqs, window=window,
-            # recurrent state: a slot a decode row, so a live sequence
-            # always has one and `free_slots` counts both
-            state_slots=self.config.max_seqs if self._ssm else 0)
-        if self._ssm:
-            # what `_count_state` multiplies: the bytes a row's slot moves
-            # both ways a decode step (over the layers with a mixer), and
-            # of K/V a token holds (over the layers with attention)
-            from .ssm_ops import state_bytes_per_slot
-            self._state_bytes_row = 2 * state_bytes_per_slot(self.cfg)
-            self._kv_bytes_token = (
-                2 * self.cfg.ssm_attn_layers * self.cfg.kv_heads
-                * self.cfg.head_dim * jnp.dtype(self.cfg.dtype).itemsize)
+            self.config.max_seqs, window=window, state_slots=state_slots)
         # per-sequence token ceiling: arena lease AND model context — learned
         # position embeddings clip silently past max_seq_len, so enforce it
         # here with a loud error instead
@@ -440,7 +404,6 @@ class InferenceEngineV2:
                                   and self.tp == 1
                                   and prefill_full_supported(self.cfg))
         self._last_logits = LogitsRows(self._fetch_logits)
-        self._window_released_seen = 0
         self._rng = jax.random.PRNGKey(0)
         # host-sync ledger: every EXPLICIT device->host fetch the engine
         # performs bumps d2h_fetches (the implicit ones are what the
@@ -481,9 +444,9 @@ class InferenceEngineV2:
         (telemetry / invalidation handle)."""
         from ...serving.kv_tier import HostKVTier
         from ...serving.prefix_cache import PrefixCache
-        self._refuse_latent("the prefix cache and its host KV tier "
-                            "(shared blocks are attached and spilled as "
-                            "K/V pages)")
+        self.family.refuse("the prefix cache and its host KV tier "
+                           "(shared blocks are attached and spilled as "
+                           "K/V pages)")
         scaling = getattr(self.cfg, "rope_scaling", None)
         if scaling and scaling[0] == "longrope":
             # phi3-style longrope picks short/long rope factors from the
@@ -518,46 +481,17 @@ class InferenceEngineV2:
             tier=tier)
         return self.prefix_cache
 
-    def _refuse_latent(self, what: str) -> None:
-        """Mechanisms written for ONE kind of per-head K/V block pair
-        refuse the latent (MLA) arena, the two-kind cache and
-        per-sequence recurrent state where they are switched on."""
-        if self._ssm:
-            raise NotImplementedError(
-                f"{what}: not wired for per-sequence recurrent state. A "
-                f"sequence here is its K/V blocks (of the layers with "
-                f"attention) AND a slot of state-space state and "
-                f"convolution tail (of the layers with a mixer) that every "
-                f"token rewrites: a block holds no snapshot of the state "
-                f"at its edge (so a cached or migrated prefix could not be "
-                f"continued), and the programs take no LoRA, draft-span, "
-                f"burst or multi-step operands")
-        if self._latent:
-            raise NotImplementedError(
-                f"{what}: not wired for the latent (MLA) arena, which "
-                f"holds one [latent | rope key] row per token and "
-                f"attention and no K/V pages")
-        if self._kinds:
-            raise NotImplementedError(
-                f"{what}: not wired for the two-kind cache, where a block "
-                f"id names a block of ONE kind of layer (global or "
-                f"window), a sequence's window-kind blocks are handed "
-                f"back as it advances (a cached prefix could not be "
-                f"re-attached under them), and the static-kind stack's "
-                f"programs take no LoRA, draft-span or expert-page "
-                f"operands")
-
     # -- multi-LoRA adapter serving (serving/tenancy) ---------------------
     # the serving layer probes this before enabling an adapter pool
     @property
     def supports_lora(self) -> bool:
-        return not (self._latent or self._kinds or self._ssm)
+        return self.family.lora
 
     # per-sequence recurrent state beside the blocks: the serving layer
     # refuses what assumes "a sequence's state is its blocks"
     @property
     def recurrent_state(self) -> bool:
-        return self._ssm
+        return self.family.row_slots
 
     def attach_lora(self, lora) -> None:
         """Attach (None = detach) the stacked multi-LoRA factors the
@@ -570,8 +504,8 @@ class InferenceEngineV2:
         see these operands — their programs stay bit-for-bit
         single-tenant."""
         if lora is not None:
-            self._refuse_latent("LoRA adapters (the gather epilogue sits "
-                                "on the dense block's output projection)")
+            self.family.refuse("LoRA adapters (the gather epilogue sits "
+                               "on the dense block's output projection)")
             a, b = lora["a"], lora["b"]
             if (a.ndim != 4 or b.ndim != 4 or a.shape[0] != b.shape[0]
                     or a.shape[1] != b.shape[1] or a.shape[3] != b.shape[2]):
@@ -628,7 +562,7 @@ class InferenceEngineV2:
         (jax.device_get): migration runs outside the serve step's
         transfer guard, but the same no-implicit-sync discipline
         applies."""
-        self._refuse_latent(_PAGE_IO)
+        self.family.refuse(_PAGE_IO)
         if not 0 <= block < self.config.num_blocks:
             raise ValueError(f"bad block id {block}")
         k = jax.device_get(self.arena["k"][:, block])
@@ -641,7 +575,7 @@ class InferenceEngineV2:
         arena.  The caller must own the block (a fresh allocator lease —
         see fleet/migration.py's insert-before-decref handoff); writing
         a block a live sequence reads would corrupt its KV."""
-        self._refuse_latent(_PAGE_IO)
+        self.family.refuse(_PAGE_IO)
         if not 0 <= block < self.config.num_blocks:
             raise ValueError(f"bad block id {block}")
         shape = self.arena["k"].shape         # [L, blocks, bs, ...minor]
@@ -669,7 +603,7 @@ class InferenceEngineV2:
         each, in ONE gather fetch per page tensor — the multi-block
         transfer unit of the disagg handoff path (one device round trip
         for the span instead of one per block)."""
-        self._refuse_latent(_PAGE_IO)
+        self.family.refuse(_PAGE_IO)
         blocks = [int(b) for b in blocks]
         for b in blocks:
             if not 0 <= b < self.config.num_blocks:
@@ -687,7 +621,7 @@ class InferenceEngineV2:
         the caller holds a fresh allocator lease on every target block,
         and the span's block ids must be distinct (a duplicated scatter
         index would silently keep only one page)."""
-        self._refuse_latent(_PAGE_IO)
+        self.family.refuse(_PAGE_IO)
         blocks = [int(b) for b in blocks]
         if len(set(blocks)) != len(blocks):
             raise ValueError(f"duplicate block ids in span {blocks}")
@@ -740,10 +674,7 @@ class InferenceEngineV2:
         out = self.state.audit(cache_blocks=cache_blocks)
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.audit_host())
-        if self._ssm:
-            # what a slot and a block stand for: the arenas' rows by kind
-            out.update(state_layers=self.arena["ssm"].shape[0],
-                       kv_layers=self.arena["k"].shape[0])
+        out.update(self.family.audit(self.arena))
         return out
 
     def _host_in(self, x):
@@ -911,10 +842,6 @@ class InferenceEngineV2:
         up when `ahead` is collected.  Every other decode row takes the
         pending token the host staged, as ever."""
         pending = LogitsRows(self._fetch_logits, self.collect)
-        if self._kinds:     # every step of a two-kind cache gives its account
-            pending.kv_kinds = dict.fromkeys(
-                ("kv_blocks_held", "kv_blocks_full_cache",
-                 "kv_window_released"), 0)
         C = self.config.prefill_chunk_size
         # a zero/negative budget must still make 1 token of progress per
         # step, or in_prefill sequences (and generate()) would spin forever
@@ -1009,7 +936,6 @@ class InferenceEngineV2:
                     flens = np.zeros(NS, np.int32)
                     ftables = np.zeros((NS,) + self.state.table_shape, np.int32)
                     factive = np.zeros(NS, bool)
-                    fslots = np.zeros(NS, np.int32)
                     for i, d in enumerate(fresh):
                         n = len(d.prompt)
                         self.state.ensure_capacity(d, n)
@@ -1017,7 +943,6 @@ class InferenceEngineV2:
                         flens[i] = n
                         ftables[i] = self.state.block_table(d)
                         factive[i] = True
-                        fslots[i] = d.state_slot
                 plan.set_metadata(rows=len(fresh))
             if fresh:
                 with span("engine.dispatch", program="prefill_full"):
@@ -1025,7 +950,7 @@ class InferenceEngineV2:
                         self.cfg, self.params, self.arena,
                         self._host_in(ftokens), self._host_in(flens),
                         self._host_in(ftables), self._host_in(factive),
-                        **self._slots_kw(fslots))
+                        **self._slots_kw(fresh, NS))
                 for d in fresh:
                     d.seen_tokens = len(d.prompt)
                 pending.prefill.append(_Program(
@@ -1059,7 +984,6 @@ class InferenceEngineV2:
             tlens = np.zeros(cap_alloc, np.int32)
             tables = np.zeros((cap_alloc,) + self.state.table_shape, np.int32)
             active = np.zeros(cap_alloc, bool)
-            cslots = np.zeros(cap_alloc, np.int32)
             # (recurrent state: a chunk starts from what the chunk before
             # it left in the slot, so a program holds one chunk a sequence)
             chunked = set()
@@ -1087,8 +1011,7 @@ class InferenceEngineV2:
                 tlens[i] = len(d.prompt)
                 tables[i] = self.state.block_table(d)
                 active[i] = True
-                cslots[i] = d.state_slot
-                if self._ssm:
+                if self.family.row_slots:
                     chunked.add(d.uid)
                 planned.append((d, start, n))
                 pseen[d.uid] = start + n
@@ -1099,7 +1022,8 @@ class InferenceEngineV2:
                 NC = 1
                 while NC < len(planned):
                     NC *= 2
-                aids = self._batch_adapter_ids([d for d, _, _ in planned], NC)
+                descs = [d for d, _, _ in planned]
+                aids = self._batch_adapter_ids(descs, NC)
                 lkw = ({} if aids is None else
                        dict(adapter_ids=self._host_in(aids), lora=self._lora))
                 logits, toks, self.arena = self._programs.prefill_chunks(
@@ -1107,23 +1031,14 @@ class InferenceEngineV2:
                     self._host_in(pos0s[:NC]), self._host_in(nvalids[:NC]),
                     self._host_in(tables[:NC]), self._host_in(active[:NC]),
                     self._host_in(tlens[:NC]), **lkw,
-                    **self._slots_kw(cslots[:NC]))
-                if self._kinds:
-                    # how often the chunk attention kernel's mask-free
-                    # body runs: its live key steps, and those an edge
-                    # crosses
-                    from .hybrid_ops import chunk_attn_steps
-                    live, masked = chunk_attn_steps(
-                        self.cfg, pos0s[:NC], nvalids[:NC], C,
-                        self.config.max_blocks_per_seq,
-                        self.config.block_size)
-                    sent.set_metadata(attn_steps_live=live,
-                                      attn_steps_masked=masked)
+                    **self._slots_kw(descs, NC))
+                sent.set_metadata(**self.family.chunk_account(
+                    self, pos0s[:NC], nvalids[:NC]))
             for d, start, n in planned:
                 d.seen_tokens = start + n
-                if self._kinds:
-                    # the blocks the chunk read and no later query will
-                    self.state.release_behind(d, d.seen_tokens)
+                # the window-kind blocks the chunk read and no later query
+                # will (a one-kind cache holds none)
+                self.state.release_behind(d, d.seen_tokens)
             rows = [(d, i) for i, (d, _, _) in enumerate(planned)
                     if not d.in_prefill]
             if rows:      # chunks that end no prompt leave nothing to fetch
@@ -1150,9 +1065,7 @@ class InferenceEngineV2:
                 lens = np.zeros(B, np.int32)
                 tables = np.zeros((B,) + self.state.table_shape, np.int32)
                 active = np.zeros(B, bool)
-                dslots = np.zeros(B, np.int32)
                 for i, d in enumerate(batch):
-                    dslots[i] = d.state_slot
                     if id(d) in fed:
                         source[i] = fed[id(d)]
                     else:
@@ -1175,71 +1088,29 @@ class InferenceEngineV2:
                         ahead.decode.toks if fed else self._no_tokens,
                         self._host_in(source)),
                     self._host_in(lens), self._host_in(tables),
-                    self._host_in(active), **lkw, **self._slots_kw(dslots))
+                    self._host_in(active), **lkw, **self._slots_kw(batch, B))
             pending.kv_live_blocks = sum(
                 d.seen_tokens // self.config.block_size + 1 for d in batch)
             pending.kv_table_blocks = tables.size
-            if self._kinds:
-                self._count_kinds(pending, batch)
             for d in batch:
                 d.seen_tokens += 1
             pending.decode = _Program("decode_step", logits, toks,
                                       list(zip(batch, range(len(batch)))))
             pending.decode_rows = len(batch)
             pending.fed_rows = sum(id(d) in fed for d in batch)
-        if self._ssm:
-            # on every step, so that every `serve.step` span has the four
-            # attributes (a reader sums them over the spans there are)
-            self._count_state(
-                pending, len(batch),
-                int(lens.sum()) + len(batch) if batch else 0)
+        # the family's account of its cache, on every step
+        self.family.step_account(self, pending, batch)
         return pending
 
-    def _slots_kw(self, slots) -> dict:
-        """The rows' recurrent-state slots as a program's `slots=`; a
-        model without such state hands its programs the operands it
-        always did."""
-        return {"slots": self._host_in(slots)} if self._ssm else {}
-
-    def _count_state(self, pending: LogitsRows, rows: int,
-                     tokens: int) -> None:
-        """A step's account of per-sequence recurrent state, from its
-        decode `rows` (none: the byte counts are 0) and the `tokens` they
-        attend to: the slots there are and those live sequences hold; the
-        bytes of state the step must read and write back (every row's
-        slot, both ways) and of cache altogether (those and the rows' keys
-        and values); the layers, and those whose kind holds a state and
-        those whose kind holds keys, which size both."""
-        state = rows * self._state_bytes_row
-        pending.state_account = dict(
-            layers=self.cfg.num_layers,
-            state_layers=self.cfg.ssm_state_layers,
-            kv_layers=self.cfg.ssm_attn_layers,
-            state_slots=self.state.state_slots,
-            state_slots_live=(self.state.state_slots
-                              - self.state.free_state_slots),
-            state_bytes_step=state,
-            cache_bytes_step=state + tokens * self._kv_bytes_token)
-
-    def _count_kinds(self, pending: LogitsRows, batch) -> None:
-        """A decode step's account of the two-kind cache, in block x layer
-        units: what the step's rows hold of both kinds, what one kind over
-        all layers would hold for them, the window-kind blocks handed back
-        since the last account; the live entries of BOTH kinds' tables."""
-        Lg, Lw = self.arena["gk"].shape[0], self.arena["wk"].shape[0]
-        bs, W = self.config.block_size, self.cfg.window
-        pending.kv_live_blocks += sum(
-            d.seen_tokens // bs - max(0, d.seen_tokens - W + 1) // bs + 1
-            for d in batch)
-        pending.kv_kinds.update(
-            kv_blocks_held=sum(
-                Lg * len(d.blocks) + Lw * len(d.window_blocks)
-                for d in batch),
-            kv_blocks_full_cache=sum(
-                (Lg + Lw) * len(d.blocks) for d in batch),
-            kv_window_released=(
-                self.state.window_released - self._window_released_seen))
-        self._window_released_seen = self.state.window_released
+    def _slots_kw(self, rows, n: int) -> dict:
+        """The state slots of a program's `n` rows (sequences `rows`, then
+        padding) as its `slots=`, where the family's programs take a row ->
+        slot vector; the others are handed the operands they always were."""
+        if not self.family.row_slots:
+            return {}
+        slots = np.zeros(n, np.int32)
+        slots[:len(rows)] = [d.state_slot for d in rows]
+        return {"slots": self._host_in(slots)}
 
     # -- burst decode: on-device sampling, one host dispatch per K tokens
     # the serving layer probes this before merging heterogeneous sampling
@@ -1249,7 +1120,7 @@ class InferenceEngineV2:
     # (decode_burst_step drafts= runs the compiled verify program)
     @property
     def supports_draft_verify(self) -> bool:
-        return not (self._latent or self._kinds or self._ssm)
+        return self.family.span_core is not None
     # per-request counter-based sampling streams (serving/streaming.
     # seeded_sample — the streaming layer's replayable stochastic
     # decode): the compiled burst and multi-step programs run the SAME
@@ -1271,14 +1142,14 @@ class InferenceEngineV2:
     # termination, and ONE packed device->host fetch (decode_multi_step)
     @property
     def supports_multi_step(self) -> bool:
-        return self._tpp is None and not self._ssm
+        return self._tpp is None and not self.family.row_slots
 
     # grammar-constrained decoding (serving/structured): fsm= operands
     # on decode_multi_step and the draft-verify path — the fused-TP
     # program set carries neither
     @property
     def supports_structured(self) -> bool:
-        return self._tpp is None and not self._ssm
+        return self._tpp is None and not self.family.row_slots
 
     # expert-paged MoE decode (serving/experts.ExpertPool): the slot
     # stacks/maps ride params["layers"] through every layer scan, which
@@ -1286,14 +1157,13 @@ class InferenceEngineV2:
     # pre-sharded per rank — a host-side slot splice would corrupt them)
     @property
     def supports_moe(self) -> bool:
-        # (a latent model holds a SHARE of its experts: nothing to page;
-        # the static-kind stack's and the state-space family's experts lie
-        # outside `params["layers"]`, where the slot stacks would ride)
+        # (the slot stacks ride `params["layers"]`, and the census rider
+        # the arena: `Family.shards`)
         return (self.cfg.moe_experts > 1 and self._tpp is None
-                and not self._latent and not self._kinds and not self._ssm)
+                and self.family.shards)
 
-    # the router counters of `latent_ops._moe`'s callers
-    # (latent_ops.count_names), a rider of their arena that every program
+    # the router counters of `expert_ffn.moe`'s callers
+    # (expert_ffn.count_names), a rider of their arena that every program
     # accumulates
     @property
     def supports_moe_counts(self) -> bool:
@@ -1303,7 +1173,7 @@ class InferenceEngineV2:
         """Fetch-and-reset the router counters: ONE explicit d2h of a few
         int32 per drain (the serve loop's interval), ledgered like every
         other fetch."""
-        from .latent_ops import count_names
+        from .expert_ffn import count_names
         counts = self.arena["moe_counts"]
         with span("engine.fetch", program="moe_counts", bytes=counts.nbytes):
             out = jax.device_get(counts)  # dstpu: noqa[DST001] intended: the periodic counter drain (a few int32 per interval), explicit so the transfer guard admits it
@@ -1433,9 +1303,9 @@ class InferenceEngineV2:
         RNG.  Unflagged rows are untouched; greedy rows never consume a
         stream.  Requires a stochastic mode ("sample" rides the per-row
         program so the seed flags get a row axis)."""
-        if self._ssm:
-            self._refuse_latent("burst decode (decode_burst_step, "
-                                "generate) and its draft-verify path")
+        if self.family.row_slots:
+            self.family.refuse("burst decode (decode_burst_step, "
+                               "generate) and its draft-verify path")
         if seeds and drafts is not None:
             raise RuntimeError(
                 "draft-and-verify cannot serve seeded sampling streams: "
@@ -1639,8 +1509,8 @@ class InferenceEngineV2:
         Returns {uid: [n_e] int32} — exactly the tokens the row
         emitted, EOS included, nothing past termination; the last
         emitted token stays pending so groups chain like bursts."""
-        if self._ssm:
-            self._refuse_latent("multi-step decode groups")
+        if self.family.row_slots:
+            self.family.refuse("multi-step decode groups")
         if k < 1:
             raise ValueError(f"decode_multi_step needs k >= 1, got {k}")
         if not self.supports_multi_step:
@@ -1962,7 +1832,7 @@ class InferenceEngineV2:
     @property
     def kind_names(self):
         from .ragged_manager import KIND_NAMES
-        return KIND_NAMES if self._kinds else None
+        return KIND_NAMES if self.state.window else None
 
     def blocks_needed(self, tokens: int):
         return self.state.blocks_needed(tokens)

@@ -36,26 +36,25 @@ entry is dead is written nowhere.  `kind_pools` sizes the two.
   (`prefill_full_supported` is False).
 
 Chunk slots are padded, so everything token-wise runs over the real
-tokens only, `ROW_TILE` at a time (`latent_ops._rows`).  The experts lie
-outside the scan and the router's counters ride the arena (`moe_counts`)
-as in `latent_ops`, whose `_moe` this shares.
+tokens only, `ROW_TILE` at a time (`expert_ffn.rows`).  The experts lie
+outside the scan and the router's counters ride the arena (`moe_counts`):
+`expert_ffn.moe`, as for every family with experts.
 """
 from __future__ import annotations
 
-import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ...models.transformer import TransformerConfig, _rope
-from .latent_ops import _moe, _rms, _rows, count_names
+from .expert_ffn import count_names, moe, rms, rows
 from .ragged_ops import (_dense, _embed, _gate_fused, _kernel_capable,
                          _lm_logits, greedy_tokens)
 
 __all__ = ["ROW_TILE", "kind_layers", "kind_pools", "window_blocks",
-           "init_kinds_arena", "chunk_attn_steps", "prefill_chunks",
-           "decode_core"]
+           "init_kinds_arena", "manager_pools", "chunk_account",
+           "step_account", "prefill_chunks", "decode_core"]
 
 # rows a token-wise pass takes at once: every pass reads the weights of
 # every expert that has a row, so a pass is as large as its float32
@@ -104,6 +103,13 @@ def init_kinds_arena(cfg: TransformerConfig, num_blocks: int,
             "moe_counts": jnp.zeros((len(count_names(cfg)),), jnp.int32)}
 
 
+def manager_pools(cfg: TransformerConfig, arena, config):
+    """(blocks, window, state slots) for `DSStateManager`: the arena has
+    divided `config.num_blocks`, its byte budget, by the model's kinds and
+    `max_seqs` (`kind_pools`), and the ledger counts the pools it made."""
+    return (arena["gk"].shape[1], (cfg.window, arena["wk"].shape[1]), 0)
+
+
 def _chunk_keys(MB: int, bs: int, S: int) -> Tuple[int, int]:
     """(length, key tile) of the buffer a chunk program lays a row's keys
     out in by position: its table's blocks, then room for a chunk that
@@ -113,25 +119,54 @@ def _chunk_keys(MB: int, bs: int, S: int) -> Tuple[int, int]:
     return -(-(MB * bs + S) // bk) * bk, bk
 
 
-def chunk_attn_steps(cfg: TransformerConfig, pos0, n_valid, S: int, MB: int,
-                     bs: int) -> Tuple[int, int]:
-    """(live, masked) key steps of `ops/chunk_attention.py` in one chunk
-    program of `S`-token slots at `pos0` with `n_valid` real tokens each,
-    summed over the layers of both kinds, a kv head (numpy on the host,
-    by the kernel's own rule): the steps that compute, and of those the
-    ones an edge crosses, which pay for the mask."""
+def chunk_account(engine, pos0s, n_valids) -> dict:
+    """The live and the masked key steps of `ops/chunk_attention.py` in the
+    chunk program just dispatched (slots at `pos0s` with `n_valids` real
+    tokens each), summed over the layers of both kinds, a kv head (numpy on
+    the host, by the kernel's own rule): the steps that compute, and of
+    those the ones an edge crosses, which pay for the mask.  Attributes of
+    the program's `engine.dispatch` span: how often the kernel's mask-free
+    body runs."""
     from ...ops.chunk_attention import count_steps
-    G, bk = cfg.num_heads // cfg.kv_heads, _chunk_keys(MB, bs, S)[1]
+    cfg, S = engine.cfg, engine.config.prefill_chunk_size
+    G, bk = cfg.num_heads // cfg.kv_heads, _chunk_keys(
+        engine.config.max_blocks_per_seq, engine.config.block_size, S)[1]
     live = masked = 0
     for layers, window in zip(kind_layers(cfg), (None, cfg.window)):
-        l, m = count_steps(pos0, n_valid, S, G, bk, window)
+        l, m = count_steps(pos0s, n_valids, S, G, bk, window)
         live, masked = live + layers * l, masked + layers * m
-    return live, masked
+    return dict(attn_steps_live=live, attn_steps_masked=masked)
+
+
+def step_account(engine, pending, batch) -> None:
+    """A step's account of the two-kind cache from its decode rows `batch`
+    (none: zeros), in block x layer units: what the rows hold of both
+    kinds, what one kind over all layers would hold for them, the
+    window-kind blocks handed back since the last account; and the live
+    entries of BOTH kinds' tables (`pending.kv_live_blocks` comes with the
+    global kind's)."""
+    pending.kv_kinds = dict.fromkeys(
+        ("kv_blocks_held", "kv_blocks_full_cache", "kv_window_released"), 0)
+    if not batch:
+        return
+    state = engine.state
+    Lg, Lw = engine.arena["gk"].shape[0], engine.arena["wk"].shape[0]
+    bs, W = engine.config.block_size, engine.cfg.window
+    # (a row's query of this step stood at `seen_tokens - 1`)
+    pending.kv_live_blocks += sum(
+        (d.seen_tokens - 1) // bs - max(0, d.seen_tokens - W) // bs + 1
+        for d in batch)
+    pending.kv_kinds.update(
+        kv_blocks_held=sum(
+            Lg * len(d.blocks) + Lw * len(d.window_blocks) for d in batch),
+        kv_blocks_full_cache=sum((Lg + Lw) * len(d.blocks) for d in batch),
+        kv_window_released=state.window_released - state.window_reported)
+    state.window_reported = state.window_released
 
 
 def _use_kernels(cfg: TransformerConfig, bs: int) -> bool:
     return _gate_fused(
-        cfg, _kernel_capable(cfg, cfg.head_dim, bs, 1),
+        cfg, _kernel_capable(cfg, cfg.head_dim, bs, 1, static_windows=True),
         reason=f"attn_impl='pallas' requested but the paged decode and "
                f"chunk attention kernels cannot run here (need TPU, "
                f"head_dim % 64 == 0 [got {cfg.head_dim}], block_size % 8 "
@@ -168,7 +203,7 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
     off, pos, real = (positions % bs).reshape(T), positions.reshape(T), \
         valid.reshape(T)
     toks, n = tokens.reshape(T), jnp.sum(valid)
-    # more rows than a pass takes: the real ones go in front (`_rows`)
+    # more rows than a pass takes: the real ones go in front (`rows`)
     compact = T > ROW_TILE and T % ROW_TILE == 0
     if compact:
         order = jnp.argsort(~real, stable=True)
@@ -228,7 +263,7 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
 
         def before(x, pos):
             with jax.named_scope(scope):
-                h = _rms(x, lp["attn_norm_scale"], cfg.norm_eps)
+                h = rms(x, lp["attn_norm_scale"], cfg.norm_eps)
                 q = _dense(h, lp["wq"]).reshape(-1, NH, D)
                 k = _dense(h, lp["wk"]).reshape(-1, NKV, D)
                 v = _dense(h, lp["wv"]).reshape(-1, NKV, D)
@@ -240,15 +275,15 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
         def after(x, o, real):
             with jax.named_scope(scope):
                 a = x + _dense(o, lp["wo"])
-            h = _rms(a, lp["mlp_norm_scale"], cfg.norm_eps)
+            h = rms(a, lp["mlp_norm_scale"], cfg.norm_eps)
             # the router reads the layer's input, not the experts' `h`
-            m, c = _moe(cfg, lp, experts, li, h, real, router_in=x)
+            m, c = moe(cfg, lp, experts, li, h, real, router_in=x)
             return (a + m,), c
 
-        (q, k, v), _ = _rows(before, n, (x, pos), none, ROW_TILE)
+        (q, k, v), _ = rows(before, n, (x, pos), none, ROW_TILE)
         with jax.named_scope(scope):
             o, ak, av = attend(kind, index, window, q, k, v, ak, av)
-        (x,), counts = _rows(after, n, (x, o, real), counts, ROW_TILE)
+        (x,), counts = rows(after, n, (x, o, real), counts, ROW_TILE)
         return x, ak, av, counts
 
     # a period's layers by kind (0 global, 1 window): which of its kind's
@@ -281,7 +316,7 @@ def _forward(cfg: TransformerConfig, params, arena, tokens, positions, valid,
 
 
 def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
-                   block_tables, active):
+                   block_tables, active, slots=None, **uniform_only):
     """`ragged_ops.prefill_chunks` for a static-kind stack (same contract;
     `block_tables` [NC, 2, MB])."""
     C = tokens.shape[1]
@@ -296,7 +331,8 @@ def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
     return logits, greedy_tokens(logits), arena
 
 
-def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active):
+def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active,
+                slots=None, **uniform_only):
     """`ragged_ops._decode_core` for a static-kind stack: (logits,
     arena)."""
     x, arena = _forward(cfg, params, arena, tokens[:, None],

@@ -15,6 +15,7 @@ from typing import Optional
 
 from ...models import MODEL_FAMILIES, get_model_config
 from .engine_v2 import InferenceEngineV2, RaggedInferenceEngineConfig
+from .families import family_of
 
 __all__ = ["ARCH_REGISTRY", "arch_config", "apply_serving_tp",
            "build_engine", "build_hf_engine", "check_serving_moe"]
@@ -102,26 +103,13 @@ def check_serving_moe(model_config, serving_config) -> None:
     if moe is None or not moe.enabled:
         return
     E = model_config.moe_experts
-    if getattr(model_config, "latent", False):
-        raise ValueError(
-            "serving.moe (expert paging) pages whole experts of a model "
-            "that holds them all; a latent-attention MoE stack (either "
-            "form) holds a fixed share of its experts "
-            "(moe_expert_first/count) and routes the rest to other chips "
-            "— drop serving.moe")
-    if getattr(model_config, "ssm", False) and E > 1:
-        raise ValueError(
-            "serving.moe (expert paging) pages whole experts of a model "
-            "that holds them all in `params['layers']`; the experts behind "
-            "a state-space mixer lie apart, outside the layer scans, as a "
-            "fixed share (moe_expert_first/count) whose rest is another "
-            "chip's work — drop serving.moe")
-    if getattr(model_config, "static_kinds", False):
-        raise ValueError(
-            "serving.moe (expert paging) swaps experts in the slot stacks "
-            "of `params['layers']`; the static-kind stack keeps its "
-            "experts apart, outside the layer scan, and holds them all — "
-            "drop serving.moe")
+    family = family_of(model_config)
+    if E > 1 and not family.shards:
+        family.refuse(
+            "serving.moe (expert paging: it swaps whole experts of a model "
+            "that holds them all in the slot stacks of `params['layers']`, "
+            "counted by the arena's census rider; drop serving.moe)",
+            ValueError)
     if E <= 1:
         raise ValueError(
             f"serving.moe needs an MoE model layout (moe_experts > 1); "

@@ -53,10 +53,6 @@ class SequenceDescriptor:
     def in_prefill(self) -> bool:
         return self.seen_tokens < len(self.prompt)
 
-    @property
-    def cur_len(self) -> int:
-        return self.seen_tokens
-
 
 class DSStateManager:
     """Owns the allocator + live sequences; builds step descriptors.
@@ -96,8 +92,9 @@ class DSStateManager:
                                  else None)
         self.window_row_blocks = -(-self.window // block_size) + 1
         # window-kind blocks handed back because they fell behind the
-        # window (flushes not counted)
-        self.window_released = 0
+        # window (flushes not counted), and those of them a step's account
+        # has reported (`hybrid_ops.step_account`)
+        self.window_released = self.window_reported = 0
         self.state_slots = state_slots
         self._free_state_slots = list(range(state_slots - 1, -1, -1))
 

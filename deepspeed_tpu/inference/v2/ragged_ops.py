@@ -36,6 +36,7 @@ from ...models.transformer import (TransformerConfig, _act_fn,
                                    _alibi_slopes, _embed_in, _head_hidden,
                                    _layer_extras, _norm, _rope,
                                    resolve_weight_scaled)
+from .families import family_of
 
 PyTree = Any
 
@@ -65,38 +66,27 @@ def init_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
     smaller arenas keep the 5-D layout the fused Pallas kernels consume
     directly.  The serving programs branch on the arena rank.
 
-    A latent-attention model (`cfg.latent`) caches one row per token and
-    attention and no V: `latent_ops.init_latent_arena` (key "c").  A
-    static-kind stack (`cfg.static_kinds`) holds its global and its window
-    layers apart, each kind with its own blocks:
-    `hybrid_ops.init_kinds_arena` (keys "gk"/"gv", "wk"/"wv"), which takes
-    `num_blocks` as the byte budget and sizes the window kind by
-    `max_seqs`.  The state-space family (`cfg.ssm`) keeps one slot of
-    recurrent state a sequence, over its layers with a mixer, beside the
-    paged K/V of its layers with attention: `ssm_ops.init_ssm_arena` (keys
-    "ssm", "conv"; `max_seqs` slots; "moe_counts" with experts)."""
-    if cfg.ssm:
-        if (topology is not None and topology.tp_size > 1) or moe_census:
-            raise ValueError(
-                "the recurrent-state arena is not sharded over tp (the "
-                "mixer's heads are not split over a mesh) and has no "
-                "expert-paging census rider")
-        from .ssm_ops import init_ssm_arena
-        return init_ssm_arena(cfg, num_blocks, block_size, max_seqs)
-    if cfg.static_kinds:
-        if (topology is not None and topology.tp_size > 1) or moe_census:
-            raise ValueError(
-                "the two-kind arena is not sharded over tp and has no "
-                "expert-paging census rider")
-        from .hybrid_ops import init_kinds_arena
-        return init_kinds_arena(cfg, num_blocks, block_size, max_seqs)
-    if cfg.latent:
-        if (topology is not None and topology.tp_size > 1) or moe_census:
-            raise ValueError(
-                "the latent (MLA) arena has no head dimension to shard "
-                "over tp and no expert-paging census rider")
-        from .latent_ops import init_latent_arena
-        return init_latent_arena(cfg, num_blocks, block_size)
+    The other families make their own (`families.family_of(cfg).
+    init_arena`: `latent_ops`, `hybrid_ops` and `ssm_ops` say what each
+    holds), none sharded over tp and none with the census rider; a
+    static-kind stack takes `num_blocks` as the byte budget of its two kinds
+    and sizes the window kind by `max_seqs`, the state-space family its
+    state slots."""
+    fam = family_of(cfg)
+    if fam.shards:
+        return fam.init_arena(cfg, num_blocks, block_size, max_seqs,
+                              topology, merged, moe_census)
+    if (topology is not None and topology.tp_size > 1) or moe_census:
+        fam.refuse("an arena sharded over tp or with the expert-paging "
+                   "census rider", ValueError)
+    return fam.init_arena(cfg, num_blocks, block_size, max_seqs)
+
+
+def _uniform_arena(cfg: TransformerConfig, num_blocks: int, block_size: int,
+                   max_seqs: int = 0, topology=None, merged="auto",
+                   moe_census: bool = False):
+    """`init_arena` of the uniform family (nothing of it is sized by
+    `max_seqs`)."""
     D = cfg.head_dim
     logical = (cfg.num_layers * num_blocks * block_size
                * cfg.kv_heads * D * jnp.dtype(cfg.dtype).itemsize)
@@ -247,7 +237,7 @@ def _use_paged_kernel(cfg: TransformerConfig, D: int, bs: int,
     dense reference (`tests/test_paged_attention.py`).  A uniform
     `sliding_window` is the kernel's static `window` (its walk starts at
     the window's first block); a window that rides the layer scan as a
-    traced scalar (`sliding_window_layers` without static kinds) is not."""
+    traced scalar (`sliding_window_layers` here) is not."""
     return _gate_fused(
         cfg, _kernel_capable(cfg, D, bs, n_tp),
         reason=f"attn_impl='pallas' requested but the paged decode kernel "
@@ -258,8 +248,10 @@ def _use_paged_kernel(cfg: TransformerConfig, D: int, bs: int,
 
 
 def _kernel_capable(cfg: TransformerConfig, D: int, bs: int,
-                    n_tp: int) -> bool:
+                    n_tp: int, static_windows: bool = False) -> bool:
     """Capability conditions shared by both fused paged kernels.
+    `static_windows`: the caller hands the kernels each layer's window as a
+    Python value (a stack whose layer kinds are static).
 
     n_tp > 1 without a mesh: operands are GSPMD-sharded and a pallas_call
     does not auto-partition, so the dense gather path serves.  WITH a mesh
@@ -270,7 +262,7 @@ def _kernel_capable(cfg: TransformerConfig, D: int, bs: int,
     return (on_tpu() and n_tp == 1 and D % 64 == 0 and bs % 8 == 0
             and cfg.pos_emb != "alibi"
             # a window the kernels can take is a Python value
-            and (cfg.sliding_window_layers is None or cfg.static_kinds))
+            and (cfg.sliding_window_layers is None or static_windows))
 
 
 def _shard_mapped_tp(fn, mesh, n_in_specs_headed, layered=False):
@@ -314,6 +306,30 @@ def _gate_fused(cfg: TransformerConfig, supported: bool,
                              "benchmark/debug the wrong implementation")
         return True
     return supported
+
+
+def _gate_merged(cfg: TransformerConfig, use_kernel: bool, D: int,
+                 n_tp: int, mesh, kernel: str) -> bool:
+    """`use_kernel` for a merged arena, which feeds kernels of its own
+    (ops/paged_merged: the stripe grid for a chunk or a verify span, packed
+    q for decode, which has no window) where the layout qualifies and the
+    dense gather elsewhere, under `_gate_fused`'s no-silent-fallback
+    contract.  `kernel`: "prefill" | "verify" | "decode"."""
+    from ...ops.paged_merged import merged_kernels_supported
+    loc = n_tp if mesh is not None else 1
+    NH, NKV, decode = cfg.num_heads // loc, cfg.kv_heads // loc, \
+        kernel == "decode"
+    ok = merged_kernels_supported(
+        NH, NKV, D, op="decode" if decode else "prefill") and not (
+            decode and cfg.sliding_window is not None)
+    if use_kernel and not ok and cfg.attn_impl == "pallas":
+        raise ValueError(
+            f"attn_impl='pallas' requested but the merged-arena {kernel} "
+            f"kernel cannot serve this layout (local heads {NH}/{NKV}, "
+            f"head_dim {D}: needs " + (
+                "128-aligned packed stripes and no sliding_window)" if decode
+                else "head_dim <= 128 and whole 128-lane kv stripes)"))
+    return use_kernel and ok
 
 
 def _use_paged_prefill(cfg: TransformerConfig, D: int, bs: int, C: int,
@@ -413,22 +429,19 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     so a later chunk attends keys a former chunk just wrote, while QKV
     projections, MLP and logits batch over all NC*C tokens (better MXU
     shapes than NC separate calls, and NC fewer host dispatches).
-    `slots` [NC]: each chunk's state slot, for a state-space parallel
-    block alone (`ssm_ops`: the scan starts from the slot where the
+    `slots` [NC]: each chunk's state slot, for a family with a row ->
+    slot vector alone (`ssm_ops`: the scan starts from the slot where the
     chunk continues a prompt and ends by writing it).
     Returns (logits [NC, V] — last valid token each, their argmax
     [NC] int32 (`greedy_tokens`), arena)."""
-    if cfg.ssm:
-        from . import ssm_ops
-        ssm_ops.refuse_lora(lora)
-        return ssm_ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
-                                      n_valids, block_tables, active, slots)
-    if cfg.latent or cfg.static_kinds:
-        from . import hybrid_ops, latent_ops
-        latent_ops.refuse_lora(lora)
-        ops = latent_ops if cfg.latent else hybrid_ops
-        return ops.prefill_chunks(cfg, params, arena, tokens, pos0s,
-                                  n_valids, block_tables, active)
+    fam = family_of(cfg)
+    fam.refuse_lora(lora)
+    if fam.prefill_chunks is not _uniform_prefill_chunks:
+        return fam.prefill_chunks(
+            cfg, params, arena, tokens, pos0s, n_valids, block_tables,
+            active, total_lens=total_lens, n_tp=n_tp, mesh=mesh,
+            adapter_ids=adapter_ids, lora=lora, slots=slots)
+    # the uniform family's body, in this frame (`_decode_core` says why)
     NC, C = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -456,20 +469,7 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
         cfg, D, bs, C, 1 if mesh is not None else n_tp,
         local_heads=NH // (n_tp if mesh is not None else 1))
     if merged:
-        # merged arenas feed the stripe-grid kernel (ops/paged_merged) —
-        # the r3 gather fallback is gone where the layout qualifies
-        from ...ops.paged_merged import merged_kernels_supported
-        loc = n_tp if mesh is not None else 1
-        m_ok = merged_kernels_supported(NH // loc, NKV // loc, D,
-                                        op="prefill")
-        if use_kernel and not m_ok and cfg.attn_impl == "pallas":
-            # keep _gate_fused's no-silent-fallback contract
-            raise ValueError(
-                f"attn_impl='pallas' requested but the merged-arena "
-                f"prefill kernel cannot serve this layout (local heads "
-                f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
-                f"head_dim <= 128 and whole 128-lane kv stripes)")
-        use_kernel = use_kernel and m_ok
+        use_kernel = _gate_merged(cfg, use_kernel, D, n_tp, mesh, "prefill")
 
     extras = _layer_extras(cfg)
     has_ex = bool(extras)
@@ -618,6 +618,11 @@ def prefill_chunks(cfg: TransformerConfig, params, arena, tokens, pos0s,
     return logits, greedy_tokens(logits), _arena_out(arena, new_k, new_v)
 
 
+# what the uniform family's record points at (`families.family_of`): the
+# functions themselves, whatever a test puts in the modules' names
+_uniform_prefill_chunks = prefill_chunks.__wrapped__
+
+
 def prefill_full_supported(cfg: TransformerConfig) -> bool:
     """Gate for the fresh-full-prompt fast path: the dense causal flash
     path handles the mainstream archs; alibi / sliding windows /
@@ -626,17 +631,17 @@ def prefill_full_supported(cfg: TransformerConfig) -> bool:
     flash-capable too — otherwise causal_attention would SILENTLY serve
     the jnp reference here while the chunked path raises, violating the
     no-silent-fallback contract (_gate_fused); such configs stay chunked
-    (and get that loud error).  A latent stack pads its own head
-    widths for the flash path (latent_ops._attend_fresh).  A static-kind
-    stack has one prefill program: a fresh prompt is a chunk at position 0
+    (and get that loud error).  Another family's own program serves every
+    configuration of it: a latent stack pads its own head widths for the
+    flash path (latent_ops._attend_fresh), and a state-space parallel
+    block's attention is plain causal attention at any head width the flash
+    path takes or pads (`ssm_ops.prefill_full`).  A static-kind stack has
+    none, and one prefill program: a fresh prompt is a chunk at position 0
     of `ops/chunk_attention.py`, which has the window the flash kernel
-    lacks and holds no whole sequence of keys in VMEM.  A state-space
-    parallel block's attention is plain causal attention at any head width
-    the flash path takes or pads (`ssm_ops.prefill_full`)."""
-    if cfg.latent or cfg.ssm:
-        return True
-    if cfg.static_kinds:
-        return False
+    lacks and holds no whole sequence of keys in VMEM."""
+    own = family_of(cfg).prefill_full
+    if own is not _uniform_prefill_full:
+        return own is not None
     D = cfg.head_dim
     flash_ok = D % 128 == 0 or D == 64
     return (cfg.pos_emb in ("rope", "learned") and cfg.sliding_window is None
@@ -675,18 +680,15 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
     `blk -> nb` for invalid slots) discards padded K/V writes, and the
     logits slice reads only each prompt's LAST VALID token.
     """
-    if cfg.ssm:
-        from . import ssm_ops
-        return ssm_ops.prefill_full(cfg, params, arena, tokens, lens,
-                                    block_tables, active, slots)
-    if cfg.latent:
-        from . import latent_ops
-        return latent_ops.prefill_full(cfg, params, arena, tokens, lens,
-                                       block_tables, active)
-    if cfg.static_kinds:
+    own = family_of(cfg).prefill_full
+    if own is None:
         raise NotImplementedError(
-            "a static-kind stack prefills through prefill_chunks alone "
+            "this family prefills through prefill_chunks alone "
             "(prefill_full_supported is False)")
+    if own is not _uniform_prefill_full:
+        return own(cfg, params, arena, tokens, lens, block_tables, active,
+                   slots=slots)
+    # the uniform family's body, in this frame (`_decode_core` says why)
     from ...ops.attention import causal_attention
     NS, S = tokens.shape
     bs = arena["k"].shape[2]
@@ -753,6 +755,9 @@ def prefill_full(cfg: TransformerConfig, params, arena, tokens, lens,
     xl = x[jnp.arange(NS), last]                           # [NS, H]
     logits = _lm_logits(cfg, params, xl)                   # [NS, V]
     return logits, greedy_tokens(logits), _arena_out(arena, new_k, new_v)
+
+
+_uniform_prefill_full = prefill_full.__wrapped__
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,),
@@ -1361,17 +1366,14 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
     query may see, so position i attends its own draft prefix — the
     conditioning speculative verification needs.  Returns
     (logits [B, S, V] at every span position, arena)."""
-    if cfg.latent or cfg.static_kinds:
-        raise NotImplementedError(
-            "speculative verify spans are not wired into the latent (MLA) "
-            "block or the static-kind stack (the engine reports "
-            "supports_draft_verify = False)")
-    if cfg.ssm:
-        raise NotImplementedError(
-            "speculative verify spans are not wired into the state-space "
-            "parallel block: a rejected draft would have to roll the "
-            "recurrent state back, and a slot holds one state (the engine "
-            "reports supports_draft_verify = False)")
+    fam = family_of(cfg)
+    if fam.span_core is None:
+        fam.refuse("speculative verify spans (the engine reports "
+                   "supports_draft_verify = False)")
+    if fam.span_core is not _span_core:
+        return fam.span_core(cfg, params, arena, tokens, seq_lens, n_valids,
+                             block_tables, active, max_len, n_tp, mesh)
+    # the uniform family's body, in this frame (`_decode_core` says why)
     B, S = tokens.shape
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1416,17 +1418,7 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
         cfg, D, bs, S, 1 if mesh is not None else n_tp,
         local_heads=NH // (n_tp if mesh is not None else 1))
     if merged:
-        from ...ops.paged_merged import merged_kernels_supported
-        loc = n_tp if mesh is not None else 1
-        m_ok = merged_kernels_supported(NH // loc, NKV // loc, D,
-                                        op="prefill")
-        if use_kernel and not m_ok and cfg.attn_impl == "pallas":
-            raise ValueError(
-                f"attn_impl='pallas' requested but the merged-arena "
-                f"verify kernel cannot serve this layout (local heads "
-                f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
-                f"head_dim <= 128 and whole 128-lane kv stripes)")
-        use_kernel = use_kernel and m_ok
+        use_kernel = _gate_merged(cfg, use_kernel, D, n_tp, mesh, "verify")
 
     extras = _layer_extras(cfg)
     has_ex = bool(extras)
@@ -1554,23 +1546,26 @@ def _span_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
 def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
                  block_tables, active, n_tp: int = 1, mesh=None,
                  adapter_ids=None, lora=None, slots=None):
-    if cfg.ssm:
-        from . import ssm_ops
-        ssm_ops.refuse_lora(lora)
-        if slots is None:
-            raise NotImplementedError(
-                "this decode program hands the state-space parallel block "
-                "no row -> slot vector: burst, multi-step and draft-verify "
-                "decode are not wired for per-sequence recurrent state "
-                "(decode_step is)")
-        return ssm_ops.decode_core(cfg, params, arena, tokens, seq_lens,
-                                   block_tables, active, slots)
-    if cfg.latent or cfg.static_kinds:
-        from . import hybrid_ops, latent_ops
-        latent_ops.refuse_lora(lora)
-        ops = latent_ops if cfg.latent else hybrid_ops
-        return ops.decode_core(cfg, params, arena, tokens, seq_lens,
-                               block_tables, active)
+    """One decode step of the family's stack: (logits [B, V], arena).
+
+    The uniform family's record points at THIS function, and its body runs
+    in this frame, as the other three dispatch points' do in theirs: a
+    program's trace is mostly its Pallas kernels' bodies (two thirds of a
+    `prefill_full` shape's), and one Python frame more between the jitted
+    function and the kernel cost each of them 40% (145 -> 200 ms a shape on
+    the chip's host, PR 50: `PERF.md` section 6), half a second of a
+    set-up."""
+    fam = family_of(cfg)
+    fam.refuse_lora(lora)
+    if fam.row_slots and slots is None:
+        fam.refuse("a decode program that hands no row -> slot vector "
+                   "(burst, multi-step and draft-verify decode; "
+                   "decode_step hands one)")
+    if fam.decode_core is not _decode_core:
+        return fam.decode_core(cfg, params, arena, tokens, seq_lens,
+                               block_tables, active, n_tp=n_tp, mesh=mesh,
+                               adapter_ids=adapter_ids, lora=lora,
+                               slots=slots)
     B = tokens.shape[0]
     bs = arena["k"].shape[2]
     nb = arena["k"].shape[1]
@@ -1633,21 +1628,8 @@ def _decode_core(cfg: TransformerConfig, params, arena, tokens, seq_lens,
             use_kernel = _use_paged_kernel(
                 cfg, D, bs, 1 if mesh is not None else n_tp)
             if merged:
-                # merged arenas feed the packed-q kernel (ops/paged_merged) —
-                # the r3 gather fallback is gone where the layout qualifies
-                from ...ops.paged_merged import merged_kernels_supported
-                loc = n_tp if mesh is not None else 1
-                # (the packed-q kernel has no window)
-                m_ok = (merged_kernels_supported(NH // loc, NKV // loc, D)
-                        and cfg.sliding_window is None)
-                if use_kernel and not m_ok and cfg.attn_impl == "pallas":
-                    # keep _gate_fused's no-silent-fallback contract
-                    raise ValueError(
-                        f"attn_impl='pallas' requested but the merged-arena "
-                        f"decode kernel cannot serve this layout (local heads "
-                        f"{NH // loc}/{NKV // loc}, head_dim {D}: needs "
-                        f"128-aligned packed stripes and no sliding_window)")
-                use_kernel = use_kernel and m_ok
+                use_kernel = _gate_merged(cfg, use_kernel, D, n_tp, mesh,
+                                          "decode")
             if use_kernel:
                 # fused Pallas paged attention: the block table is a
                 # scalar-prefetch operand whose index map DMAs arena blocks

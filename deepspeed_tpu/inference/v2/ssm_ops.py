@@ -30,7 +30,7 @@ layer, input x [T, H], `n = rms(x, g_in)`, r = `residual_multiplier`:
     out = x1 + r mlp_multipliers[1] * ((W_up h) * silu(mlp_multipliers[0] *
         (W_gate h))) W_down                         moe_experts == 1
     out = x1 + r (sum_picks w_e E_e(h) + Shared(h))  moe_experts > 1:
-        `latent_ops._moe` over the experts held here, the shared expert
+        `expert_ffn.moe` over the experts held here, the shared expert
         (`moe_shared_expert_ffn`) on every token
 
 with `embedding_multiplier` on the embedding and `lm_head_multiplier` on
@@ -45,7 +45,7 @@ leaves of `params["layers"]` are stacked by what has them: the mixer's
 (`ssm_*`) over the layers with a mixer, attention's (`wq`, `wk`, `wv`,
 `wo`) over those with attention, the norms and the FFN's over all; the
 routed experts lie apart (`params["experts"]` `[L, local, ...]`), outside
-the scans, as in `latent_ops`.
+the scans, as `expert_ffn.moe` takes them.
 
 The arena is sized BY KIND: attention's paged `k`/`v` `[La, blocks, bs,
 NKV, D]` over the La layers with attention, and one SLOT a live sequence
@@ -87,16 +87,16 @@ import jax
 import jax.numpy as jnp
 
 from ...models.transformer import TransformerConfig, _rope
-from .latent_ops import _moe, _rms, _rows, count_names
+from .expert_ffn import count_names, moe, rms, rows
 from .ragged_ops import (_embed, _kv_write, _lm_logits, _plain_mlp,
                          _use_paged_kernel, _use_paged_prefill, greedy_tokens)
 
-__all__ = ["ROW_TILE", "layer_runs", "init_ssm_arena",
-           "state_bytes_per_slot", "prefill_full", "prefill_chunks",
-           "decode_core", "refuse_lora"]
+__all__ = ["ROW_TILE", "layer_runs", "init_ssm_arena", "manager_pools",
+           "state_bytes_per_slot", "kv_bytes_per_token", "step_account",
+           "arena_layers", "prefill_full", "prefill_chunks", "decode_core"]
 
 # rows the experts take at once where a program has more slots than that
-# (chunk slots are padded: the real rows go in front, `latent_ops._rows`)
+# (chunk slots are padded: the real rows go in front, `expert_ffn.rows`)
 ROW_TILE = 512
 ATTN_LEAVES = ("wq", "wk", "wv", "wo")
 
@@ -149,12 +149,42 @@ def state_bytes_per_slot(cfg: TransformerConfig) -> int:
         * jnp.dtype(cfg.dtype).itemsize)
 
 
-def refuse_lora(lora) -> None:
-    if lora is not None:
-        raise NotImplementedError(
-            "LoRA adapters are not wired for the state-space family: the "
-            "gather epilogue sits on the dense block's output projection, "
-            "and these programs take no adapter operands")
+def kv_bytes_per_token(cfg: TransformerConfig) -> int:
+    """K and V a token holds over the layers with attention."""
+    return (2 * cfg.ssm_attn_layers * cfg.kv_heads * cfg.head_dim
+            * jnp.dtype(cfg.dtype).itemsize)
+
+
+def manager_pools(cfg: TransformerConfig, arena, config):
+    """(blocks, window, state slots) for `DSStateManager`: a slot a decode
+    row, so a live sequence always has one and `free_slots` counts both."""
+    return config.num_blocks, None, config.max_seqs
+
+
+def step_account(engine, pending, batch) -> None:
+    """A step's account of per-sequence recurrent state, from its decode
+    rows `batch` (none: the byte counts are 0) and the tokens they attend
+    to; on every step, so that every `serve.step` span has the attributes
+    (a reader sums them over the spans there are).  The slots there are and
+    those live sequences hold; the bytes of state the step must read and
+    write back (every row's slot, both ways) and of cache altogether (those
+    and the rows' keys and values); the layers, and those whose kind holds a
+    state and those whose kind holds keys, which size both."""
+    cfg, slots = engine.cfg, engine.state
+    state = len(batch) * 2 * state_bytes_per_slot(cfg)
+    pending.state_account = dict(
+        layers=cfg.num_layers, state_layers=cfg.ssm_state_layers,
+        kv_layers=cfg.ssm_attn_layers, state_slots=slots.state_slots,
+        state_slots_live=slots.state_slots - slots.free_state_slots,
+        state_bytes_step=state,
+        cache_bytes_step=state + sum(d.seen_tokens for d in batch)
+        * kv_bytes_per_token(cfg))
+
+
+def arena_layers(arena) -> dict:
+    """What a slot and a block stand for: the arenas' rows by kind."""
+    return dict(state_layers=arena["ssm"].shape[0],
+                kv_layers=arena["k"].shape[0])
 
 
 def _scaled(h, w, mult):
@@ -281,7 +311,7 @@ def _qkv(cfg, lp, n, positions):
 
 def _mlp(cfg, lp, x1):
     with jax.named_scope("dense_ffn"):
-        h = _rms(x1, lp["mlp_norm_scale"], cfg.norm_eps)
+        h = rms(x1, lp["mlp_norm_scale"], cfg.norm_eps)
         gate = _scaled(h, lp["w_gate"], cfg.mlp_multipliers[0])
         up = _scaled(h, lp["w_up"], 1.0)
         act = (jax.nn.silu(gate.astype(jnp.float32))
@@ -293,8 +323,8 @@ def _mlp(cfg, lp, x1):
 def _experts_ffn(cfg, lp, experts, li, x1, real):
     """x1 + r (the held experts' part + the shared expert) on [T, H] rows
     (`real`: the rows that are tokens), and the router's counts."""
-    h = _rms(x1, lp["mlp_norm_scale"], cfg.norm_eps)
-    m, counts = _moe(cfg, lp, experts, li, h, real)
+    h = rms(x1, lp["mlp_norm_scale"], cfg.norm_eps)
+    m, counts = moe(cfg, lp, experts, li, h, real)
     m = m.astype(jnp.float32)
     if cfg.moe_shared_expert_ffn:
         with jax.named_scope("shared_expert"):
@@ -357,7 +387,7 @@ def _stack(cfg: TransformerConfig, params, arena, x, real, mixer, attend):
         if not compact:
             (out,), c = one(x1, real)
             return out, counts + c
-        (out,), counts = _rows(one, n_real, (x1[order], real[order]),
+        (out,), counts = rows(one, n_real, (x1[order], real[order]),
                                counts, ROW_TILE)
         return out[back], counts
 
@@ -367,9 +397,9 @@ def _stack(cfg: TransformerConfig, params, arena, x, real, mixer, attend):
         mine = [n for n in leaves if kind == "both"
                 or _stacked_over(n) in ("all", kind)]
 
-        def layer(carry, rows):
+        def layer(carry, places):
             x, ak, av, ssm, conv, counts = carry                    # [T, H]
-            li, srow, arow = rows
+            li, srow, arow = places
             # (a layer takes its leaves out of the whole stacks by its own
             # place among each: a run's slice of a stack handed to the scan
             # as `xs` is a copy, 1.2 GB of them in the decode program of
@@ -377,7 +407,7 @@ def _stack(cfg: TransformerConfig, params, arena, x, real, mixer, attend):
             row = {"all": li, "ssm": srow, "attn": arow}
             lp = {n: jax.tree.map(lambda a: a[row[_stacked_over(n)]],
                                   leaves[n]) for n in mine}
-            n = _rms(x, lp["attn_norm_scale"], cfg.norm_eps)
+            n = rms(x, lp["attn_norm_scale"], cfg.norm_eps)
             x1 = x
             if kind != "attn":
                 with jax.named_scope("ssm"):
@@ -553,7 +583,7 @@ def prefill_full(cfg, params, arena, tokens, lens, block_tables, active,
 
 
 def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
-                   block_tables, active, slots):
+                   block_tables, active, slots, **uniform_only):
     """`ragged_ops.prefill_chunks` for the state-space family: a chunk
     slot a SEQUENCE (the engine plans no two chunks of one sequence into a
     program), each from its slot's state where it continues."""
@@ -562,7 +592,7 @@ def prefill_chunks(cfg, params, arena, tokens, pos0s, n_valids,
 
 
 def decode_core(cfg, params, arena, tokens, seq_lens, block_tables, active,
-                slots):
+                slots, **uniform_only):
     """`ragged_ops._decode_core` for the state-space family: (logits [B,
     V], arena), the active rows' slots updated in place."""
     from ...ops import ssm as kernels

@@ -1,6 +1,6 @@
 """Pallas TPU grouped matmul over the rows a step really has: what the
 experts' share of a latent or static-kind stack multiplies with
-(`inference/v2/latent_ops._moe`).
+(`inference/v2/expert_ffn.moe`).
 
 The rows `x [rows, K]` lie sorted by expert, the experts' rows first and
 dead rows behind them; `sizes` says how many rows each of one layer's

@@ -118,12 +118,13 @@ class _StepPhases:
         return False
 
 
-def _refuse_recurrent_state(config: ServingConfig) -> None:
+def _refuse_recurrent_state(config: ServingConfig, family) -> None:
     """What assumes "a sequence's state is its K/V blocks" cannot serve an
-    engine that keeps per-sequence recurrent state beside them (a
-    state-space mixer: `engine.recurrent_state`).  The prefix cache and
-    its host tier, page export and import, LoRA and tensor parallelism
-    are refused by the engine where they are switched on."""
+    engine that keeps per-sequence recurrent state beside them
+    (`engine.recurrent_state`): refused with the reason of the engine's
+    `family`.  The prefix cache and its host tier, page export and import,
+    LoRA and tensor parallelism are refused by the engine where they are
+    switched on."""
     on = lambda c: c is not None and getattr(c, "enabled", True)  # noqa: E731
     asked = {
         "speculative decoding (draft and verify)":
@@ -138,14 +139,7 @@ def _refuse_recurrent_state(config: ServingConfig) -> None:
     }
     for what, wanted in asked.items():
         if wanted:
-            raise NotImplementedError(
-                f"{what}: not wired for per-sequence recurrent state. A "
-                f"sequence of this model holds a slot of state-space "
-                f"state that every decoded token rewrites in place: the "
-                f"burst, multi-step and verify programs take no row -> "
-                f"slot vector, a rejected draft or a preempted row would "
-                f"need the state rolled back or saved, and a slot holds "
-                f"one state")
+            family.refuse(what)
 
 
 class ServeLoop:
@@ -220,7 +214,7 @@ class ServeLoop:
                     f"this config (model_registry.apply_serving_tp) or "
                     f"make them agree")
         if getattr(engine, "recurrent_state", False):
-            _refuse_recurrent_state(self.config)
+            _refuse_recurrent_state(self.config, engine.family)
         # burst serving needs the extended engine contract: decode_burst_
         # step(uids, n_steps, mode, temperature, top_k, max_tokens) and
         # the decode= kwarg on put()/step().  Loud here, not a silent
@@ -508,7 +502,7 @@ class ServeLoop:
         # 0 = an engine without them, never asked
         self._moe_counts_every = 0
         if getattr(engine, "supports_moe_counts", False):
-            from ..inference.v2.latent_ops import COUNT_DRAIN_STEPS
+            from ..inference.v2.expert_ffn import COUNT_DRAIN_STEPS
             self._moe_counts_every = COUNT_DRAIN_STEPS
         # observability (serving/tracing.py): per-request span traces +
         # the per-step timeline profiler.  Both default off (tracing is
@@ -1376,7 +1370,7 @@ class ServeLoop:
             self._rollback_admission(admitted)
             raise
         # a two-kind cache's account of this step's decode rows (block x
-        # layer units: `InferenceEngineV2._count_kinds`); else nothing
+        # layer units: `hybrid_ops.step_account`); else nothing
         kinds = getattr(out, "kv_kinds", {})
         whole.set_metadata(
             decode_rows=getattr(out, "decode_rows", 0),
@@ -1384,7 +1378,7 @@ class ServeLoop:
             kv_live_blocks=getattr(out, "kv_live_blocks", 0),
             kv_table_blocks=getattr(out, "kv_table_blocks", 0), **kinds,
             # per-sequence recurrent state's account of the step's decode
-            # rows (`InferenceEngineV2._count_state`); else nothing
+            # rows (`ssm_ops.step_account`); else nothing
             **getattr(out, "state_account", {}))
         for name, n in kinds.items():
             self.telemetry.count(name, n)

@@ -83,7 +83,7 @@ class ServingTelemetry:
             # plus the rows of a step dropped whole (`drop_pending`)
             "steps_run_ahead": 0, "steps_collected_at_once": 0,
             "rows_overrun": 0,
-            # latent MoE stacks (inference/v2/latent_ops.count_names),
+            # latent MoE stacks (inference/v2/expert_ffn.count_names),
             # drained from the device every COUNT_DRAIN_STEPS serve steps:
             # top-k picks, those on identity experts, those on the
             # experts held here, the busiest local expert's rows

@@ -7,6 +7,7 @@ DistributedExec).  Under SPMD-JAX a single process with
 paths (XLA emits real AllReduce/AllGather/ReduceScatter between the virtual
 devices), so every ZeRO/TP/SP/PP test runs on one CPU host.
 """
+import gc
 import os
 
 os.environ.setdefault("DSTPU_LOG_LEVEL", "WARNING")
@@ -42,6 +43,35 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {len(devs)}"
     return devs
+
+
+def _mappings() -> int:
+    """Memory mappings this process holds (0 where there is no procfs)."""
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Every program a worker has compiled stays mapped for the life of the
+    process (jax's jit caches hold the executables: about 150 mappings a
+    serving program) and the kernel allows a process `vm.max_map_count` of
+    them, 65,530.  Six workers over ~2,000 tests come within a few hundred
+    (measured at PR 50: 64,968 in one worker, 57.8k in a second, at the end
+    of a whole run), and the worker that crosses it aborts inside XLA
+    ("Fatal Python error: Aborted", in the backend's compile or in the
+    compile cache's read) in whatever test it happens to run: the one test
+    that failed in every whole run of the driver's at PRs 47 to 49 and
+    passes alone.  Past a third of the limit a module's end lets them go
+    (11,955 -> 705 mappings after two files, measured); what the next
+    module needs it compiles again or reads from the persistent cache."""
+    yield
+    if _mappings() > 65530 // 3:
+        jax.clear_caches()
+        gc.collect()
 
 
 @pytest.fixture(autouse=True)

@@ -207,7 +207,7 @@ def test_the_arenas_have_a_row_a_layer_of_their_kind():
     assert kernels.state_shape(32, 2, 256, 128) == (32, 256, 128)
     assert ssm_ops.state_bytes_per_slot(cfg) == 3 * (4 * 64 * 16 * 4
                                                      + 3 * 288 * 4)
-    assert eng._kv_bytes_token == 2 * 1 * 2 * 32 * 4
+    assert ssm_ops.kv_bytes_per_token(cfg) == 2 * 1 * 2 * 32 * 4
     # every layer of both kinds is the other family of this module
     falcon = get_model_config("falcon_h1", "tiny")
     assert falcon.ssm_period == ("both",)

@@ -82,7 +82,7 @@ def operands(rows, seed=0):
 
 
 def ragged(x, weights, sizes, first, gate_act):
-    """The three calls `latent_ops._moe` makes off the chip."""
+    """The three calls `expert_ffn.moe` makes off the chip."""
     groups = jnp.zeros((STACK,), jnp.int32).at[
         first:first + len(sizes)].set(jnp.asarray(sizes, jnp.int32))
     out = [jax.lax.ragged_dot(x, w, groups,
@@ -240,7 +240,7 @@ def test_the_column_block_follows_the_weights_shape(K_, N_, weights, cols):
 
 
 # ----------------------------------------------------------------------
-# `latent_ops._moe` through the kernel: the families' own test files call
+# `expert_ffn.moe` through the kernel: the families' own test files call
 # these with their layer and engine (`test_latent_serving.py`,
 # `test_latent_single_serving.py`, `test_hybrid_serving.py`,
 # `test_window_kernels.py`)
@@ -248,9 +248,9 @@ def test_the_column_block_follows_the_weights_shape(K_, N_, weights, cols):
 def arena_copy(eng):
     """A copy of the engine's arena for a program traced AFTER a test has
     flipped the platform's gate: the counters' number follows the gate
-    (`latent_ops.count_names`)."""
-    from deepspeed_tpu.inference.v2 import latent_ops
-    names = latent_ops.count_names(eng.cfg)
+    (`expert_ffn.count_names`)."""
+    from deepspeed_tpu.inference.v2 import expert_ffn
+    names = expert_ffn.count_names(eng.cfg)
     return {**jax.tree.map(jnp.copy, eng.arena),
             "moe_counts": jnp.zeros((len(names),), jnp.int32)}
 
@@ -264,33 +264,33 @@ def moe_through_the_kernel(monkeypatch, cfg, lp, experts, li, h, valid, tol,
     import jax.experimental.pallas as pl
 
     import deepspeed_tpu.utils.device as device_mod
-    from deepspeed_tpu.inference.v2 import latent_ops
-    want, counts = latent_ops._moe(cfg, lp, experts, li, h, valid,
+    from deepspeed_tpu.inference.v2 import expert_ffn
+    want, counts = expert_ffn.moe(cfg, lp, experts, li, h, valid,
                                    router_in=router_in)
-    names = latent_ops.count_names(cfg)
-    assert not set(latent_ops.KERNEL_COUNT_NAMES) & set(names)
+    names = expert_ffn.count_names(cfg)
+    assert not set(expert_ffn.KERNEL_COUNT_NAMES) & set(names)
     monkeypatch.setattr(pl, "pallas_call", functools.partial(
         pl.pallas_call, interpret=True))
     monkeypatch.setattr(device_mod, "platform", lambda: "tpu")
-    got, kernel_counts = latent_ops._moe(cfg, lp, experts, li, h, valid,
+    got, kernel_counts = expert_ffn.moe(cfg, lp, experts, li, h, valid,
                                          router_in=router_in)
-    assert latent_ops.count_names(cfg) \
-        == names + latent_ops.KERNEL_COUNT_NAMES
+    assert expert_ffn.count_names(cfg) \
+        == names + expert_ffn.KERNEL_COUNT_NAMES
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < tol
     assert np.array_equal(np.asarray(kernel_counts)[:len(names)],
                           np.asarray(counts))
-    c = dict(zip(latent_ops.count_names(cfg),
+    c = dict(zip(expert_ffn.count_names(cfg),
                  np.asarray(kernel_counts).tolist()))
     # the experts each pass reaches, from the router's picks
     logits = (h if router_in is None else router_in).astype(
         jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
-    topi, _, _ = latent_ops._route(latent_ops.router_of(cfg), logits,
+    topi, _, _ = expert_ffn.route(expert_ffn.router_of(cfg), logits,
                                    lp.get("moe_router_bias"), cfg.moe_top_k)
     ids = np.asarray(topi)[np.asarray(valid)].reshape(-1) \
         - cfg.moe_expert_first
     sizes = np.bincount(ids[(ids >= 0) & (ids < cfg.local_experts)],
                         minlength=cfg.local_experts)
-    cap = latent_ops.local_rows_cap(
+    cap = expert_ffn.local_rows_cap(
         h.shape[0] * cfg.moe_top_k, cfg.local_experts,
         cfg.moe_experts + cfg.moe_zero_experts)
     ends = np.cumsum(sizes)
@@ -365,12 +365,12 @@ def test_the_share_counts_ragged_dot_and_the_kernel_alike(name):
 @pytest.mark.parametrize("name", ["expert_weight_passes.closed",
                                   "expert_weight_passes.ktok.closed"])
 def test_the_passes_are_the_kernels_two_counters(name):
-    from deepspeed_tpu.inference.v2 import latent_ops
+    from deepspeed_tpu.inference.v2 import expert_ffn
     spec = metric(name)
     assert spec["reader"] == "span_attr_ratio"
     assert spec["params"]["span"] == "serve.moe_census"
     assert (spec["params"]["num"], spec["params"]["den"]) \
-        == latent_ops.KERNEL_COUNT_NAMES[:2]
+        == expert_ffn.KERNEL_COUNT_NAMES[:2]
     entry = {m["name"]: m for m in json.load(open(os.path.join(
         harness.ROOT, "BENCHMARK.json")))["per_layer"]}[name]
     assert entry["unit"] == "passes" and entry["better"] == "lower"

@@ -204,7 +204,7 @@ def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
     on a later layer of this stack: ReLU gates, the router on the layer's
     input, every expert held here (no pass but the first); as it routes,
     and with the router drawn to one expert, whose rows then span tiles."""
-    from deepspeed_tpu.inference.v2 import latent_ops
+    from deepspeed_tpu.inference.v2 import expert_ffn
     eng = engine()
     cfg, li, T = eng.cfg, 5, 96
     lp = jax.tree.map(lambda a: a[li], eng.params["layers"])
@@ -217,7 +217,7 @@ def test_the_experts_through_the_kernel_are_the_ragged_dots(monkeypatch,
         router_in=jnp.abs(x) if drawn else x)
     assert passes == 1 and counts["zero_picks"] == 0
     assert counts["local_rows"] == counts["picks"] == 90 * cfg.moe_top_k
-    assert latent_ops.local_rows_cap(T * cfg.moe_top_k, cfg.local_experts,
+    assert expert_ffn.local_rows_cap(T * cfg.moe_top_k, cfg.local_experts,
                                      cfg.moe_experts) == T * cfg.moe_top_k
     if drawn:
         assert counts["busiest_rows"] > 80
@@ -613,7 +613,8 @@ def test_a_uniform_window_reaches_the_kernels_as_a_static_argument():
     device.platform = lambda: "tpu"
     try:
         assert ragged_ops._use_paged_kernel(cfg, 64, 16, 1)
-        assert ragged_ops._kernel_capable(engine().cfg, 64, 8, 1)
+        assert ragged_ops._kernel_capable(engine().cfg, 64, 8, 1,
+                                          static_windows=True)
         assert not ragged_ops._kernel_capable(traced, 64, 16, 1)
         assert not ragged_ops.prefill_full_supported(engine().cfg)
     finally:

@@ -24,7 +24,8 @@ import pytest
 import deepspeed_tpu as ds
 from benchmark import harness
 from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
-                                        build_engine, latent_ops, ragged_ops)
+                                        build_engine, expert_ffn, latent_ops,
+                                        ragged_ops)
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import mla_paged
 from test_grouped_matmul import arena_copy, moe_through_the_kernel
@@ -183,9 +184,9 @@ def test_assignments_past_the_buffer_run_it_again():
     # the program's view: the stacks of TWO layers, this one the second
     experts = {n: jnp.concatenate([jnp.ones_like(w), w])
                for n, w in lp["experts"].items()}
-    got, counts = latent_ops._moe(cfg, lp, experts, 1, h, valid)
-    counts = dict(zip(latent_ops.COUNT_NAMES, np.asarray(counts)))
-    cap = latent_ops.local_rows_cap(64 * 4, 8, 48)
+    got, counts = expert_ffn.moe(cfg, lp, experts, 1, h, valid)
+    counts = dict(zip(expert_ffn.COUNT_NAMES, np.asarray(counts)))
+    cap = expert_ffn.local_rows_cap(64 * 4, 8, 48)
     assert cap == 176 and counts["local_rows"] > cap
     assert counts["picks"] == 60 * 4 and counts["router_calls"] == 1
     routed, identity = REF.moe_parts(h[None], lp, S, functools.partial(
@@ -557,8 +558,8 @@ def test_an_engine_on_the_chips_path_serves_the_reference_and_counts(
     got, toks = serve(eng, prompt(n, seed=6), steps=2)
     assert np.abs(got - ref_logits(toks)[n - 1:]).max() < TOL
     counts = eng.drain_moe_counts()
-    assert tuple(counts) == latent_ops.COUNT_NAMES \
-        + latent_ops.KERNEL_COUNT_NAMES
+    assert tuple(counts) == expert_ffn.COUNT_NAMES \
+        + expert_ffn.KERNEL_COUNT_NAMES
     assert counts["picks"] == (n + 2) * 2 * 4 and counts["local_rows"] > 0
     # at most every held expert of both layers, each program
     assert 0 < counts["experts_reached"] <= counts["router_calls"] * 8

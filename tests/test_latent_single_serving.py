@@ -27,7 +27,8 @@ import pytest
 import deepspeed_tpu as ds
 from benchmark import harness
 from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
-                                        build_engine, latent_ops, ragged_ops)
+                                        build_engine, expert_ffn, latent_ops,
+                                        ragged_ops)
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import mla_paged
 from test_grouped_matmul import arena_copy, moe_through_the_kernel
@@ -216,7 +217,7 @@ def numpy_route(logits, bias, groups, kept, k, scale):
 def test_the_router_is_the_numpy_transcription():
     """Random logits; a tie inside a group and between two groups' scores;
     a bias that flips a pick; every token's kept groups."""
-    r = latent_ops.Router("sigmoid", True, 4, 2, True, 2.5, 0)
+    r = expert_ffn.Router("sigmoid", True, 4, 2, True, 2.5, 0)
     rng = np.random.RandomState(0)
     logits = rng.randn(40, 16).astype(np.float32) * 1.5
     logits[0, :] = 0.0                           # every score a tie
@@ -224,7 +225,7 @@ def test_the_router_is_the_numpy_transcription():
     logits[2, 8] = logits[2, 9]                  # a tie inside a group
     bias = (rng.randn(16) * 0.02).astype(np.float32)
     want_p, want_w, want_keep = numpy_route(logits, bias, 4, 2, 4, 2.5)
-    picks, weight, kept = latent_ops._route(r, jnp.asarray(logits),
+    picks, weight, kept = expert_ffn.route(r, jnp.asarray(logits),
                                             jnp.asarray(bias), 4)
     assert np.array_equal(np.asarray(picks), want_p)
     np.testing.assert_allclose(np.asarray(weight), want_w, rtol=2e-6)
@@ -235,7 +236,7 @@ def test_the_router_is_the_numpy_transcription():
     # (and its group) in, and its weight is still its unbiased score's
     flip = bias.copy()
     flip[13] = 1.0
-    p2, w2, _ = latent_ops._route(r, jnp.asarray(logits), jnp.asarray(flip),
+    p2, w2, _ = expert_ffn.route(r, jnp.asarray(logits), jnp.asarray(flip),
                                   4)
     p2, w2 = np.asarray(p2), np.asarray(w2)
     assert (p2 == 13).any(1).all() and not (want_p == 13).any(1).all()
@@ -243,8 +244,8 @@ def test_the_router_is_the_numpy_transcription():
     assert np.array_equal(p2, want_p2)
     np.testing.assert_allclose(w2, want_w2, rtol=2e-6)
     # and the other family's router is a value of the same description
-    soft = latent_ops.Router("softmax", True, 0, 0, False, 6.0, 8)
-    p3, w3, none = latent_ops._route(soft, jnp.asarray(logits),
+    soft = expert_ffn.Router("softmax", True, 0, 0, False, 6.0, 8)
+    p3, w3, none = expert_ffn.route(soft, jnp.asarray(logits),
                                      jnp.asarray(bias), 4)
     sm = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))
     assert none is None and np.array_equal(
@@ -266,8 +267,8 @@ def test_a_token_none_of_whose_groups_is_local_costs_no_row():
     valid = jnp.arange(24) < 20
     experts = {n: jnp.concatenate([w, jnp.ones_like(w)])
                for n, w in lp["experts"].items()}
-    names = latent_ops.count_names(cfg)
-    got, counts = latent_ops._moe(cfg, lp, experts, 0, h, valid)
+    names = expert_ffn.count_names(cfg)
+    got, counts = expert_ffn.moe(cfg, lp, experts, 0, h, valid)
     counts = dict(zip(names, np.asarray(counts)))
     mm = functools.partial(REF._mm, precision=None)
     routed, _ = REF.moe_parts(h[None], lp, S, mm)
@@ -276,7 +277,7 @@ def test_a_token_none_of_whose_groups_is_local_costs_no_row():
     assert 0 < counts["group_hit_tokens"] < 20
     assert 0 < counts["local_rows"] <= 4 * counts["group_hit_tokens"]
     away = dict(lp, moe_router_bias=lp["moe_router_bias"].at[:4].add(-5.0))
-    got, counts = latent_ops._moe(cfg, away, experts, 0, h, valid)
+    got, counts = expert_ffn.moe(cfg, away, experts, 0, h, valid)
     counts = dict(zip(names, np.asarray(counts)))
     assert counts["group_hit_tokens"] == 0 and counts["local_rows"] == 0
     assert counts["router_tokens"] == 20
@@ -551,7 +552,7 @@ def test_the_loop_drains_the_group_counters():
     rng = np.random.RandomState(0)
     for n in (9, 30, 41):
         loop.submit(rng.randint(0, 512, n).astype(np.int32),
-                    max_new_tokens=2 * latent_ops.COUNT_DRAIN_STEPS + 3)
+                    max_new_tokens=2 * expert_ffn.COUNT_DRAIN_STEPS + 3)
     while loop.has_work:
         loop.step()
     tel = loop.telemetry.counters

@@ -1,4 +1,4 @@
-"""How `latent_ops._moe` brings the experts' outputs back to their tokens.
+"""How `expert_ffn.moe` brings the experts' outputs back to their tokens.
 
 Where the compact buffer holds every assignment (`cap == T * k`: every
 expert the router scores is held here, or the program is tiny) the k outputs
@@ -23,7 +23,7 @@ import pytest
 
 from benchmark import harness
 from benchmark.readers import scope_share
-from deepspeed_tpu.inference.v2 import latent_ops
+from deepspeed_tpu.inference.v2 import expert_ffn
 from deepspeed_tpu.models import get_model_config
 
 pytestmark = pytest.mark.serving
@@ -48,7 +48,7 @@ def layer(family, kw):
     outputs = cfg.moe_experts + cfg.moe_zero_experts
     ks = jax.random.split(jax.random.PRNGKey(0), 7)
     lp = {"moe_gate": jax.random.normal(ks[0], (H, outputs), F32)}
-    if latent_ops.router_of(cfg).bias:
+    if expert_ffn.router_of(cfg).bias:
         lp["moe_router_bias"] = 0.1 * jax.random.normal(ks[1], (outputs,))
     experts = {
         "w_gate_proj": jax.random.normal(ks[2], (2 * El, H, F)) / 8,
@@ -68,8 +68,8 @@ def dense(cfg, lp, experts, li, h, valid, router_in):
     E, first, El = cfg.moe_experts, cfg.moe_expert_first, cfg.local_experts
     gate_act = jax.nn.relu if cfg.activation == "reglu" else jax.nn.silu
     logits = (h if router_in is None else router_in) @ lp["moe_gate"]
-    topi, weight, _ = latent_ops._route(
-        latent_ops.router_of(cfg), logits, lp.get("moe_router_bias"),
+    topi, weight, _ = expert_ffn.route(
+        expert_ffn.router_of(cfg), logits, lp.get("moe_router_bias"),
         cfg.moe_top_k)
     w = {n: np.asarray(a[li * El:(li + 1) * El], np.float64)
          for n, a in experts.items()}
@@ -123,12 +123,12 @@ def test_gathered_outputs_are_the_dense_reference(router, undefined_rows):
     family, kw = WHOLE[router]
     cfg, lp, experts, h, x, valid = layer(family, kw)
     k = cfg.moe_top_k
-    assert latent_ops.local_rows_cap(
+    assert expert_ffn.local_rows_cap(
         T * k, cfg.local_experts, cfg.moe_experts + cfg.moe_zero_experts) \
         == T * k
     # smallthinker's router reads the layer's input
     router_in = x if family == "smallthinker" else None
-    got, counts = latent_ops._moe(cfg, lp, experts, 1, h, valid,
+    got, counts = expert_ffn.moe(cfg, lp, experts, 1, h, valid,
                                   router_in=router_in)
     routed, identity = dense(cfg, lp, experts, 1, h, valid, router_in)
     got = np.asarray(got)
@@ -138,7 +138,7 @@ def test_gathered_outputs_are_the_dense_reference(router, undefined_rows):
     # a padding token gets nothing from the experts
     assert np.array_equal(got[REAL:], identity[REAL:])
     assert identity.any() == bool(cfg.moe_zero_experts)
-    c = dict(zip(latent_ops.count_names(cfg), np.asarray(counts)))
+    c = dict(zip(expert_ffn.count_names(cfg), np.asarray(counts)))
     assert c["picks"] == REAL * k and c["router_calls"] == 1
     assert c["local_rows"] + c["zero_picks"] == REAL * k
 
@@ -147,7 +147,7 @@ def test_gathered_outputs_are_the_dense_reference(router, undefined_rows):
 def test_the_gathered_path_has_no_loop_and_no_row_scatter(router):
     family, kw = WHOLE[router]
     cfg, lp, experts, h, x, valid = layer(family, kw)
-    fn = functools.partial(latent_ops._moe, cfg)
+    fn = functools.partial(expert_ffn.moe, cfg)
     eqns = primitives(jax.make_jaxpr(fn)(lp, experts, 1, h, valid).jaxpr)
     names = {e.primitive.name for e in eqns}
     assert "while" not in names and "sort" in names and "gather" in names
@@ -208,7 +208,7 @@ def test_the_gathered_path_through_the_kernel_on_aligned_segments(
     counts, passes = moe_through_the_kernel(
         monkeypatch, cfg, lp, experts, 1, h, valid, TOL, router_in=x)
     assert passes == 1 and counts["local_rows"] == REAL * k
-    topi, _, _ = latent_ops._route(latent_ops.router_of(cfg),
+    topi, _, _ = expert_ffn.route(expert_ffn.router_of(cfg),
                                    x @ lp["moe_gate"], None, k)
     sizes = np.bincount(np.asarray(topi)[:REAL].reshape(-1), minlength=El)
     assert sizes.max() > 4          # an expert of more than a tile's rows
@@ -216,7 +216,7 @@ def test_the_gathered_path_through_the_kernel_on_aligned_segments(
         == int(gm.list_items(jnp.asarray(sizes), T * k, 4,
                              aligned=True).count[0])
     # and against every expert applied to every token, the padding poisoned
-    got, _ = latent_ops._moe(cfg, lp, experts, 1, h, valid, router_in=x)
+    got, _ = expert_ffn.moe(cfg, lp, experts, 1, h, valid, router_in=x)
     routed, identity = dense(cfg, lp, experts, 1, h, valid, x)
     got = np.asarray(got)
     assert np.isfinite(got).all()
@@ -233,19 +233,19 @@ def test_a_share_keeps_its_pieces_and_their_scatter_add(drawn,
     family, kw = SHARE
     cfg, lp, experts, h, x, valid = layer(family, kw)
     k, first, El = cfg.moe_top_k, cfg.moe_expert_first, cfg.local_experts
-    cap = latent_ops.local_rows_cap(
+    cap = expert_ffn.local_rows_cap(
         T * k, El, cfg.moe_experts + cfg.moe_zero_experts)
     assert cap == 64 < T * k
     lp = dict(lp, moe_router_bias=lp["moe_router_bias"].at[
         first:first + El].add(drawn))
-    fn = functools.partial(latent_ops._moe, cfg)
+    fn = functools.partial(expert_ffn.moe, cfg)
     eqns = primitives(jax.make_jaxpr(fn)(lp, experts, 1, h, valid).jaxpr)
     assert "while" in {e.primitive.name for e in eqns}
     assert len(row_scatter_adds(eqns)) == 1
     assert "experts/combine" not in jax.jit(fn).lower(
         lp, experts, 1, h, valid).as_text(debug_info=True)
     got, counts = fn(lp, experts, 1, h, valid)
-    c = dict(zip(latent_ops.count_names(cfg), np.asarray(counts)))
+    c = dict(zip(expert_ffn.count_names(cfg), np.asarray(counts)))
     if drawn:
         assert c["local_rows"] == REAL * k > cap
     else:

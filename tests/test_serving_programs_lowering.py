@@ -9,10 +9,13 @@ they were.
 run against the parent's tree.  A PR that changes one of these programs on
 purpose prints them again and says so: PR 43 did for `longcat_flash` and
 `smallthinker` (their tiny configurations hold every expert, so
-`latent_ops._moe` gathers the experts' outputs where it used to scatter-add
+`expert_ffn.moe` gathers the experts' outputs where it used to scatter-add
 them); `qwen2`'s are PR 34's; `deepseek_v3`'s were printed at PR 46 from its
 parent (73ee70b), when the state-space family learned layer kinds and
-experts through `latent_ops._moe`.
+experts through `expert_ffn.moe`; `falcon_h1`'s and `granite_moe_hybrid`'s
+were printed at PR 50 from its parent (e33567f), before the expert layer
+moved to `expert_ffn` and the families behind `families.family_of`: that PR
+printed none again.
 """
 import hashlib
 
@@ -42,6 +45,13 @@ EXPECTED = {
     "smallthinker": {"decode_step": "bc5c37f8dcbb06aa",
                      "decode_tokens": "0b37c9ef7ff20e91",
                      "prefill_chunks": "63f68572ffa79331"},
+    "falcon_h1": {"decode_step": "50d0df3c47db1bd4",
+                  "prefill_chunks": "760f80f8a92b991e",
+                  "prefill_full": "5219eb873856dea0"},
+    "granite_moe_hybrid": {"decode_step": "9735574c647120f2",
+                           "prefill_chunks": "63c173dcde8fb604",
+                           "prefill_chunks_tiled": "30d108a1f408458a",
+                           "prefill_full": "75789631d5811eb3"},
 }
 
 
@@ -57,23 +67,30 @@ def program_hashes(family: str) -> dict:
     flags = jax.ShapeDtypeStruct((B,), jnp.bool_)
     # a static-kind stack's tables are [rows, 2, MB]
     tables = i32(B, 2, MB) if cfg.static_kinds else i32(B, MB)
+    # per-sequence recurrent state: every program takes the rows' slots, and
+    # the burst program, which hands none, does not serve the family
+    slots = dict(slots=i32(B)) if cfg.ssm else {}
     calls = {
         "decode_step": (ragged_ops.decode_step,
-                        (i32(B), i32(B), tables, flags), {}),
+                        (i32(B), i32(B), tables, flags), slots),
         "decode_tokens": (ragged_ops.decode_tokens,
                           (i32(B), i32(B), tables, flags,
                            jax.eval_shape(lambda: jax.random.PRNGKey(0))),
                           dict(n_steps=4)),
         "prefill_chunks": (ragged_ops.prefill_chunks,
-                           (i32(B, 32), i32(B), i32(B), tables, flags), {}),
+                           (i32(B, 32), i32(B), i32(B), tables, flags),
+                           slots),
     }
-    if cfg.latent:         # more rows than a token-wise pass takes (`_rows`)
+    if cfg.ssm:
+        del calls["decode_tokens"]
+    # more rows than a token-wise pass takes (`expert_ffn.rows`)
+    if cfg.latent or (cfg.ssm and cfg.moe_experts > 1):
         calls["prefill_chunks_tiled"] = (
             ragged_ops.prefill_chunks,
-            (i32(B, 512), i32(B), i32(B), tables, flags), {})
+            (i32(B, 512), i32(B), i32(B), tables, flags), slots)
     if ragged_ops.prefill_full_supported(cfg):
         calls["prefill_full"] = (ragged_ops.prefill_full,
-                                 (i32(B, 128), i32(B), tables, flags), {})
+                                 (i32(B, 128), i32(B), tables, flags), slots)
     # (the tests' own matmul precision, `tests/conftest.py`: it is part of
     # the text)
     with jax.default_matmul_precision("highest"):
@@ -82,14 +99,11 @@ def program_hashes(family: str) -> dict:
         ).hexdigest()[:16] for name, (fn, args, kw) in calls.items()}
 
 
-@pytest.mark.parametrize("family", ["longcat_flash", "qwen2", "smallthinker",
-                                    "deepseek_v3"])
+@pytest.mark.parametrize("family", sorted(EXPECTED))
 def test_a_family_the_benchmark_runs_lowers_to_the_parents_programs(family):
     assert program_hashes(family) == EXPECTED[family]
 
 
 if __name__ == "__main__":
     import pprint
-    pprint.pprint({f: program_hashes(f)
-                   for f in ("longcat_flash", "qwen2", "smallthinker",
-                             "deepseek_v3")})
+    pprint.pprint({f: program_hashes(f) for f in sorted(EXPECTED)})

@@ -27,6 +27,7 @@ from benchmark import harness
 from deepspeed_tpu.config.config import ServingConfig
 from deepspeed_tpu.inference.v2 import (RaggedInferenceEngineConfig,
                                         build_engine, ragged_ops, ssm_ops)
+from deepspeed_tpu.inference.v2.families import family_of
 from deepspeed_tpu.inference.v2.ragged_manager import DSStateManager
 from deepspeed_tpu.models import Transformer, get_model_config
 from deepspeed_tpu.ops import ssm as kernels
@@ -455,7 +456,7 @@ REFUSED = {
     "lora": (NotImplementedError, "LoRA adapters.*" + RECURRENT, lambda:
              engine().attach_lora({"a": None, "b": None})),
     "lora_operands": (NotImplementedError, "LoRA adapters", lambda:
-                      ssm_ops.refuse_lora({})),
+                      family_of(engine().cfg).refuse_lora({})),
     "preemption": (NotImplementedError, "preemption.*" + RECURRENT,
                    lambda: _loop(engine(), preemption={"enabled": True})),
     "speculative": (NotImplementedError, "speculative.*" + RECURRENT,

@@ -306,7 +306,7 @@ def test_mla_paged_attention(chip, B, Q, NH, R, Dr, A, nb, bs, MB):
     pytest.param(176, True, 2560, 768, 64, 8, True, id="partial-last-tile"),
 ])
 def test_grouped_matmul(chip, rows, whole, H, F, El, L, relu):
-    """The experts' two passes of `latent_ops._moe` (gate and up fused,
+    """The experts' two passes of `expert_ffn.moe` (gate and up fused,
     then down) over the whole weight stack at the cells' widths, in the
     layout the shape asks for: two Mosaic kernels within the VMEM they ask
     for, and no copy of the stack (a per-layer slice handed to a custom
